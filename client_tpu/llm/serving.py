@@ -336,111 +336,95 @@ class LlmEngineModel(Model):
         # step); the CPU backend does not implement donation and warns,
         # so only donate on real accelerators.
         donate = jax.default_backend() != "cpu"
-        # kernel selection: env override > platform preference, probed by
-        # actually compiling+running the smallest shapes — a backend that
-        # cannot serve this host falls down the chain at WARMUP, never at
-        # request time. The survivor is reported in the model config.
-        preferred, _ = paged_attention.resolve_decode_attention(
+        # kernel selection: env override > platform. The choice is final —
+        # the probes below compile and run the smallest shapes the engine
+        # serves, and a kernel that cannot serve this host fails the LOAD
+        # with the compiler's message (never a quiet step down to another
+        # implementation). The choice is reported in the model config.
+        name, attn = paged_attention.resolve_decode_attention(
             os.environ.get("CLIENT_TPU_LLM_KERNEL"), jax.default_backend()
         )
-        candidates = [preferred]
-        for fallback in ("fused_xla", "standin"):
-            if fallback not in candidates:
-                candidates.append(fallback)
+        if name == "standin":
+            # inline attention of llama.decode_step_paged, plain XLA
+            # throughout: left to GSPMD propagation under tp
+            attn = None
+        # speculative verify rides the SAME kernel choice (decode and
+        # verify must agree numerically): every implementation has a
+        # multi-query twin
+        attn_mq = (
+            paged_attention.get_attention_impl_mq(name)
+            if self.speculation is not None
+            else None
+        )
+        # under tp the kernel runs per-shard via shard_map (GSPMD cannot
+        # partition a pallas_call; for the XLA variants the wrap pins the
+        # no-communication head partitioning)
+        if plan is not None and attn is not None:
+            attn = paged_attention.make_tp_attention(attn, plan.mesh)
+        if plan is not None and attn_mq is not None:
+            attn_mq = paged_attention.make_tp_attention(
+                attn_mq, plan.mesh, multi_query=True
+            )
         max_blocks = engine_config.max_blocks_per_seq
         table = np.zeros([max_blocks], dtype=np.int32)
-        last_error: Optional[Exception] = None
-        prefill = decode = decode_multi = pages = None
-        for name in candidates:
-            attn = (
-                None if name == "standin"
-                else paged_attention.get_attention_impl(name)
+        prefill, decode, decode_multi = self._build_device_fns(
+            params, config, engine_config, attn, attn_mq, donate
+        )
+        pages = llama.init_kv_pages(
+            config, engine_config.num_blocks, engine_config.block_size
+        )
+        if plan is not None:
+            pages = self._shard_pages(pages, plan)
+        # probe the shapes the engine actually serves (page table
+        # all-zeros = every write lands in the reserved trash block): full
+        # prefill at the smallest bucket, the ragged decode at block
+        # buckets 1 AND multi-block (a kernel whose tiling only breaks at
+        # wider widths must fail HERE, not engine-fatally at request
+        # time), and — when sharing is on — one suffix prefill so the
+        # shared-prefix path is both validated and pre-compiled before
+        # the first hit.
+        probe_tokens = np.zeros(
+            [1, engine_config.prefill_bucket_min], dtype=np.int32
+        )
+        try:
+            logits, pages = prefill(
+                probe_tokens,
+                table,
+                pages,
+                engine_config.prefill_bucket_min - 1,
+                0,
             )
-            # speculative verify rides the SAME kernel choice: every
-            # implementation has a multi-query twin, and a kernel whose
-            # mq variant cannot compile falls down the chain as a whole
-            # (decode and verify must agree numerically)
-            attn_mq = (
-                paged_attention.get_attention_impl_mq(name)
-                if self.speculation is not None
-                else None
-            )
-            # under tp the kernel runs per-shard via shard_map (GSPMD
-            # cannot partition a pallas_call; for the XLA variants the
-            # wrap pins the no-communication head partitioning). The
-            # standin path (attn=None, inline attention) is left to
-            # GSPMD propagation — it is plain XLA throughout.
-            if plan is not None and attn is not None:
-                attn = paged_attention.make_tp_attention(attn, plan.mesh)
-            if plan is not None and attn_mq is not None:
-                attn_mq = paged_attention.make_tp_attention(
-                    attn_mq, plan.mesh, multi_query=True
-                )
-            try:
-                prefill, decode, decode_multi = self._build_device_fns(
-                    params, config, engine_config, attn, attn_mq, donate
-                )
-                # fresh pool per attempt: a candidate that failed after
-                # donation may have consumed the previous buffers
-                pages = llama.init_kv_pages(
-                    config, engine_config.num_blocks, engine_config.block_size
-                )
-                if plan is not None:
-                    pages = self._shard_pages(pages, plan)
-                # probe the shapes the engine actually serves (page
-                # table all-zeros = every write lands in the reserved
-                # trash block): full prefill at the smallest bucket, the
-                # ragged decode at block buckets 1 AND multi-block (a
-                # kernel whose tiling only breaks at wider widths must
-                # fall down the chain HERE, not engine-fatally at
-                # request time), and — when sharing is on — one suffix
-                # prefill so the shared-prefix path is both validated
-                # and pre-compiled before the first hit.
-                probe_tokens = np.zeros(
-                    [1, engine_config.prefill_bucket_min], dtype=np.int32
-                )
+            if engine_config.prefix_sharing and max_blocks > 1:
                 logits, pages = prefill(
                     probe_tokens,
                     table,
                     pages,
                     engine_config.prefill_bucket_min - 1,
-                    0,
+                    engine_config.block_size,
                 )
-                if engine_config.prefix_sharing and max_blocks > 1:
-                    logits, pages = prefill(
-                        probe_tokens,
-                        table,
-                        pages,
-                        engine_config.prefill_bucket_min - 1,
-                        engine_config.block_size,
-                    )
-                for nb in {1, min(8, max_blocks)}:
-                    logits, pages = decode(
-                        np.zeros([1], dtype=np.int32),
-                        np.zeros([1], dtype=np.int32),
-                        table[None, :nb],
-                        pages,
-                    )
-                if decode_multi is not None:
-                    # probe the verify shape too (T=2: one real token +
-                    # one draft) — all writes land in the trash block
-                    logits, pages = decode_multi(
-                        np.zeros([1, 2], dtype=np.int32),
-                        np.zeros([1, 2], dtype=np.int32),
-                        np.zeros([1], dtype=np.int32),
-                        table[None, :1],
-                        pages,
-                    )
-                jax.block_until_ready(logits)
-                self.decode_kernel = name
-                break
-            except Exception as e:  # noqa: BLE001 - fall down the chain
-                last_error = e
-                prefill = decode = decode_multi = pages = None
-        if decode is None:
+            for nb in {1, min(8, max_blocks)}:
+                logits, pages = decode(
+                    np.zeros([1], dtype=np.int32),
+                    np.zeros([1], dtype=np.int32),
+                    table[None, :nb],
+                    pages,
+                )
+            if decode_multi is not None:
+                # probe the verify shape too (T=2: one real token + one
+                # draft) — all writes land in the trash block
+                logits, pages = decode_multi(
+                    np.zeros([1, 2], dtype=np.int32),
+                    np.zeros([1, 2], dtype=np.int32),
+                    np.zeros([1], dtype=np.int32),
+                    table[None, :1],
+                    pages,
+                )
+            jax.block_until_ready(logits)
+        except Exception as e:  # noqa: BLE001 - any compile/run failure
             raise InferenceServerException(
-                f"no paged-attention kernel usable on this host: {last_error}"
-            ) from last_error
+                f"warmup probes failed with decode_kernel='{name}': {e}"
+            ) from e
+        self.decode_kernel = name
         proposer = None
         if self.speculation is not None:
             from client_tpu.llm.speculation import build_proposer

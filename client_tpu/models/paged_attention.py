@@ -17,19 +17,19 @@ Attention", PAPERS.md arxiv 2604.15464, is the blueprint):
   and works on whatever page-table width the caller passes — the engine
   buckets that width to the live batch's longest sequence, so compute
   scales with actual context instead of ``max_seq_len``.  This is the
-  fallback wherever Pallas is unavailable.
+  implementation off-TPU.
 - ``pallas``: a flash-style Pallas kernel.  The grid walks
-  ``(sequence, block)``; the page table and positions ride scalar
-  prefetch so each grid step's BlockSpec ``index_map`` streams exactly
-  ONE physical block from the pool into VMEM — no ``[B, S]`` gather ever
-  materializes.  Online-softmax scratch (running max / denominator /
-  accumulator) carries across the block axis.  ``pallas_interpret`` runs
-  the same kernel under the Pallas interpreter for CPU parity tests.
+  ``(sequence, block)``; the page table rides scalar prefetch so each
+  grid step's BlockSpec ``index_map`` streams exactly ONE physical block
+  from the pool into VMEM — no ``[B, S]`` gather ever materializes.
+  Online-softmax scratch (running max / denominator / accumulator)
+  carries across the block axis.  ``pallas_interpret`` runs the same
+  kernel under the Pallas interpreter for CPU parity tests.
 
-Selection happens once at model warmup (``llm/serving.py``): real TPU
-hosts probe the Pallas kernel, everything else takes ``fused_xla``, and
-the chosen backend is reported in the model's config parameters.  All
-implementations share one contract::
+Selection happens once at model warmup (``llm/serving.py``), by
+platform: TPU hosts take the Pallas kernel, everything else
+``fused_xla``, and the choice is reported in the model's config
+parameters.  All implementations share one contract::
 
     attn(q[B, H, D], k_pages[N, bs, KV, D], v_pages[N, bs, KV, D],
          page_tables[B, NB], positions[B]) -> out[B, H, D]
@@ -63,6 +63,8 @@ from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -201,101 +203,129 @@ def paged_attention_fused_xla_mq(q, k_pages, v_pages, page_tables, positions):
 # Pallas kernel: per-block streaming + online softmax
 # ---------------------------------------------------------------------------
 
+#: lane width of a TPU vector register: the running max/denominator
+#: scratch is kept lane-aligned (each value broadcast across one vreg
+#: row) rather than as a 1-wide column
+_LANES = 128
 
-def _rpa_kernel(block_size, n_rep, scale,
-                tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
+
+def _rpa_kernel(block_size, scale,
+                tbl_ref, q_ref, pos_ref, k_ref, v_ref, o_ref,
                 m_ref, l_ref, acc_ref):
     """Grid step (b, j): fold physical block ``tbl[b, j]`` of sequence
-    ``b`` into its online-softmax state.  Scratch (running max ``m``,
-    denominator ``l``, accumulator ``acc``) persists across the block
-    axis; the first block initializes it, the last normalizes out."""
-    b = pl.program_id(0)
+    ``b`` into the online-softmax state of all its query rows.
+
+    Queries arrive grouped by kv head (``q_ref`` block ``[1, KV, M, D]``,
+    ``M`` = query positions x group size), so both contractions are
+    batched matmuls with the kv head LEADING — the only batched form
+    Mosaic lowers — and grouped-query heads share their kv head's block
+    with no in-kernel repeat. ``pos_ref`` (``[1, M, 1]`` int32, VMEM)
+    carries each row's validity threshold as a vector: SMEM, where the
+    scalar-prefetched page table lives, only serves scalar loads.
+    Scratch (running max ``m``, denominator ``l``, accumulator ``acc``)
+    persists across the block axis; the first block initializes it, the
+    last normalizes out."""
     j = pl.program_id(1)
     nb = pl.num_programs(1)
 
     @pl.when(j == 0)
     def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0].astype(jnp.float32)  # [H, D]
-    k = jnp.repeat(k_ref[0].astype(jnp.float32), n_rep, axis=1)  # [bs, H, D]
-    v = jnp.repeat(v_ref[0].astype(jnp.float32), n_rep, axis=1)
-    s = jnp.einsum("hd,thd->ht", q, k) * scale  # [H, bs]
-    # slot validity: absolute slot index <= this sequence's position
+    q = q_ref[0]  # [KV, M, D]
+    k = jnp.swapaxes(k_ref[0], 0, 1)  # [bs, KV, D] -> [KV, bs, D]
+    v = jnp.swapaxes(v_ref[0], 0, 1)
+    s = jnp.einsum(
+        "kmd,ktd->kmt", q, k, preferred_element_type=jnp.float32
+    ) * scale  # [KV, M, bs]
+    # per-row slot validity: absolute slot index <= this ROW's position
     # (covers ragged tails, padding lanes, and the trash block alike)
     slot = j * block_size + jax.lax.broadcasted_iota(
         jnp.int32, (1, block_size), 1
     )
-    valid = slot <= pos_ref[b]
+    valid = (slot <= pos_ref[0])[None]  # [1, M, bs]
     s = jnp.where(valid, s, NEG_INF)
-    m_prev = m_ref[:]
-    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+    m_prev = m_ref[...]  # [KV, M, LANES]
+    m_new = jnp.maximum(m_prev, s.max(axis=2, keepdims=True))
+    p = jnp.where(valid, jnp.exp(s - m_new[..., :1]), 0.0)
     alpha = jnp.exp(m_prev - m_new)
-    l_ref[:] = l_ref[:] * alpha + p.sum(axis=1, keepdims=True)
-    acc_ref[:] = acc_ref[:] * alpha + jnp.einsum("ht,thd->hd", p, v)
-    m_ref[:] = m_new
+    l_ref[...] = l_ref[...] * alpha + p.sum(axis=2, keepdims=True)
+    # weights ride the MXU in the page dtype (f32 accumulate), the same
+    # operand precision XLA's default gives the fused variant on TPU
+    acc_ref[...] = acc_ref[...] * alpha[..., :1] + jnp.einsum(
+        "kmt,ktd->kmd", p.astype(v.dtype), v,
+        preferred_element_type=jnp.float32,
+    )
+    m_ref[...] = m_new
 
     @pl.when(j == nb - 1)
     def _finalize():
-        o_ref[0] = (acc_ref[:] / l_ref[:]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l_ref[..., :1]).astype(o_ref.dtype)
 
 
-try:  # Pallas is part of jax but platform support varies
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+def paged_attention_pallas_mq(q, k_pages, v_pages, page_tables, positions,
+                              *, interpret: bool = False):
+    """Flash-style multi-query ragged paged attention as a Pallas kernel.
 
-    _PALLAS_IMPORT_ERROR: Optional[Exception] = None
-except Exception as e:  # noqa: BLE001 - degrade to the XLA variants
-    pl = None
-    pltpu = None
-    _PALLAS_IMPORT_ERROR = e
+    ``page_tables`` is scalar-prefetched so the BlockSpec index maps can
+    stream block ``page_tables[b, j]`` (ONE physical block,
+    ``[bs, KV, D]``) into VMEM per grid step — sequence ``b`` never
+    touches pages it does not own, no contiguous per-sequence view is
+    ever materialized in HBM, and the T verify rows of a sequence share
+    each streamed block (the pages cross HBM->VMEM once for all K+1
+    positions). Queries are regrouped ``[B, T, H, D] -> [B, KV, T*G, D]``
+    outside the kernel (head ``k*G + g`` reads kv head ``k``, matching
+    ``_repeat_kv``) — a copy of the queries only, never of the pages."""
+    b, t, h, d = q.shape
+    _, bs, kv, _ = k_pages.shape
+    g = h // kv
+    m = t * g
+    nb = page_tables.shape[1]
+    q_rows = (
+        q.reshape(b, t, kv, g, d).transpose(0, 2, 1, 3, 4).reshape(b, kv, m, d)
+    )
+    row_positions = jnp.repeat(positions.astype(jnp.int32), g, axis=1)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b, nb),
+        in_specs=[
+            pl.BlockSpec((1, kv, m, d), lambda i, j, tbl: (i, 0, 0, 0)),
+            pl.BlockSpec((1, m, 1), lambda i, j, tbl: (i, 0, 0)),
+            pl.BlockSpec(
+                (1, bs, kv, d), lambda i, j, tbl: (tbl[i, j], 0, 0, 0)
+            ),
+            pl.BlockSpec(
+                (1, bs, kv, d), lambda i, j, tbl: (tbl[i, j], 0, 0, 0)
+            ),
+        ],
+        out_specs=pl.BlockSpec((1, kv, m, d), lambda i, j, tbl: (i, 0, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((kv, m, _LANES), jnp.float32),  # running max
+            pltpu.VMEM((kv, m, _LANES), jnp.float32),  # running denominator
+            pltpu.VMEM((kv, m, d), jnp.float32),  # weighted-value accumulator
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_rpa_kernel, bs, 1.0 / (d ** 0.5)),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(q_rows.shape, q.dtype),
+        interpret=interpret,
+    )(page_tables, q_rows, row_positions[:, :, None], k_pages, v_pages)
+    return (
+        out.reshape(b, kv, t, g, d).transpose(0, 2, 1, 3, 4).reshape(b, t, h, d)
+    )
 
 
 def paged_attention_pallas(q, k_pages, v_pages, page_tables, positions,
                            *, interpret: bool = False):
-    """Flash-style ragged paged attention as a Pallas kernel.
-
-    ``page_tables``/``positions`` are scalar-prefetched so the BlockSpec
-    index maps can stream block ``page_tables[b, j]`` (ONE physical
-    block, ``[bs, KV, D]``) into VMEM per grid step — sequence ``b``
-    never touches pages it does not own, and no contiguous per-sequence
-    view is ever materialized in HBM."""
-    if pl is None:  # pragma: no cover - import-gated host
-        raise RuntimeError(f"pallas unavailable: {_PALLAS_IMPORT_ERROR}")
-    b, h, d = q.shape
-    _, bs, kv, _ = k_pages.shape
-    nb = page_tables.shape[1]
-    kernel = functools.partial(
-        _rpa_kernel, bs, h // kv, 1.0 / (d ** 0.5)
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, nb),
-        in_specs=[
-            pl.BlockSpec((1, h, d), lambda i, j, tbl, pos: (i, 0, 0)),
-            pl.BlockSpec(
-                (1, bs, kv, d), lambda i, j, tbl, pos: (tbl[i, j], 0, 0, 0)
-            ),
-            pl.BlockSpec(
-                (1, bs, kv, d), lambda i, j, tbl, pos: (tbl[i, j], 0, 0, 0)
-            ),
-        ],
-        out_specs=pl.BlockSpec((1, h, d), lambda i, j, tbl, pos: (i, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((h, 1), jnp.float32),  # running max
-            pltpu.VMEM((h, 1), jnp.float32),  # running denominator
-            pltpu.VMEM((h, d), jnp.float32),  # weighted-value accumulator
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+    """Single-query decode: the T=1 case of
+    :func:`paged_attention_pallas_mq`."""
+    return paged_attention_pallas_mq(
+        q[:, None], k_pages, v_pages, page_tables, positions[:, None],
         interpret=interpret,
-    )(page_tables, positions, q, k_pages, v_pages)
+    )[:, 0]
 
 
 def paged_attention_pallas_interpret(q, k_pages, v_pages, page_tables,
@@ -305,92 +335,6 @@ def paged_attention_pallas_interpret(q, k_pages, v_pages, page_tables,
     return paged_attention_pallas(
         q, k_pages, v_pages, page_tables, positions, interpret=True
     )
-
-
-def _rpa_kernel_mq(block_size, n_rep, scale,
-                   tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_ref, l_ref, acc_ref):
-    """Multi-query grid step (b, j): fold physical block ``tbl[b, j]``
-    into the online-softmax state of ALL T query rows of sequence ``b``
-    at once.  Identical structure to :func:`_rpa_kernel` with a leading
-    query-position axis on q/scratch and a PER-ROW validity threshold
-    (``pos_ref[b, t]``) instead of one per sequence."""
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    nb = pl.num_programs(1)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    q = q_ref[0].astype(jnp.float32)  # [T, H, D]
-    k = jnp.repeat(k_ref[0].astype(jnp.float32), n_rep, axis=1)  # [bs, H, D]
-    v = jnp.repeat(v_ref[0].astype(jnp.float32), n_rep, axis=1)
-    s = jnp.einsum("thd,uhd->thu", q, k) * scale  # [T, H, bs]
-    # per-row slot validity: absolute slot index <= this ROW's position
-    slot = j * block_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, 1, block_size), 2
-    )  # [1, 1, bs]
-    valid = slot <= pos_ref[b][:, None, None]  # [T, 1, bs]
-    s = jnp.where(valid, s, NEG_INF)
-    m_prev = m_ref[:]
-    m_new = jnp.maximum(m_prev, s.max(axis=2, keepdims=True))
-    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-    alpha = jnp.exp(m_prev - m_new)
-    l_ref[:] = l_ref[:] * alpha + p.sum(axis=2, keepdims=True)
-    acc_ref[:] = acc_ref[:] * alpha + jnp.einsum("thu,uhd->thd", p, v)
-    m_ref[:] = m_new
-
-    @pl.when(j == nb - 1)
-    def _finalize():
-        o_ref[0] = (acc_ref[:] / l_ref[:]).astype(o_ref.dtype)
-
-
-def paged_attention_pallas_mq(q, k_pages, v_pages, page_tables, positions,
-                              *, interpret: bool = False):
-    """Flash-style multi-query ragged paged attention (Pallas).
-
-    Streams one physical block per grid step exactly like the
-    single-query kernel; the T verify rows of a sequence share each
-    streamed block (the whole point of batched verification — the pages
-    cross HBM->VMEM once for all K+1 positions)."""
-    if pl is None:  # pragma: no cover - import-gated host
-        raise RuntimeError(f"pallas unavailable: {_PALLAS_IMPORT_ERROR}")
-    b, t, h, d = q.shape
-    _, bs, kv, _ = k_pages.shape
-    nb = page_tables.shape[1]
-    kernel = functools.partial(
-        _rpa_kernel_mq, bs, h // kv, 1.0 / (d ** 0.5)
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, nb),
-        in_specs=[
-            pl.BlockSpec((1, t, h, d), lambda i, j, tbl, pos: (i, 0, 0, 0)),
-            pl.BlockSpec(
-                (1, bs, kv, d), lambda i, j, tbl, pos: (tbl[i, j], 0, 0, 0)
-            ),
-            pl.BlockSpec(
-                (1, bs, kv, d), lambda i, j, tbl, pos: (tbl[i, j], 0, 0, 0)
-            ),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, t, h, d), lambda i, j, tbl, pos: (i, 0, 0, 0)
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((t, h, 1), jnp.float32),  # running max
-            pltpu.VMEM((t, h, 1), jnp.float32),  # running denominator
-            pltpu.VMEM((t, h, d), jnp.float32),  # weighted-value accumulator
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
-    )(page_tables, positions, q, k_pages, v_pages)
 
 
 def paged_attention_pallas_interpret_mq(q, k_pages, v_pages, page_tables,
@@ -463,9 +407,8 @@ def make_tp_attention(
     no-communication partitioning instead of trusting sharding
     propagation to find it. Page tables and positions are replicated
     (they index POOL ROWS, which are not sharded — the head axis is).
-    ``check_rep=False``: the impls are opaque to the replication checker.
+    ``check_vma=False``: the impls are opaque to the varying-axes checker.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
 
     q_spec = (
@@ -475,12 +418,12 @@ def make_tp_attention(
     )
     pages_spec = PartitionSpec(None, None, tp_axis, None)
     replicated = PartitionSpec()
-    return shard_map(
+    return jax.shard_map(
         attn,
         mesh=mesh,
         in_specs=(q_spec, pages_spec, pages_spec, replicated, replicated),
         out_specs=q_spec,
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -491,12 +434,13 @@ def resolve_decode_attention(
     ``jax.default_backend()`` string).
 
     ``requested`` (the ``CLIENT_TPU_LLM_KERNEL`` env override) forces a
-    specific implementation; otherwise real TPU hosts get the Pallas
-    kernel and everything else the fused XLA variant.  Callers probe the
-    returned callable at warmup and fall back down :data:`KERNELS` on
-    failure, so this only encodes the *preference*."""
+    specific implementation; otherwise TPU hosts get the Pallas kernel
+    and everything else the fused XLA variant. The choice is final: a
+    kernel that fails to compile at warmup is a load failure carrying
+    the compiler's message, never a silent step down to another
+    implementation."""
     if requested:
         return requested, get_attention_impl(requested)
-    if platform == "tpu" and pl is not None:
+    if platform == "tpu":
         return "pallas", paged_attention_pallas
     return "fused_xla", paged_attention_fused_xla

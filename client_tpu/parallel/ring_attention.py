@@ -18,24 +18,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map  # jax >= 0.8
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 NEG_INF = -1e30
-
-
-def _pvary(x, axis_names):
-    """Mark a constant as varying over ``axis_names`` (jax>=0.9 shard_map
-    typing: scan carries must match the varying-axes type of the body's
-    outputs)."""
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, tuple(axis_names))
-    if hasattr(jax.lax, "pcast"):  # pragma: no cover - jax variants
-        return jax.lax.pcast(x, tuple(axis_names), to="varying")
-    return x  # pragma: no cover - older jax has no vma typing
 
 
 def _local_ring_attention(
@@ -85,16 +71,16 @@ def _local_ring_attention(
         v_next = jax.lax.ppermute(v_cur, axis_name, perm)
         return (o_new, m_new, l_new, k_next, v_next), None
 
-    o0 = _pvary(
-        jnp.zeros((batch, heads, q_len, head_dim), dtype=jnp.float32),
-        mesh_axis_names,
-    )
-    m0 = _pvary(
-        jnp.full((batch, heads, q_len), NEG_INF, dtype=jnp.float32),
-        mesh_axis_names,
-    )
-    l0 = _pvary(
-        jnp.zeros((batch, heads, q_len), dtype=jnp.float32), mesh_axis_names
+    # shard_map typing: scan carries must match the varying-axes type of
+    # the body's outputs, so the constant initial state is cast to varying
+    o0, m0, l0 = jax.lax.pcast(
+        (
+            jnp.zeros((batch, heads, q_len, head_dim), dtype=jnp.float32),
+            jnp.full((batch, heads, q_len), NEG_INF, dtype=jnp.float32),
+            jnp.zeros((batch, heads, q_len), dtype=jnp.float32),
+        ),
+        tuple(mesh_axis_names),
+        to="varying",
     )
     (o_final, _, l_final, _, _), _ = jax.lax.scan(
         step, (o0, m0, l0, k, v), jnp.arange(axis_size)

@@ -297,7 +297,7 @@ def format_shm_delta(
 ) -> str:
     """The shm-vs-inline verdict as a named number.
 
-    BENCH_r05 buried an inversion (tpu-shm slower than inline gRPC at
+    The round-5 bench row buried an inversion (tpu-shm slower than inline gRPC at
     small tensor sizes) in an unlabeled JSON field for four rounds; this
     renders the delta explicitly and FLAGS the loss, so a shm path that
     stops paying for itself is a headline, not an easter egg.

@@ -18,7 +18,7 @@ TPU pods get their collectives from the platform and ignore that knob.
 its device count (and its distributed-ness) at first backend init.
 
 Self-healing (PR 20): the distributed runtime is constructed MANUALLY
-(service + client via ``xla_extension``) rather than through
+(service + client via ``jax._src.lib._jax``) rather than through
 ``jax.distributed.initialize``, for one reason — survivability.  The
 stock client installs a missed-heartbeat callback that LOG(FATAL)s the
 whole process the moment a peer dies, and its destructor runs a
@@ -161,6 +161,11 @@ class PodRuntime:
 _ABANDONED: List[Tuple[Any, Any]] = []
 
 
+#: seconds of silence before the coordination service declares a member
+#: dead (service and client must agree)
+_HEARTBEAT_TIMEOUT_S = 100
+
+
 def _heartbeat_logger(process_index: int):
     """The client's missed-heartbeat callback. The stock one aborts the
     process; ours records the event and keeps serving — the supervisor
@@ -193,21 +198,19 @@ def _pod_init(
     the coordination service, bound on every interface at the
     address's port."""
     from jax._src import distributed
-    from jax._src.lib import xla_extension
+    from jax._src.lib import _jax
 
     state = distributed.global_state
     if process_index == 0:
         bind = "[::]:" + address.rsplit(":", 1)[1]
-        state.service = xla_extension.get_distributed_runtime_service(
-            bind,
-            process_count,
-            heartbeat_interval=10,
-            max_missing_heartbeats=10,
+        state.service = _jax.get_distributed_runtime_service(
+            bind, process_count, heartbeat_timeout=_HEARTBEAT_TIMEOUT_S
         )
-    client = xla_extension.get_distributed_runtime_client(
+    client = _jax.get_distributed_runtime_client(
         address,
         process_index,
         init_timeout=int(timeout_s),
+        heartbeat_timeout=_HEARTBEAT_TIMEOUT_S,
         shutdown_on_destruction=False,
         missed_heartbeat_callback=_heartbeat_logger(process_index),
         use_compression=True,

@@ -34,6 +34,7 @@ from typing import Callable, Dict, Optional
 
 import numpy as np
 
+from client_tpu.compile_cache import enable_compile_cache
 from client_tpu.pod.bus import REINIT_OP, StepBus, StepFollower
 from client_tpu.pod.runtime import (
     PodConfig,
@@ -580,6 +581,7 @@ def main() -> int:
             file=sys.stderr,
         )
         return 2
+    enable_compile_cache()
     runtime = initialize(config)
     print(f"pod member up: {runtime.describe()}", flush=True)
     model = build_model(runtime)

@@ -47,9 +47,8 @@ def main(argv=None) -> int:
     parser.add_argument(
         "--platform",
         default=None,
-        help="force the JAX platform (e.g. 'cpu', 'tpu'); overrides any "
-        "site default — useful for dev loops on hosts where the default "
-        "platform is a remote TPU relay",
+        help="force the JAX platform (e.g. 'cpu', 'tpu'); overrides "
+        "JAX_PLATFORMS — useful for CPU dev loops on a host with a chip",
     )
     parser.add_argument(
         "--grpc-frontend",
@@ -75,6 +74,11 @@ def main(argv=None) -> int:
         import jax
 
         jax.config.update("jax_platforms", args.platform)
+
+    from client_tpu.compile_cache import enable_compile_cache
+
+    # before the repository: model warmups are the first compilations
+    enable_compile_cache()
 
     from client_tpu.server.core import ServerCore
     from client_tpu.server.model_repository import build_repository

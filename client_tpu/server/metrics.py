@@ -59,8 +59,7 @@ try:  # jax powers the optional device-memory gauges
 except Exception:  # pragma: no cover - jax is an optional extra
     jax = None
 
-# Seconds buckets tuned for TPU relays: sub-ms host models through
-# multi-second LLM decodes.
+# Seconds buckets: sub-ms host models through multi-second LLM decodes.
 DURATION_BUCKETS_S = (
     0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025,
     0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0,

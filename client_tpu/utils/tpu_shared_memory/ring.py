@@ -7,7 +7,7 @@ that swamp the copy savings at small tensor sizes: per-tensor parameter
 maps on the wire, per-request region lookups, and a response that must
 round-trip output staging through the same machinery — at r05 the shm
 path was *slower* than inline gRPC on add_sub (12,237 vs 13,549
-infer/sec, BENCH_r05). The ring closes that gap with ONE pre-registered
+infer/sec, round-5 CPU bench). The ring closes that gap with ONE pre-registered
 region laid out as fixed-size slots:
 
 * the client packs a whole request's tensors into a free slot (name/

@@ -12,11 +12,10 @@ platform:
 
     CLIENT_TPU_TEST_PLATFORM=tpu python -m pytest tests/ -m tpu -q
 
-Without that env var, ``-m tpu`` tests skip themselves (they would measure
-the CPU backend and pass vacuously). This keeps the default suite hermetic
-while making real-device coverage a first-class, one-command tier — the
-round-1 failure mode (a ~67 ms-per-readback pathology shipping unnoticed,
-VERDICT r1 weak #3) is exactly what this tier exists to catch.
+Without that env var, ``-m tpu`` tests are skipped (they would run on the
+CPU backend and pass vacuously); with it, a missing accelerator FAILS
+them. The sandbox has no chip: the tier runs on one through
+``python chip_smoke.py``, which owns the chip one child at a time.
 """
 
 import os
@@ -46,6 +45,12 @@ if not TPU_TIER:
     jax.config.update("jax_platforms", "cpu")
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+if TPU_TIER:
+    # the device tier compiles full-width programs: keep them across runs
+    from client_tpu.compile_cache import enable_compile_cache  # noqa: E402
+
+    enable_compile_cache()
 
 import pytest  # noqa: E402  (after the platform pinning above)
 

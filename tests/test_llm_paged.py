@@ -320,6 +320,32 @@ def test_warmup_selects_and_reports_kernel(shared_model):
     assert doc["parameters"]["prefix_sharing"]["string_value"] == "cow"
 
 
+def test_kernel_that_cannot_compile_is_a_load_failure(tiny_llama, monkeypatch):
+    """No step down the kernel list: the compiled Pallas kernel cannot
+    lower on the CPU backend, so forcing it makes the LOAD fail, with the
+    kernel's name and the compiler's words in the index reason."""
+    from client_tpu.llm.serving import LlmEngineModel
+    from client_tpu.server.model_repository import ModelRepository
+
+    config, params = tiny_llama
+    monkeypatch.setenv("CLIENT_TPU_LLM_KERNEL", "pallas")
+    model = LlmEngineModel(
+        name="forced_pallas",
+        config=config,
+        params=params,
+        engine_config=EngineConfig(
+            block_size=8, num_blocks=9, max_active=1, max_seq_len=64
+        ),
+    )
+    repository = ModelRepository()
+    repository.add_model(model)
+    (entry,) = repository.index()
+    assert entry["state"] == "UNAVAILABLE"
+    assert "decode_kernel='pallas'" in entry["reason"]
+    assert "interpret" in entry["reason"].lower()  # the compiler's message
+    assert model.decode_kernel is None and model.engine is None
+
+
 def test_shared_prefix_generations_match_dense_and_share_blocks(shared_model):
     """The acceptance test: concurrent shared-prefix generations EXACTLY
     match the dense oracle, hit the prefix index, and keep peak

@@ -16,14 +16,9 @@ BUILD = os.path.join(REPO, "build")
 def _build():
     if shutil.which("cmake") is None or shutil.which("ninja") is None:
         pytest.skip("cmake/ninja not available")
-    subprocess.run(
-        ["cmake", "-S", os.path.join(REPO, "native"), "-B", BUILD,
-         "-G", "Ninja"],
-        check=True, capture_output=True,
-    )
-    subprocess.run(
-        ["ninja", "-C", BUILD], check=True, capture_output=True, timeout=600
-    )
+    from tools.build_wheel import build_native
+
+    build_native(BUILD, capture_output=True, timeout=600)
 
 
 @pytest.fixture(scope="module")

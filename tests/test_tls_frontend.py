@@ -43,7 +43,7 @@ def tls_certs(tmp_path_factory):
 
 @pytest.fixture(scope="module")
 def tls_server(tls_certs):
-    from client_tpu.testing import hermetic_child_env
+    from client_tpu.testing import hermetic_child_env, parse_server_started
 
     cert, key = tls_certs
     proc = subprocess.Popen(
@@ -65,12 +65,9 @@ def tls_server(tls_certs):
         line = proc.stdout.readline()
         if not line:
             break
-        # the startup banner is a structured server_started JSON event
-        if "server_started" in line:
-            try:
-                grpc_port = int(json.loads(line)["grpc_port"])
-            except (ValueError, KeyError, TypeError):
-                continue
+        event = parse_server_started(line)
+        if event is not None:
+            grpc_port = event["grpc_port"]
             break
     if grpc_port is None:
         proc.kill()

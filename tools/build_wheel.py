@@ -21,13 +21,42 @@ import sys
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def build_native(build_dir: str) -> None:
-    subprocess.run(
-        ["cmake", "-S", os.path.join(REPO, "native"), "-B", build_dir,
-         "-G", "Ninja"],
-        check=True,
+def _cache_is_foreign(build_dir: str, source_dir: str) -> bool:
+    """True when ``build_dir``'s CMakeCache.txt was written for another
+    source or build path — what a copied checkout carries."""
+    recorded = {}
+    try:
+        with open(os.path.join(build_dir, "CMakeCache.txt")) as f:
+            for line in f:
+                key, sep, value = line.strip().partition(":INTERNAL=")
+                if sep:
+                    recorded[key] = value
+    except FileNotFoundError:
+        return False
+    expected = {
+        "CMAKE_HOME_DIRECTORY": source_dir,
+        "CMAKE_CACHEFILE_DIR": build_dir,
+    }
+    return any(
+        os.path.realpath(recorded.get(key, "")) != os.path.realpath(path)
+        for key, path in expected.items()
     )
-    subprocess.run(["ninja", "-C", build_dir], check=True)
+
+
+def build_native(build_dir: str, targets=(), **run_kwargs) -> None:
+    """Configure and build ``native/`` into ``build_dir`` (every target,
+    or just ``targets``). A build directory whose cache names another
+    path is reconfigured from scratch, not trusted: CMake refuses to
+    reuse it, and its binaries bake in the other checkout's root.
+    ``run_kwargs`` go to both ``subprocess.run`` calls."""
+    source_dir = os.path.join(REPO, "native")
+    configure = ["cmake", "-S", source_dir, "-B", build_dir, "-G", "Ninja"]
+    if _cache_is_foreign(build_dir, source_dir):
+        configure.insert(1, "--fresh")
+    subprocess.run(configure, check=True, **run_kwargs)
+    subprocess.run(
+        ["ninja", "-C", build_dir, *targets], check=True, **run_kwargs
+    )
 
 
 def main() -> int:

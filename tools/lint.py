@@ -9,7 +9,8 @@ external linters cannot be installed. Checks every tracked .py file for
 import ast
 import os
 
-ROOTS = ["client_tpu", "tools", "tests", "bench.py", "__graft_entry__.py"]
+ROOTS = ["client_tpu", "tools", "tests", "examples/model_repository", "bench.py",
+         "chip_smoke.py", "__graft_entry__.py"]
 # Imports with side effects or re-export duties.
 ALLOWED_UNUSED = {"client_tpu", "conftest"}
 
