@@ -31,6 +31,12 @@ paged-attention model functions (``models/llama.py``):
   call; accepted tokens stream as multiple queue entries per step.  The
   emitted stream is token-for-token identical to plain decoding (greedy
   and seeded sampling both) — see :meth:`LlmEngine._spec_decode`.
+- **lap spans**: the step loop's wall time is tiled by named phase
+  (:data:`PHASES`; one clock read at each phase boundary), as monotone
+  counters in ``stats()["phase_ns"]`` and as ``engine.<phase>``
+  annotations on a ``jax.profiler`` trace's host line (which the
+  trace's device lines lead by a millisecond or two: see
+  :class:`~client_tpu.observability.profiling.LapSpans`).
 
 Single-owner concurrency: every public method runs on the serving event
 loop (the decoupled path executes models there); device calls hop to the
@@ -46,6 +52,7 @@ from typing import Any, Callable, Dict, List, Optional
 import numpy as np
 
 from client_tpu.llm.kv_cache import BlockAllocator, CacheCapacityError, TRASH_BLOCK
+from client_tpu.observability.profiling import LapSpans
 from client_tpu.scheduling import (
     PriorityQueue,
     QueueFullError,
@@ -132,9 +139,34 @@ class EngineConfig:
         return (self.max_seq_len + self.block_size - 1) // self.block_size
 
 
+#: The step loop's phases (``stats()["phase_ns"]`` keys, ``engine.<phase>``
+#: trace annotations). They tile the loop's un-parked wall time:
+#: ``schedule`` prune, admission without its prefills, block growth and
+#: preemption, building the step's arrays, the COW check, ``_publish``;
+#: ``prefill`` each ``_prefill_one`` (dispatch, device, read-back);
+#: ``propose`` the speculative drafts; ``dispatch`` the device call until
+#: it returns un-waited arrays; ``wait`` blocking on the step's result
+#: (the host's view of device-busy); ``readback`` device-to-host copy
+#: and untiling of the logits; ``sample`` ``_sample_rows``; ``emit``
+#: booking and streaming the tokens, metrics hooks; ``yield`` the
+#: ``asyncio.sleep(0)``: everything else on the event loop.
+PHASES = (
+    "schedule", "prefill", "propose", "dispatch", "wait", "readback",
+    "sample", "emit", "yield",
+)
+
 _WAITING = "waiting"
 _RUNNING = "running"
 _DONE = "done"
+
+
+def _wait_ready(result: Any) -> None:
+    """Block until a device call's result is computed: the device's time,
+    apart from the copy to the host that reading it then costs. Plain
+    numpy (the test doubles' results) has nothing to wait for."""
+    wait = getattr(result, "block_until_ready", None)
+    if wait is not None:
+        wait()
 
 
 def block_bucket(n: int) -> int:
@@ -243,6 +275,7 @@ class Sequence:
         "shared_blocks",
         "spec_enabled",
         "recovery_resume",
+        "submitted_ns",
         "_out",
         "_engine",
     )
@@ -278,6 +311,10 @@ class Sequence:
         # reload (the PRNG chain keyed on (seed, token-index) makes the
         # resumed stream token-identical), False fails it immediately
         self.recovery_resume = recovery_resume
+        # the submit instant, until the first admission has booked the
+        # queue wait (then None: a resume after preemption is no wait
+        # in the queue a client sees)
+        self.submitted_ns: Optional[int] = None
         # chained content hashes of the prompt's FULL blocks (computed
         # once at submit; matched against / published to the allocator's
         # shared index at every admission, including resumes)
@@ -431,6 +468,15 @@ class LlmEngine:
         # (hits / demand), since the allocator only ever sees the
         # pre-matched hash slice
         self.prefix_block_demand = 0
+        # lap spans over the step loop (see PHASES), and what they are
+        # divided by: prefill calls, first admissions with the time
+        # their sequences spent queued
+        self._laps = LapSpans(
+            {phase: f"engine.{phase}" for phase in PHASES}, clock_ns=clock_ns
+        )
+        self.prefills = 0
+        self.admitted = 0
+        self.queue_wait_ns = 0
 
     # -- submission / cancellation (serving-loop only) -----------------------
 
@@ -564,6 +610,7 @@ class LlmEngine:
             recovery_resume=recovery_resume,
         )
         seq.block_hashes = block_hashes
+        seq.submitted_ns = now_ns
         self._waiting.push(seq, level=level, deadline_ns=deadline_ns)
         self._ensure_task()
         self._publish()
@@ -819,6 +866,12 @@ class LlmEngine:
             "spec_acceptance_rate": (
                 self.spec_accepted / max(1, self.spec_proposed)
             ),
+            # lap spans: ns per phase of the step loop (PHASES), which
+            # add up to its wall time not parked; all monotone
+            "phase_ns": dict(self._laps.ns),
+            "prefills": self.prefills,
+            "admitted": self.admitted,
+            "queue_wait_ns": self.queue_wait_ns,
         }
 
     # -- step loop -----------------------------------------------------------
@@ -846,19 +899,24 @@ class LlmEngine:
         )
 
     async def _run(self) -> None:
+        laps = self._laps
         try:
             while not self._closed:
                 if not self._running and not len(self._waiting):
+                    laps.park()
                     self._wake.clear()
                     await self._wake.wait()
                     continue
+                laps.enter("schedule")
                 self._prune()
                 await self._admit()
                 if self._running:
                     await self._step()
+                laps.enter("schedule")
                 self._publish()
                 # one cooperative yield per iteration: stream consumers
                 # on this loop drain their queues between steps
+                laps.enter("yield")
                 await asyncio.sleep(0)
         except asyncio.CancelledError:
             # shutdown mid-iteration (possibly mid-prefill): clean up on
@@ -871,6 +929,8 @@ class LlmEngine:
             raise
         except Exception as e:  # noqa: BLE001 - engine must not die silently
             self._quarantine(e)
+        finally:
+            laps.park()
 
     def _prune(self) -> None:
         """Drop cancelled sequences and expire waiting deadlines."""
@@ -971,9 +1031,15 @@ class LlmEngine:
             # device failure it must still be set when the _run handlers
             # reclaim it; only a successful prefill clears it here.
             self._admitting = seq
+            now_ns = self._laps.enter("prefill")
+            if seq.submitted_ns is not None:
+                self.queue_wait_ns += now_ns - seq.submitted_ns
+                self.admitted += 1
+                seq.submitted_ns = None
             logits = await self._prefill_one(
                 seq, context, matched * allocator.block_size
             )
+            self._laps.enter("schedule")
             # the sequence's full prompt blocks (matched + just
             # prefilled) now hold valid K/V — publish them for the next
             # identical prefix
@@ -1013,6 +1079,7 @@ class LlmEngine:
         )
         tokens = np.zeros([1, bucket], dtype=np.int32)
         tokens[0, : len(suffix)] = suffix
+        self.prefills += 1
         # A failing device call is ENGINE-fatal, not sequence-fatal: the
         # inputs were engine-constructed (request validation happened at
         # submit) and the donated page pool may be gone — let it
@@ -1171,7 +1238,9 @@ class LlmEngine:
         if not batch:
             return
         if self._speculative:
+            self._laps.enter("propose")
             drafts = await self._propose(batch)
+            self._laps.enter("schedule")
             if any(drafts):
                 await self._spec_decode(batch, drafts)
             else:
@@ -1213,11 +1282,17 @@ class LlmEngine:
                     f"block {seq.blocks[write_block]} with refcount "
                     f"{allocator.refcount(seq.blocks[write_block])}"
                 )
+        laps = self._laps
+        laps.enter("dispatch")
         logits, self._pages = await self._run_device(
             self._decode, tokens, positions, page_tables, self._pages
         )
+        laps.enter("wait")
+        _wait_ready(logits)
+        laps.enter("readback")
         logits_rows = np.asarray(logits)[:n]
         self.steps += 1
+        laps.enter("sample")
         live = [
             (seq, row) for seq, row in zip(batch, logits_rows)
             if not seq.cancelled  # pruned (and freed) next iteration
@@ -1226,6 +1301,7 @@ class LlmEngine:
             [(seq, row, len(seq.generated)) for seq, row in live]
         )
         self.lane_steps += len(live)
+        laps.enter("emit")
         emitted = 0
         for (seq, _), token in zip(live, picks):
             self._emit_step_token(seq, token)
@@ -1372,13 +1448,19 @@ class LlmEngine:
                         f"with refcount "
                         f"{allocator.refcount(seq.blocks[wb])}"
                     )
+        laps = self._laps
+        laps.enter("dispatch")
         logits, self._pages = await self._run_device(
             self._decode_multi, tokens, positions, lengths, page_tables,
             self._pages,
         )
+        laps.enter("wait")
+        _wait_ready(logits)
+        laps.enter("readback")
         logits_rows = np.asarray(logits)
         self.steps += 1
         self.spec_steps += 1
+        laps.enter("sample")
         # batched sampling across every candidate row of every live lane
         # (the verify consumes the vectorized sampler wholesale): rows
         # sampled past a lane's first mismatch are simply discarded —
@@ -1399,6 +1481,7 @@ class LlmEngine:
             spans.append((start, k_eff + 1))
         picks = self._sample_rows(items) if items else []
         self.lane_steps += sum(1 for _, count in spans if count)
+        laps.enter("emit")
         emitted_total = 0
         proposed_total = 0
         accepted_total = 0

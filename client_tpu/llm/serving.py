@@ -156,31 +156,47 @@ class LlmEngineModel(Model):
 
             return place_global(array, rep)
 
+        # The four device programs, jitted under their own names: XLA
+        # calls the modules jit_llm_prefill, jit_llm_prefill_suffix,
+        # jit_llm_decode and jit_llm_verify, which is how a device trace
+        # and a compile log tell them apart.
         # params ride as an explicit jit argument (not a closure): a
         # process-spanning param pytree cannot be closed over — jax
         # forbids baking non-addressable arrays into the jaxpr as
         # constants — and the argument form is identical for the
         # single-process case
+        def llm_prefill(params_, tokens, page_table, pages, last_index):
+            return llama.prefill_into_pages(
+                params_, tokens, page_table, pages, last_index, config
+            )
+
+        def llm_prefill_suffix(params_, tokens, page_table, pages,
+                               last_index, start_index, prefix_blocks):
+            return llama.prefill_suffix_into_pages(
+                params_, tokens, page_table, pages, last_index,
+                start_index, prefix_blocks, config,
+            )
+
+        def llm_decode(params_, tokens, positions, page_tables, pages):
+            if attn is None:
+                return llama.decode_step_paged(
+                    params_, tokens, positions, page_tables, pages, config
+                )
+            return llama.decode_step_paged_attn(
+                params_, tokens, positions, page_tables, pages, config, attn
+            )
+
+        def llm_verify(params_, tokens, positions, lengths, page_tables,
+                       pages):
+            return llama.decode_step_paged_multi(
+                params_, tokens, positions, lengths, page_tables, pages,
+                config, attn_mq,
+            )
+
         donate_kw = {"donate_argnums": (3,)} if donate else {}
-        prefill_full = jax.jit(
-            lambda params_, tokens, page_table, pages, last_index: (
-                llama.prefill_into_pages(
-                    params_, tokens, page_table, pages, last_index, config
-                )
-            ),
-            **donate_kw,
-            **jit_out,
-        )
+        prefill_full = jax.jit(llm_prefill, **donate_kw, **jit_out)
         prefill_suffix = jax.jit(
-            lambda params_, tokens, page_table, pages, last_index, start_index, prefix_blocks: (  # noqa: E501
-                llama.prefill_suffix_into_pages(
-                    params_, tokens, page_table, pages, last_index,
-                    start_index, prefix_blocks, config,
-                )
-            ),
-            static_argnums=(6,),
-            **donate_kw,
-            **jit_out,
+            llm_prefill_suffix, static_argnums=(6,), **donate_kw, **jit_out
         )
         block_size = engine_config.block_size
 
@@ -205,27 +221,7 @@ class LlmEngineModel(Model):
             )
 
         donate_kw = {"donate_argnums": (4,)} if donate else {}
-        if attn is None:
-            decode_jit = jax.jit(
-                lambda params_, tokens, positions, page_tables, pages: (
-                    llama.decode_step_paged(
-                        params_, tokens, positions, page_tables, pages, config
-                    )
-                ),
-                **donate_kw,
-                **jit_out,
-            )
-        else:
-            decode_jit = jax.jit(
-                lambda params_, tokens, positions, page_tables, pages: (
-                    llama.decode_step_paged_attn(
-                        params_, tokens, positions, page_tables, pages,
-                        config, attn,
-                    )
-                ),
-                **donate_kw,
-                **jit_out,
-            )
+        decode_jit = jax.jit(llm_decode, **donate_kw, **jit_out)
 
         def decode(tokens, positions, page_tables, pages):
             return decode_jit(
@@ -236,16 +232,7 @@ class LlmEngineModel(Model):
         decode_multi = None
         if attn_mq is not None:
             donate_kw = {"donate_argnums": (5,)} if donate else {}
-            decode_multi_jit = jax.jit(
-                lambda params_, tokens, positions, lengths, page_tables, pages: (  # noqa: E501
-                    llama.decode_step_paged_multi(
-                        params_, tokens, positions, lengths, page_tables,
-                        pages, config, attn_mq,
-                    )
-                ),
-                **donate_kw,
-                **jit_out,
-            )
+            decode_multi_jit = jax.jit(llm_verify, **donate_kw, **jit_out)
 
             def decode_multi(tokens, positions, lengths, page_tables, pages):
                 return decode_multi_jit(

@@ -312,6 +312,7 @@ def paged_attention_pallas_mq(q, k_pages, v_pages, page_tables, positions,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q_rows.shape, q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(page_tables, q_rows, row_positions[:, :, None], k_pages, v_pages)
     return (
         out.reshape(b, kv, t, g, d).transpose(0, 2, 1, 3, 4).reshape(b, t, h, d)

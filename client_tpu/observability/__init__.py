@@ -29,7 +29,8 @@ Profiling (:mod:`client_tpu.observability.profiling`)
     stage event while disabled) and an on-demand wall-stack sampler with
     collapsed-stack / speedscope exporters (``GET /v2/debug/profile``).
     The perf harness's ``--profile-server`` turns both into the
-    "Wire-gap attribution" report.
+    "Wire-gap attribution" report. :class:`LapSpans` is the always-on
+    wall-clock counterpart for one loop (the LLM engine's step phases).
 
 Logging (:mod:`client_tpu.observability.logging`)
     The real backend of the ``/v2/logging`` extension: a dependency-free
@@ -90,6 +91,7 @@ from client_tpu.observability.window import (
 )
 from client_tpu.observability.profiling import (
     STAGES,
+    LapSpans,
     ProfileResult,
     StageCpuAccounting,
     WallProfiler,
@@ -129,6 +131,7 @@ __all__ = [
     "Histogram",
     "InMemoryExporter",
     "JsonlExporter",
+    "LapSpans",
     "LiveTelemetry",
     "MetricsRegistry",
     "ProfileResult",

@@ -19,6 +19,20 @@ passed". This module is the instrument that splits them:
     (:mod:`client_tpu.server.metrics`), which the perf harness's
     ``--profile-server`` reduces to the "Wire-gap attribution" report.
 
+:class:`LapSpans`
+    Wall-clock laps that tile one loop by named phase: one injected
+    clock read at each phase boundary, credited to the phase that just
+    ended, so the phases add up to the loop's un-parked wall time by
+    construction. **Always on** (a handful of clock reads an iteration).
+    Each lap is also a ``jax.profiler.TraceAnnotation``, so a
+    ``jax.profiler`` trace shows the same phases on the loop thread's
+    host line (against the device's lines only after alignment: see the
+    class). The LLM engine's step loop runs on one
+    (``engine.stats()["phase_ns"]``).
+    Which of the two: ``StageCpuAccounting`` for thread CPU per request
+    stage across many threads, sampled, default off; ``LapSpans`` for
+    where one loop's wall time goes, every iteration, always on.
+
 :class:`WallProfiler`
     An on-demand sampling profiler over ``sys._current_frames()``:
     samples every thread's Python stack at ``hz`` for ``duration_s``,
@@ -41,6 +55,7 @@ clocks without sleeping.
 """
 
 import contextlib
+import functools
 import os
 import sys
 import threading
@@ -50,6 +65,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 __all__ = [
     "STAGES",
+    "LapSpans",
     "ProfileResult",
     "StageCpuAccounting",
     "WallProfiler",
@@ -291,6 +307,91 @@ def stage_scope(accounting: Optional[StageCpuAccounting], stage: str):
         yield
     finally:
         accounting.account(stage, accounting.cpu_now() - c0)
+
+
+# -- lap spans ----------------------------------------------------------------
+
+
+@functools.lru_cache(maxsize=None)
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, resolved once; None where jax
+    or its profiler is missing (laps then keep their counters only)."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except Exception:  # noqa: BLE001 - optional capture, never fatal
+        return None
+    return TraceAnnotation
+
+
+class LapSpans:
+    """Laps of one loop by named phase, tiling its wall time.
+
+    ``names`` maps each phase to its trace annotation's name.
+    ``enter(phase)`` is a phase boundary: ONE read of the injected clock,
+    and the interval since the previous boundary is credited to the
+    phase that just ended. Neighbours share their boundary read, so
+    ``sum(ns.values())`` is exactly the wall time from the first
+    ``enter`` to the last boundary, less what was parked: what no phase
+    names shows up in the phase that surrounds it, never in a hole.
+    ``park()`` ends the open phase without opening another (the loop
+    sleeps until there is work); parked time is in no phase.
+
+    Every lap also opens a ``TraceAnnotation`` on the calling thread and
+    closes the previous one, so a ``jax.profiler`` trace carries the
+    phases as events on that thread's host line. Their durations are the
+    counters'. Their place against the trace's DEVICE lines is not to be
+    trusted raw: on a TPU v5e the device's line ran 1.65 and 2.13 ms
+    ahead of the host's in two traces (PERF.md section 6, PR 25), so
+    overlap with device gaps needs the alignment that
+    ``benchmark/lib/span_reduce.py`` fits first. A lap may cross an
+    ``await``: the annotation is closed at the next boundary, on the
+    loop's own thread, whatever else ran on it in between. With no
+    profiler session the annotation is a flag test.
+
+    One loop, one thread: boundaries are not locked. Readers on other
+    threads (``ns`` is a dict of ints) see each counter whole and the
+    set at most one lap apart.
+    """
+
+    __slots__ = ("ns", "_clock_ns", "_names", "_phase", "_since",
+                 "_annotation", "_open")
+
+    def __init__(self, names: Dict[str, str],
+                 clock_ns: Callable[[], int] = time.monotonic_ns):
+        self.ns: Dict[str, int] = dict.fromkeys(names, 0)
+        self._clock_ns = clock_ns
+        self._names = dict(names)
+        self._phase: Optional[str] = None  # None: parked
+        self._since = 0
+        self._annotation = _trace_annotation()
+        self._open = None
+
+    def enter(self, phase: str) -> int:
+        """End the open phase here and open ``phase``; returns the
+        boundary's instant. Entering the open phase again is no boundary
+        (and returns the last one's instant)."""
+        if phase == self._phase:
+            return self._since
+        now = self._clock_ns()
+        if self._phase is not None:
+            self.ns[self._phase] += now - self._since
+        self._phase, self._since = phase, now
+        if self._annotation is not None:
+            if self._open is not None:
+                self._open.__exit__(None, None, None)
+            self._open = self._annotation(self._names[phase])
+            self._open.__enter__()
+        return now
+
+    def park(self) -> None:
+        """End the open phase; nothing is open until the next ``enter``."""
+        if self._phase is None:
+            return
+        self.ns[self._phase] += self._clock_ns() - self._since
+        self._phase = None
+        if self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = None
 
 
 # -- sampling profiler --------------------------------------------------------
