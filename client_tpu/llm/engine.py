@@ -461,6 +461,11 @@ class LlmEngine:
         # speculation accounting: drafts verified, drafts accepted, and
         # how many steps ran the multi-query verify path
         self.spec_steps = 0
+        # page-table columns the decode kernel was handed (bucket x nb)
+        # and those of them that hold a live block: the share of the
+        # table it has to stream, since it stops at each lane's length
+        self.attn_blocks_live = 0
+        self.attn_blocks_bucket = 0
         self.spec_proposed = 0
         self.spec_accepted = 0
         # full prompt blocks demanded across admissions — with
@@ -861,6 +866,8 @@ class LlmEngine:
             "lane_steps": self.lane_steps,
             "tokens_per_step": self.step_tokens / max(1, self.lane_steps),
             "spec_steps": self.spec_steps,
+            "attn_blocks_live": self.attn_blocks_live,
+            "attn_blocks_bucket": self.attn_blocks_bucket,
             "spec_proposed": self.spec_proposed,
             "spec_accepted": self.spec_accepted,
             "spec_acceptance_rate": (
@@ -1267,10 +1274,12 @@ class LlmEngine:
         tokens = np.zeros([bucket], dtype=np.int32)
         positions = np.zeros([bucket], dtype=np.int32)
         page_tables = np.zeros([bucket, nb], dtype=np.int32)
+        self.attn_blocks_bucket += bucket * nb
         for i, seq in enumerate(batch):
             tokens[i] = seq.last_token
             positions[i] = seq.position
             page_tables[i] = seq.page_table[:nb]
+            self.attn_blocks_live += len(seq.blocks)
             # COW invariant: the block this lane is about to write must
             # be exclusively owned (shared prefix blocks are read-only;
             # growth always lands in fresh blocks). A violation means
@@ -1421,6 +1430,7 @@ class LlmEngine:
         positions = np.zeros([bucket, t_width], dtype=np.int32)
         lengths = np.zeros([bucket], dtype=np.int32)
         page_tables = np.zeros([bucket, nb], dtype=np.int32)
+        self.attn_blocks_bucket += bucket * nb
         row_offsets = np.arange(t_width)
         for i, (seq, proposal, k_eff) in enumerate(
             zip(batch, drafts, k_effs)
@@ -1433,6 +1443,7 @@ class LlmEngine:
             positions[i] = seq.position + np.minimum(row_offsets, k_eff)
             lengths[i] = k_eff + 1
             page_tables[i] = seq.page_table[:nb]
+            self.attn_blocks_live += len(seq.blocks)
             # COW invariant over the WHOLE speculative write range: the
             # verify scatters K/V at position..position+k_eff, and none
             # of those blocks may be shared. Engine-fatal on violation,

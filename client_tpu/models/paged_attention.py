@@ -18,13 +18,16 @@ Attention", PAPERS.md arxiv 2604.15464, is the blueprint):
   buckets that width to the live batch's longest sequence, so compute
   scales with actual context instead of ``max_seq_len``.  This is the
   implementation off-TPU.
-- ``pallas``: a flash-style Pallas kernel.  The grid walks
-  ``(sequence, block)``; the page table rides scalar prefetch so each
-  grid step's BlockSpec ``index_map`` streams exactly ONE physical block
-  from the pool into VMEM — no ``[B, S]`` gather ever materializes.
-  Online-softmax scratch (running max / denominator / accumulator)
-  carries across the block axis.  ``pallas_interpret`` runs the same
-  kernel under the Pallas interpreter for CPU parity tests.
+- ``pallas``: a flash-style Pallas kernel, one grid step per sequence.
+  The page table and each sequence's length ride scalar prefetch; the
+  pools stay in HBM and the kernel copies a TILE of pages (as many as a
+  fixed VMEM budget holds for the pool's shapes: 8 at KV 8 / D 128 /
+  bf16) into one of two VMEM slots itself, the next tile in flight
+  while this one is folded into the online softmax, and stops at the
+  sequence's own last tile — no ``[B, S]`` gather ever materializes and
+  the padding of the table to its bucket is never read.
+  ``pallas_interpret`` runs the same kernel under the Pallas
+  interpreter for CPU parity tests.
 
 Selection happens once at model warmup (``llm/serving.py``), by
 platform: TPU hosts take the Pallas kernel, everything else
@@ -200,120 +203,223 @@ def paged_attention_fused_xla_mq(q, k_pages, v_pages, page_tables, positions):
 
 
 # ---------------------------------------------------------------------------
-# Pallas kernel: per-block streaming + online softmax
+# Pallas kernel: tiles of pages, double-buffered by hand, ragged trip count
 # ---------------------------------------------------------------------------
 
-#: lane width of a TPU vector register: the running max/denominator
-#: scratch is kept lane-aligned (each value broadcast across one vreg
-#: row) rather than as a 1-wide column
-_LANES = 128
+#: VMEM the kernel may hold in K/V page buffers: two slots (one being
+#: folded, one in flight) of one K tile and one V tile each
+_KV_VMEM_BUDGET = 1 << 20
 
 
-def _rpa_kernel(block_size, scale,
-                tbl_ref, q_ref, pos_ref, k_ref, v_ref, o_ref,
-                m_ref, l_ref, acc_ref):
-    """Grid step (b, j): fold physical block ``tbl[b, j]`` of sequence
-    ``b`` into the online-softmax state of all its query rows.
+def pages_per_tile(block_size: int, kv_heads: int, head_dim: int,
+                   dtype) -> int:
+    """Pages the kernel brings into VMEM per tile: as many as fit a
+    quarter of :data:`_KV_VMEM_BUDGET` (K and V, two slots each). A
+    function of the pool's shapes alone, so one program serves every
+    batch, and every model finds its own tile: 8 pages (128 tokens) at
+    KV 8 / D 128 / bf16, 2 at KV 32, 32 for a KV 2 tensor-parallel
+    shard."""
+    page_bytes = block_size * kv_heads * head_dim * jnp.dtype(dtype).itemsize
+    return max(1, _KV_VMEM_BUDGET // 4 // page_bytes)
 
-    Queries arrive grouped by kv head (``q_ref`` block ``[1, KV, M, D]``,
-    ``M`` = query positions x group size), so both contractions are
-    batched matmuls with the kv head LEADING — the only batched form
-    Mosaic lowers — and grouped-query heads share their kv head's block
-    with no in-kernel repeat. ``pos_ref`` (``[1, M, 1]`` int32, VMEM)
-    carries each row's validity threshold as a vector: SMEM, where the
-    scalar-prefetched page table lives, only serves scalar loads.
-    Scratch (running max ``m``, denominator ``l``, accumulator ``acc``)
-    persists across the block axis; the first block initializes it, the
-    last normalizes out."""
-    j = pl.program_id(1)
-    nb = pl.num_programs(1)
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+def _rpa_kernel(kv, scale,
+                tbl_ref, len_ref, q_ref, pos_ref, k_hbm, v_hbm, o_ref,
+                k_buf, v_buf, sems, slot_ref):
+    """Grid step ``b``: fold sequence ``b``'s live pages, one tile of
+    ``P`` pages at a time, into the online softmax of all its query rows.
 
-    q = q_ref[0]  # [KV, M, D]
-    k = jnp.swapaxes(k_ref[0], 0, 1)  # [bs, KV, D] -> [KV, bs, D]
-    v = jnp.swapaxes(v_ref[0], 0, 1)
-    s = jnp.einsum(
-        "kmd,ktd->kmt", q, k, preferred_element_type=jnp.float32
-    ) * scale  # [KV, M, bs]
-    # per-row slot validity: absolute slot index <= this ROW's position
-    # (covers ragged tails, padding lanes, and the trash block alike)
-    slot = j * block_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, block_size), 1
+    The pools stay in HBM, viewed ``[N, bs*KV, D]`` (pool row ``t*KV +
+    h`` is token ``t``, kv head ``h``). A tile is ``P`` page copies into
+    slot ``s`` of ``k_buf`` / ``v_buf`` (``[2, P, bs*KV, D]``), all on
+    ``sems[0|1, s]``; while a tile is folded the NEXT tile's copies are
+    in flight in the other slot, and the next tile of a sequence's last
+    tile is the next sequence's first, so only the very first tile of
+    the call is waited for with nothing to do. ``slot_ref`` (SMEM)
+    carries the slot across grid steps. The trip count is the
+    sequence's own ``cdiv(len, P*bs)``: table columns past it are never
+    read.
+
+    Queries arrive as rows ``[KV*M, D]`` (row ``h*M + m``: kv head
+    ``h``, ``M`` = query positions x group size). Both contractions are
+    plain matmuls against the tile as it lies in VMEM, ``[P*bs*KV, D]``:
+    every row meets every (token, kv head) column and the columns of the
+    other kv heads are masked with the slots past the row's position, so
+    their weights are exact zeros in the second matmul. The MXU loads
+    the same K and V tiles it would for per-head dots; no page is
+    transposed and no head repeated. ``pos_ref`` (``[1, KV*M, 1]``
+    int32, VMEM) carries each row's validity threshold as a vector:
+    SMEM, where the scalar-prefetched table and lengths live, only
+    serves scalar loads."""
+    b = pl.program_id(0)
+    n_seqs = pl.num_programs(0)
+    nb = tbl_ref.shape[1]
+    _, pages, page_rows, _ = k_buf.shape
+    tile_rows = pages * page_rows
+    tile_slots = tile_rows // kv
+    rows = q_ref.shape[1]
+
+    # at least one tile, whatever the length says: the sequence before
+    # has this one's first tile in flight, and somebody has to wait for it
+    n_tiles = jnp.maximum(1, (len_ref[b] + tile_slots - 1) // tile_slots)
+
+    def page_copies(slot, j, page):
+        return (
+            pltpu.make_async_copy(
+                k_hbm.at[page], k_buf.at[slot, j], sems.at[0, slot]
+            ),
+            pltpu.make_async_copy(
+                v_hbm.at[page], v_buf.at[slot, j], sems.at[1, slot]
+            ),
+        )
+
+    # the page loops are rolled: a copy traced once per site, not once
+    # per page, keeps the program's trace (paid at every server start,
+    # once per layer) as short as the kernel it replaces
+
+    def start_tile(seq, tile, slot):
+        @pl.loop(0, pages)
+        def _start(j):
+            # a table narrower than a whole number of tiles: the last
+            # tile's spare pages re-read the last column, masked as
+            # slots past every position
+            column = jnp.minimum(tile * pages + j, nb - 1)
+            for copy in page_copies(slot, j, tbl_ref[seq, column]):
+                copy.start()
+
+    def wait_tile(slot):
+        @pl.loop(0, pages)
+        def _wait(j):
+            # a wait takes its size from the copy, not its source
+            for copy in page_copies(slot, j, 0):
+                copy.wait()
+
+    @pl.when(b == 0)
+    def _first_tile():
+        slot_ref[0] = 0
+        start_tile(0, 0, 0)
+
+    first_slot = slot_ref[0]
+    q = q_ref[0]  # [rows, D]
+    pos = pos_ref[0]  # [rows, 1]
+    column = jax.lax.broadcasted_iota(jnp.int32, (rows, tile_rows), 1)
+    row_head = jax.lax.broadcasted_iota(
+        jnp.int32, (rows, tile_rows), 0
+    ) // (rows // kv)
+    own_head = column % kv == row_head
+    slot_in_tile = column // kv
+
+    def fold(i, carry):
+        m_prev, l_prev, acc = carry
+        slot = (first_slot + i) % 2
+        last = i + 1 == n_tiles
+        next_seq = jnp.where(last, b + 1, b)
+
+        @pl.when(next_seq < n_seqs)
+        def _next_tile():
+            start_tile(next_seq, jnp.where(last, 0, i + 1), 1 - slot)
+
+        wait_tile(slot)
+        k = k_buf[slot].reshape(tile_rows, -1)
+        v = v_buf[slot].reshape(tile_rows, -1)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [rows, tile_rows]
+        # per-row slot validity: absolute slot index <= this ROW's
+        # position (covers ragged tails, padding lanes, and the trash
+        # block alike), in the row's own kv head
+        valid = own_head & (slot_in_tile <= pos - i * tile_slots)
+        s = jnp.where(valid, s, NEG_INF)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_prev * alpha + p.sum(axis=1, keepdims=True)
+        # weights ride the MXU in the page dtype (f32 accumulate), the
+        # same operand precision XLA's default gives the fused variant
+        acc = acc * alpha + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32
+        )
+        return m_new, l_new, acc
+
+    _, l, acc = jax.lax.fori_loop(
+        0, n_tiles, fold,
+        (
+            jnp.full((rows, 1), NEG_INF, jnp.float32),
+            jnp.zeros((rows, 1), jnp.float32),
+            jnp.zeros(q.shape, jnp.float32),
+        ),
     )
-    valid = (slot <= pos_ref[0])[None]  # [1, M, bs]
-    s = jnp.where(valid, s, NEG_INF)
-    m_prev = m_ref[...]  # [KV, M, LANES]
-    m_new = jnp.maximum(m_prev, s.max(axis=2, keepdims=True))
-    p = jnp.where(valid, jnp.exp(s - m_new[..., :1]), 0.0)
-    alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * alpha + p.sum(axis=2, keepdims=True)
-    # weights ride the MXU in the page dtype (f32 accumulate), the same
-    # operand precision XLA's default gives the fused variant on TPU
-    acc_ref[...] = acc_ref[...] * alpha[..., :1] + jnp.einsum(
-        "kmt,ktd->kmd", p.astype(v.dtype), v,
-        preferred_element_type=jnp.float32,
-    )
-    m_ref[...] = m_new
-
-    @pl.when(j == nb - 1)
-    def _finalize():
-        o_ref[0] = (acc_ref[...] / l_ref[..., :1]).astype(o_ref.dtype)
+    slot_ref[0] = (first_slot + n_tiles) % 2
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnames="interpret")
 def paged_attention_pallas_mq(q, k_pages, v_pages, page_tables, positions,
                               *, interpret: bool = False):
     """Flash-style multi-query ragged paged attention as a Pallas kernel.
 
-    ``page_tables`` is scalar-prefetched so the BlockSpec index maps can
-    stream block ``page_tables[b, j]`` (ONE physical block,
-    ``[bs, KV, D]``) into VMEM per grid step — sequence ``b`` never
-    touches pages it does not own, no contiguous per-sequence view is
-    ever materialized in HBM, and the T verify rows of a sequence share
-    each streamed block (the pages cross HBM->VMEM once for all K+1
-    positions). Queries are regrouped ``[B, T, H, D] -> [B, KV, T*G, D]``
-    outside the kernel (head ``k*G + g`` reads kv head ``k``, matching
-    ``_repeat_kv``) — a copy of the queries only, never of the pages."""
+    One grid step per sequence. ``page_tables`` and each sequence's
+    length (its largest query position + 1) are scalar-prefetched; the
+    kernel copies pages ``page_tables[b, j]`` from the pools in HBM into
+    VMEM itself, :func:`pages_per_tile` at a time and one tile ahead of
+    the arithmetic, and stops at the sequence's own last tile — sequence
+    ``b`` never touches pages it does not own, the padding of the table
+    to its bucket costs nothing, no contiguous per-sequence view is ever
+    materialized in HBM, and the T verify rows of a sequence share each
+    tile (the pages cross HBM->VMEM once for all K+1 positions). Queries
+    are regrouped ``[B, T, H, D] -> [B, KV*T*G, D]`` outside the kernel
+    (head ``k*G + g`` reads kv head ``k``, matching ``_repeat_kv``) — a
+    copy of the queries only; the pools are only re-viewed
+    ``[N, bs*KV, D]``.
+
+    Jitted, so that the layers of a model, which all call it with the
+    same shapes, share one trace and one lowering of the kernel: a
+    server traces every decode program anew at each start, whatever the
+    compile cache holds."""
     b, t, h, d = q.shape
-    _, bs, kv, _ = k_pages.shape
+    n, bs, kv, _ = k_pages.shape
     g = h // kv
-    m = t * g
+    rows = kv * t * g
     nb = page_tables.shape[1]
+    pages = min(pages_per_tile(bs, kv, d, k_pages.dtype), nb)
     q_rows = (
-        q.reshape(b, t, kv, g, d).transpose(0, 2, 1, 3, 4).reshape(b, kv, m, d)
+        q.reshape(b, t, kv, g, d).transpose(0, 2, 1, 3, 4).reshape(b, rows, d)
     )
-    row_positions = jnp.repeat(positions.astype(jnp.int32), g, axis=1)
+    positions = positions.astype(jnp.int32)
+    row_positions = jnp.tile(jnp.repeat(positions, g, axis=1), (1, kv))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, nb),
+        num_scalar_prefetch=2,
+        grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, kv, m, d), lambda i, j, tbl: (i, 0, 0, 0)),
-            pl.BlockSpec((1, m, 1), lambda i, j, tbl: (i, 0, 0)),
-            pl.BlockSpec(
-                (1, bs, kv, d), lambda i, j, tbl: (tbl[i, j], 0, 0, 0)
-            ),
-            pl.BlockSpec(
-                (1, bs, kv, d), lambda i, j, tbl: (tbl[i, j], 0, 0, 0)
-            ),
+            pl.BlockSpec((1, rows, d), lambda i, tbl, lens: (i, 0, 0)),
+            pl.BlockSpec((1, rows, 1), lambda i, tbl, lens: (i, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, kv, m, d), lambda i, j, tbl: (i, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, rows, d), lambda i, tbl, lens: (i, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((kv, m, _LANES), jnp.float32),  # running max
-            pltpu.VMEM((kv, m, _LANES), jnp.float32),  # running denominator
-            pltpu.VMEM((kv, m, d), jnp.float32),  # weighted-value accumulator
+            pltpu.VMEM((2, pages, bs * kv, d), k_pages.dtype),
+            pltpu.VMEM((2, pages, bs * kv, d), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),  # the slot the next tile is in
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_rpa_kernel, bs, 1.0 / (d ** 0.5)),
+        functools.partial(_rpa_kernel, kv, 1.0 / (d ** 0.5)),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q_rows.shape, q.dtype),
+        # sequence b prefetches sequence b+1's first tile: in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
         interpret=interpret,
         name="paged_attention",
-    )(page_tables, q_rows, row_positions[:, :, None], k_pages, v_pages)
+    )(
+        page_tables.astype(jnp.int32), positions.max(axis=1) + 1,
+        q_rows, row_positions[:, :, None],
+        k_pages.reshape(n, bs * kv, d), v_pages.reshape(n, bs * kv, d),
+    )
     return (
         out.reshape(b, kv, t, g, d).transpose(0, 2, 1, 3, 4).reshape(b, t, h, d)
     )

@@ -31,7 +31,7 @@ pytestmark = pytest.mark.llm
 
 VOCAB = 32
 COUNTERS = ("prefills", "admitted", "queue_wait_ns", "steps", "lane_steps",
-            "tokens_generated")
+            "tokens_generated", "attn_blocks_live", "attn_blocks_bucket")
 
 
 class _TickingClock:
@@ -232,6 +232,42 @@ def test_counters_count_what_was_submitted():
     waited = stats["queue_wait_ns"]
     assert waited > 3 * 3 * 1_000
     assert waited < 5 * (clock.now - submitted_at)
+    engine.close()
+
+
+@pytest.mark.parametrize("speculative", [False, True],
+                         ids=["decode", "verify"])
+def test_attn_block_counters_follow_the_tables_the_device_saw(speculative):
+    """``attn_blocks_bucket`` is every column of every page table a
+    decode or verify step handed the device, ``attn_blocks_live`` those
+    that hold a sequence's block (block 0 is the trash block: padding
+    lanes and the columns past a lane's last block)."""
+    engine = _stub_engine(_TickingClock(), speculative=speculative)
+    tables = {"decode": [], "verify": []}
+
+    def watched(name, call, table_at):
+        def step(*args):
+            tables[name].append(np.array(args[table_at]))
+            seen = engine.stats()
+            # booked where the table is built: this step is in already
+            assert seen["attn_blocks_bucket"] == sum(
+                t.size for ts in tables.values() for t in ts)
+            assert seen["attn_blocks_live"] == sum(
+                np.count_nonzero(t) for ts in tables.values() for t in ts)
+            return call(*args)
+        return step
+
+    engine._decode = watched("decode", engine._decode, 2)
+    if speculative:
+        engine._decode_multi = watched("verify", engine._decode_multi, 3)
+    # three lanes pad to a batch bucket of 4; contexts pass 2 blocks of 4
+    out = _run_stub(engine, [[1, 2, 1, 2, 1, 2], [3, 3, 3, 3], [5]], 12)
+    assert [len(tokens) for tokens in out] == [12, 12, 12]
+    stats = engine.stats()
+    assert len(tables["verify"]) == stats["spec_steps"]
+    assert (len(tables["verify"]) > 0) == speculative
+    assert len(tables["decode"]) + len(tables["verify"]) == stats["steps"]
+    assert 0 < stats["attn_blocks_live"] < stats["attn_blocks_bucket"]
     engine.close()
 
 

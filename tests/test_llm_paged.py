@@ -175,6 +175,115 @@ def test_attention_impls_agree_on_ragged_layouts(b, nb):
         assert np.abs(out - ref).max() <= 1e-5, name
 
 
+GARBAGE = 3.0e4  # large and finite: what a masked slot may hold
+
+
+def _ragged_case(rng, kv, g, b, nb, t, bs=16, d=128):
+    """A float32 pool and the tables the engine would write for ``b``
+    lanes of a ``nb``-column bucket, at real page shapes (so that
+    ``pages_per_tile`` is what a served model gets). Lane lengths fall
+    before, on and one past the first tile boundary, at 1, at the full
+    table; the last lane of a batch of three or more is a padding lane
+    (table all trash block, position 0). Every slot no query may see —
+    past a lane's last position, and the whole trash block — holds
+    ``GARBAGE``; table columns past a lane's last live block are 0.
+    Returns (q, k_pages, v_pages, tables, positions[b, t])."""
+    from client_tpu.models import paged_attention as pa
+
+    tile = min(pa.pages_per_tile(bs, kv, d, np.float32), nb) * bs
+    wanted = [tile + 1, tile - 1, tile, 1, nb * bs, 2 * tile + 1, tile + bs]
+    lengths = [
+        min(max(1, wanted[i % len(wanted)]), nb * bs) for i in range(b)
+    ]
+    if b > len(wanted):
+        lengths[len(wanted):] = rng.integers(
+            1, nb * bs + 1, size=b - len(wanted)
+        ).tolist()
+    shape = (1 + b * nb, bs, kv, d)
+    k_pages = np.full(shape, GARBAGE, dtype=np.float32)
+    v_pages = np.full(shape, -GARBAGE, dtype=np.float32)
+    tables = np.zeros((b, nb), dtype=np.int32)
+    positions = np.zeros((b, t), dtype=np.int32)
+    for i, length in enumerate(lengths):
+        if b >= 3 and i == b - 1:
+            continue  # the padding lane
+        owned = (length + bs - 1) // bs
+        blocks = 1 + i * nb + np.arange(owned)
+        tables[i, :owned] = blocks
+        live = rng.normal(size=(2, owned * bs, kv, d)).astype(np.float32)
+        live[:, length:] = GARBAGE
+        k_pages[blocks] = live[0].reshape(owned, bs, kv, d)
+        v_pages[blocks] = live[1].reshape(owned, bs, kv, d)
+        # verify rows: the last t positions of the context, clamped as
+        # the engine clamps padding rows (and contexts shorter than t)
+        first = max(0, length - t)
+        positions[i] = first + np.minimum(np.arange(t), length - 1 - first)
+    q = rng.normal(size=(b, t, kv * g, d)).astype(np.float32)
+    return q, k_pages, v_pages, tables, positions
+
+
+RAGGED_CASES = [
+    # (kv, g, batch, nb, t): GQA 32/8 as the benchmark's cell serves it,
+    # MHA (KV 32), a KV 2 tensor-parallel shard
+    (8, 4, 16, 64, 1), (8, 4, 3, 24, 5), (8, 4, 1, 8, 1), (8, 4, 3, 8, 5),
+    (8, 4, 3, 2, 1), (8, 4, 1, 1, 1), (8, 4, 3, 1, 5), (8, 4, 3, 6, 1),
+    (32, 1, 3, 8, 1), (32, 1, 3, 24, 5), (32, 1, 1, 2, 1), (32, 1, 16, 8, 1),
+    (2, 4, 3, 24, 1), (2, 4, 16, 64, 5), (2, 4, 1, 8, 5), (2, 4, 3, 2, 1),
+]
+
+
+@pytest.mark.parametrize(
+    "kv,g,b,nb,t", RAGGED_CASES,
+    ids=[f"kv{c[0]}-b{c[2]}-nb{c[3]}-t{c[4]}" for c in RAGGED_CASES],
+)
+def test_pallas_tiles_match_standin_on_ragged_lengths(kv, g, b, nb, t):
+    """The Pallas kernel (interpreted) against the stand-in where its
+    tiling shows: lengths around a tile boundary, one-tile and padding
+    lanes, tables narrower than a tile and not a multiple of one, with
+    garbage wherever the mask must hold."""
+    from client_tpu.models import paged_attention as pa
+
+    rng = np.random.default_rng(kv * 1000 + b * 100 + nb + t)
+    q, k_pages, v_pages, tables, positions = _ragged_case(
+        rng, kv, g, b, nb, t
+    )
+    if t == 1:
+        args = (q[:, 0], k_pages, v_pages, tables, positions[:, 0])
+        ref = pa.paged_attention_standin(*args)
+        out = pa.paged_attention_pallas_interpret(*args)
+    else:
+        args = (q, k_pages, v_pages, tables, positions)
+        ref = pa.paged_attention_standin_mq(*args)
+        out = pa.paged_attention_pallas_interpret_mq(*args)
+    ref, out = np.asarray(ref), np.asarray(out)
+    assert out.shape == ref.shape and np.isfinite(out).all()
+    # a padding lane reads the trash block's one visible slot: GARBAGE
+    scale = np.maximum(1.0, np.abs(ref).max(axis=-1, keepdims=True))
+    assert (np.abs(out - ref) / scale).max() <= 1e-5
+
+
+def test_pages_per_tile_follows_the_shapes_alone():
+    """The tile is a function of the pool's shapes and nothing else (no
+    batch, table or length goes in); K and V, two slots each, stay
+    inside the VMEM budget."""
+    import jax.numpy as jnp
+
+    from client_tpu.models import paged_attention as pa
+
+    assert pa.pages_per_tile(16, 8, 128, jnp.bfloat16) == 8
+    assert pa.pages_per_tile(16, 32, 128, jnp.bfloat16) == 2
+    assert pa.pages_per_tile(16, 2, 128, jnp.bfloat16) == 32
+    for bs, kv, d, dtype in [(16, 32, 128, jnp.bfloat16),
+                             (16, 32, 128, jnp.float32),
+                             (32, 8, 128, jnp.bfloat16),
+                             (16, 2, 64, jnp.bfloat16)]:
+        pages = pa.pages_per_tile(bs, kv, d, dtype)
+        scratch = 2 * 2 * pages * bs * kv * d * jnp.dtype(dtype).itemsize
+        assert pages >= 1 and scratch <= pa._KV_VMEM_BUDGET
+    # a page larger than a slot still moves, one at a time
+    assert pa.pages_per_tile(64, 64, 256, jnp.float32) == 1
+
+
 def test_decode_step_kernels_match_standin_on_tiny_llama(tiny_llama):
     """Full decode-step logits parity (<=1e-5) vs the stand-in, including
     at the engine's ragged (narrower) page-table width."""

@@ -41,20 +41,23 @@ def device():
 # ---------------------------------------------------------------------------
 
 
-def _ragged_pool(rng, batch, kv_heads, n_blocks, rows):
+def _ragged_pool(rng, batch, kv_heads, n_blocks, rows, last=None):
     """A bf16 block pool plus ragged page tables: sequence ``b`` owns just
     the blocks its context needs (the rest of its row is the trash block
-    0). Contexts are random, with the longest filling the table and the
-    shortest one token, so full, partial and all-trash rows all occur.
+    0). Contexts are random unless ``last`` gives them, with the longest
+    filling the table and the shortest one token, so full, partial and
+    all-trash rows all occur.
     Returns (k_pages, v_pages, tables, last) with ``last[b]`` the context's
     final position less ``rows - 1`` of headroom for verify rows."""
     import jax.numpy as jnp
 
     limit = n_blocks * BLOCK - rows
-    last = rng.integers(0, limit + 1, size=batch)
-    last[0] = limit
-    if batch > 1:
-        last[-1] = 0
+    if last is None:
+        last = rng.integers(0, limit + 1, size=batch)
+        last[0] = limit
+        if batch > 1:
+            last[-1] = 0
+    last = np.asarray(last)
     shape = (1 + batch * n_blocks, BLOCK, kv_heads, HEAD_DIM)
     k_pages = jnp.asarray(rng.normal(size=shape), dtype=jnp.bfloat16)
     v_pages = jnp.asarray(rng.normal(size=shape), dtype=jnp.bfloat16)
@@ -67,7 +70,7 @@ def _ragged_pool(rng, batch, kv_heads, n_blocks, rows):
 
 def _assert_bf16_close(out, ref, what):
     """Both sides sum in float32 with bf16 MXU operands, in different
-    orders (one online softmax over 16-slot blocks, one full-width), and
+    orders (one online softmax over tiles of pages, one full-width), and
     round the result to bf16, whose spacing is 2^-7 relative at worst. Two
     such steps at the output's largest magnitude are allowed; a wrong
     mask, head mapping or page shows as O(0.1..1)."""
@@ -136,6 +139,44 @@ def test_compiled_pallas_verify_matches_fused_xla(device, kv_heads, batch,
         jax.jit(pa.paged_attention_pallas_mq)(*args),
         jax.jit(pa.paged_attention_fused_xla_mq)(*args),
         f"multi-query KV={kv_heads} B={batch} NB={n_blocks}",
+    )
+
+
+@pytest.mark.parametrize("rows", [1, 5], ids=["decode", "verify"])
+def test_compiled_pallas_at_the_benchmark_cells_shapes(device, rows):
+    """``mistral7b.batch`` as the kernel sees it: 16 lanes, GQA 32/8, a
+    64-column table, contexts staggered over 512..1,024 (so every lane
+    stops at another tile, and the columns past its last block hold the
+    trash block), and two padding lanes at position 0."""
+    import jax
+    import jax.numpy as jnp
+
+    from client_tpu.models import paged_attention as pa
+
+    batch, n_blocks, kv_heads = 16, 64, 8
+    rng = np.random.default_rng(26 + rows)
+    last = 511 + 32 * np.arange(batch) + rng.integers(0, 32, size=batch)
+    last = np.minimum(last, n_blocks * BLOCK - rows)
+    last[-2:] = 0
+    k_pages, v_pages, tables, first = _ragged_pool(
+        rng, batch, kv_heads, n_blocks, rows=rows, last=last
+    )
+    tables[-2:] = 0
+    positions = (first[:, None] + np.arange(rows)[None, :]).astype(np.int32)
+    positions[-2:] = 0
+    q = jnp.asarray(
+        rng.normal(size=(batch, rows, HEADS, HEAD_DIM)), dtype=jnp.bfloat16
+    )
+    if rows == 1:
+        args = (q[:, 0], k_pages, v_pages, tables, positions[:, 0])
+        pallas, fused = pa.paged_attention_pallas, pa.paged_attention_fused_xla
+    else:
+        args = (q, k_pages, v_pages, tables, positions)
+        pallas = pa.paged_attention_pallas_mq
+        fused = pa.paged_attention_fused_xla_mq
+    _assert_bf16_close(
+        jax.jit(pallas)(*args), jax.jit(fused)(*args),
+        f"the cell's shapes, T={rows}",
     )
 
 
