@@ -51,7 +51,13 @@ from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
-from client_tpu.llm.kv_cache import BlockAllocator, CacheCapacityError, TRASH_BLOCK
+from client_tpu.llm.kv_cache import (
+    TRASH_BLOCK,
+    BlockAllocator,
+    CacheCapacityError,
+    window_ring_blocks,
+    window_tables,
+)
 from client_tpu.observability.profiling import LapSpans
 from client_tpu.scheduling import (
     PriorityQueue,
@@ -95,6 +101,13 @@ class EngineConfig:
     lookahead — the most draft tokens one verify step may carry per
     sequence (0 disables speculation; admission counts the worst-case
     ``K+1`` growth for speculation-enabled sequences).
+
+    ``cache_groups`` is the served model's (``models/engine_model.py``
+    ``CacheGroup``; empty: one full group), set by ``LlmEngineModel``
+    from the model. ``num_blocks`` sizes the full group's pool; a
+    window group's is worked out: a ring for each of ``max_active``
+    sequences, and the trash block, so a ring is there for whoever
+    ``max_active`` admits.
     """
 
     __slots__ = (
@@ -108,6 +121,7 @@ class EngineConfig:
         "prefill_bucket_min",
         "prefix_sharing",
         "spec_k",
+        "cache_groups",
     )
 
     def __init__(
@@ -122,6 +136,7 @@ class EngineConfig:
         prefill_bucket_min: int = 8,
         prefix_sharing: bool = True,
         spec_k: int = 0,
+        cache_groups=(),
     ):
         self.block_size = int(block_size)
         self.num_blocks = int(num_blocks)
@@ -133,10 +148,23 @@ class EngineConfig:
         self.prefill_bucket_min = int(prefill_bucket_min)
         self.prefix_sharing = bool(prefix_sharing)
         self.spec_k = max(0, int(spec_k))
+        self.cache_groups = tuple(cache_groups)
 
     @property
     def max_blocks_per_seq(self) -> int:
         return (self.max_seq_len + self.block_size - 1) // self.block_size
+
+    def group_num_blocks(self) -> List[int]:
+        """Physical blocks of each cache group's pool, in the groups'
+        order (one full group when none is declared)."""
+        sizes = []
+        for group in self.cache_groups or (None,):
+            if group is None or group.window is None:
+                sizes.append(self.num_blocks)
+            else:
+                ring = window_ring_blocks(group.window, self.block_size)
+                sizes.append(1 + self.max_active * ring)
+        return sizes
 
 
 #: The step loop's phases (``stats()["phase_ns"]`` keys, ``engine.<phase>``
@@ -263,6 +291,7 @@ class Sequence:
         "timeout_us",
         "state",
         "blocks",
+        "rings",
         "page_table",
         "last_token",
         "position",
@@ -293,6 +322,9 @@ class Sequence:
         self.timeout_us = timeout_us
         self.state = _WAITING
         self.blocks: List[int] = []
+        # one fixed ring of blocks for each WINDOW cache group, held
+        # from admission to free (kv_cache.window_tables)
+        self.rings: List[List[int]] = []
         self.page_table = np.zeros([max_blocks], dtype=np.int32)
         self.last_token = 0
         self.position = 0
@@ -366,7 +398,13 @@ class LlmEngine:
     and ``decode_fn(tokens[B], positions[B], page_tables[B, NB], pages)
     -> (logits[B, V], pages)`` (``NB`` is the engine's ragged block
     bucket — any width up to ``max_blocks_per_seq``) are the injected
-    (jitted) device callables; ``pages`` is opaque to the engine.
+    (jitted) device callables; ``pages`` is opaque to the engine. A
+    model with several cache groups (``engine_config.cache_groups``: one
+    full group, the rest window groups) gets one table row a group,
+    stacked in the groups' order: ``page_table[G, max_blocks]`` and
+    ``page_tables[G, B, NB]``. ``decode_fn`` may return a third value, an
+    int32 vector of per-step counters named by ``step_counters``; it is
+    read back with the logits and summed into ``stats()``.
     ``metrics`` implements the ServerMetrics LLM hooks (set_kv_blocks /
     set_llm_sequences / observe_llm_step / observe_llm_preemption /
     observe_prefix_hits / observe_rejection / observe_llm_speculation);
@@ -402,12 +440,44 @@ class LlmEngine:
         clock_ns: Callable[[], int] = time.monotonic_ns,
         decode_multi_fn: Optional[Callable] = None,
         proposer: Any = None,
+        step_counters: Any = (),
     ):
         self.config = engine_config
         self.model_name = model_name
         self.allocator = BlockAllocator(
             engine_config.num_blocks, engine_config.block_size
         )
+        # cache groups: the full group is `self.allocator` and the
+        # sequences' `blocks`; each window group has an allocator of its
+        # own that hands out whole rings. `_windows` holds (index among
+        # the groups, ring length, allocator).
+        groups = engine_config.cache_groups
+        sizes = engine_config.group_num_blocks()
+        self._n_groups = max(1, len(groups))
+        self._full_group = 0
+        self._windows: List[tuple] = []
+        for index, group in enumerate(groups):
+            if group.window is None:
+                self._full_group = index
+                continue
+            self._windows.append((
+                index,
+                window_ring_blocks(group.window, engine_config.block_size),
+                BlockAllocator(sizes[index], engine_config.block_size),
+            ))
+        if groups and len(groups) - len(self._windows) != 1:
+            raise ValueError(
+                "the engine serves exactly one full cache group beside "
+                f"any window groups, got {[g.kind for g in groups]}"
+            )
+        if self._windows and (
+            engine_config.prefix_sharing or engine_config.spec_k
+        ):
+            raise ValueError(
+                "window cache groups are served without prefix sharing "
+                "and without speculation: shared blocks and draft "
+                "lookahead have no ring to live in"
+            )
         self.metrics = metrics
         self.logger = logger
         self._clock_ns = clock_ns
@@ -466,6 +536,16 @@ class LlmEngine:
         # table it has to stream, since it stops at each lane's length
         self.attn_blocks_live = 0
         self.attn_blocks_bucket = 0
+        # window groups: blocks a whole-length cache would hold for the
+        # lanes of every decode step (summed over window groups), and
+        # those of them that the rings do not hold
+        self.window_blocks_whole = 0
+        self.window_blocks_unheld = 0
+        # the model's own per-step counters (decode_fn's third value)
+        self._step_counter_names = tuple(step_counters)
+        self.model_counters: Dict[str, int] = dict.fromkeys(
+            self._step_counter_names, 0
+        )
         self.spec_proposed = 0
         self.spec_accepted = 0
         # full prompt blocks demanded across admissions — with
@@ -678,11 +758,11 @@ class LlmEngine:
         one possibly mid-prefill — so no consumer hangs and no KV block
         leaks. Idempotent (free is; fail on a done sequence is inert)."""
         if self._admitting is not None:
-            self.allocator.free(self._admitting.seq_id)
+            self._free_blocks(self._admitting)
             self._admitting.fail(error)
             self._admitting = None
         for seq in self._running:
-            self.allocator.free(seq.seq_id)
+            self._free_blocks(seq)
             seq.fail(error)
         self._running.clear()
         items = self._waiting.scan()
@@ -724,7 +804,7 @@ class LlmEngine:
         survivors: List[Sequence] = []
 
         def triage(seq: Sequence) -> None:
-            self.allocator.free(seq.seq_id)
+            self._free_blocks(seq)
             seq.blocks = []
             seq.shared_blocks = 0
             seq.page_table[:] = TRASH_BLOCK
@@ -848,6 +928,11 @@ class LlmEngine:
             "kv_blocks_in_use": self.allocator.blocks_in_use,
             "kv_blocks_total": self.allocator.capacity,
             "kv_blocks_shared": self.allocator.blocks_shared,
+            # every cache group's blocks in use, in the groups' order
+            "kv_blocks_in_use_by_group": self._blocks_in_use_by_group(),
+            "window_blocks_whole": self.window_blocks_whole,
+            "window_blocks_unheld": self.window_blocks_unheld,
+            **self.model_counters,
             "block_size": self.allocator.block_size,
             "steps": self.steps,
             "tokens_generated": self.tokens_generated,
@@ -880,6 +965,43 @@ class LlmEngine:
             "admitted": self.admitted,
             "queue_wait_ns": self.queue_wait_ns,
         }
+
+    # -- cache groups ---------------------------------------------------------
+
+    def _free_blocks(self, seq: Sequence) -> None:
+        """Give back what ``seq`` holds in every cache group."""
+        self.allocator.free(seq.seq_id)
+        for _, _, ring_allocator in self._windows:
+            ring_allocator.free(seq.seq_id)
+        seq.rings = []
+
+    def _blocks_in_use_by_group(self) -> List[int]:
+        in_use = [self.allocator.blocks_in_use] * self._n_groups
+        for index, _, ring_allocator in self._windows:
+            in_use[index] = ring_allocator.blocks_in_use
+        return in_use
+
+    def _group_tables(self, full: np.ndarray, seqs: List[Sequence],
+                      last_positions) -> np.ndarray:
+        """The tables a device call takes: ``full`` itself (``[..., NB]``)
+        for a one-group model, else one such array a group, stacked in
+        the groups' order. A window group's rows are written from the
+        sequences' rings for the block of each ``last_positions`` entry
+        (the newest position the call reads or writes)."""
+        if not self._windows:
+            return full
+        rows = full.reshape(-1, full.shape[-1])
+        tables = np.zeros((self._n_groups,) + rows.shape, dtype=np.int32)
+        tables[self._full_group] = rows
+        last_blocks = [
+            int(p) // self.allocator.block_size for p in last_positions
+        ]
+        for window, (index, _, _) in enumerate(self._windows):
+            tables[index, : len(seqs)] = window_tables(
+                [seq.rings[window] for seq in seqs], last_blocks,
+                rows.shape[1],
+            )
+        return tables.reshape((self._n_groups,) + full.shape)
 
     # -- step loop -----------------------------------------------------------
 
@@ -958,7 +1080,7 @@ class LlmEngine:
         if any(seq.cancelled for seq in self._running):
             for seq in self._running:
                 if seq.cancelled:
-                    self.allocator.free(seq.seq_id)
+                    self._free_blocks(seq)
                     seq.state = _DONE
             self._running = [s for s in self._running if not s.cancelled]
 
@@ -1032,6 +1154,12 @@ class LlmEngine:
             seq.shared_blocks = matched
             seq.page_table[:] = TRASH_BLOCK
             seq.page_table[: len(blocks)] = blocks
+            # a window pool holds a ring for each of max_active
+            # sequences: this one's is free
+            seq.rings = [
+                ring_allocator.allocate(seq.seq_id, ring)
+                for _, ring, ring_allocator in self._windows
+            ]
             # visible to _fail_all while the prefill await is in flight:
             # the sequence owns blocks but is in neither queue nor batch.
             # Deliberately NOT cleared in a finally — on cancellation or
@@ -1095,7 +1223,7 @@ class LlmEngine:
         logits, self._pages = await self._run_device(
             self._prefill,
             tokens,
-            seq.page_table,
+            self._group_tables(seq.page_table, [seq], [len(context) - 1]),
             self._pages,
             len(suffix) - 1,
             start,
@@ -1177,7 +1305,7 @@ class LlmEngine:
         blocks NOW; it resumes later by re-prefilling prompt+generated
         (tokens already streamed stay streamed — deterministic greedy
         decode regenerates the identical cache)."""
-        self.allocator.free(victim.seq_id)
+        self._free_blocks(victim)
         victim.blocks = []
         victim.shared_blocks = 0
         victim.page_table[:] = TRASH_BLOCK
@@ -1227,7 +1355,7 @@ class LlmEngine:
                         # can never fit would drain the whole batch
                         # first, and preempt-and-retry on itself would
                         # loop forever.
-                        allocator.free(seq.seq_id)
+                        self._free_blocks(seq)
                         self._running.remove(seq)
                         seq.fail(
                             CacheCapacityError(
@@ -1280,6 +1408,9 @@ class LlmEngine:
             positions[i] = seq.position
             page_tables[i] = seq.page_table[:nb]
             self.attn_blocks_live += len(seq.blocks)
+            for _, ring, _ in self._windows:
+                self.window_blocks_whole += len(seq.blocks)
+                self.window_blocks_unheld += max(0, len(seq.blocks) - ring)
             # COW invariant: the block this lane is about to write must
             # be exclusively owned (shared prefix blocks are read-only;
             # growth always lands in fresh blocks). A violation means
@@ -1293,13 +1424,21 @@ class LlmEngine:
                 )
         laps = self._laps
         laps.enter("dispatch")
-        logits, self._pages = await self._run_device(
-            self._decode, tokens, positions, page_tables, self._pages
+        logits, self._pages, *counted = await self._run_device(
+            self._decode, tokens, positions,
+            self._group_tables(page_tables, batch, positions[:n]),
+            self._pages,
         )
         laps.enter("wait")
         _wait_ready(logits)
         laps.enter("readback")
         logits_rows = np.asarray(logits)[:n]
+        if counted:
+            # computed by the same program: ready with the logits
+            for name, value in zip(
+                self._step_counter_names, np.asarray(counted[0]).tolist()
+            ):
+                self.model_counters[name] += value
         self.steps += 1
         laps.enter("sample")
         live = [
@@ -1535,7 +1674,7 @@ class LlmEngine:
             )
 
     def _finish(self, seq: Sequence) -> None:
-        self.allocator.free(seq.seq_id)
+        self._free_blocks(seq)
         seq.state = _DONE
         self.completed += 1
 

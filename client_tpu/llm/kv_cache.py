@@ -24,12 +24,25 @@ block), and :meth:`free` only returns a block to the pool when its LAST
 reference drops, unpublishing it from the index in the same breath
 (refcount==0 means reclaimed, nothing lingers).
 
+Cache groups (``models/engine_model.py``): a model whose layers keep
+their K/V in different ways has one pool, one allocator and one
+page-table row a sequence PER GROUP. The full group is everything above.
+A window group holds a fixed RING of blocks a sequence
+(:func:`window_ring_blocks`), claimed whole at admission and returned
+whole on free; logical block ``j`` lives in ring entry ``j % len(ring)``,
+and :func:`window_tables` writes the row a window layer is handed: the
+ring's blocks at the last ``len(ring)`` logical columns, the trash block
+at every column wholly behind the window, which is therefore neither
+held nor read.
+
 Pure bookkeeping: no clocks, no jax, single-owner (the engine's step
 loop) — no locks.
 """
 
 import hashlib
 from typing import Dict, Iterable, List, Sequence, Tuple
+
+import numpy as np
 
 from client_tpu.utils import InferenceServerException
 
@@ -40,6 +53,29 @@ TRASH_BLOCK = 0
 
 # chain seed: makes the empty-prefix digest explicit
 _CHAIN_SEED = b"kv-block-chain"
+
+
+def window_ring_blocks(window: int, block_size: int) -> int:
+    """Blocks a window of ``window`` tokens can touch at once: a span of
+    W slots starts anywhere in a block, so ``ceil(W / bs) + 1``."""
+    return -(-int(window) // int(block_size)) + 1
+
+
+def window_tables(rings, last_blocks, width: int) -> np.ndarray:
+    """Page-table rows ``[B, width]`` of a window group: row ``b`` holds
+    ``rings[b][j % R]`` at logical column ``j`` for the ``R`` columns up
+    to ``last_blocks[b]`` (the block of the newest position, which the
+    step writes), and the trash block everywhere else."""
+    rings = np.asarray(rings, dtype=np.int32).reshape(len(last_blocks), -1)
+    held = rings.shape[1]
+    columns = (np.asarray(last_blocks, dtype=np.int64)[:, None]
+               - np.arange(held - 1, -1, -1)[None, :])
+    lanes = np.broadcast_to(np.arange(len(rings))[:, None], columns.shape)
+    live = (columns >= 0) & (columns < width)
+    tables = np.zeros([len(rings), width], dtype=np.int32)
+    tables[lanes[live], columns[live]] = rings[lanes[live],
+                                               columns[live] % held]
+    return tables
 
 
 class CacheCapacityError(InferenceServerException):

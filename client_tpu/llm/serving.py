@@ -27,6 +27,14 @@ class LlmEngineModel(Model):
     :class:`client_tpu.models.serving.LlmDecodeModel` but backed by the
     shared engine — concurrent generations interleave at every decode
     step rather than running serial single-sequence loops.
+
+    The decoder behind it is an ``EngineModel``
+    (``models/engine_model.py``; ``model=``, default the Llama family's):
+    the functions the four jitted programs call and the cache groups
+    its layers fall into. A model that lacks the part a requested
+    feature needs (``verify`` for speculation, ``prefill_suffix`` for
+    prefix sharing, ``param_specs`` for ``tp > 1``) fails its load with
+    an error that names the part.
     """
 
     decoupled = True
@@ -64,9 +72,18 @@ class LlmEngineModel(Model):
         draft_config=None,
         draft_params=None,
         tp: int = 1,
+        model=None,
     ):
-        from client_tpu.models import llama
+        if model is None:
+            from client_tpu.models import llama
 
+            model = llama.ENGINE_MODEL
+            config = config or llama.LlamaConfig.tiny(max_seq_len=512)
+        elif config is None:
+            raise ValueError(
+                f"model family '{model.name}' needs its config= given"
+            )
+        self._model = model
         self.name = name
         # tensor-parallel width: tp > 1 shards params and the paged KV
         # pool over a "tp" mesh axis resolved against the GLOBAL device
@@ -84,7 +101,7 @@ class LlmEngineModel(Model):
             self.speculation = dict(type(self).speculation)
         self._draft_config = draft_config
         self._draft_params = draft_params
-        self._config = config or llama.LlamaConfig.tiny(max_seq_len=512)
+        self._config = config
         if engine_config is None:
             # default pool: 8 full-length sequences' worth of blocks —
             # small enough that sustained overload exercises the
@@ -116,11 +133,11 @@ class LlmEngineModel(Model):
         # the first warmup and re-attached across engine swaps
         self._recovery = None
 
-    def _build_device_fns(self, params, config, engine_config, attn,
-                          attn_mq, donate):
-        """The engine's jitted device callables for one attention
-        implementation: (prefill, decode, decode_multi). ``prefill``
-        routes start==0 (no shared prefix) through the untouched
+    def _build_device_fns(self, params, config, engine_config, kernels,
+                          donate):
+        """The engine's jitted device callables for one kernel choice
+        (``engine_model.Kernels``): (prefill, decode, decode_multi).
+        ``prefill`` routes start==0 (no shared prefix) through the untouched
         full-prompt path and block-aligned suffixes through
         ``prefill_suffix_into_pages`` with a STATIC power-of-two
         prefix-gather bucket (bounded recompiles, one program per
@@ -136,8 +153,7 @@ class LlmEngineModel(Model):
         kv-head sharding end to end."""
         import jax
 
-        from client_tpu.models import llama
-
+        model = self._model
         plan = self.mesh_plan
         jit_out = {}
         rep = None
@@ -166,31 +182,29 @@ class LlmEngineModel(Model):
         # constants — and the argument form is identical for the
         # single-process case
         def llm_prefill(params_, tokens, page_table, pages, last_index):
-            return llama.prefill_into_pages(
-                params_, tokens, page_table, pages, last_index, config
+            return model.prefill(
+                params_, tokens, page_table, pages, last_index, config,
+                kernels,
             )
 
         def llm_prefill_suffix(params_, tokens, page_table, pages,
                                last_index, start_index, prefix_blocks):
-            return llama.prefill_suffix_into_pages(
+            return model.prefill_suffix(
                 params_, tokens, page_table, pages, last_index,
-                start_index, prefix_blocks, config,
+                start_index, prefix_blocks, config, kernels,
             )
 
         def llm_decode(params_, tokens, positions, page_tables, pages):
-            if attn is None:
-                return llama.decode_step_paged(
-                    params_, tokens, positions, page_tables, pages, config
-                )
-            return llama.decode_step_paged_attn(
-                params_, tokens, positions, page_tables, pages, config, attn
+            return model.decode(
+                params_, tokens, positions, page_tables, pages, config,
+                kernels,
             )
 
         def llm_verify(params_, tokens, positions, lengths, page_tables,
                        pages):
-            return llama.decode_step_paged_multi(
+            return model.verify(
                 params_, tokens, positions, lengths, page_tables, pages,
-                config, attn_mq,
+                config, kernels,
             )
 
         donate_kw = {"donate_argnums": (3,)} if donate else {}
@@ -230,7 +244,7 @@ class LlmEngineModel(Model):
             )
 
         decode_multi = None
-        if attn_mq is not None:
+        if kernels.attn_mq is not None:
             donate_kw = {"donate_argnums": (5,)} if donate else {}
             decode_multi_jit = jax.jit(llm_verify, **donate_kw, **jit_out)
 
@@ -248,10 +262,11 @@ class LlmEngineModel(Model):
         the head counts don't divide or the devices aren't there."""
         from client_tpu.parallel import TP_AXIS, sharding as mesh_sharding
 
-        if config.n_heads % self.tp or config.n_kv_heads % self.tp:
+        n_heads, n_kv_heads = self._model.heads(config)
+        if n_heads % self.tp or n_kv_heads % self.tp:
             raise InferenceServerException(
-                f"tp={self.tp} must divide n_heads={config.n_heads} and "
-                f"n_kv_heads={config.n_kv_heads}"
+                f"tp={self.tp} must divide n_heads={n_heads} and "
+                f"n_kv_heads={n_kv_heads}"
             )
         try:
             spec = mesh_sharding.MeshSpec.parse({"axes": {TP_AXIS: self.tp}})
@@ -263,18 +278,17 @@ class LlmEngineModel(Model):
             raise InferenceServerException(str(e)) from e
 
     def _shard_params(self, params, config, plan):
-        """Place the param pytree onto the tp mesh per
-        ``llama.param_specs`` (global placement: works whether or not
-        the mesh spans processes)."""
+        """Place the param pytree onto the tp mesh per the model's
+        ``param_specs`` (global placement: works whether or not the mesh
+        spans processes)."""
         import jax
         from jax.sharding import PartitionSpec
 
-        from client_tpu.models import llama
         from client_tpu.parallel.executor import place_global
 
         shardings = jax.tree_util.tree_map(
             lambda entries: plan.sharding(*entries),
-            llama.param_specs(config),
+            self._model.param_specs(config),
             is_leaf=lambda node: isinstance(node, PartitionSpec),
         )
         return jax.tree_util.tree_map(
@@ -299,12 +313,32 @@ class LlmEngineModel(Model):
     def warmup(self) -> None:
         import jax
 
-        from client_tpu.models import llama, paged_attention
+        from client_tpu.models import paged_attention
+        from client_tpu.models.engine_model import Kernels
 
-        config = self._config
-        if self._params is None:
-            self._params = llama.init_params(jax.random.PRNGKey(0), config)
+        config, model = self._config, self._model
         engine_config = self.engine_config
+        # kernel selection: env override > platform. The choice is final —
+        # the probes below compile and run the smallest shapes the engine
+        # serves, and a kernel that cannot serve this host fails the LOAD
+        # with the compiler's message (never a quiet step down to another
+        # implementation), as does a model with no path for the choice.
+        # It reaches every program of the model as one `Kernels` and is
+        # reported in the model config.
+        name, attn = paged_attention.resolve_decode_attention(
+            os.environ.get("CLIENT_TPU_LLM_KERNEL"), jax.default_backend()
+        )
+        missing = model.missing_for(
+            speculation=self.speculation is not None,
+            prefix_sharing=engine_config.prefix_sharing,
+            tp=self.tp,
+            kernel=name,
+        )
+        if missing is not None:
+            raise InferenceServerException(missing)
+        if self._params is None:
+            self._params = model.init_params(jax.random.PRNGKey(0), config)
+        engine_config.cache_groups = tuple(model.cache_groups(config))
         params = self._params
         plan = None
         if self.tp > 1:
@@ -323,17 +357,9 @@ class LlmEngineModel(Model):
         # step); the CPU backend does not implement donation and warns,
         # so only donate on real accelerators.
         donate = jax.default_backend() != "cpu"
-        # kernel selection: env override > platform. The choice is final —
-        # the probes below compile and run the smallest shapes the engine
-        # serves, and a kernel that cannot serve this host fails the LOAD
-        # with the compiler's message (never a quiet step down to another
-        # implementation). The choice is reported in the model config.
-        name, attn = paged_attention.resolve_decode_attention(
-            os.environ.get("CLIENT_TPU_LLM_KERNEL"), jax.default_backend()
-        )
         if name == "standin":
-            # inline attention of llama.decode_step_paged, plain XLA
-            # throughout: left to GSPMD propagation under tp
+            # the model's own inline attention, plain XLA throughout:
+            # left to GSPMD propagation under tp
             attn = None
         # speculative verify rides the SAME kernel choice (decode and
         # verify must agree numerically): every implementation has a
@@ -353,12 +379,19 @@ class LlmEngineModel(Model):
                 attn_mq, plan.mesh, multi_query=True
             )
         max_blocks = engine_config.max_blocks_per_seq
-        table = np.zeros([max_blocks], dtype=np.int32)
-        prefill, decode, decode_multi = self._build_device_fns(
-            params, config, engine_config, attn, attn_mq, donate
+        n_groups = len(engine_config.cache_groups)
+        # one table row a cache group, stacked when there are several
+        table = np.zeros(
+            ([n_groups] if n_groups > 1 else []) + [max_blocks],
+            dtype=np.int32,
         )
-        pages = llama.init_kv_pages(
-            config, engine_config.num_blocks, engine_config.block_size
+        prefill, decode, decode_multi = self._build_device_fns(
+            params, config, engine_config,
+            Kernels(name, attn, attn_mq), donate,
+        )
+        pages = model.init_pages(
+            config, engine_config.group_num_blocks(),
+            engine_config.block_size,
         )
         if plan is not None:
             pages = self._shard_pages(pages, plan)
@@ -393,9 +426,9 @@ class LlmEngineModel(Model):
                 logits, pages = decode(
                     np.zeros([1], dtype=np.int32),
                     np.zeros([1], dtype=np.int32),
-                    table[None, :nb],
+                    table[..., None, :nb],
                     pages,
-                )
+                )[:2]
             if decode_multi is not None:
                 # probe the verify shape too (T=2: one real token + one
                 # draft) — all writes land in the trash block
@@ -456,6 +489,7 @@ class LlmEngineModel(Model):
             model_name=self.name,
             decode_multi_fn=decode_multi,
             proposer=proposer,
+            step_counters=model.step_counters,
         )
         self._core = None  # rebind metrics/executor after a reload
         self._wire_recovery()

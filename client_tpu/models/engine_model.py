@@ -1,0 +1,115 @@
+"""The seam between the LLM engine and a decoder it serves.
+
+``LlmEngineModel`` (``llm/serving.py``) is built over an
+:class:`EngineModel`: the functions the engine's four jitted programs
+call, and the cache groups the model's layers fall into. The engine,
+its allocator and its front-ends know nothing else of a model, and
+nothing under ``llm/`` branches on which model it is.
+
+A cache group is a set of layers whose K/V a sequence keeps in the same
+way: ``full`` layers keep every block of the context, ``window`` layers
+only the blocks a sliding window of ``window`` tokens can still see.
+Each group has a block pool of its own and a sequence has one
+page-table row a group; a model with one group gets its tables as
+``[max_blocks]`` / ``[B, NB]``, a model with several as ``[G, ...]``, in
+the order of :attr:`EngineModel.cache_groups`.
+
+Which kernels run is chosen once, at load (``llm/serving.py``, from
+``CLIENT_TPU_LLM_KERNEL`` or the platform), and handed to every program
+of the model as one :class:`Kernels`; a model says in
+:attr:`EngineModel.kernels` which choices it has a path for and is
+refused the others at load. No model picks a device path by itself.
+
+The optional parts are what the engine's optional features need:
+``prefill_suffix`` copy-on-write prefix sharing, ``verify`` speculative
+decoding, ``param_specs`` tensor parallelism. A model that lacks one is
+refused the feature at load, by the name of the missing part.
+"""
+
+import dataclasses
+from typing import Any, Callable, Optional, Sequence, Tuple
+
+FULL, WINDOW = "full", "window"
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheGroup:
+    """``kind`` :data:`FULL` or :data:`WINDOW`; ``layers`` the indices of
+    the model's layers in the group; ``window`` the tokens a
+    :data:`WINDOW` layer's query sees, itself included (the engine holds
+    ``kv_cache.window_ring_blocks`` blocks a sequence for it)."""
+
+    kind: str
+    layers: Tuple[int, ...]
+    window: Optional[int] = None
+
+
+@dataclasses.dataclass(frozen=True)
+class Kernels:
+    """The load-time kernel choice. ``name`` is one of
+    ``paged_attention.KERNELS``: ``pallas`` (every Pallas kernel
+    compiled by Mosaic), ``pallas_interpret`` (the same kernels under
+    the Pallas interpreter), ``fused_xla`` and ``standin`` (plain XLA,
+    no Pallas kernel anywhere). ``attn`` / ``attn_mq`` are that choice's
+    paged attention and its multi-query twin (wrapped per shard under
+    ``tp``); ``attn`` is None for ``standin``, whose attention is the
+    model's own inline one, ``attn_mq`` None without speculation."""
+
+    name: str
+    attn: Optional[Callable] = None
+    attn_mq: Optional[Callable] = None
+
+
+@dataclasses.dataclass(frozen=True)
+class EngineModel:
+    """What a decoder gives the engine. Signatures, with ``tables`` as
+    the module docstring has them and ``kernels`` a :class:`Kernels`:
+
+    ``init_params(key, config) -> params``
+    ``cache_groups(config) -> [CacheGroup]``
+    ``init_pages(config, num_blocks: [int per group], block_size) -> pages``
+    ``prefill(params, tokens[1, L], tables, pages, last_index, config,
+    kernels) -> (logits[1, V], pages)``
+    ``decode(params, tokens[B], positions[B], tables, pages, config,
+    kernels) -> (logits[B, V], pages[, counters])`` where ``counters``
+    is an int32 vector named by ``step_counters``, summed by the engine
+    into its ``stats()``
+    ``prefill_suffix(params, tokens, tables, pages, last_index,
+    start_index, prefix_blocks, config, kernels)``, ``verify(params,
+    tokens[B, T], positions, lengths, tables, pages, config, kernels)``,
+    ``param_specs(config)``: optional, see the module docstring.
+    ``heads(config) -> (n_heads, n_kv_heads)``: what ``tp`` must divide.
+    ``kernels``: the :attr:`Kernels.name` s the model has a path for.
+    """
+
+    name: str
+    init_params: Callable
+    cache_groups: Callable[[Any], Sequence[CacheGroup]]
+    init_pages: Callable
+    prefill: Callable
+    decode: Callable
+    prefill_suffix: Optional[Callable] = None
+    verify: Optional[Callable] = None
+    param_specs: Optional[Callable] = None
+    heads: Optional[Callable] = None
+    step_counters: Tuple[str, ...] = ()
+    kernels: Tuple[str, ...] = (
+        "pallas", "pallas_interpret", "fused_xla", "standin")
+
+    def missing_for(self, *, speculation: bool, prefix_sharing: bool,
+                    tp: int, kernel: str) -> Optional[str]:
+        """The first feature asked for that this model has no part for,
+        as a sentence for a load failure; None if it has them all."""
+        if kernel not in self.kernels:
+            return (f"model family '{self.name}' has no '{kernel}' path "
+                    f"(it runs {', '.join(self.kernels)})")
+        wanted = (
+            (speculation, "speculation", "verify"),
+            (prefix_sharing, "prefix_sharing=True", "prefill_suffix"),
+            (tp > 1, f"tp={tp}", "param_specs"),
+        )
+        for asked, feature, part in wanted:
+            if asked and getattr(self, part) is None:
+                return (f"model family '{self.name}' gives no `{part}`, "
+                        f"which {feature} needs")
+        return None

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from client_tpu.models.engine_model import FULL, CacheGroup, EngineModel
 from client_tpu.parallel import DP_AXIS, SP_AXIS, TP_AXIS
 from client_tpu.parallel.ring_attention import reference_attention, ring_attention
 
@@ -736,3 +737,41 @@ def generate(
         keys,
     )
     return tokens.T  # [B, max_new_tokens]
+
+
+# ---------------------------------------------------------------------------
+# the engine's seam (models/engine_model.py)
+# ---------------------------------------------------------------------------
+
+
+def _engine_decode(params, tokens, positions, page_tables, pages, config,
+                   kernels):
+    if kernels.attn is None:  # standin: the inline attention, plain XLA
+        return decode_step_paged(
+            params, tokens, positions, page_tables, pages, config
+        )
+    return decode_step_paged_attn(
+        params, tokens, positions, page_tables, pages, config, kernels.attn
+    )
+
+
+ENGINE_MODEL = EngineModel(
+    name="llama",
+    init_params=init_params,
+    # every layer keeps every block: one full group
+    cache_groups=lambda config: [
+        CacheGroup(FULL, tuple(range(config.n_layers)))
+    ],
+    init_pages=lambda config, num_blocks, block_size: init_kv_pages(
+        config, num_blocks[0], block_size
+    ),
+    # the prefills are plain XLA whatever the kernel choice
+    prefill=lambda *args: prefill_into_pages(*args[:-1]),
+    decode=_engine_decode,
+    prefill_suffix=lambda *args: prefill_suffix_into_pages(*args[:-1]),
+    verify=lambda *args: decode_step_paged_multi(
+        *args[:-1], args[-1].attn_mq
+    ),
+    param_specs=param_specs,
+    heads=lambda config: (config.n_heads, config.n_kv_heads),
+)

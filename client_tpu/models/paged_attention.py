@@ -146,7 +146,40 @@ def paged_attention_standin_mq(q, k_pages, v_pages, page_tables, positions):
 # ---------------------------------------------------------------------------
 
 
-def paged_attention_fused_xla(q, k_pages, v_pages, page_tables, positions):
+def _pool_shape(k_pages, v_pages, kv_heads):
+    """(block size, kv heads, V row size) of pools ``[N, bs, KV, D]`` or,
+    with ``kv_heads`` given, ``[N, bs*KV, D]`` (row ``t*KV + h``). The
+    flat form is for models whose KV is under the 8 sublanes of a tile:
+    ``[..., 4, D]`` is padded to 8 rows in HBM, twice the bytes, and
+    re-viewing it flat is then a copy of the pool."""
+    if kv_heads is None:
+        return k_pages.shape[1], k_pages.shape[2], v_pages.shape[-1]
+    return k_pages.shape[1] // kv_heads, kv_heads, v_pages.shape[-1]
+
+
+def _window_valid(valid, slots, positions, window):
+    """``valid`` and, under a sliding ``window``, ``slot > position -
+    window``: a query sees its own position and the window - 1 before."""
+    if window is None:
+        return valid
+    return valid & (slots > positions - window)
+
+
+def _softmax_with_sink(scores, sink):
+    """Softmax over the last axis; ``sink`` (broadcastable to
+    ``scores[..., 0]``) is one more logit a row that adds to the
+    denominator and has no value row."""
+    if sink is None:
+        return jax.nn.softmax(scores, axis=-1)
+    sink = sink.astype(jnp.float32)[..., None]
+    m = jnp.maximum(scores.max(axis=-1, keepdims=True), sink)
+    p = jnp.exp(scores - m)
+    return p / (p.sum(axis=-1, keepdims=True) + jnp.exp(sink - m))
+
+
+def paged_attention_fused_xla(q, k_pages, v_pages, page_tables, positions,
+                              *, window=None, sink=None, scale=None,
+                              kv_heads=None):
     """One fused XLA computation over the gathered pages.
 
     Head layout matches ``_repeat_kv`` (head ``k*g + r`` reads kv head
@@ -159,23 +192,30 @@ def paged_attention_fused_xla(q, k_pages, v_pages, page_tables, positions):
     (batch, kv) dims, which measures ~25% faster than contracting the
     ``[B, S, KV, D]`` gather layout in place (PERF.md PR-14)."""
     b, h, d = q.shape
-    _, bs, kv, _ = k_pages.shape
+    bs, kv, dv = _pool_shape(k_pages, v_pages, kv_heads)
     g = h // kv
     s = page_tables.shape[1] * bs
     k_ctx = k_pages[page_tables].reshape(b, s, kv, d).transpose(0, 2, 1, 3)
-    v_ctx = v_pages[page_tables].reshape(b, s, kv, d).transpose(0, 2, 1, 3)
+    v_ctx = v_pages[page_tables].reshape(b, s, kv, dv).transpose(0, 2, 1, 3)
     qg = q.reshape(b, kv, g, d)
     scores = jnp.einsum(
         "bkgd,bksd->bkgs", qg, k_ctx, preferred_element_type=jnp.float32
-    ) / (d ** 0.5)
-    valid = jnp.arange(s)[None, :] <= positions[:, None]  # [B, S]
+    ) * (scale or d ** -0.5)
+    slots = jnp.arange(s)[None, :]
+    valid = _window_valid(
+        slots <= positions[:, None], slots, positions[:, None], window
+    )  # [B, S]
     scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
-    weights = jax.nn.softmax(scores, axis=-1)
+    weights = _softmax_with_sink(
+        scores, None if sink is None else sink.reshape(kv, g)
+    )
     out = jnp.einsum("bkgs,bksd->bkgd", weights, v_ctx.astype(weights.dtype))
-    return out.reshape(b, h, d).astype(q.dtype)
+    return out.reshape(b, h, dv).astype(q.dtype)
 
 
-def paged_attention_fused_xla_mq(q, k_pages, v_pages, page_tables, positions):
+def paged_attention_fused_xla_mq(q, k_pages, v_pages, page_tables, positions,
+                                 *, window=None, sink=None, scale=None,
+                                 kv_heads=None):
     """Multi-query fused XLA variant (the verify-step workhorse off-TPU).
 
     Same layout choices as :func:`paged_attention_fused_xla` — gathered
@@ -184,22 +224,27 @@ def paged_attention_fused_xla_mq(q, k_pages, v_pages, page_tables, positions):
     einsums, so one call scores all K+1 verify positions against the
     same gathered pages instead of gathering K+1 times."""
     b, t, h, d = q.shape
-    _, bs, kv, _ = k_pages.shape
+    bs, kv, dv = _pool_shape(k_pages, v_pages, kv_heads)
     g = h // kv
     s = page_tables.shape[1] * bs
     k_ctx = k_pages[page_tables].reshape(b, s, kv, d).transpose(0, 2, 1, 3)
-    v_ctx = v_pages[page_tables].reshape(b, s, kv, d).transpose(0, 2, 1, 3)
+    v_ctx = v_pages[page_tables].reshape(b, s, kv, dv).transpose(0, 2, 1, 3)
     qg = q.reshape(b, t, kv, g, d)
     scores = jnp.einsum(
         "btkgd,bksd->bkgts", qg, k_ctx, preferred_element_type=jnp.float32
-    ) / (d ** 0.5)
-    valid = jnp.arange(s)[None, None, :] <= positions[:, :, None]  # [B, T, S]
+    ) * (scale or d ** -0.5)
+    slots = jnp.arange(s)[None, None, :]
+    valid = _window_valid(
+        slots <= positions[:, :, None], slots, positions[:, :, None], window
+    )  # [B, T, S]
     scores = jnp.where(valid[:, None, None, :, :], scores, NEG_INF)
-    weights = jax.nn.softmax(scores, axis=-1)
+    weights = _softmax_with_sink(
+        scores, None if sink is None else sink.reshape(kv, g, 1)
+    )
     out = jnp.einsum(
         "bkgts,bksd->btkgd", weights, v_ctx.astype(weights.dtype)
     )
-    return out.reshape(b, t, h, d).astype(q.dtype)
+    return out.reshape(b, t, h, dv).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -223,14 +268,13 @@ def pages_per_tile(block_size: int, kv_heads: int, head_dim: int,
     return max(1, _KV_VMEM_BUDGET // 4 // page_bytes)
 
 
-def _rpa_kernel(kv, scale,
-                tbl_ref, len_ref, q_ref, pos_ref, k_hbm, v_hbm, o_ref,
-                k_buf, v_buf, sems, slot_ref):
+def _rpa_kernel(kv, scale, window, has_sink, *refs):
     """Grid step ``b``: fold sequence ``b``'s live pages, one tile of
     ``P`` pages at a time, into the online softmax of all its query rows.
 
     The pools stay in HBM, viewed ``[N, bs*KV, D]`` (pool row ``t*KV +
-    h`` is token ``t``, kv head ``h``). A tile is ``P`` page copies into
+    h`` is token ``t``, kv head ``h``; K rows are ``Dk`` wide and V rows
+    ``Dv``, which need not be equal). A tile is ``P`` page copies into
     slot ``s`` of ``k_buf`` / ``v_buf`` (``[2, P, bs*KV, D]``), all on
     ``sems[0|1, s]``; while a tile is folded the NEXT tile's copies are
     in flight in the other slot, and the next tile of a sequence's last
@@ -238,7 +282,10 @@ def _rpa_kernel(kv, scale,
     the call is waited for with nothing to do. ``slot_ref`` (SMEM)
     carries the slot across grid steps. The trip count is the
     sequence's own ``cdiv(len, P*bs)``: table columns past it are never
-    read.
+    read. Under a sliding ``window`` a third scalar-prefetched vector
+    gives each sequence's first visible position: the walk starts at the
+    tile that holds it, tiles wholly behind the window are never
+    fetched, and a row sees ``slot > position - window`` only.
 
     Queries arrive as rows ``[KV*M, D]`` (row ``h*M + m``: kv head
     ``h``, ``M`` = query positions x group size). Both contractions are
@@ -250,7 +297,17 @@ def _rpa_kernel(kv, scale,
     transposed and no head repeated. ``pos_ref`` (``[1, KV*M, 1]``
     int32, VMEM) carries each row's validity threshold as a vector:
     SMEM, where the scalar-prefetched table and lengths live, only
-    serves scalar loads."""
+    serves scalar loads. With ``has_sink`` a ``[KV*M, 1]`` float32
+    column holds each row's sink logit: it seeds the running maximum and
+    a denominator of one, a key with no value row."""
+    refs = list(refs)
+    tbl_ref, len_ref = refs[:2]
+    del refs[:2]
+    first_ref = refs.pop(0) if window is not None else None
+    q_ref, pos_ref = refs[:2]
+    del refs[:2]
+    sink_ref = refs.pop(0) if has_sink else None
+    k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, slot_ref = refs
     b = pl.program_id(0)
     n_seqs = pl.num_programs(0)
     nb = tbl_ref.shape[1]
@@ -259,9 +316,17 @@ def _rpa_kernel(kv, scale,
     tile_slots = tile_rows // kv
     rows = q_ref.shape[1]
 
+    def first_tile_of(seq):
+        if first_ref is None:
+            return 0
+        return first_ref[seq] // tile_slots
+
     # at least one tile, whatever the length says: the sequence before
     # has this one's first tile in flight, and somebody has to wait for it
-    n_tiles = jnp.maximum(1, (len_ref[b] + tile_slots - 1) // tile_slots)
+    tile0 = first_tile_of(b)
+    n_tiles = jnp.maximum(
+        tile0 + 1, (len_ref[b] + tile_slots - 1) // tile_slots
+    )
 
     def page_copies(slot, j, page):
         return (
@@ -297,10 +362,10 @@ def _rpa_kernel(kv, scale,
     @pl.when(b == 0)
     def _first_tile():
         slot_ref[0] = 0
-        start_tile(0, 0, 0)
+        start_tile(0, first_tile_of(0), 0)
 
     first_slot = slot_ref[0]
-    q = q_ref[0]  # [rows, D]
+    q = q_ref[0]  # [rows, Dk]
     pos = pos_ref[0]  # [rows, 1]
     column = jax.lax.broadcasted_iota(jnp.int32, (rows, tile_rows), 1)
     row_head = jax.lax.broadcasted_iota(
@@ -311,13 +376,14 @@ def _rpa_kernel(kv, scale,
 
     def fold(i, carry):
         m_prev, l_prev, acc = carry
-        slot = (first_slot + i) % 2
+        slot = (first_slot + i - tile0) % 2
         last = i + 1 == n_tiles
         next_seq = jnp.where(last, b + 1, b)
 
         @pl.when(next_seq < n_seqs)
         def _next_tile():
-            start_tile(next_seq, jnp.where(last, 0, i + 1), 1 - slot)
+            after = first_tile_of(jnp.minimum(b + 1, n_seqs - 1))
+            start_tile(next_seq, jnp.where(last, after, i + 1), 1 - slot)
 
         wait_tile(slot)
         k = k_buf[slot].reshape(tile_rows, -1)
@@ -330,6 +396,8 @@ def _rpa_kernel(kv, scale,
         # position (covers ragged tails, padding lanes, and the trash
         # block alike), in the row's own kv head
         valid = own_head & (slot_in_tile <= pos - i * tile_slots)
+        if window is not None:
+            valid &= slot_in_tile > pos - window - i * tile_slots
         s = jnp.where(valid, s, NEG_INF)
         m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
@@ -342,21 +410,26 @@ def _rpa_kernel(kv, scale,
         )
         return m_new, l_new, acc
 
+    if sink_ref is None:
+        m0 = jnp.full((rows, 1), NEG_INF, jnp.float32)
+        l0 = jnp.zeros((rows, 1), jnp.float32)
+    else:
+        m0 = sink_ref[...]
+        l0 = jnp.ones((rows, 1), jnp.float32)
     _, l, acc = jax.lax.fori_loop(
-        0, n_tiles, fold,
-        (
-            jnp.full((rows, 1), NEG_INF, jnp.float32),
-            jnp.zeros((rows, 1), jnp.float32),
-            jnp.zeros(q.shape, jnp.float32),
-        ),
+        tile0, n_tiles, fold,
+        (m0, l0, jnp.zeros((rows, v_buf.shape[-1]), jnp.float32)),
     )
-    slot_ref[0] = (first_slot + n_tiles) % 2
+    slot_ref[0] = (first_slot + n_tiles - tile0) % 2
     o_ref[0] = (acc / l).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames="interpret")
+@functools.partial(
+    jax.jit, static_argnames=("interpret", "window", "scale", "kv_heads")
+)
 def paged_attention_pallas_mq(q, k_pages, v_pages, page_tables, positions,
-                              *, interpret: bool = False):
+                              *, interpret: bool = False, window=None,
+                              sink=None, scale=None, kv_heads=None):
     """Flash-style multi-query ragged paged attention as a Pallas kernel.
 
     One grid step per sequence. ``page_tables`` and each sequence's
@@ -373,42 +446,68 @@ def paged_attention_pallas_mq(q, k_pages, v_pages, page_tables, positions,
     copy of the queries only; the pools are only re-viewed
     ``[N, bs*KV, D]``.
 
+    ``v_pages`` may hold rows of another size than ``k_pages`` (the
+    output has V's); both are whole numbers of 128 lanes, which is what
+    Mosaic copies, so a model with K rows of 192 pads them (and its
+    queries) with zeros to 256 and gives its own ``scale`` (default
+    ``D ** -0.5``). With ``kv_heads`` the pools come flat,
+    ``[N, bs*KV, D]`` (:func:`_pool_shape`). ``window`` (static) makes each row see only the
+    ``window`` slots up to its position, the walk starting at the tile
+    of the sequence's earliest visible slot; ``sink`` (``[H]``) is one
+    more logit a head in the softmax's denominator. With neither, the
+    program is the one it was without them.
+
     Jitted, so that the layers of a model, which all call it with the
     same shapes, share one trace and one lowering of the kernel: a
     server traces every decode program anew at each start, whatever the
     compile cache holds."""
     b, t, h, d = q.shape
-    n, bs, kv, _ = k_pages.shape
+    n = k_pages.shape[0]
+    bs, kv, dv = _pool_shape(k_pages, v_pages, kv_heads)
     g = h // kv
     rows = kv * t * g
     nb = page_tables.shape[1]
-    pages = min(pages_per_tile(bs, kv, d, k_pages.dtype), nb)
+    pages = min(pages_per_tile(bs, kv, max(d, dv), k_pages.dtype), nb)
     q_rows = (
         q.reshape(b, t, kv, g, d).transpose(0, 2, 1, 3, 4).reshape(b, rows, d)
     )
     positions = positions.astype(jnp.int32)
     row_positions = jnp.tile(jnp.repeat(positions, g, axis=1), (1, kv))
+    prefetch = [page_tables.astype(jnp.int32), positions.max(axis=1) + 1]
+    if window is not None:
+        prefetch.append(jnp.maximum(positions.min(axis=1) - window + 1, 0))
+    row_block = lambda width: pl.BlockSpec(  # noqa: E731
+        (1, rows, width), lambda i, *_: (i, 0, 0))
+    inputs = [q_rows, row_positions[:, :, None]]
+    in_specs = [row_block(d), row_block(1)]
+    if sink is not None:
+        # row k*(T*G) + t*G + gi is head k*G + gi
+        sink_rows = jnp.broadcast_to(
+            sink.astype(jnp.float32).reshape(kv, 1, g), (kv, t, g)
+        ).reshape(rows, 1)
+        inputs.append(sink_rows)
+        in_specs.append(pl.BlockSpec((rows, 1), lambda i, *_: (0, 0)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(prefetch),
         grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, rows, d), lambda i, tbl, lens: (i, 0, 0)),
-            pl.BlockSpec((1, rows, 1), lambda i, tbl, lens: (i, 0, 0)),
+        in_specs=in_specs + [
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, rows, d), lambda i, tbl, lens: (i, 0, 0)),
+        out_specs=row_block(dv),
         scratch_shapes=[
             pltpu.VMEM((2, pages, bs * kv, d), k_pages.dtype),
-            pltpu.VMEM((2, pages, bs * kv, d), v_pages.dtype),
+            pltpu.VMEM((2, pages, bs * kv, dv), v_pages.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((1,), jnp.int32),  # the slot the next tile is in
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_rpa_kernel, kv, 1.0 / (d ** 0.5)),
+        functools.partial(
+            _rpa_kernel, kv, scale or d ** -0.5, window, sink is not None
+        ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q_rows.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, rows, dv), q.dtype),
         # sequence b prefetches sequence b+1's first tile: in order
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)
@@ -416,39 +515,41 @@ def paged_attention_pallas_mq(q, k_pages, v_pages, page_tables, positions,
         interpret=interpret,
         name="paged_attention",
     )(
-        page_tables.astype(jnp.int32), positions.max(axis=1) + 1,
-        q_rows, row_positions[:, :, None],
-        k_pages.reshape(n, bs * kv, d), v_pages.reshape(n, bs * kv, d),
+        *prefetch, *inputs,
+        k_pages.reshape(n, bs * kv, d), v_pages.reshape(n, bs * kv, dv),
     )
     return (
-        out.reshape(b, kv, t, g, d).transpose(0, 2, 1, 3, 4).reshape(b, t, h, d)
+        out.reshape(b, kv, t, g, dv).transpose(0, 2, 1, 3, 4)
+        .reshape(b, t, h, dv)
     )
 
 
 def paged_attention_pallas(q, k_pages, v_pages, page_tables, positions,
-                           *, interpret: bool = False):
+                           *, interpret: bool = False, **masking):
     """Single-query decode: the T=1 case of
     :func:`paged_attention_pallas_mq`."""
     return paged_attention_pallas_mq(
         q[:, None], k_pages, v_pages, page_tables, positions[:, None],
-        interpret=interpret,
+        interpret=interpret, **masking,
     )[:, 0]
 
 
 def paged_attention_pallas_interpret(q, k_pages, v_pages, page_tables,
-                                     positions):
+                                     positions, **masking):
     """The Pallas kernel under the interpreter — CPU-runnable for parity
     tests and for forcing the kernel path off-TPU."""
     return paged_attention_pallas(
-        q, k_pages, v_pages, page_tables, positions, interpret=True
+        q, k_pages, v_pages, page_tables, positions, interpret=True,
+        **masking,
     )
 
 
 def paged_attention_pallas_interpret_mq(q, k_pages, v_pages, page_tables,
-                                        positions):
+                                        positions, **masking):
     """The multi-query Pallas kernel under the interpreter."""
     return paged_attention_pallas_mq(
-        q, k_pages, v_pages, page_tables, positions, interpret=True
+        q, k_pages, v_pages, page_tables, positions, interpret=True,
+        **masking,
     )
 
 

@@ -178,7 +178,7 @@ def test_attention_impls_agree_on_ragged_layouts(b, nb):
 GARBAGE = 3.0e4  # large and finite: what a masked slot may hold
 
 
-def _ragged_case(rng, kv, g, b, nb, t, bs=16, d=128):
+def _ragged_case(rng, kv, g, b, nb, t, bs=16, d=128, dv=None):
     """A float32 pool and the tables the engine would write for ``b``
     lanes of a ``nb``-column bucket, at real page shapes (so that
     ``pages_per_tile`` is what a served model gets). Lane lengths fall
@@ -187,10 +187,12 @@ def _ragged_case(rng, kv, g, b, nb, t, bs=16, d=128):
     (table all trash block, position 0). Every slot no query may see —
     past a lane's last position, and the whole trash block — holds
     ``GARBAGE``; table columns past a lane's last live block are 0.
+    ``dv`` is V's row size where it is not K's.
     Returns (q, k_pages, v_pages, tables, positions[b, t])."""
     from client_tpu.models import paged_attention as pa
 
-    tile = min(pa.pages_per_tile(bs, kv, d, np.float32), nb) * bs
+    dv = dv or d
+    tile = min(pa.pages_per_tile(bs, kv, max(d, dv), np.float32), nb) * bs
     wanted = [tile + 1, tile - 1, tile, 1, nb * bs, 2 * tile + 1, tile + bs]
     lengths = [
         min(max(1, wanted[i % len(wanted)]), nb * bs) for i in range(b)
@@ -199,9 +201,8 @@ def _ragged_case(rng, kv, g, b, nb, t, bs=16, d=128):
         lengths[len(wanted):] = rng.integers(
             1, nb * bs + 1, size=b - len(wanted)
         ).tolist()
-    shape = (1 + b * nb, bs, kv, d)
-    k_pages = np.full(shape, GARBAGE, dtype=np.float32)
-    v_pages = np.full(shape, -GARBAGE, dtype=np.float32)
+    k_pages = np.full((1 + b * nb, bs, kv, d), GARBAGE, dtype=np.float32)
+    v_pages = np.full((1 + b * nb, bs, kv, dv), -GARBAGE, dtype=np.float32)
     tables = np.zeros((b, nb), dtype=np.int32)
     positions = np.zeros((b, t), dtype=np.int32)
     for i, length in enumerate(lengths):
@@ -210,10 +211,12 @@ def _ragged_case(rng, kv, g, b, nb, t, bs=16, d=128):
         owned = (length + bs - 1) // bs
         blocks = 1 + i * nb + np.arange(owned)
         tables[i, :owned] = blocks
-        live = rng.normal(size=(2, owned * bs, kv, d)).astype(np.float32)
-        live[:, length:] = GARBAGE
-        k_pages[blocks] = live[0].reshape(owned, bs, kv, d)
-        v_pages[blocks] = live[1].reshape(owned, bs, kv, d)
+        live_k = rng.normal(size=(owned * bs, kv, d)).astype(np.float32)
+        live_v = rng.normal(size=(owned * bs, kv, dv)).astype(np.float32)
+        live_k[length:] = GARBAGE
+        live_v[length:] = -GARBAGE
+        k_pages[blocks] = live_k.reshape(owned, bs, kv, d)
+        v_pages[blocks] = live_v.reshape(owned, bs, kv, dv)
         # verify rows: the last t positions of the context, clamped as
         # the engine clamps padding rows (and contexts shorter than t)
         first = max(0, length - t)
@@ -260,6 +263,88 @@ def test_pallas_tiles_match_standin_on_ragged_lengths(kv, g, b, nb, t):
     # a padding lane reads the trash block's one visible slot: GARBAGE
     scale = np.maximum(1.0, np.abs(ref).max(axis=-1, keepdims=True))
     assert (np.abs(out - ref) / scale).max() <= 1e-5
+
+
+MASKING_CASES = [
+    # (kv, g, batch, nb, t, window, sink): the two cache groups of
+    # mimo_v2_flash (K rows of 192, V rows of 128; 64 query heads over 4
+    # and over 8 kv heads), each new argument alone and all together
+    (4, 16, 3, 24, 1, None, False), (4, 16, 1, 8, 1, None, True),
+    (8, 8, 16, 24, 1, 128, True), (8, 8, 3, 24, 1, 128, False),
+    (8, 8, 3, 6, 1, 128, True), (8, 8, 1, 1, 1, 128, True),
+    (8, 8, 3, 24, 5, 128, True), (8, 8, 3, 24, 1, 16, True),
+    (8, 8, 3, 24, 1, 17, False), (4, 16, 3, 8, 5, 40, True),
+]
+
+
+@pytest.mark.parametrize(
+    "kv,g,b,nb,t,window,sink", MASKING_CASES,
+    ids=[f"kv{c[0]}-b{c[2]}-nb{c[3]}-t{c[4]}-w{c[5]}-s{int(c[6])}"
+         for c in MASKING_CASES],
+)
+def test_pallas_window_sink_and_v_size_match_fused_xla(
+        kv, g, b, nb, t, window, sink):
+    """The kernel's three new arguments against the XLA implementation on
+    the ragged layouts: V rows narrower than K rows, a sliding window
+    whose blocks wholly behind it are the trash block in the table (as
+    the engine's window group hands them over) and hold garbage in the
+    pool, and a per-head sink logit. A kernel that fetched a tile behind
+    the window, or was one slot off at either edge of it, reads garbage
+    and fails."""
+    from client_tpu.models import paged_attention as pa
+
+    rng = np.random.default_rng(kv * 1000 + b * 100 + nb + t + (window or 0))
+    q, k_pages, v_pages, tables, positions = _ragged_case(
+        rng, kv, g, b, nb, t, d=256, dv=128
+    )
+    # rows of 192 as the model pads them: zeros in the last 64 sizes of
+    # q (so garbage there in a masked K row still multiplies to 0)
+    q[..., 192:] = 0.0
+    bs = k_pages.shape[1]
+    if window is not None:
+        for i in range(b):
+            first = max(0, int(positions[i].min()) - window + 1)
+            behind = tables[i, : first // bs].copy()
+            tables[i, : first // bs] = 0
+            k_pages[behind[behind > 0]] = GARBAGE
+            v_pages[behind[behind > 0]] = -GARBAGE
+            # and the slots of the first visible block that lie behind
+            # the window of every row
+            block = tables[i, first // bs]
+            if block > 0:
+                k_pages[block, : first % bs] = GARBAGE
+                v_pages[block, : first % bs] = -GARBAGE
+    masking = {"window": window, "scale": 192 ** -0.5}
+    if kv == 4:
+        # the flat pools a model under 8 kv heads keeps
+        k_pages = k_pages.reshape(len(k_pages), bs * kv, -1)
+        v_pages = v_pages.reshape(len(v_pages), bs * kv, -1)
+        masking["kv_heads"] = kv
+    if sink:
+        masking["sink"] = rng.normal(size=(kv * g,)).astype(np.float32) * 3
+    if t == 1:
+        args = (q[:, 0], k_pages, v_pages, tables, positions[:, 0])
+        ref = pa.paged_attention_fused_xla(*args, **masking)
+        out = pa.paged_attention_pallas_interpret(*args, **masking)
+    else:
+        args = (q, k_pages, v_pages, tables, positions)
+        ref = pa.paged_attention_fused_xla_mq(*args, **masking)
+        out = pa.paged_attention_pallas_interpret_mq(*args, **masking)
+    ref, out = np.asarray(ref), np.asarray(out)
+    assert out.shape == ref.shape and out.shape[-1] == 128
+    assert np.isfinite(out).all()
+    # float32 throughout; 3e-5 and not the 1e-5 of the test above: the
+    # online softmax rescales a sink of a few units tile by tile, in
+    # another order than XLA's one pass (1.5e-5 seen)
+    scale = np.maximum(1.0, np.abs(ref).max(axis=-1, keepdims=True))
+    assert (np.abs(out - ref) / scale).max() <= 3e-5
+    if sink:
+        # the sink is in the denominator: without it the rows differ
+        bare = pa.paged_attention_fused_xla_mq(
+            q, k_pages, v_pages, tables, positions,
+            **{**masking, "sink": None},
+        )
+        assert np.abs(np.asarray(bare) - ref.reshape(bare.shape)).max() > 1e-3
 
 
 def test_pages_per_tile_follows_the_shapes_alone():

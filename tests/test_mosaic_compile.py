@@ -40,6 +40,85 @@ CASES = {
 }
 
 
+MASKING_CASES = {
+    # batch, heads, kv heads, table columns, pool blocks, window, sink:
+    # mimo_v2_flash.reason's two cache groups (K rows 192, V rows 128)
+    "mimo-full": (64, 64, 4, 128, 8193, None, False),
+    "mimo-window": (64, 64, 8, 128, 577, 128, True),
+    "mimo-window-one-lane": (1, 64, 8, 8, 577, 128, True),
+}
+
+
+@pytest.mark.parametrize("case", MASKING_CASES)
+def test_mosaic_compiles_unequal_rows_window_and_sink(one_chip, case):
+    """Mosaic's verdict on K rows of 256 (192 padded: it refuses to copy
+    a 192-wide row out of HBM's 128-lane tiles) beside V rows of 128,
+    the window's third prefetched vector and the sink column."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from client_tpu.models import paged_attention as pa
+
+    batch, heads, kv_heads, columns, blocks, window, sink = MASKING_CASES[case]
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = [
+        shaped((batch, heads, 256), jnp.bfloat16),
+        # flat pools: at KV 4 a [.., 4, D] pool is padded to 8 rows in
+        # HBM and its flat view is a copy of the whole pool
+        shaped((blocks, BLOCK * kv_heads, 256), jnp.bfloat16),
+        shaped((blocks, BLOCK * kv_heads, 128), jnp.bfloat16),
+        shaped((batch, columns), jnp.int32),
+        shaped((batch,), jnp.int32),
+    ]
+    masking = {"window": window, "scale": 192 ** -0.5, "kv_heads": kv_heads}
+    fn = functools.partial(pa.paged_attention_pallas, **masking)
+    if sink:
+        args.append(shaped((heads,), jnp.float32))
+        fn = lambda *a: pa.paged_attention_pallas(  # noqa: E731
+            *a[:5], sink=a[5], **masking)
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert "%paged_attention" in text
+    assert f"bf16[{blocks},{BLOCK * kv_heads},256]" in text
+    assert f"bf16[{blocks},{BLOCK * kv_heads},128]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
+
+
+@pytest.mark.parametrize("tokens", [64, 512, 2048])
+def test_mosaic_compiles_the_expert_kernel(one_chip, tokens):
+    """Mosaic's verdict on ``moe_experts`` under the load-time choice
+    ``pallas`` at ``mimo_v2_flash.reason``'s sizes: a decode step's 64
+    lanes (row tiles of 16) and a 512- and a 2,048-token prefill (tiles
+    of 128), 16 held experts of 4096 x 2048 in bf16."""
+    import jax
+    import jax.numpy as jnp
+
+    from client_tpu.models import moe
+
+    d, f, held, top_k = 4096, 2048, (0, 16), 8
+
+    def shaped(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    experts = {"w_gate": shaped((16, d, f)), "w_up": shaped((16, d, f)),
+               "w_down": shaped((16, f, d))}
+    compiled = jax.jit(
+        lambda h, ids, weights, experts: moe.expert_layer(
+            h, ids, weights, experts, held, kernel="pallas")
+    ).lower(shaped((tokens, d)), shaped((tokens, top_k), jnp.int32),
+            shaped((tokens, top_k), jnp.float32), experts).compile()
+    assert "%moe_experts" in compiled.as_text()
+    # the rows gathered by expert and their outputs, no dense pass
+    rows = moe.row_tile(tokens, top_k) * (
+        -(-tokens * top_k // moe.row_tile(tokens, top_k)) + 16)
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 * rows * d
+
+
 @pytest.mark.parametrize("case", CASES)
 def test_mosaic_compiles_the_paged_attention_kernel(one_chip, case):
     import jax

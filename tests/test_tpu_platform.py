@@ -5,9 +5,10 @@
 Covers what the hermetic CPU suite cannot see: the Pallas paged-attention
 kernels COMPILED by Mosaic (the CPU tier only interprets them) against
 the fused XLA reference at the shapes the server serves, a full-width
-decode step through both, the client→server infer path executing on the
-real platform, and the tpu-shm staging round-trip. ``python chip_smoke.py``
-runs this tier on the chip as one of its phases.
+decode step through both, ``mimo_v2_flash.reason``'s attention groups
+and expert layer at the cell's sizes, the client→server infer path
+executing on the real platform, and the tpu-shm staging round-trip.
+``python chip_smoke.py`` runs this tier on the chip as one of its phases.
 """
 
 import asyncio
@@ -273,6 +274,112 @@ def test_full_width_decode_logits_match_through_both_kernels(device):
     # Reading the wrong pages moves them by O(1).
     worst = float(np.abs(pallas - fused).max())
     assert worst <= 2.0 ** -4, f"logits differ by {worst}"
+
+
+# ---------------------------------------------------------------------------
+# mimo_v2_flash.reason's kernels at the cell's shapes
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("group", ["full", "window"])
+def test_compiled_pallas_at_the_mimo_cells_shapes(device, group):
+    """``mimo_v2_flash.reason`` as the kernel sees it: 64 lanes, 64 query
+    heads over K rows of 256 (192 and zeros) and V rows of 128 in flat
+    pools, contexts staggered over 512..2,048; the full group's 4 KV
+    heads over every block, the window group's 8 over a ring of 9
+    blocks a lane behind the engine's own window tables, with a sink."""
+    import jax
+    import jax.numpy as jnp
+
+    from client_tpu.llm.kv_cache import window_ring_blocks, window_tables
+    from client_tpu.models import paged_attention as pa
+
+    batch, heads, columns = 64, 64, 128
+    rng = np.random.default_rng(27)
+    positions = (511 + 24 * np.arange(batch)).astype(np.int32)
+    masking = {"scale": 192 ** -0.5}
+    if group == "full":
+        kv_heads, blocks = 4, 1 + batch * columns
+        tables = (1 + np.arange(batch * columns)).reshape(batch, columns)
+    else:
+        ring = window_ring_blocks(128, BLOCK)
+        kv_heads, blocks = 8, 1 + batch * ring
+        tables = window_tables(
+            1 + np.arange(batch * ring).reshape(batch, ring),
+            positions // BLOCK, columns)
+        masking.update(
+            window=128,
+            sink=jnp.asarray(2 * rng.normal(size=heads), jnp.float32))
+    masking["kv_heads"] = kv_heads
+
+    def rows(shape, stored, real):
+        full = np.zeros(shape + (stored,), np.float32)
+        full[..., :real] = rng.normal(size=shape + (real,))
+        return jnp.asarray(full, jnp.bfloat16)
+
+    q = rows((batch, heads), 256, 192)
+    k_pages = rows((blocks, BLOCK * kv_heads), 256, 192)
+    v_pages = rows((blocks, BLOCK * kv_heads), 128, 128)
+    args = (q, k_pages, v_pages, tables.astype(np.int32), positions)
+    _assert_bf16_close(
+        jax.jit(lambda *a: pa.paged_attention_pallas(*a, **masking))(*args),
+        jax.jit(lambda *a: pa.paged_attention_fused_xla(*a, **masking))(*args),
+        f"mimo's {group} group",
+    )
+
+
+@pytest.mark.parametrize("tokens,kernel", [
+    (64, "pallas"), (512, "pallas"), (2048, "pallas"), (64, "fused_xla"),
+])
+def test_expert_layer_matches_a_dense_pass(device, tokens, kernel):
+    """``moe.expert_layer`` at the cell's sizes (16 held of 256 experts
+    of 4096 x 2048, 8 a token; a decode step's 64 lanes and a 512- and a
+    2,048-token prefill) through the compiled ``moe_experts`` kernel,
+    and a decode step through the plain XLA path, against every held
+    expert run over every token and kept where the router chose it."""
+    import jax
+    import jax.numpy as jnp
+
+    from client_tpu.models import moe
+
+    d, f, held = 4096, 2048, (0, 16)
+    keys = iter(jax.random.split(jax.random.PRNGKey(tokens), 6))
+
+    def normal(shape, scale, dtype=jnp.bfloat16):
+        # drawn on the device: 400M numbers from the host's generator
+        # and their transfer cost this tier minutes
+        return (jax.random.normal(next(keys), shape, jnp.float32)
+                * scale).astype(dtype)
+
+    experts = {"w_gate": normal((16, d, f), d ** -0.5),
+               "w_up": normal((16, d, f), d ** -0.5),
+               "w_down": normal((16, f, d), f ** -0.5)}
+    router = normal((d, 256), d ** -0.5)
+    bias = normal((256,), 0.02, jnp.float32)
+    h = normal((tokens, d), 1.0)
+    ids, weights = jax.jit(lambda h: moe.route(h, router, bias, 8))(h)
+
+    def dense(h, ids, weights):
+        out = jnp.zeros(h.shape, jnp.float32)
+        for e in range(held[1]):
+            share = (weights * (ids == e)).sum(-1, keepdims=True)
+            gate = jax.nn.silu(jnp.dot(
+                h, experts["w_gate"][e], preferred_element_type=jnp.float32))
+            up = jnp.dot(
+                h, experts["w_up"][e], preferred_element_type=jnp.float32)
+            out = out + share * jnp.dot(
+                (gate * up).astype(h.dtype), experts["w_down"][e],
+                preferred_element_type=jnp.float32)
+        return out
+
+    out, counters = jax.jit(lambda *a: moe.expert_layer(
+        *a, experts, held, kernel=kernel))(h, ids, weights)
+    on = np.asarray(ids) < held[1]
+    assert int(counters[0]) == on.sum() > 0
+    _assert_bf16_close(
+        out, jax.jit(dense)(h, ids, weights),
+        f"expert layer, {tokens} tokens, {kernel}",
+    )
 
 
 # ---------------------------------------------------------------------------
