@@ -31,6 +31,13 @@ paged-attention model functions (``models/llama.py``):
   call; accepted tokens stream as multiple queue entries per step.  The
   emitted stream is token-for-token identical to plain decoding (greedy
   and seeded sampling both) — see :meth:`LlmEngine._spec_decode`.
+- **one step ahead of the device**: a greedy decode step's token ids
+  stay on the device. With step k dispatched and its ids unread, the
+  loop builds step k+1 from what it knows without them (positions + 1,
+  blocks grown for them, lanes that end at k left out, a map from each
+  lane to its lane in k), dispatches it, and only then reads, books and
+  streams k's tokens: the host's work runs under the device's. See
+  :class:`LlmEngine` for when the step in flight is consumed first.
 - **lap spans**: the step loop's wall time is tiled by named phase
   (:data:`PHASES`; one clock read at each phase boundary), as monotone
   counters in ``stats()["phase_ns"]`` and as ``engine.<phase>``
@@ -154,6 +161,16 @@ class EngineConfig:
     def max_blocks_per_seq(self) -> int:
         return (self.max_seq_len + self.block_size - 1) // self.block_size
 
+    @property
+    def ids_width(self) -> int:
+        """Width of the token-id vector a decode step returns and the
+        next one takes: the batch bucket of ``max_active``, whatever
+        the step's own bucket, so that a change of batch bucket makes
+        no new program."""
+        from client_tpu.server.models import pad_batch_bucket
+
+        return pad_batch_bucket(self.max_active)
+
     def group_num_blocks(self) -> List[int]:
         """Physical blocks of each cache group's pool, in the groups'
         order (one full group when none is declared)."""
@@ -173,11 +190,14 @@ class EngineConfig:
 #: preemption, building the step's arrays, the COW check, ``_publish``;
 #: ``prefill`` each ``_prefill_one`` (dispatch, device, read-back);
 #: ``propose`` the speculative drafts; ``dispatch`` the device call until
-#: it returns un-waited arrays; ``wait`` blocking on the step's result
-#: (the host's view of device-busy); ``readback`` device-to-host copy
-#: and untiling of the logits; ``sample`` ``_sample_rows``; ``emit``
-#: booking and streaming the tokens, metrics hooks; ``yield`` the
-#: ``asyncio.sleep(0)``: everything else on the event loop.
+#: it returns un-waited arrays; ``wait`` blocked on the result of the
+#: step being consumed, which for a step that ran ahead is what is left
+#: of the device's time once the host's own work is done (no longer the
+#: device's step); ``readback`` the copy to the host of that step's ids
+#: and counters, or of its logits where a lane samples; ``sample``
+#: ``_sample_rows`` (on an all-greedy step it only passes the program's
+#: argmax through); ``emit`` booking and streaming the tokens, metrics hooks;
+#: ``yield`` the ``asyncio.sleep(0)``: everything else on the event loop.
 PHASES = (
     "schedule", "prefill", "propose", "dispatch", "wait", "readback",
     "sample", "emit", "yield",
@@ -195,6 +215,51 @@ def _wait_ready(result: Any) -> None:
     wait = getattr(result, "block_until_ready", None)
     if wait is not None:
         wait()
+
+
+class _Flight:
+    """One dispatched decode step whose tokens are not booked yet: the
+    lanes it ran (``lane_of``: sequence id to lane) and its results as
+    the un-waited device arrays they are."""
+
+    __slots__ = ("batch", "lane_of", "ids", "logits", "counted")
+
+    def __init__(self, batch, ids, logits, counted):
+        self.batch: List["Sequence"] = batch
+        self.lane_of = {seq.seq_id: lane for lane, seq in enumerate(batch)}
+        self.ids = ids
+        self.logits = logits
+        self.counted = counted
+
+
+def _samples(batch) -> bool:
+    """Whether a live lane of ``batch`` draws its token on the host."""
+    return any(
+        seq.temperature > 0.0 for seq in batch if not seq.cancelled
+    )
+
+
+def decode_fn_from_logits(step: Callable) -> Callable:
+    """The engine's ``decode_fn`` over a host function of the plainer
+    shape ``step(tokens[B], positions[B], page_tables, pages) ->
+    (logits[B, V], pages[, counters])``: the token select, the argmax
+    and the padding of the ids in numpy, as the jitted wrapper of
+    ``serving.py::_build_device_fns`` has them on the device. For test
+    doubles and reference loops; see :class:`LlmEngine` for the
+    contract."""
+
+    def decode_fn(prev_ids, lane_map, host_tokens, positions, page_tables,
+                  pages):
+        prev = np.asarray(prev_ids)
+        tokens = np.where(
+            lane_map >= 0, prev[np.maximum(lane_map, 0)], host_tokens
+        ).astype(np.int32)
+        logits, pages, *counted = step(tokens, positions, page_tables, pages)
+        ids = np.zeros_like(prev)
+        ids[: len(tokens)] = np.asarray(logits).argmax(axis=-1)
+        return (ids, logits, pages, *counted)
+
+    return decode_fn
 
 
 def block_bucket(n: int) -> int:
@@ -395,16 +460,56 @@ class LlmEngine:
     start_index) -> (logits[1, V], pages)`` (``tokens`` holds ONLY the
     unshared suffix ``context[start_index:]``; ``last_index`` is its
     local last-token index; ``start_index`` is 0 when nothing matched)
-    and ``decode_fn(tokens[B], positions[B], page_tables[B, NB], pages)
-    -> (logits[B, V], pages)`` (``NB`` is the engine's ragged block
-    bucket — any width up to ``max_blocks_per_seq``) are the injected
-    (jitted) device callables; ``pages`` is opaque to the engine. A
+    and ``decode_fn(prev_ids[W], lane_map[B], host_tokens[B],
+    positions[B], page_tables[B, NB], pages) -> (ids[W], logits[B, V],
+    pages)`` (``NB`` is the engine's ragged block bucket — any width up
+    to ``max_blocks_per_seq``; ``W`` is ``engine_config.ids_width``) are
+    the injected (jitted) device callables; ``pages`` is opaque to the
+    engine. Lane ``i`` of a decode step reads the token
+    ``prev_ids[lane_map[i]]`` where ``lane_map[i] >= 0`` and
+    ``host_tokens[i]`` where it is -1; ``ids[:B]`` is the argmax of the
+    float32 ``logits`` (first index on ties, as ``np.argmax``), int32,
+    zero-padded to ``W``. ``prev_ids`` is the ``ids`` the last call
+    returned, handed back as the (device) array it is, and zeros before
+    the first call; every result may be an un-waited device array, and
+    the engine copies to the host only ``ids`` (and the logits of a step
+    in which a live lane samples). :func:`decode_fn_from_logits` builds
+    such a callable in numpy from a plain logits function. A
     model with several cache groups (``engine_config.cache_groups``: one
     full group, the rest window groups) gets one table row a group,
     stacked in the groups' order: ``page_table[G, max_blocks]`` and
-    ``page_tables[G, B, NB]``. ``decode_fn`` may return a third value, an
+    ``page_tables[G, B, NB]``. ``decode_fn`` may return a fourth value, an
     int32 vector of per-step counters named by ``step_counters``; it is
-    read back with the logits and summed into ``stats()``.
+    read back with the ids and summed into ``stats()``.
+
+    **The step in flight.** A decode step whose live lanes are all greedy
+    is left *in flight* when dispatched: its ids stay on the device. The
+    next iteration builds the following step without them (positions +
+    1, blocks grown for those, lanes that end at the step in flight and
+    cancelled lanes left out, ``lane_map`` naming each lane's place in
+    it), dispatches it with ``prev_ids`` = the ids in flight, and only
+    then waits for, books and streams the step in flight
+    (``stats()["steps_ahead"]`` counts the steps dispatched so). At most
+    one step is unconsumed at any time. The loop decides from its own
+    state, never from a setting, and consumes the step in flight
+    *first* — so that everything below sees ``generated`` and the block
+    lists as a loop that never ran ahead would — before it
+
+    - admits a waiting request or fails one that can never fit
+      (``_admit``: the re-prefill of ``prompt + generated`` after a
+      preemption or an :meth:`adopt`, and ``allocator.publish``);
+    - preempts a victim or fails a sequence because the pool is dry
+      (``_grow``);
+    - parks.
+
+    A step is consumed in the iteration that dispatched it, as before
+    this existed, when a live lane has ``temperature > 0`` (its draw is
+    numpy's over the logits, read back for it) and in a speculative
+    engine (``_propose`` needs host tokens). A step still in flight when
+    the engine closes, is quarantined or fails is dropped unbooked: no
+    token of it was streamed, so a survivor's re-prefilled stream is
+    what it would have been. A device failure therefore surfaces up to
+    one step later, at the next dispatch or at the wait for the ids.
     ``metrics`` implements the ServerMetrics LLM hooks (set_kv_blocks /
     set_llm_sequences / observe_llm_step / observe_llm_preemption /
     observe_prefix_hits / observe_rejection / observe_llm_speculation);
@@ -521,6 +626,13 @@ class LlmEngine:
         self.completed = 0
         self.cancelled_count = 0
         self.expired = 0
+        # the decode step dispatched and not yet booked (see the class
+        # docstring), the newest ids any decode call returned (what the
+        # next call takes as prev_ids), and the steps that were
+        # dispatched while the step before them was still unconsumed
+        self._flight: Optional[_Flight] = None
+        self._ids: Any = np.zeros([engine_config.ids_width], dtype=np.int32)
+        self.steps_ahead = 0
         # decode-step emissions only (prefill first-tokens excluded) and
         # the lane-steps that produced them (one per live lane per
         # step): step_tokens / lane_steps is the tokens-per-step A/B
@@ -757,6 +869,7 @@ class LlmEngine:
         """Free and fail every live sequence — running, waiting, and the
         one possibly mid-prefill — so no consumer hangs and no KV block
         leaks. Idempotent (free is; fail on a done sequence is inert)."""
+        self._flight = None  # dropped unbooked: nothing of it streamed
         if self._admitting is not None:
             self._free_blocks(self._admitting)
             self._admitting.fail(error)
@@ -800,6 +913,9 @@ class LlmEngine:
         )
         self.last_failure = exc
         self._closed = True
+        # a step in flight is dropped unbooked: a survivor resumes from
+        # the tokens it streamed, and re-prefilling those regenerates it
+        self._flight = None
         resumable = self.on_fatal is not None
         survivors: List[Sequence] = []
 
@@ -935,6 +1051,9 @@ class LlmEngine:
             **self.model_counters,
             "block_size": self.allocator.block_size,
             "steps": self.steps,
+            # decode steps dispatched before the step ahead of them was
+            # read: steps_ahead / steps is the share that ran ahead
+            "steps_ahead": self.steps_ahead,
             "tokens_generated": self.tokens_generated,
             "preemptions": self.preemptions,
             "completed": self.completed,
@@ -1031,7 +1150,11 @@ class LlmEngine:
         laps = self._laps
         try:
             while not self._closed:
-                if not self._running and not len(self._waiting):
+                if (
+                    not self._running
+                    and not len(self._waiting)
+                    and self._flight is None
+                ):
                     laps.park()
                     self._wake.clear()
                     await self._wake.wait()
@@ -1039,7 +1162,7 @@ class LlmEngine:
                 laps.enter("schedule")
                 self._prune()
                 await self._admit()
-                if self._running:
+                if self._running or self._flight is not None:
                     await self._step()
                 laps.enter("schedule")
                 self._publish()
@@ -1099,7 +1222,12 @@ class LlmEngine:
         starvation of large prompts). Prompt blocks already in the shared
         index are referenced instead of allocated (capacity math counts
         NEW blocks only) and their prefill is skipped: TTFT is one
-        partial prefill of the unshared suffix."""
+        partial prefill of the unshared suffix.
+
+        With a step in flight the scan only looks: before the first
+        request is admitted or failed the step is consumed, and the scan
+        starts again over what that booked (a lane that ended, its
+        blocks freed)."""
         allocator = self.allocator
         for item in self._waiting.scan():
             seq: Sequence = item.value
@@ -1123,6 +1251,13 @@ class LlmEngine:
             usable = min(
                 allocator.match_count(seq.block_hashes), cap, len(seq.block_hashes)
             )
+            if self._flight is not None and (
+                need - usable > allocator.capacity
+                or need - usable <= allocator.free_blocks
+            ):
+                self._consume(self._flight)
+                await self._admit()
+                return
             if need - usable > allocator.capacity:
                 # admitted on the strength of a shared prefix that has
                 # since been reclaimed (its sharers finished): the
@@ -1238,7 +1373,11 @@ class LlmEngine:
     def _sample_rows(self, items) -> List[int]:
         """Sample one token per ``(seq, logits_row, gen_index)`` item in
         ONE vectorized pass — the full-batch decode step and the K+1
-        rows of a speculative verify all share it.
+        rows of a speculative verify all share it. Every token the
+        engine streams comes out of here. The items of an all-greedy
+        decode step carry, in the row's place, the id the decode
+        program already chose over that row (its argmax): it passes
+        through.
 
         The softmax/top-k pipeline runs batched in float64 (elementwise
         ops and per-row reductions, so each row's bits match the scalar
@@ -1254,7 +1393,8 @@ class LlmEngine:
         sampled = [i for i in range(n) if items[i][0].temperature > 0.0]
         if greedy:
             rows = np.stack([np.asarray(items[i][1]) for i in greedy])
-            for i, pick in zip(greedy, rows.argmax(axis=-1)):
+            picks = rows if rows.ndim == 1 else rows.argmax(axis=-1)
+            for i, pick in zip(greedy, picks):
                 out[i] = int(pick)
         if sampled:
             rows = np.stack(
@@ -1329,21 +1469,68 @@ class LlmEngine:
             )
 
     async def _step(self) -> None:
-        """One iteration-level decode step over every running sequence."""
-        from client_tpu.server.models import pad_batch_bucket
+        """One iteration of the decode loop: dispatch the next step over
+        every running lane, then book the step that was in flight."""
+        batch = self._grow()
+        flight = self._flight  # after _grow, which may have consumed it
+        if not batch:
+            if flight is not None:
+                self._consume(flight)  # every lane ended at it
+            return
+        if self._speculative:
+            self._laps.enter("propose")
+            drafts = await self._propose(batch)
+            self._laps.enter("schedule")
+            if any(drafts):
+                await self._spec_decode(batch, drafts)
+            else:
+                await self._plain_decode(batch)
+            return
+        ahead = await self._dispatch(batch, flight)
+        if flight is not None:
+            self._consume(flight)
+        if _samples(batch):
+            # the draw is numpy's, over logits read back for it, and
+            # the next step needs its token from the host
+            self._consume(ahead)
+        else:
+            self._flight = ahead
 
+    def _grow(self) -> List[Sequence]:
+        """The lanes of the next decode step, each with the blocks its
+        write position needs (allocate-on-demand: a sequence whose next
+        write enters a new block claims it now). A lane of the step in
+        flight writes one position further on; one that ends at that
+        step, and a cancelled one, is left out. A dry pool preempts
+        until the step fits, once the step in flight is booked."""
         allocator = self.allocator
-        # allocate-on-demand: sequences whose next write position enters
-        # a new block claim it now; a dry pool preempts until it fits
-        for seq in list(self._running):
-            if seq not in self._running:
+        flight = self._flight
+        ahead = flight.lane_of if flight is not None else {}
+        lanes = [
+            seq for seq in self._running
+            if not seq.cancelled  # pruned (and freed) next iteration
+            and not (
+                seq.seq_id in ahead
+                and len(seq.generated) + 1 >= seq.max_tokens
+            )
+        ]
+        for seq in lanes:
+            if seq.state != _RUNNING:
                 continue  # already preempted below
-            while seq.position // allocator.block_size >= len(seq.blocks):
+            position = seq.position + (seq.seq_id in ahead)
+            while position // allocator.block_size >= len(seq.blocks):
                 try:
                     block = allocator.extend(seq.seq_id)
                     seq.blocks.append(block)
                     seq.page_table[len(seq.blocks) - 1] = block
                 except CacheCapacityError:
+                    if flight is not None:
+                        # who owns blocks has to change: book the step
+                        # in flight first (a lane may end at it and free
+                        # what is missing; a victim re-prefills what it
+                        # streamed), then grow from what that left
+                        self._consume(flight)
+                        return self._grow()
                     if allocator.blocks_for(
                         seq.position + 1
                     ) > allocator.capacity:
@@ -1369,26 +1556,25 @@ class LlmEngine:
                     self._preempt(victim)
                     if victim is seq:
                         break
-        batch = self._running
-        if not batch:
-            return
-        if self._speculative:
-            self._laps.enter("propose")
-            drafts = await self._propose(batch)
-            self._laps.enter("schedule")
-            if any(drafts):
-                await self._spec_decode(batch, drafts)
-            else:
-                await self._plain_decode(batch)
-        else:
-            await self._plain_decode(batch)
-        self._running = [s for s in self._running if s.state == _RUNNING]
+        return [seq for seq in lanes if seq.state == _RUNNING]
 
     async def _plain_decode(self, batch: List[Sequence]) -> None:
-        """The non-speculative decode body: one token per live lane."""
+        """One decode step dispatched and booked at once (the
+        speculative engine's step without drafts)."""
+        self._consume(await self._dispatch(batch))
+
+    async def _dispatch(self, batch: List[Sequence],
+                        flight: Optional[_Flight] = None) -> _Flight:
+        """Build and dispatch the non-speculative decode step over
+        ``batch``, one token per lane, and return it un-waited. A lane
+        that also ran in ``flight`` (the step before, not booked yet)
+        takes its token from that step's ids on the device and writes
+        one position past what is booked; every other lane takes
+        ``last_token`` from the host."""
         from client_tpu.server.models import pad_batch_bucket
 
         allocator = self.allocator
+        ahead = flight.lane_of if flight is not None else {}
         n = len(batch)
         bucket = pad_batch_bucket(n)
         # ragged page-table width: the decode kernel's attention cost is
@@ -1399,13 +1585,18 @@ class LlmEngine:
             block_bucket(max(len(seq.blocks) for seq in batch)),
             self.config.max_blocks_per_seq,
         )
-        tokens = np.zeros([bucket], dtype=np.int32)
+        lane_map = np.full([bucket], -1, dtype=np.int32)
+        host_tokens = np.zeros([bucket], dtype=np.int32)
         positions = np.zeros([bucket], dtype=np.int32)
         page_tables = np.zeros([bucket, nb], dtype=np.int32)
         self.attn_blocks_bucket += bucket * nb
         for i, seq in enumerate(batch):
-            tokens[i] = seq.last_token
-            positions[i] = seq.position
+            lane = ahead.get(seq.seq_id, -1)
+            lane_map[i] = lane
+            if lane < 0:
+                host_tokens[i] = seq.last_token
+            position = seq.position + (lane >= 0)
+            positions[i] = position
             page_tables[i] = seq.page_table[:nb]
             self.attn_blocks_live += len(seq.blocks)
             for _, ring, _ in self._windows:
@@ -1415,51 +1606,71 @@ class LlmEngine:
             # be exclusively owned (shared prefix blocks are read-only;
             # growth always lands in fresh blocks). A violation means
             # allocator state is corrupt — engine-fatal, not a lane skip.
-            write_block = seq.position // allocator.block_size
+            write_block = position // allocator.block_size
             if allocator.refcount(seq.blocks[write_block]) != 1:
                 raise InferenceServerException(
                     f"COW violation: sequence {seq.seq_id} would write "
                     f"block {seq.blocks[write_block]} with refcount "
                     f"{allocator.refcount(seq.blocks[write_block])}"
                 )
-        laps = self._laps
-        laps.enter("dispatch")
-        logits, self._pages, *counted = await self._run_device(
-            self._decode, tokens, positions,
+        self._laps.enter("dispatch")
+        ids, logits, self._pages, *counted = await self._run_device(
+            self._decode, self._ids, lane_map, host_tokens, positions,
             self._group_tables(page_tables, batch, positions[:n]),
             self._pages,
         )
+        self._ids = ids
+        if flight is not None:
+            self.steps_ahead += 1
+        for result in (ids, *counted):
+            # what the host will read: its copy starts when the program
+            # ends, not when the loop gets round to asking for it
+            start_copy = getattr(result, "copy_to_host_async", None)
+            if start_copy is not None:
+                start_copy()
+        return _Flight(batch, ids, logits, counted)
+
+    def _consume(self, flight: _Flight) -> None:
+        """Wait for a dispatched step, then book and stream its tokens:
+        ``_sample_rows`` over the ids the program chose or, where a
+        live lane samples, over the logits read back for it."""
+        laps = self._laps
+        if self._flight is flight:
+            self._flight = None
+        live = [
+            (lane, seq) for lane, seq in enumerate(flight.batch)
+            if not seq.cancelled  # pruned (and freed) next iteration
+        ]
+        sampled = _samples(flight.batch)
+        result = flight.logits if sampled else flight.ids
         laps.enter("wait")
-        _wait_ready(logits)
+        _wait_ready(result)
         laps.enter("readback")
-        logits_rows = np.asarray(logits)[:n]
-        if counted:
-            # computed by the same program: ready with the logits
+        rows = np.asarray(result)
+        if flight.counted:
+            # computed by the same program: ready with the ids
             for name, value in zip(
-                self._step_counter_names, np.asarray(counted[0]).tolist()
+                self._step_counter_names,
+                np.asarray(flight.counted[0]).tolist(),
             ):
                 self.model_counters[name] += value
         self.steps += 1
         laps.enter("sample")
-        live = [
-            (seq, row) for seq, row in zip(batch, logits_rows)
-            if not seq.cancelled  # pruned (and freed) next iteration
-        ]
         picks = self._sample_rows(
-            [(seq, row, len(seq.generated)) for seq, row in live]
+            [(seq, rows[lane], len(seq.generated)) for lane, seq in live]
         )
         self.lane_steps += len(live)
         laps.enter("emit")
-        emitted = 0
-        for (seq, _), token in zip(live, picks):
+        for (_, seq), token in zip(live, picks):
             self._emit_step_token(seq, token)
-            emitted += 1
         if self.metrics is not None:
-            # emitted (not n): cancelled lanes decoded but streamed
-            # nothing, and the exported counter must agree with stats()
-            self.metrics.observe_llm_step(self.model_name, n)
-            if emitted:
-                self.metrics.observe_llm_tokens(self.model_name, emitted)
+            # tokens: the live lanes, not the batch (cancelled lanes
+            # decoded but streamed nothing, and the exported counter
+            # must agree with stats())
+            self.metrics.observe_llm_step(self.model_name, len(flight.batch))
+            if live:
+                self.metrics.observe_llm_tokens(self.model_name, len(live))
+        self._running = [s for s in self._running if s.state == _RUNNING]
 
     def _emit_step_token(self, seq: Sequence, token: int) -> bool:
         """Book ONE decode-step emission (plain and speculative paths
@@ -1672,6 +1883,7 @@ class LlmEngine:
             self.metrics.observe_llm_speculation(
                 self.model_name, proposed_total, accepted_total, lane_tokens
             )
+        self._running = [s for s in self._running if s.state == _RUNNING]
 
     def _finish(self, seq: Sequence) -> None:
         self._free_blocks(seq)

@@ -145,13 +145,27 @@ class LlmEngineModel(Model):
         speculative verify step; None when the model does not opt in)
         rides the multi-query twin of the same attention kernel.
 
+        ``decode`` is the engine's ``decode_fn`` (``LlmEngine``):
+        ``jit_llm_decode`` picks each lane's input token inside the
+        program, from the ids the step before left on the device
+        (``prev_ids[lane_map]``) or from the host's (``host_tokens``,
+        where the map is -1), calls the model's ``decode`` as before,
+        and also returns ``argmax(logits, -1)`` as int32, zero-padded to
+        the width of ``prev_ids``. The select, the argmax and the padding
+        live here, in no model; the width of ``prev_ids`` is fixed
+        (``EngineConfig.ids_width``), so there is still one program per
+        (batch bucket, table bucket). The logits stay an output and stay
+        on the device unless the engine reads them.
+
         Under a tp mesh plan (``self.mesh_plan``) the same callables are
         built sharded: host args are placed as REPLICATED global arrays
         (on a pod, ``jax.device_put`` cannot reach other processes'
-        devices — ``place_global`` can), logits are pinned replicated so
-        every process can read them locally, and the page pool keeps its
-        kv-head sharding end to end."""
+        devices — ``place_global`` can), logits and ids are pinned
+        replicated so every process can read them locally (and hand the
+        ids back as they are), and the page pool keeps its kv-head
+        sharding end to end."""
         import jax
+        import jax.numpy as jnp
 
         model = self._model
         plan = self.mesh_plan
@@ -165,6 +179,8 @@ class LlmEngineModel(Model):
             jit_out = {"out_shardings": (rep, pages_sharding)}
 
         def _host(value, dtype=np.int32):
+            if isinstance(value, jax.Array):
+                return value  # a step's ids, handed back: already placed
             array = np.asarray(value, dtype=dtype)
             if plan is None:
                 return array
@@ -194,11 +210,19 @@ class LlmEngineModel(Model):
                 start_index, prefix_blocks, config, kernels,
             )
 
-        def llm_decode(params_, tokens, positions, page_tables, pages):
-            return model.decode(
+        def llm_decode(params_, prev_ids, lane_map, host_tokens, positions,
+                       page_tables, pages):
+            tokens = jnp.where(
+                lane_map >= 0, prev_ids[jnp.maximum(lane_map, 0)],
+                host_tokens,
+            )
+            logits, pages, *counted = model.decode(
                 params_, tokens, positions, page_tables, pages, config,
                 kernels,
             )
+            ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            ids = jnp.pad(ids, (0, prev_ids.shape[0] - ids.shape[0]))
+            return (ids, logits, pages, *counted)
 
         def llm_verify(params_, tokens, positions, lengths, page_tables,
                        pages):
@@ -234,13 +258,19 @@ class LlmEngineModel(Model):
                 _host(np.int32(start_index)), prefix_blocks,
             )
 
-        donate_kw = {"donate_argnums": (4,)} if donate else {}
-        decode_jit = jax.jit(llm_decode, **donate_kw, **jit_out)
+        donate_kw = {"donate_argnums": (6,)} if donate else {}
+        decode_out = (
+            {"out_shardings": (rep, rep, pages_sharding)}
+            if plan is not None else {}
+        )
+        decode_jit = jax.jit(llm_decode, **donate_kw, **decode_out)
 
-        def decode(tokens, positions, page_tables, pages):
+        def decode(prev_ids, lane_map, host_tokens, positions, page_tables,
+                   pages):
             return decode_jit(
-                params, _host(tokens), _host(positions),
-                _host(page_tables), pages,
+                params, _host(prev_ids), _host(lane_map),
+                _host(host_tokens), _host(positions), _host(page_tables),
+                pages,
             )
 
         decode_multi = None
@@ -422,13 +452,14 @@ class LlmEngineModel(Model):
                     engine_config.prefill_bucket_min - 1,
                     engine_config.block_size,
                 )
+            one = np.zeros([1], dtype=np.int32)
+            ids = np.zeros([engine_config.ids_width], dtype=np.int32)
             for nb in {1, min(8, max_blocks)}:
-                logits, pages = decode(
-                    np.zeros([1], dtype=np.int32),
-                    np.zeros([1], dtype=np.int32),
-                    table[..., None, :nb],
-                    pages,
-                )[:2]
+                # the second probe takes the first one's ids as the
+                # device array they are, as a step that runs ahead does
+                ids, logits, pages = decode(
+                    ids, one, one, one, table[..., None, :nb], pages,
+                )[:3]
             if decode_multi is not None:
                 # probe the verify shape too (T=2: one real token + one
                 # draft) — all writes land in the trash block

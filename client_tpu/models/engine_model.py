@@ -73,7 +73,11 @@ class EngineModel:
     ``decode(params, tokens[B], positions[B], tables, pages, config,
     kernels) -> (logits[B, V], pages[, counters])`` where ``counters``
     is an int32 vector named by ``step_counters``, summed by the engine
-    into its ``stats()``
+    into its ``stats()``. The logits are float32. Which token a lane
+    reads (the host's, or the id the step before left on the device)
+    and the greedy ``argmax`` over the logits are ``jit_llm_decode``'s
+    own, around this call (``llm/serving.py::_build_device_fns``): a
+    model sees ``tokens[B]`` and returns logits, as ever
     ``prefill_suffix(params, tokens, tables, pages, last_index,
     start_index, prefix_blocks, config, kernels)``, ``verify(params,
     tokens[B, T], positions, lengths, tables, pages, config, kernels)``,

@@ -132,16 +132,23 @@ def make_bus_wrapper(
                 prefill, tokens, page_table, pages, last_index, start_index
             )
 
-        def wrapped_decode(tokens, positions, page_tables, pages):
+        def wrapped_decode(prev_ids, lane_map, host_tokens, positions,
+                           page_tables, pages):
+            # prev_ids is not sent: it is the ids of the last decode
+            # call, which every member has from its own copy of it
             bus.broadcast(
                 "decode",
                 (
-                    np.asarray(tokens, np.int32),
+                    np.asarray(lane_map, np.int32),
+                    np.asarray(host_tokens, np.int32),
                     np.asarray(positions, np.int32),
                     np.asarray(page_tables, np.int32),
                 ),
             )
-            return timed(decode, tokens, positions, page_tables, pages)
+            return timed(
+                decode, prev_ids, lane_map, host_tokens, positions,
+                page_tables, pages,
+            )
 
         wrapped_multi = None
         if decode_multi is not None:
@@ -174,7 +181,12 @@ def follower_handlers(model) -> Dict[str, Callable[..., None]]:
     import jax
 
     prefill, decode, decode_multi = model._device_fns
-    state = {"pages": model.engine._pages}
+    state = {
+        "pages": model.engine._pages,
+        # the ids of this member's last decode call: what the
+        # coordinator's engine hands its own copy as prev_ids
+        "ids": np.zeros([model.engine_config.ids_width], np.int32),
+    }
 
     def on_prefill(tokens, page_table, last_index, start_index):
         logits, state["pages"] = prefill(
@@ -183,9 +195,10 @@ def follower_handlers(model) -> Dict[str, Callable[..., None]]:
         )
         jax.block_until_ready(logits)
 
-    def on_decode(tokens, positions, page_tables):
-        logits, state["pages"] = decode(
-            tokens, positions, page_tables, state["pages"]
+    def on_decode(lane_map, host_tokens, positions, page_tables):
+        state["ids"], logits, state["pages"] = decode(
+            state["ids"], lane_map, host_tokens, positions, page_tables,
+            state["pages"],
         )
         jax.block_until_ready(logits)
 

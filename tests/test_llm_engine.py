@@ -26,6 +26,7 @@ from client_tpu.llm import (
     EngineConfig,
     LlmEngine,
 )
+from client_tpu.llm.engine import decode_fn_from_logits
 from client_tpu.scheduling import QueueFullError, QueueTimeoutError
 from client_tpu.utils import InferenceServerException
 
@@ -108,7 +109,7 @@ def _stub_engine(clock, **overrides):
     defaults.update(overrides)
     return LlmEngine(
         prefill,
-        decode,
+        decode_fn_from_logits(decode),
         pages=object(),
         engine_config=EngineConfig(**defaults),
         model_name="stub",

@@ -23,7 +23,7 @@ import numpy as np
 import pytest
 
 from client_tpu.llm import EngineConfig, LlmEngine, NgramProposer
-from client_tpu.llm.engine import PHASES
+from client_tpu.llm.engine import PHASES, decode_fn_from_logits
 from client_tpu.observability import LapSpans
 from client_tpu.observability import profiling
 
@@ -128,7 +128,7 @@ def _stub_engine(clock, speculative=False, **overrides):
                     max_seq_len=64, spec_k=3 if speculative else 0)
     defaults.update(overrides)
     engine = LlmEngine(
-        prefill, decode, pages=object(),
+        prefill, decode_fn_from_logits(decode), pages=object(),
         engine_config=EngineConfig(**defaults), model_name="stub",
         clock_ns=clock,
         decode_multi_fn=decode_multi if speculative else None,
@@ -257,7 +257,7 @@ def test_attn_block_counters_follow_the_tables_the_device_saw(speculative):
             return call(*args)
         return step
 
-    engine._decode = watched("decode", engine._decode, 2)
+    engine._decode = watched("decode", engine._decode, 4)
     if speculative:
         engine._decode_multi = watched("verify", engine._decode_multi, 3)
     # three lanes pad to a batch bucket of 4; contexts pass 2 blocks of 4

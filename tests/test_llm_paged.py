@@ -29,6 +29,7 @@ from client_tpu.llm import (
     EngineConfig,
     LlmEngine,
 )
+from client_tpu.llm.engine import decode_fn_from_logits
 from client_tpu.utils import InferenceServerException
 
 pytestmark = pytest.mark.llm
@@ -755,7 +756,7 @@ def _consistent_stub_engine(clock, **overrides):
     defaults.update(overrides)
     return LlmEngine(
         prefill,
-        decode,
+        decode_fn_from_logits(decode),
         pages=object(),
         engine_config=EngineConfig(**defaults),
         model_name="stub",

@@ -31,6 +31,7 @@ from client_tpu.llm import (
     LlmEngine,
     NgramProposer,
 )
+from client_tpu.llm.engine import decode_fn_from_logits
 from client_tpu.utils import InferenceServerException
 
 pytestmark = pytest.mark.llm
@@ -521,7 +522,7 @@ def _stub_engine(clock, spec_k=3, proposer=None, metrics=None, **overrides):
     defaults.update(overrides)
     return LlmEngine(
         prefill,
-        decode,
+        decode_fn_from_logits(decode),
         pages=object(),
         engine_config=EngineConfig(**defaults),
         model_name="stub",

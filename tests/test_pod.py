@@ -486,17 +486,28 @@ def test_tp_paged_decode_parity_per_kernel(sharded_devices, monkeypatch, kernel)
         assert np.abs(a1 - a4).max() <= 1e-5
         assert a1[0].argmax() == a4[0].argmax()
         position = bucket
+        # the first step takes prefill's token from the host; every
+        # later one takes the ids the step before left on the device
+        width = oracle.engine_config.ids_width
+        i1 = i4 = np.zeros([width], np.int32)
+        lane_map = np.array([-1], np.int32)
+        tok = np.array([int(a1[0].argmax())], np.int32)
         for _step in range(4):
-            tok = np.array([int(a1[0].argmax())], np.int32)
-            o1, pages1 = d1(
-                tok, np.array([position], np.int32), table[None, :4], pages1
+            i1, o1, pages1 = d1(
+                i1, lane_map, tok, np.array([position], np.int32),
+                table[None, :4], pages1,
             )
-            o4, pages4 = d4(
-                tok, np.array([position], np.int32), table[None, :4], pages4
+            i4, o4, pages4 = d4(
+                i4, lane_map, tok, np.array([position], np.int32),
+                table[None, :4], pages4,
             )
             a1, a4 = np.asarray(o1), np.asarray(o4)
             assert np.abs(a1 - a4).max() <= 1e-5, f"decode step {_step}"
             assert a1[0].argmax() == a4[0].argmax()
+            assert np.asarray(i1).tolist() == np.asarray(i4).tolist() == (
+                [int(a1[0].argmax())] + [0] * (width - 1)
+            )
+            lane_map = np.array([0], np.int32)
             position += 1
     finally:
         oracle.shutdown()
