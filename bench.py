@@ -617,14 +617,13 @@ def _bench_llm_decode_kernel() -> dict:
         num_blocks = 1 + 8 * max_blocks
 
         standin = jax.jit(
-            lambda t, p, pt, pg: llama.decode_step_paged(
-                params, t, p, pt, pg, config
+            lambda t, p, pt, pg: llama.decode_step_paged_attn(
+                params, t, p, pt, pg, config, pa.paged_attention_reference
             )
         )
         fused = jax.jit(
             lambda t, p, pt, pg: llama.decode_step_paged_attn(
-                params, t, p, pt, pg, config,
-                pa.paged_attention_fused_xla,
+                params, t, p, pt, pg, config, pa.paged_attention_xla
             )
         )
 

@@ -125,7 +125,7 @@ class LlmEngineModel(Model):
         self._params = params
         self.engine: Optional[LlmEngine] = None
         # which ragged paged-attention implementation warmup selected
-        # ("pallas" / "pallas_interpret" / "fused_xla" / "standin");
+        # ("pallas" / "pallas_interpret" / "fused_xla");
         # reported in the model config's parameters map
         self.decode_kernel: Optional[str] = None
         self._core = None
@@ -143,7 +143,7 @@ class LlmEngineModel(Model):
         prefix-gather bucket (bounded recompiles, one program per
         (suffix bucket, prefix bucket) pair). ``decode_multi`` (the
         speculative verify step; None when the model does not opt in)
-        rides the multi-query twin of the same attention kernel.
+        calls the same ``kernels.attn`` with K+1 rows a sequence.
 
         ``decode`` is the engine's ``decode_fn`` (``LlmEngine``):
         ``jit_llm_decode`` picks each lane's input token inside the
@@ -274,7 +274,7 @@ class LlmEngineModel(Model):
             )
 
         decode_multi = None
-        if kernels.attn_mq is not None:
+        if self.speculation is not None:
             donate_kw = {"donate_argnums": (5,)} if donate else {}
             decode_multi_jit = jax.jit(llm_verify, **donate_kw, **jit_out)
 
@@ -352,9 +352,8 @@ class LlmEngineModel(Model):
         # the probes below compile and run the smallest shapes the engine
         # serves, and a kernel that cannot serve this host fails the LOAD
         # with the compiler's message (never a quiet step down to another
-        # implementation), as does a model with no path for the choice.
-        # It reaches every program of the model as one `Kernels` and is
-        # reported in the model config.
+        # implementation). It reaches every program of the model as one
+        # `Kernels` and is reported in the model config.
         name, attn = paged_attention.resolve_decode_attention(
             os.environ.get("CLIENT_TPU_LLM_KERNEL"), jax.default_backend()
         )
@@ -362,7 +361,6 @@ class LlmEngineModel(Model):
             speculation=self.speculation is not None,
             prefix_sharing=engine_config.prefix_sharing,
             tp=self.tp,
-            kernel=name,
         )
         if missing is not None:
             raise InferenceServerException(missing)
@@ -387,27 +385,12 @@ class LlmEngineModel(Model):
         # step); the CPU backend does not implement donation and warns,
         # so only donate on real accelerators.
         donate = jax.default_backend() != "cpu"
-        if name == "standin":
-            # the model's own inline attention, plain XLA throughout:
-            # left to GSPMD propagation under tp
-            attn = None
-        # speculative verify rides the SAME kernel choice (decode and
-        # verify must agree numerically): every implementation has a
-        # multi-query twin
-        attn_mq = (
-            paged_attention.get_attention_impl_mq(name)
-            if self.speculation is not None
-            else None
-        )
         # under tp the kernel runs per-shard via shard_map (GSPMD cannot
-        # partition a pallas_call; for the XLA variants the wrap pins the
-        # no-communication head partitioning)
-        if plan is not None and attn is not None:
+        # partition a pallas_call; for the XLA function the wrap pins the
+        # no-communication head partitioning). Decode and speculative
+        # verify call the one function, so they agree numerically.
+        if plan is not None:
             attn = paged_attention.make_tp_attention(attn, plan.mesh)
-        if plan is not None and attn_mq is not None:
-            attn_mq = paged_attention.make_tp_attention(
-                attn_mq, plan.mesh, multi_query=True
-            )
         max_blocks = engine_config.max_blocks_per_seq
         n_groups = len(engine_config.cache_groups)
         # one table row a cache group, stacked when there are several
@@ -416,8 +399,7 @@ class LlmEngineModel(Model):
             dtype=np.int32,
         )
         prefill, decode, decode_multi = self._build_device_fns(
-            params, config, engine_config,
-            Kernels(name, attn, attn_mq), donate,
+            params, config, engine_config, Kernels(name, attn), donate,
         )
         pages = model.init_pages(
             config, engine_config.group_num_blocks(),

@@ -16,9 +16,8 @@ the order of :attr:`EngineModel.cache_groups`.
 
 Which kernels run is chosen once, at load (``llm/serving.py``, from
 ``CLIENT_TPU_LLM_KERNEL`` or the platform), and handed to every program
-of the model as one :class:`Kernels`; a model says in
-:attr:`EngineModel.kernels` which choices it has a path for and is
-refused the others at load. No model picks a device path by itself.
+of the model as one :class:`Kernels`. Every model behind the seam runs
+every choice, and none picks a device path by itself.
 
 The optional parts are what the engine's optional features need:
 ``prefill_suffix`` copy-on-write prefix sharing, ``verify`` speculative
@@ -49,15 +48,15 @@ class Kernels:
     """The load-time kernel choice. ``name`` is one of
     ``paged_attention.KERNELS``: ``pallas`` (every Pallas kernel
     compiled by Mosaic), ``pallas_interpret`` (the same kernels under
-    the Pallas interpreter), ``fused_xla`` and ``standin`` (plain XLA,
-    no Pallas kernel anywhere). ``attn`` / ``attn_mq`` are that choice's
-    paged attention and its multi-query twin (wrapped per shard under
-    ``tp``); ``attn`` is None for ``standin``, whose attention is the
-    model's own inline one, ``attn_mq`` None without speculation."""
+    the Pallas interpreter) or ``fused_xla`` (plain XLA, no Pallas
+    kernel anywhere). ``attn`` is that choice's paged attention under
+    the one contract of ``models/paged_attention.py`` (``q[B, T, H,
+    D]``, ``positions[B, T]``; wrapped per shard under ``tp``): a
+    model's decode step calls it with ``T = 1``, its verify step with
+    the K+1 rows of a sequence."""
 
     name: str
-    attn: Optional[Callable] = None
-    attn_mq: Optional[Callable] = None
+    attn: Callable
 
 
 @dataclasses.dataclass(frozen=True)
@@ -83,7 +82,6 @@ class EngineModel:
     tokens[B, T], positions, lengths, tables, pages, config, kernels)``,
     ``param_specs(config)``: optional, see the module docstring.
     ``heads(config) -> (n_heads, n_kv_heads)``: what ``tp`` must divide.
-    ``kernels``: the :attr:`Kernels.name` s the model has a path for.
     """
 
     name: str
@@ -97,16 +95,11 @@ class EngineModel:
     param_specs: Optional[Callable] = None
     heads: Optional[Callable] = None
     step_counters: Tuple[str, ...] = ()
-    kernels: Tuple[str, ...] = (
-        "pallas", "pallas_interpret", "fused_xla", "standin")
 
     def missing_for(self, *, speculation: bool, prefix_sharing: bool,
-                    tp: int, kernel: str) -> Optional[str]:
+                    tp: int) -> Optional[str]:
         """The first feature asked for that this model has no part for,
         as a sentence for a load failure; None if it has them all."""
-        if kernel not in self.kernels:
-            return (f"model family '{self.name}' has no '{kernel}' path "
-                    f"(it runs {', '.join(self.kernels)})")
         wanted = (
             (speculation, "speculation", "verify"),
             (prefix_sharing, "prefix_sharing=True", "prefill_suffix"),

@@ -400,10 +400,11 @@ def decode_step(params, token, position, cache, config: LlamaConfig):
 #
 # A sequence's logical view is its *page table* — a row of physical block
 # ids, one per ``block_size`` tokens of context. Decode scatters the step's
-# K/V into (page_table[pos // bs], pos % bs) and gathers the sequence's
-# pages back into a contiguous [B, S, KV, D] view for attention (the
-# XLA-level stand-in for the fused Pallas kernel; the manager semantics —
-# allocate-on-demand, free-on-completion, shared pool — are identical).
+# K/V into (page_table[pos // bs], pos % bs) and reads the sequence's
+# pages through the paged attention chosen at load
+# (``models/paged_attention.py``); the manager semantics —
+# allocate-on-demand, free-on-completion, shared pool — are the same
+# under every choice.
 #
 # Physical block 0 is reserved as the TRASH block: padding lanes of a
 # bucketed decode batch and padded prompt-tail positions point their
@@ -458,80 +459,23 @@ def prefill_into_pages(
     return logits, new_pages
 
 
-def decode_step_paged(
-    params, tokens, positions, page_tables, pages, config: LlamaConfig
+def decode_step_paged_attn(
+    params, tokens, positions, page_tables, pages, config: LlamaConfig, attn
 ):
     """One continuous-batching decode step over the block pool.
 
     ``tokens`` [B] (each sequence's most recent token), ``positions`` [B]
     (that token's context position — PER SEQUENCE, unlike
-    :func:`decode_step`'s shared scalar), ``page_tables`` [B, max_blocks]
-    physical block ids. Writes each token's K/V into its sequence's
-    current block, gathers each sequence's pages into a contiguous view,
-    and attends under a per-sequence validity mask (slot <= position).
-    Padding lanes (page table all zeros, position 0) write to the trash
-    block and produce garbage logits the caller discards. Returns
-    (logits [B, V], new_pages).
-    """
-    b = tokens.shape[0]
-    block_size = pages[0][0].shape[1]
-    max_blocks = page_tables.shape[1]
-    s = max_blocks * block_size
-    n_rep = config.n_heads // config.n_kv_heads
-    pos2 = positions[:, None]  # [B, 1]
-    phys = page_tables[jnp.arange(b), positions // block_size]  # [B]
-    off = positions % block_size
-    valid = jnp.arange(s)[None, :] <= pos2  # [B, S]
-    x = params["embed"][tokens][:, None, :].astype(config.dtype)
-    new_pages = []
-    for layer, (k_pages, v_pages) in zip(params["layers"], pages):
-        normed = rms_norm(x, layer["attn_norm"], config.norm_eps)
-        q = jnp.einsum("bld,dhk->blhk", normed, layer["wq"])
-        k = jnp.einsum("bld,dhk->blhk", normed, layer["wk"])
-        v = jnp.einsum("bld,dhk->blhk", normed, layer["wv"])
-        q = _rope(q, pos2, config.rope_theta)
-        k = _rope(k, pos2, config.rope_theta)
-        # scatter this step's K/V, THEN gather: the current position's
-        # entry must be visible to its own attention
-        k_pages = k_pages.at[phys, off].set(k[:, 0])
-        v_pages = v_pages.at[phys, off].set(v[:, 0])
-        new_pages.append((k_pages, v_pages))
-        k_ctx = k_pages[page_tables].reshape(
-            b, s, config.n_kv_heads, config.head_dim
-        )
-        v_ctx = v_pages[page_tables].reshape(
-            b, s, config.n_kv_heads, config.head_dim
-        )
-        qh = q.transpose(0, 2, 1, 3)  # [B, H, 1, D]
-        kh = _repeat_kv(k_ctx, n_rep).transpose(0, 2, 1, 3)  # [B, H, S, D]
-        vh = _repeat_kv(v_ctx, n_rep).transpose(0, 2, 1, 3)
-        scores = jnp.einsum(
-            "bhqd,bhkd->bhqk", qh, kh, preferred_element_type=jnp.float32
-        ) / np.sqrt(config.head_dim)
-        scores = jnp.where(valid[:, None, None, :], scores, -1e30)
-        weights = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum("bhqk,bhkd->bhqd", weights, vh.astype(weights.dtype))
-        out = out.astype(x.dtype).transpose(0, 2, 1, 3)  # [B, 1, H, D]
-        x = x + jnp.einsum("blhk,hkd->bld", out, layer["wo"])
-        x = x + _mlp_block(layer, rms_norm(x, layer["mlp_norm"], config.norm_eps))
-    x = rms_norm(x, params["final_norm"], config.norm_eps)
-    logits = jnp.einsum("bd,dv->bv", x[:, 0], params["lm_head"])
-    return logits.astype(jnp.float32), new_pages
-
-
-def decode_step_paged_attn(
-    params, tokens, positions, page_tables, pages, config: LlamaConfig, attn
-):
-    """:func:`decode_step_paged` with the attention read delegated to a
-    ragged paged-attention kernel (``models/paged_attention.py``).
-
-    Same contract as the stand-in, with one extra degree of freedom: the
-    page-table width ``page_tables.shape[1]`` may be any bucket the
-    caller chooses — the engine slices it to the live batch's longest
-    sequence, so attention cost follows actual context instead of
-    ``max_seq_len``.  ``attn(q[B, H, D], k_pages, v_pages, page_tables,
-    positions) -> [B, H, D]`` is one of the implementations selected at
-    warmup (Pallas on TPU, fused XLA elsewhere)."""
+    :func:`decode_step`'s shared scalar), ``page_tables`` [B, NB]
+    physical block ids, at any width ``NB`` the caller chooses: the
+    engine slices it to a bucket of the live batch's longest sequence,
+    so attention cost follows actual context instead of ``max_seq_len``.
+    Writes each token's K/V into its sequence's current block, then
+    reads the ragged pages through ``attn``, the load-time choice of
+    ``models/paged_attention.py`` (its ``T = 1`` case: one query row a
+    sequence, valid slots ``<= position``). Padding lanes (page table
+    all zeros, position 0) write to the trash block and produce garbage
+    logits the caller discards. Returns (logits [B, V], new_pages)."""
     b = tokens.shape[0]
     block_size = pages[0][0].shape[1]
     pos2 = positions[:, None]  # [B, 1]
@@ -551,7 +495,7 @@ def decode_step_paged_attn(
         k_pages = k_pages.at[phys, off].set(k[:, 0])
         v_pages = v_pages.at[phys, off].set(v[:, 0])
         new_pages.append((k_pages, v_pages))
-        out = attn(q[:, 0], k_pages, v_pages, page_tables, positions)
+        out = attn(q, k_pages, v_pages, page_tables, pos2)[:, 0]
         x = x + jnp.einsum("bhk,hkd->bd", out, layer["wo"])[:, None, :]
         x = x + _mlp_block(layer, rms_norm(x, layer["mlp_norm"], config.norm_eps))
     x = rms_norm(x, params["final_norm"], config.norm_eps)
@@ -561,7 +505,7 @@ def decode_step_paged_attn(
 
 def decode_step_paged_multi(
     params, tokens, positions, lengths, page_tables, pages,
-    config: LlamaConfig, attn_mq
+    config: LlamaConfig, attn
 ):
     """Speculative-verify decode step: K+1 query positions per sequence
     in ONE ragged paged-attention call (the batched-verify half of
@@ -573,11 +517,11 @@ def decode_step_paged_multi(
     of each lane are real — rows at index >= ``lengths[b]`` are padding:
     their K/V writes are redirected to the trash block and their logits
     are garbage the caller discards.  All T rows' K/V are scattered
-    BEFORE the attention read, and the multi-query kernel's per-position
-    validity mask (``slot <= positions[b, t]``) is what gives row ``t``
-    exactly its own speculative prefix — so the T logits rows equal T
-    sequential :func:`decode_step_paged` calls feeding the draft tokens
-    one at a time.  Returns (logits [B, T, V], new_pages).
+    BEFORE the attention read, and ``attn``'s per-position validity mask
+    (``slot <= positions[b, t]``) is what gives row ``t`` exactly its
+    own speculative prefix — so the T logits rows equal T sequential
+    :func:`decode_step_paged_attn` calls feeding the draft tokens one at
+    a time.  Returns (logits [B, T, V], new_pages).
     """
     b, t = tokens.shape
     block_size = pages[0][0].shape[1]
@@ -605,7 +549,7 @@ def decode_step_paged_multi(
         k_pages = k_pages.at[phys, off].set(k)
         v_pages = v_pages.at[phys, off].set(v)
         new_pages.append((k_pages, v_pages))
-        out = attn_mq(q, k_pages, v_pages, page_tables, positions)
+        out = attn(q, k_pages, v_pages, page_tables, positions)
         x = x + jnp.einsum("bthk,hkd->btd", out, layer["wo"])
         x = x + _mlp_block(layer, rms_norm(x, layer["mlp_norm"], config.norm_eps))
     x = rms_norm(x, params["final_norm"], config.norm_eps)
@@ -744,17 +688,6 @@ def generate(
 # ---------------------------------------------------------------------------
 
 
-def _engine_decode(params, tokens, positions, page_tables, pages, config,
-                   kernels):
-    if kernels.attn is None:  # standin: the inline attention, plain XLA
-        return decode_step_paged(
-            params, tokens, positions, page_tables, pages, config
-        )
-    return decode_step_paged_attn(
-        params, tokens, positions, page_tables, pages, config, kernels.attn
-    )
-
-
 ENGINE_MODEL = EngineModel(
     name="llama",
     init_params=init_params,
@@ -767,11 +700,9 @@ ENGINE_MODEL = EngineModel(
     ),
     # the prefills are plain XLA whatever the kernel choice
     prefill=lambda *args: prefill_into_pages(*args[:-1]),
-    decode=_engine_decode,
+    decode=lambda *args: decode_step_paged_attn(*args[:-1], args[-1].attn),
     prefill_suffix=lambda *args: prefill_suffix_into_pages(*args[:-1]),
-    verify=lambda *args: decode_step_paged_multi(
-        *args[:-1], args[-1].attn_mq
-    ),
+    verify=lambda *args: decode_step_paged_multi(*args[:-1], args[-1].attn),
     param_specs=param_specs,
     heads=lambda config: (config.n_heads, config.n_kv_heads),
 )

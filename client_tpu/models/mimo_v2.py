@@ -318,7 +318,7 @@ def decode_step_paged(params, tokens, positions, page_tables, pages,
     """One decode step for ``B`` lanes. ``page_tables`` [2, B, NB]: row
     0 the full group's, row 1 the window group's. Writes each token's
     K/V into its sequence's current block of each group, then attends
-    through ``kernels.attn`` and runs the experts on the path
+    through ``kernels.attn`` (its ``T = 1`` case) and runs the experts on the path
     ``kernels.name`` says. Returns (logits [B, V], pages, counters [3]
     int32: ``moe.COUNTERS`` summed over the expert layers)."""
     lanes = tokens.shape[0]
@@ -339,9 +339,10 @@ def decode_step_paged(params, tokens, positions, page_tables, pages,
         v_pages = _write(v_pages, phys[kind], off, v, kv)
         new_pages.append((k_pages, v_pages))
         out = kernels.attn(
-            q, k_pages, v_pages, page_tables[kind], positions,
+            q[:, None], k_pages, v_pages, page_tables[kind],
+            positions[:, None],
             window=config.window if kind else None, sink=layer.get("sink"),
-            scale=config.head_dim ** -0.5, kv_heads=kv)
+            scale=config.head_dim ** -0.5, kv_heads=kv)[:, 0]
         out = (out * config.value_scale).astype(x.dtype)
         x = x + jnp.einsum("bhk,hkd->bd", out, layer["wo"])
         x, counted = _ffn(layer, x, config, index, kernels.name)
@@ -358,7 +359,4 @@ ENGINE_MODEL = EngineModel(
     prefill=prefill_into_pages,
     decode=decode_step_paged,
     step_counters=moe.COUNTERS,
-    # no inline attention of its own: window, sink and unequal rows
-    # live in the paged implementations
-    kernels=("pallas", "pallas_interpret", "fused_xla"),
 )

@@ -1,64 +1,54 @@
-"""Ragged paged-attention decode kernels (the compute half of ROADMAP item 2).
+"""Ragged paged attention: one contract, three functions, one chooser.
 
 The continuous-batching engine stores every live sequence's KV cache as a
 page table over ONE physical block pool (``models/llama.py``
-``init_kv_pages``).  The decode step's attention must therefore read a
-*ragged* set of pages per sequence — each sequence attends over however
-many blocks it has actually earned.  This module provides the three
-implementations of that read, in ascending order of fusion ("Ragged Paged
-Attention", PAPERS.md arxiv 2604.15464, is the blueprint):
+``init_kv_pages``). A step's attention must therefore read a *ragged*
+set of pages per sequence: each sequence attends over however many
+blocks it has actually earned ("Ragged Paged Attention", PAPERS.md arxiv
+2604.15464, is the blueprint). Every function here does that read under
+the one contract::
 
-- ``standin``: the PR-9 XLA gather/scatter stand-in — gathers every
-  sequence's pages into a contiguous ``[B, S, KV, D]`` view, materializes
-  the grouped-query head repeat, and runs a validity-masked softmax over
-  the FULL padded width.  Kept as the bench baseline.
-- ``fused_xla``: one fused XLA call that skips the ``repeat_kv``
-  materialization entirely (grouped-query einsum over the gathered pages)
-  and works on whatever page-table width the caller passes — the engine
-  buckets that width to the live batch's longest sequence, so compute
-  scales with actual context instead of ``max_seq_len``.  This is the
-  implementation off-TPU.
-- ``pallas``: a flash-style Pallas kernel, one grid step per sequence.
-  The page table and each sequence's length ride scalar prefetch; the
-  pools stay in HBM and the kernel copies a TILE of pages (as many as a
-  fixed VMEM budget holds for the pool's shapes: 8 at KV 8 / D 128 /
-  bf16) into one of two VMEM slots itself, the next tile in flight
-  while this one is folded into the online softmax, and stops at the
-  sequence's own last tile — no ``[B, S]`` gather ever materializes and
-  the padding of the table to its bucket is never read.
-  ``pallas_interpret`` runs the same kernel under the Pallas
-  interpreter for CPU parity tests.
+    attn(q[B, T, H, D], k_pages[N, bs, KV, D], v_pages[N, bs, KV, Dv],
+         page_tables[B, NB], positions[B, T], **masking) -> out[B, T, H, Dv]
 
-Selection happens once at model warmup (``llm/serving.py``), by
-platform: TPU hosts take the Pallas kernel, everything else
-``fused_xla``, and the choice is reported in the model's config
-parameters.  All implementations share one contract::
+Query row ``t`` of sequence ``b`` sits at absolute position
+``positions[b, t]`` and sees slot ``block*bs + offset`` iff it is ``<=
+positions[b, t]`` (a freshly scattered token attends to itself);
+physical block 0 is the trash block, whose slots that rule always
+masks. A decode step is the ``T = 1`` case (``q[:, None]``,
+``positions[:, None]``, ``out[:, 0]``); the verify step of speculative
+decoding asks for K+1 positions a sequence in ONE call, and the
+per-position mask is the whole verification trick: row ``t`` sees
+exactly its own speculative prefix (rows ``0..t`` were scattered at
+``positions[b, 0..t]`` before the read), never the draft tokens after
+it, so the K+1 logits rows are what K+1 sequential decode steps would
+have produced. Padding lanes and padding rows produce garbage the
+caller discards. ``masking`` is ``window``, ``sink``, ``scale`` and
+``kv_heads`` (:func:`paged_attention_pallas`).
 
-    attn(q[B, H, D], k_pages[N, bs, KV, D], v_pages[N, bs, KV, D],
-         page_tables[B, NB], positions[B]) -> out[B, H, D]
+- :func:`paged_attention_pallas`: the flash-style Pallas kernel, one
+  grid step per sequence. The page table and each sequence's length ride
+  scalar prefetch; the pools stay in HBM and the kernel copies a TILE of
+  pages (as many as a fixed VMEM budget holds for the pool's shapes: 8
+  at KV 8 / D 128 / bf16) into one of two VMEM slots itself, the next
+  tile in flight while this one is folded into the online softmax, and
+  stops at the sequence's own last tile: no ``[B, S]`` gather ever
+  materializes and the padding of the table to its bucket is never
+  read. What a TPU serves. ``interpret=True`` runs the same kernel under
+  the Pallas interpreter, for CPU tests and rehearsals.
+- :func:`paged_attention_xla`: one fused XLA computation over the
+  gathered pages, the grouped-query einsum with no head repeat, at
+  whatever (bucketed) table width the caller passes. What every other
+  platform serves, and the XLA side of every on-chip comparison.
+- :func:`paged_attention_reference`: gather, materialized head repeat,
+  full-width masked softmax, no ``masking``. The tests' oracle, kept as
+  dumb as possible and served by nothing.
 
-with slot validity ``block*bs + offset <= positions[b]`` (the freshly
-scattered token attends to itself) and physical block 0 reserved as the
-trash block whose slots are always masked by that rule.
-
-Speculative decoding (PR-15) adds a MULTI-QUERY variant of the same
-contract: the verify step of draft-propose/paged-verify asks the target
-model for logits at K+1 positions per sequence in ONE call, so each
-implementation grows an ``*_mq`` twin::
-
-    attn_mq(q[B, T, H, D], k_pages[N, bs, KV, D], v_pages[N, bs, KV, D],
-            page_tables[B, NB], positions[B, T]) -> out[B, T, H, D]
-
-where query row ``t`` of sequence ``b`` sits at absolute position
-``positions[b, t]`` and slot validity generalizes PER POSITION:
-``block*bs + offset <= positions[b, t]``.  That one mask is the whole
-verification trick — row ``t`` sees exactly its own speculative prefix
-(rows ``0..t`` were scattered at ``positions[b, 0..t]`` before the
-read), never the draft tokens after it, so the K+1 logits rows are
-bit-for-bit what K+1 sequential decode steps would have produced.
-Padding rows (``t`` beyond a lane's draft length) produce garbage the
-caller discards, exactly like padding lanes do in the single-query
-contract.
+:func:`resolve_decode_attention` is the one place the choice is made,
+once, at model warmup (``llm/serving.py``): by platform (TPU hosts take
+the Pallas kernel, everything else plain XLA), or as
+``CLIENT_TPU_LLM_KERNEL`` says, over the three names of :data:`KERNELS`.
+The choice is reported in the model's config parameters.
 """
 
 import functools
@@ -72,49 +62,20 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 #: names accepted by :func:`resolve_decode_attention`, best first
-KERNELS = ("pallas", "pallas_interpret", "fused_xla", "standin")
+KERNELS = ("pallas", "pallas_interpret", "fused_xla")
 
 
 # ---------------------------------------------------------------------------
-# stand-in (PR-9 baseline): gather + repeat_kv + full-width masked softmax
+# the tests' oracle: gather + repeat_kv + full-width masked softmax
 # ---------------------------------------------------------------------------
 
 
-def paged_attention_standin(q, k_pages, v_pages, page_tables, positions):
-    """The gather/scatter stand-in, lifted to the shared attention
-    contract (numerically identical to the inline attention of
-    ``llama.decode_step_paged``)."""
-    b, h, d = q.shape
-    _, bs, kv, _ = k_pages.shape
-    n_rep = h // kv
-    s = page_tables.shape[1] * bs
-    k_ctx = k_pages[page_tables].reshape(b, s, kv, d)
-    v_ctx = v_pages[page_tables].reshape(b, s, kv, d)
-    # the materialized head repeat the fused variants avoid
-    k_rep = jnp.broadcast_to(
-        k_ctx[:, :, :, None, :], (b, s, kv, n_rep, d)
-    ).reshape(b, s, h, d)
-    v_rep = jnp.broadcast_to(
-        v_ctx[:, :, :, None, :], (b, s, kv, n_rep, d)
-    ).reshape(b, s, h, d)
-    qh = q[:, None, :, :].transpose(0, 2, 1, 3)  # [B, H, 1, D]
-    kh = k_rep.transpose(0, 2, 1, 3)  # [B, H, S, D]
-    vh = v_rep.transpose(0, 2, 1, 3)
-    scores = jnp.einsum(
-        "bhqd,bhkd->bhqk", qh, kh, preferred_element_type=jnp.float32
-    ) / (d ** 0.5)
-    valid = jnp.arange(s)[None, :] <= positions[:, None]  # [B, S]
-    scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
-    weights = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bhqk,bhkd->bhqd", weights, vh.astype(weights.dtype))
-    return out[:, :, 0, :].astype(q.dtype)  # [B, H, D]
+def paged_attention_reference(q, k_pages, v_pages, page_tables, positions):
+    """Gather + repeat_kv + a ``[B, T, S]`` mask.
 
-
-def paged_attention_standin_mq(q, k_pages, v_pages, page_tables, positions):
-    """Multi-query stand-in: gather + repeat_kv + a ``[B, T, S]`` mask.
-
-    The oracle the fused/Pallas mq variants are pinned against — kept as
-    dumb as possible (materialized head repeat, full-width softmax)."""
+    The oracle the XLA and Pallas functions are pinned against: kept as
+    dumb as possible (materialized head repeat, full-width softmax) and
+    served by nothing."""
     b, t, h, d = q.shape
     _, bs, kv, _ = k_pages.shape
     n_rep = h // kv
@@ -142,7 +103,7 @@ def paged_attention_standin_mq(q, k_pages, v_pages, page_tables, positions):
 
 
 # ---------------------------------------------------------------------------
-# fused XLA variant: grouped-query einsum, no repeat materialization
+# plain XLA: grouped-query einsum, no repeat materialization
 # ---------------------------------------------------------------------------
 
 
@@ -177,52 +138,23 @@ def _softmax_with_sink(scores, sink):
     return p / (p.sum(axis=-1, keepdims=True) + jnp.exp(sink - m))
 
 
-def paged_attention_fused_xla(q, k_pages, v_pages, page_tables, positions,
-                              *, window=None, sink=None, scale=None,
-                              kv_heads=None):
+def paged_attention_xla(q, k_pages, v_pages, page_tables, positions,
+                        *, window=None, sink=None, scale=None,
+                        kv_heads=None):
     """One fused XLA computation over the gathered pages.
 
     Head layout matches ``_repeat_kv`` (head ``k*g + r`` reads kv head
-    ``k``), so ``q.reshape(b, kv, g, d)`` lines queries up with their kv
-    group and the score/weighted-sum einsums contract directly against
-    the un-repeated context — the ``[B, S, H, D]`` repeat never exists,
+    ``k``), so ``q.reshape(b, t, kv, g, d)`` lines queries up with their
+    kv group and the score/weighted-sum einsums contract directly against
+    the un-repeated context: the ``[B, S, H, D]`` repeat never exists,
     and S is whatever (bucketed) width the caller's page table has. The
     gathered context is transposed to ``[B, KV, S, D]`` up front: both
     contractions then run as plain batched matmuls over adjacent
     (batch, kv) dims, which measures ~25% faster than contracting the
-    ``[B, S, KV, D]`` gather layout in place (PERF.md PR-14)."""
-    b, h, d = q.shape
-    bs, kv, dv = _pool_shape(k_pages, v_pages, kv_heads)
-    g = h // kv
-    s = page_tables.shape[1] * bs
-    k_ctx = k_pages[page_tables].reshape(b, s, kv, d).transpose(0, 2, 1, 3)
-    v_ctx = v_pages[page_tables].reshape(b, s, kv, dv).transpose(0, 2, 1, 3)
-    qg = q.reshape(b, kv, g, d)
-    scores = jnp.einsum(
-        "bkgd,bksd->bkgs", qg, k_ctx, preferred_element_type=jnp.float32
-    ) * (scale or d ** -0.5)
-    slots = jnp.arange(s)[None, :]
-    valid = _window_valid(
-        slots <= positions[:, None], slots, positions[:, None], window
-    )  # [B, S]
-    scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
-    weights = _softmax_with_sink(
-        scores, None if sink is None else sink.reshape(kv, g)
-    )
-    out = jnp.einsum("bkgs,bksd->bkgd", weights, v_ctx.astype(weights.dtype))
-    return out.reshape(b, h, dv).astype(q.dtype)
-
-
-def paged_attention_fused_xla_mq(q, k_pages, v_pages, page_tables, positions,
-                                 *, window=None, sink=None, scale=None,
-                                 kv_heads=None):
-    """Multi-query fused XLA variant (the verify-step workhorse off-TPU).
-
-    Same layout choices as :func:`paged_attention_fused_xla` — gathered
-    context transposed to ``[B, KV, S, D]``, queries regrouped to their
-    kv head — with the query-position axis ``T`` riding along both
-    einsums, so one call scores all K+1 verify positions against the
-    same gathered pages instead of gathering K+1 times."""
+    ``[B, S, KV, D]`` gather layout in place (PERF.md PR-14). The
+    query-position axis ``T`` rides along both einsums, so one call
+    scores all K+1 verify positions against the same gathered pages
+    instead of gathering K+1 times."""
     b, t, h, d = q.shape
     bs, kv, dv = _pool_shape(k_pages, v_pages, kv_heads)
     g = h // kv
@@ -404,7 +336,7 @@ def _rpa_kernel(kv, scale, window, has_sink, *refs):
         alpha = jnp.exp(m_prev - m_new)
         l_new = l_prev * alpha + p.sum(axis=1, keepdims=True)
         # weights ride the MXU in the page dtype (f32 accumulate), the
-        # same operand precision XLA's default gives the fused variant
+        # same operand precision XLA's default gives the XLA function
         acc = acc * alpha + jnp.dot(
             p.astype(v.dtype), v, preferred_element_type=jnp.float32
         )
@@ -427,10 +359,10 @@ def _rpa_kernel(kv, scale, window, has_sink, *refs):
 @functools.partial(
     jax.jit, static_argnames=("interpret", "window", "scale", "kv_heads")
 )
-def paged_attention_pallas_mq(q, k_pages, v_pages, page_tables, positions,
-                              *, interpret: bool = False, window=None,
-                              sink=None, scale=None, kv_heads=None):
-    """Flash-style multi-query ragged paged attention as a Pallas kernel.
+def paged_attention_pallas(q, k_pages, v_pages, page_tables, positions,
+                           *, interpret: bool = False, window=None,
+                           sink=None, scale=None, kv_heads=None):
+    """Flash-style ragged paged attention as a Pallas kernel.
 
     One grid step per sequence. ``page_tables`` and each sequence's
     length (its largest query position + 1) are scalar-prefetched; the
@@ -524,80 +456,12 @@ def paged_attention_pallas_mq(q, k_pages, v_pages, page_tables, positions,
     )
 
 
-def paged_attention_pallas(q, k_pages, v_pages, page_tables, positions,
-                           *, interpret: bool = False, **masking):
-    """Single-query decode: the T=1 case of
-    :func:`paged_attention_pallas_mq`."""
-    return paged_attention_pallas_mq(
-        q[:, None], k_pages, v_pages, page_tables, positions[:, None],
-        interpret=interpret, **masking,
-    )[:, 0]
-
-
-def paged_attention_pallas_interpret(q, k_pages, v_pages, page_tables,
-                                     positions, **masking):
-    """The Pallas kernel under the interpreter — CPU-runnable for parity
-    tests and for forcing the kernel path off-TPU."""
-    return paged_attention_pallas(
-        q, k_pages, v_pages, page_tables, positions, interpret=True,
-        **masking,
-    )
-
-
-def paged_attention_pallas_interpret_mq(q, k_pages, v_pages, page_tables,
-                                        positions, **masking):
-    """The multi-query Pallas kernel under the interpreter."""
-    return paged_attention_pallas_mq(
-        q, k_pages, v_pages, page_tables, positions, interpret=True,
-        **masking,
-    )
-
-
 # ---------------------------------------------------------------------------
 # selection
 # ---------------------------------------------------------------------------
 
-_IMPLS = {
-    "standin": paged_attention_standin,
-    "fused_xla": paged_attention_fused_xla,
-    "pallas": paged_attention_pallas,
-    "pallas_interpret": paged_attention_pallas_interpret,
-}
 
-# every kernel name has a multi-query twin so the speculative verify
-# path rides whatever implementation warmup selected for plain decode
-_IMPLS_MQ = {
-    "standin": paged_attention_standin_mq,
-    "fused_xla": paged_attention_fused_xla_mq,
-    "pallas": paged_attention_pallas_mq,
-    "pallas_interpret": paged_attention_pallas_interpret_mq,
-}
-
-
-def get_attention_impl(name: str) -> Callable:
-    try:
-        return _IMPLS[name]
-    except KeyError:
-        raise ValueError(
-            f"unknown paged-attention kernel '{name}' "
-            f"(choose from {', '.join(KERNELS)})"
-        ) from None
-
-
-def get_attention_impl_mq(name: str) -> Callable:
-    """The multi-query (speculative verify) twin of ``name``."""
-    try:
-        return _IMPLS_MQ[name]
-    except KeyError:
-        raise ValueError(
-            f"unknown paged-attention kernel '{name}' "
-            f"(choose from {', '.join(KERNELS)})"
-        ) from None
-
-
-def make_tp_attention(
-    attn: Callable, mesh, tp_axis: str = "tp", multi_query: bool = False
-) -> Callable:
+def make_tp_attention(attn: Callable, mesh, tp_axis: str = "tp") -> Callable:
     """Wrap an attention impl so it runs per-shard under a ``tp`` mesh.
 
     Tensor-parallel paged decode shards BOTH q (on the query-head axis)
@@ -611,7 +475,7 @@ def make_tp_attention(
 
     The wrap exists because GSPMD cannot partition a ``pallas_call`` (it
     would replicate the whole pool per device); ``shard_map`` hands each
-    device its local block, which also pins the XLA variants to the
+    device its local block, which also pins the XLA function to the
     no-communication partitioning instead of trusting sharding
     propagation to find it. Page tables and positions are replicated
     (they index POOL ROWS, which are not sharded — the head axis is).
@@ -619,18 +483,13 @@ def make_tp_attention(
     """
     from jax.sharding import PartitionSpec
 
-    q_spec = (
-        PartitionSpec(None, None, tp_axis, None)
-        if multi_query
-        else PartitionSpec(None, tp_axis, None)
-    )
-    pages_spec = PartitionSpec(None, None, tp_axis, None)
+    heads_spec = PartitionSpec(None, None, tp_axis, None)  # q, pools, out
     replicated = PartitionSpec()
     return jax.shard_map(
         attn,
         mesh=mesh,
-        in_specs=(q_spec, pages_spec, pages_spec, replicated, replicated),
-        out_specs=q_spec,
+        in_specs=(heads_spec, heads_spec, heads_spec, replicated, replicated),
+        out_specs=heads_spec,
         check_vma=False,
     )
 
@@ -638,17 +497,23 @@ def make_tp_attention(
 def resolve_decode_attention(
     requested: Optional[str], platform: str
 ) -> Tuple[str, Callable]:
-    """Pick the decode attention for ``platform`` (a
-    ``jax.default_backend()`` string).
+    """Pick the paged attention for ``platform`` (a
+    ``jax.default_backend()`` string): the name the model reports and
+    the function every program of the model calls.
 
-    ``requested`` (the ``CLIENT_TPU_LLM_KERNEL`` env override) forces a
-    specific implementation; otherwise TPU hosts get the Pallas kernel
-    and everything else the fused XLA variant. The choice is final: a
-    kernel that fails to compile at warmup is a load failure carrying
-    the compiler's message, never a silent step down to another
-    implementation."""
-    if requested:
-        return requested, get_attention_impl(requested)
-    if platform == "tpu":
-        return "pallas", paged_attention_pallas
-    return "fused_xla", paged_attention_fused_xla
+    ``requested`` (the ``CLIENT_TPU_LLM_KERNEL`` env override) forces
+    one of :data:`KERNELS`; otherwise TPU hosts get the Pallas kernel
+    and everything else plain XLA. The choice is final: a kernel that
+    fails to compile at warmup is a load failure carrying the compiler's
+    message, never a silent step down to another implementation."""
+    name = requested or ("pallas" if platform == "tpu" else "fused_xla")
+    if name == "pallas":
+        return name, paged_attention_pallas
+    if name == "pallas_interpret":
+        return name, functools.partial(paged_attention_pallas, interpret=True)
+    if name == "fused_xla":
+        return name, paged_attention_xla
+    raise ValueError(
+        f"unknown paged-attention kernel '{name}' "
+        f"(choose from {', '.join(KERNELS)})"
+    )

@@ -4,7 +4,10 @@ import json
 import os
 from typing import Any, Dict, Optional
 
-from client_tpu.testing.flake import retry_grpc_poller_flake  # noqa: F401
+from client_tpu.testing.flake import (  # noqa: F401
+    rerun_on_grpc_poller_breakdown,
+    retry_grpc_poller_flake,
+)
 from client_tpu.testing.inprocess import InProcessServer  # noqa: F401
 
 

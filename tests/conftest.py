@@ -16,9 +16,22 @@ Without that env var, ``-m tpu`` tests are skipped (they would run on the
 CPU backend and pass vacuously); with it, a missing accelerator FAILS
 them. The sandbox has no chip: the tier runs on one through
 ``python chip_smoke.py``, which owns the chip one child at a time.
+
+Native tier: the session builds ``native/`` into ``build/`` first, once,
+in the process that starts the run (the xdist controller; the one
+process when there are no workers) and before any test file is
+imported, so that ``build/`` is the same for every worker from its first
+test: the skip conditions of the files in :data:`NATIVE_TEST_FILES` and
+which gRPC front-end ``InProcessServer(grpc=True)`` serves no longer
+depend on who got there first. A build that fails FAILS the tests of
+those files, each with the compiler's last lines, and every other file
+runs. Without ``cmake`` or ``ninja`` nothing is built and those files
+skip, which the run's header says once.
 """
 
 import os
+import shutil
+import subprocess
 import sys
 
 TPU_TIER = os.environ.get("CLIENT_TPU_TEST_PLATFORM", "").lower() in (
@@ -44,7 +57,8 @@ if not TPU_TIER:
 
     jax.config.update("jax_platforms", "cpu")
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
 
 if TPU_TIER:
     # the device tier compiles full-width programs: keep them across runs
@@ -54,8 +68,71 @@ if TPU_TIER:
 
 import pytest  # noqa: E402  (after the platform pinning above)
 
+#: the files whose tests drive the binaries and the extension in build/
+NATIVE_TEST_FILES = frozenset({
+    "test_native.py",
+    "test_native_frontend.py",
+    "test_tls_frontend.py",
+    "test_integration_cc.py",
+})
+
+#: how the session's one native build went, handed from the process that
+#: made it to the workers and the re-exec'd children it starts (set in no
+#: other way): "" built, "absent" no cmake/ninja, else the build's error
+NATIVE_BUILD_ENV = "CLIENT_TPU_TEST_NATIVE_BUILD"
+
+
+def _build_native_once() -> None:
+    """Build ``native/`` unless a parent of this process already did."""
+    if NATIVE_BUILD_ENV in os.environ:
+        return
+    if shutil.which("cmake") is None or shutil.which("ninja") is None:
+        os.environ[NATIVE_BUILD_ENV] = "absent"
+        return
+    from tools.build_wheel import build_native
+
+    try:
+        build_native(
+            os.path.join(REPO, "build"), capture_output=True, text=True,
+            timeout=600,
+        )
+    except (subprocess.CalledProcessError, subprocess.TimeoutExpired) as e:
+        # a timeout hands back bytes whatever `text` says
+        said = "".join(
+            part.decode(errors="replace") if isinstance(part, bytes) else part
+            for part in (e.stdout, e.stderr) if part
+        )
+        os.environ[NATIVE_BUILD_ENV] = (
+            f"native build failed: {e}\n{said[-3000:]}"
+        )
+    else:
+        os.environ[NATIVE_BUILD_ENV] = ""
+
+
+def pytest_report_header(config):
+    state = os.environ.get(NATIVE_BUILD_ENV)
+    if state is None:  # the device tier builds nothing
+        return None
+    files = ", ".join(sorted(NATIVE_TEST_FILES))
+    if state == "absent":
+        return f"native: cmake/ninja absent, nothing built; {files} skip"
+    if state:
+        return f"native: BUILD FAILED, the tests of {files} fail with it"
+    return "native: build/ is current"
+
+
+@pytest.hookimpl(tryfirst=True)
+def pytest_runtest_setup(item):
+    """Ahead of the skip marks: a native build that failed is a failure
+    of every test that needs it, never a skip for an absent file."""
+    state = os.environ.get(NATIVE_BUILD_ENV, "")
+    if state and state != "absent" and item.path.name in NATIVE_TEST_FILES:
+        pytest.fail(state, pytrace=False)
+
 
 def pytest_configure(config):
+    if not TPU_TIER and not hasattr(config, "workerinput"):
+        _build_native_once()
     config.addinivalue_line(
         "markers",
         "tpu: runs on the real TPU device (select with -m tpu and "
@@ -174,7 +251,6 @@ def sharded_devices(request):
             f"{len(devices)} device(s) (need {required}) despite "
             f"XLA_FLAGS={os.environ.get('XLA_FLAGS', '')!r}"
         )
-    repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     proc = subprocess.run(
         [
             sys.executable,
@@ -185,7 +261,7 @@ def sharded_devices(request):
             "no:cacheprovider",
             request.node.nodeid,
         ],
-        cwd=repo_root,
+        cwd=REPO,
         env=sharded_reexec_env(),
         capture_output=True,
         text=True,
@@ -245,7 +321,6 @@ def pod_runtime(request):
 
     process_count, devices_per_process = 2, 2
     coordinator = f"127.0.0.1:{_free_port()}"
-    repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     procs = []
     for index in range(process_count):
         env = dict(os.environ)
@@ -275,7 +350,7 @@ def pod_runtime(request):
                     "no:cacheprovider",
                     request.node.nodeid,
                 ],
-                cwd=repo_root,
+                cwd=REPO,
                 env=env,
                 stdout=subprocess.PIPE,
                 stderr=subprocess.STDOUT,

@@ -25,6 +25,7 @@ from client_tpu.lifecycle import (
     resolve_hedge_policy,
     resolve_routing_policy,
 )
+from client_tpu.testing import rerun_on_grpc_poller_breakdown
 from client_tpu.utils import InferenceServerException
 
 
@@ -583,6 +584,7 @@ def test_fleet_runner_restart_keeps_ports_and_serves():
 @pytest.mark.fleet
 @pytest.mark.chaos
 @pytest.mark.parametrize("policy", ["least_outstanding", "p2c"])
+@rerun_on_grpc_poller_breakdown
 def test_chaos_kill_one_replica_zero_client_failures(policy):
     """The chaos acceptance: N=3 replicas under sustained concurrent
     load; one replica is drained and killed mid-run; every client

@@ -432,8 +432,10 @@ def test_the_pallas_kernel_carries_its_name():
     from client_tpu.models import paged_attention
 
     b, h, kv, d, bs, nb = 2, 4, 2, 8, 8, 2
-    jaxpr = jax.make_jaxpr(paged_attention.paged_attention_pallas_interpret)(
-        jnp.zeros([b, h, d]), jnp.zeros([4, bs, kv, d]),
+    _, attn = paged_attention.resolve_decode_attention(
+        "pallas_interpret", "cpu")
+    jaxpr = jax.make_jaxpr(attn)(
+        jnp.zeros([b, 1, h, d]), jnp.zeros([4, bs, kv, d]),
         jnp.zeros([4, bs, kv, d]), jnp.zeros([b, nb], jnp.int32),
-        jnp.zeros([b], jnp.int32))
+        jnp.zeros([b, 1], jnp.int32))
     assert "name=paged_attention" in str(jaxpr)

@@ -155,8 +155,8 @@ def _random_paged_state(rng, b, kv, d, bs, nb, num_blocks):
 
 @pytest.mark.parametrize("b,nb", [(1, 2), (3, 4), (8, 4)])
 def test_attention_impls_agree_on_ragged_layouts(b, nb):
-    """fused XLA and Pallas(interpret) within 1e-5 of the stand-in on
-    random pages with ragged per-sequence fill."""
+    """XLA and Pallas(interpret) within 1e-5 of the reference on random
+    pages with ragged per-sequence fill."""
     from client_tpu.models import paged_attention as pa
 
     kv, g, d, bs = 2, 2, 16, 8
@@ -165,15 +165,12 @@ def test_attention_impls_agree_on_ragged_layouts(b, nb):
     k_pages, v_pages, tables, positions = _random_paged_state(
         rng, b, kv, d, bs, nb, num_blocks=1 + b * nb
     )
-    q = rng.normal(size=(b, h, d)).astype(np.float32)
-    ref = np.asarray(
-        pa.paged_attention_standin(q, k_pages, v_pages, tables, positions)
-    )
+    q = rng.normal(size=(b, 1, h, d)).astype(np.float32)
+    args = (q, k_pages, v_pages, tables, positions[:, None])
+    ref = np.asarray(pa.paged_attention_reference(*args))
     for name in ("fused_xla", "pallas_interpret"):
-        out = np.asarray(
-            pa.get_attention_impl(name)(q, k_pages, v_pages, tables, positions)
-        )
-        assert np.abs(out - ref).max() <= 1e-5, name
+        _, attn = pa.resolve_decode_attention(name, "cpu")
+        assert np.abs(np.asarray(attn(*args)) - ref).max() <= 1e-5, name
 
 
 GARBAGE = 3.0e4  # large and finite: what a masked slot may hold
@@ -240,8 +237,8 @@ RAGGED_CASES = [
     "kv,g,b,nb,t", RAGGED_CASES,
     ids=[f"kv{c[0]}-b{c[2]}-nb{c[3]}-t{c[4]}" for c in RAGGED_CASES],
 )
-def test_pallas_tiles_match_standin_on_ragged_lengths(kv, g, b, nb, t):
-    """The Pallas kernel (interpreted) against the stand-in where its
+def test_pallas_tiles_match_reference_on_ragged_lengths(kv, g, b, nb, t):
+    """The Pallas kernel (interpreted) against the reference where its
     tiling shows: lengths around a tile boundary, one-tile and padding
     lanes, tables narrower than a tile and not a multiple of one, with
     garbage wherever the mask must hold."""
@@ -251,15 +248,9 @@ def test_pallas_tiles_match_standin_on_ragged_lengths(kv, g, b, nb, t):
     q, k_pages, v_pages, tables, positions = _ragged_case(
         rng, kv, g, b, nb, t
     )
-    if t == 1:
-        args = (q[:, 0], k_pages, v_pages, tables, positions[:, 0])
-        ref = pa.paged_attention_standin(*args)
-        out = pa.paged_attention_pallas_interpret(*args)
-    else:
-        args = (q, k_pages, v_pages, tables, positions)
-        ref = pa.paged_attention_standin_mq(*args)
-        out = pa.paged_attention_pallas_interpret_mq(*args)
-    ref, out = np.asarray(ref), np.asarray(out)
+    args = (q, k_pages, v_pages, tables, positions)
+    ref = np.asarray(pa.paged_attention_reference(*args))
+    out = np.asarray(pa.paged_attention_pallas(*args, interpret=True))
     assert out.shape == ref.shape and np.isfinite(out).all()
     # a padding lane reads the trash block's one visible slot: GARBAGE
     scale = np.maximum(1.0, np.abs(ref).max(axis=-1, keepdims=True))
@@ -283,7 +274,7 @@ MASKING_CASES = [
     ids=[f"kv{c[0]}-b{c[2]}-nb{c[3]}-t{c[4]}-w{c[5]}-s{int(c[6])}"
          for c in MASKING_CASES],
 )
-def test_pallas_window_sink_and_v_size_match_fused_xla(
+def test_pallas_window_sink_and_v_size_match_xla(
         kv, g, b, nb, t, window, sink):
     """The kernel's three new arguments against the XLA implementation on
     the ragged layouts: V rows narrower than K rows, a sliding window
@@ -323,15 +314,11 @@ def test_pallas_window_sink_and_v_size_match_fused_xla(
         masking["kv_heads"] = kv
     if sink:
         masking["sink"] = rng.normal(size=(kv * g,)).astype(np.float32) * 3
-    if t == 1:
-        args = (q[:, 0], k_pages, v_pages, tables, positions[:, 0])
-        ref = pa.paged_attention_fused_xla(*args, **masking)
-        out = pa.paged_attention_pallas_interpret(*args, **masking)
-    else:
-        args = (q, k_pages, v_pages, tables, positions)
-        ref = pa.paged_attention_fused_xla_mq(*args, **masking)
-        out = pa.paged_attention_pallas_interpret_mq(*args, **masking)
-    ref, out = np.asarray(ref), np.asarray(out)
+    args = (q, k_pages, v_pages, tables, positions)
+    ref = np.asarray(pa.paged_attention_xla(*args, **masking))
+    out = np.asarray(
+        pa.paged_attention_pallas(*args, interpret=True, **masking)
+    )
     assert out.shape == ref.shape and out.shape[-1] == 128
     assert np.isfinite(out).all()
     # float32 throughout; 3e-5 and not the 1e-5 of the test above: the
@@ -341,11 +328,8 @@ def test_pallas_window_sink_and_v_size_match_fused_xla(
     assert (np.abs(out - ref) / scale).max() <= 3e-5
     if sink:
         # the sink is in the denominator: without it the rows differ
-        bare = pa.paged_attention_fused_xla_mq(
-            q, k_pages, v_pages, tables, positions,
-            **{**masking, "sink": None},
-        )
-        assert np.abs(np.asarray(bare) - ref.reshape(bare.shape)).max() > 1e-3
+        bare = pa.paged_attention_xla(*args, **{**masking, "sink": None})
+        assert np.abs(np.asarray(bare) - ref).max() > 1e-3
 
 
 def test_pages_per_tile_follows_the_shapes_alone():
@@ -370,8 +354,8 @@ def test_pages_per_tile_follows_the_shapes_alone():
     assert pa.pages_per_tile(64, 64, 256, jnp.float32) == 1
 
 
-def test_decode_step_kernels_match_standin_on_tiny_llama(tiny_llama):
-    """Full decode-step logits parity (<=1e-5) vs the stand-in, including
+def test_decode_step_kernels_match_reference_on_tiny_llama(tiny_llama):
+    """Full decode-step logits parity (<=1e-5) vs the reference, including
     at the engine's ragged (narrower) page-table width."""
     from client_tpu.models import llama
     from client_tpu.models import paged_attention as pa
@@ -393,20 +377,21 @@ def test_decode_step_kernels_match_standin_on_tiny_llama(tiny_llama):
         )
     tokens = np.array([11, 12, 13], dtype=np.int32)
     positions = np.array([len(c) for c in contexts], dtype=np.int32)
-    ref, _ = llama.decode_step_paged(
-        params, tokens, positions, tables, pages, config
+    ref, _ = llama.decode_step_paged_attn(
+        params, tokens, positions, tables, pages, config,
+        pa.paged_attention_reference,
     )
     ref = np.asarray(ref)
-    for name in ("standin", "fused_xla", "pallas_interpret"):
+    for name in ("fused_xla", "pallas_interpret"):
         out, _ = llama.decode_step_paged_attn(
             params, tokens, positions, tables, pages, config,
-            pa.get_attention_impl(name),
+            pa.resolve_decode_attention(name, "cpu")[1],
         )
         assert np.abs(np.asarray(out) - ref).max() <= 1e-5, name
     # ragged width: 2 blocks cover the longest context (11+1 tokens)
     out, _ = llama.decode_step_paged_attn(
         params, tokens, positions, tables[:, :2], pages, config,
-        pa.paged_attention_fused_xla,
+        pa.paged_attention_xla,
     )
     assert np.abs(np.asarray(out) - ref).max() <= 1e-5
 
@@ -505,9 +490,9 @@ async def _model_generate(model, prompt, max_tokens, parameters=None):
 def test_warmup_selects_and_reports_kernel(shared_model):
     """Off-TPU the probe lands on fused_xla (or a forced override), and
     the choice rides the model config's parameters map."""
-    assert shared_model.decode_kernel in (
-        "pallas", "pallas_interpret", "fused_xla", "standin"
-    )
+    from client_tpu.models import paged_attention
+
+    assert shared_model.decode_kernel in paged_attention.KERNELS
     doc = shared_model.config()
     assert doc["parameters"]["decode_kernel"]["string_value"] == (
         shared_model.decode_kernel
@@ -539,6 +524,85 @@ def test_kernel_that_cannot_compile_is_a_load_failure(tiny_llama, monkeypatch):
     assert "decode_kernel='pallas'" in entry["reason"]
     assert "interpret" in entry["reason"].lower()  # the compiler's message
     assert model.decode_kernel is None and model.engine is None
+
+
+@pytest.mark.parametrize(
+    "family,config_type",
+    [("llama", "LlamaConfig"), ("mimo_v2", "MimoV2Config")],
+)
+def test_unknown_kernel_name_is_a_load_failure(
+        family, config_type, monkeypatch):
+    """``CLIENT_TPU_LLM_KERNEL`` takes the three served names only: any
+    other, the tests' reference among them, fails the LOAD of every
+    model family, by the name asked for and with the choices listed."""
+    import importlib
+
+    from client_tpu.llm.serving import LlmEngineModel
+    from client_tpu.server.model_repository import ModelRepository
+
+    module = importlib.import_module(f"client_tpu.models.{family}")
+    monkeypatch.setenv("CLIENT_TPU_LLM_KERNEL", "standin")
+    model = LlmEngineModel(
+        name=f"unknown_kernel_{family}",
+        model=module.ENGINE_MODEL,
+        config=getattr(module, config_type).tiny(),
+        engine_config=EngineConfig(
+            block_size=8, num_blocks=33, max_active=1, max_seq_len=64,
+            prefix_sharing=False,
+        ),
+    )
+    repository = ModelRepository()
+    repository.add_model(model)
+    (entry,) = repository.index()
+    assert entry["state"] == "UNAVAILABLE"
+    assert "'standin'" in entry["reason"]
+    assert "pallas, pallas_interpret, fused_xla" in entry["reason"]
+    assert model.decode_kernel is None and model.engine is None
+
+
+def test_decode_and_verify_programs_hold_the_one_kernel(
+        tiny_llama, monkeypatch):
+    """The design, held: under the load-time choice ``pallas_interpret``
+    the ``jit_llm_decode`` and the ``jit_llm_verify`` program of one
+    model both contain the ``pallas_call`` named ``paged_attention``,
+    the one function at T = 1 and at T = K+1. A decode step forked from
+    the verify step's attention fails here, not in a review."""
+    import jax
+
+    from client_tpu.llm.serving import LlmEngineModel
+
+    config, params = tiny_llama
+    monkeypatch.setenv("CLIENT_TPU_LLM_KERNEL", "pallas_interpret")
+    model = LlmEngineModel(
+        name="one_kernel",
+        config=config,
+        params=params,
+        engine_config=EngineConfig(
+            block_size=8, num_blocks=9, max_active=1, max_seq_len=64
+        ),
+        speculation={"mode": "ngram", "k": 2},
+    )
+    model.warmup()
+    try:
+        assert model.decode_kernel == "pallas_interpret"
+        _, decode, verify = model._device_fns
+        pages = model.engine._pages
+        one = np.zeros([1], dtype=np.int32)
+        ids = np.zeros([model.engine_config.ids_width], dtype=np.int32)
+        table = np.zeros([1, 1], dtype=np.int32)
+        programs = {
+            "llm_decode": jax.make_jaxpr(decode)(
+                ids, one, one, one, table, pages),
+            "llm_verify": jax.make_jaxpr(verify)(
+                np.zeros([1, 3], dtype=np.int32),
+                np.zeros([1, 3], dtype=np.int32), one, table, pages),
+        }
+        for name, jaxpr in programs.items():
+            text = str(jaxpr)
+            assert f"name={name}" in text
+            assert "name=paged_attention" in text, name
+    finally:
+        model.shutdown()
 
 
 def test_shared_prefix_generations_match_dense_and_share_blocks(shared_model):

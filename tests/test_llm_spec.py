@@ -4,7 +4,7 @@ Five tiers:
 
 - proposer units (no jax): n-gram prompt-lookup matching, draft-length
   clamping, allocator rollback (``truncate``) COW discipline;
-- multi-query kernel parity (jax): every ``*_mq`` attention twin and
+- multi-query kernel parity (jax): every paged-attention function and
   ``decode_step_paged_multi`` within 1e-5 of K+1 SEQUENTIAL decode
   steps, including ragged page-table widths and padding rows;
 - engine correctness on the float32 tiny llama: greedy spec-on output
@@ -140,8 +140,9 @@ def test_decode_multi_matches_sequential_oracle(tiny_llama):
     p_seq = pages
     toks, pos = last.copy(), pos0.copy()
     for step in range(3):
-        lo, p_seq = llama.decode_step_paged(
-            params, toks, pos, tables, p_seq, config
+        lo, p_seq = llama.decode_step_paged_attn(
+            params, toks, pos, tables, p_seq, config,
+            pa.paged_attention_reference,
         )
         seq_logits.append(np.asarray(lo))
         if step < 2:
@@ -153,10 +154,15 @@ def test_decode_multi_matches_sequential_oracle(tiny_llama):
     tokens = np.concatenate([last[:, None], drafts], axis=1)
     positions = (pos0[:, None] + np.arange(t)[None, :]).astype(np.int32)
     lengths = np.full([3], t, dtype=np.int32)
-    for name in ("standin", "fused_xla", "pallas_interpret"):
+    impls = {
+        "reference": pa.paged_attention_reference,
+        "fused_xla": pa.paged_attention_xla,
+        "pallas_interpret": pa.resolve_decode_attention(
+            "pallas_interpret", "cpu")[1],
+    }
+    for name, attn in impls.items():
         out, _ = llama.decode_step_paged_multi(
-            params, tokens, positions, lengths, tables, pages, config,
-            pa.get_attention_impl_mq(name),
+            params, tokens, positions, lengths, tables, pages, config, attn,
         )
         assert np.abs(np.asarray(out) - oracle).max() <= 1e-5, name
 
@@ -168,7 +174,7 @@ def test_decode_multi_matches_sequential_oracle(tiny_llama):
     ).astype(np.int32)
     out, _ = llama.decode_step_paged_multi(
         params, tokens, clamped, lengths2, tables[:, :2], pages, config,
-        pa.paged_attention_fused_xla_mq,
+        pa.paged_attention_xla,
     )
     out = np.asarray(out)
     for i in range(3):
@@ -198,12 +204,12 @@ def test_padding_rows_never_clobber_live_pages(tiny_llama):
     positions = np.array([[5, 5, 5]], dtype=np.int32)
     _, wide = llama.decode_step_paged_multi(
         params, tokens, positions, np.array([1], dtype=np.int32),
-        table[None], pages, config, pa.paged_attention_fused_xla_mq,
+        table[None], pages, config, pa.paged_attention_xla,
     )
     _, narrow = llama.decode_step_paged_multi(
         params, tokens[:, :1], positions[:, :1],
         np.array([1], dtype=np.int32), table[None], pages, config,
-        pa.paged_attention_fused_xla_mq,
+        pa.paged_attention_xla,
     )
     # the ONLY slot a verify of length 1 may touch is (block 1, offset
     # 5); everything else must be BIT-identical to the padding-free run
@@ -359,8 +365,15 @@ def test_spec_kv_airtight_under_mixed_traffic(tiny_llama):
 
         async def run():
             # a holder pins the shared prefix blocks while spec traffic
-            # churns around it
-            holder = engine.submit(prefix + [77, 78], max_tokens=8)
+            # churns around it. It has to outlast that traffic: the
+            # engine decodes it whether or not its stream is read, so it
+            # takes one token a step (speculation off), has room for
+            # three times the steps the traffic needs, and the highest
+            # priority keeps it from being the preemption's victim
+            holder = engine.submit(
+                prefix + [77, 78], max_tokens=32,
+                parameters={"priority": 1, "speculation": "off"},
+            )
             token, final = await holder.__anext__()
             assert not final
             shared_phys = list(engine.allocator.owned(holder.seq_id))[:1]
@@ -384,6 +397,11 @@ def test_spec_kv_airtight_under_mixed_traffic(tiny_llama):
                 *[_model_generate(model, p, 14) for p in prompts]
             )
             after = snapshot()
+            # the snapshots are of a block the holder still pins: a freed
+            # block handed to a later sequence would be rewritten, by
+            # right, and say nothing about copy-on-write
+            assert engine.allocator.owned(holder.seq_id)[:1] == shared_phys
+            assert holder.preemptions == 0
             for (bk, bv), (ak, av) in zip(before, after):
                 np.testing.assert_array_equal(bk, ak)
                 np.testing.assert_array_equal(bv, av)

@@ -44,7 +44,7 @@ def _kernels(name):
     from client_tpu.models import paged_attention
     from client_tpu.models.engine_model import Kernels
 
-    return Kernels(name, paged_attention.get_attention_impl(name))
+    return Kernels(*paged_attention.resolve_decode_attention(name, "cpu"))
 
 
 @pytest.fixture(scope="module", params=["fused_xla", "pallas_interpret"])
@@ -349,10 +349,9 @@ def test_window_pool_holds_a_ring_for_each_of_max_active():
     (dict(speculation={"mode": "ngram", "k": 2}), "verify"),
     (dict(engine=dict(prefix_sharing=True)), "prefill_suffix"),
     (dict(tp=2), "param_specs"),
-    (dict(kernel="standin"), "standin"),
 ])
 def test_a_model_without_the_part_is_refused_the_feature_at_load(
-        feature, part, monkeypatch):
+        feature, part):
     from client_tpu.llm.engine import EngineConfig
     from client_tpu.llm.serving import LlmEngineModel
     from client_tpu.models import mimo_v2
@@ -362,8 +361,6 @@ def test_a_model_without_the_part_is_refused_the_feature_at_load(
                  max_seq_len=128, prefix_sharing=False)
     feature = dict(feature)
     sizes.update(feature.pop("engine", {}))
-    if "kernel" in feature:  # the load-time choice: no inline attention
-        monkeypatch.setenv("CLIENT_TPU_LLM_KERNEL", feature.pop("kernel"))
     model = LlmEngineModel(
         name="mimo_toy", model=mimo_v2.ENGINE_MODEL,
         config=mimo_v2.MimoV2Config.tiny(),
@@ -396,7 +393,6 @@ def test_llama_declares_one_full_group_and_serves_as_before():
     assert group.kind == FULL and group.layers == (0, 1)
     assert group.window is None
     assert llama.ENGINE_MODEL.missing_for(
-        speculation=True, prefix_sharing=True, tp=4,
-        kernel="standin") is None
+        speculation=True, prefix_sharing=True, tp=4) is None
     sizes = EngineConfig(num_blocks=65, cache_groups=(group,))
     assert sizes.group_num_blocks() == [65]

@@ -67,13 +67,13 @@ def test_mosaic_compiles_unequal_rows_window_and_sink(one_chip, case):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     args = [
-        shaped((batch, heads, 256), jnp.bfloat16),
+        shaped((batch, 1, heads, 256), jnp.bfloat16),
         # flat pools: at KV 4 a [.., 4, D] pool is padded to 8 rows in
         # HBM and its flat view is a copy of the whole pool
         shaped((blocks, BLOCK * kv_heads, 256), jnp.bfloat16),
         shaped((blocks, BLOCK * kv_heads, 128), jnp.bfloat16),
         shaped((batch, columns), jnp.int32),
-        shaped((batch,), jnp.int32),
+        shaped((batch, 1), jnp.int32),
     ]
     masking = {"window": window, "scale": 192 ** -0.5, "kv_heads": kv_heads}
     fn = functools.partial(pa.paged_attention_pallas, **masking)
@@ -133,15 +133,10 @@ def test_mosaic_compiles_the_paged_attention_kernel(one_chip, case):
 
     pool = shaped((blocks, BLOCK, kv_heads, HEAD_DIM), jnp.bfloat16)
     tables = shaped((batch, columns), jnp.int32)
-    if rows == 1:
-        fn = pa.paged_attention_pallas
-        q = shaped((batch, heads, HEAD_DIM), jnp.bfloat16)
-        positions = shaped((batch,), jnp.int32)
-    else:
-        fn = pa.paged_attention_pallas_mq
-        q = shaped((batch, rows, heads, HEAD_DIM), jnp.bfloat16)
-        positions = shaped((batch, rows), jnp.int32)
-    compiled = jax.jit(fn).lower(q, pool, pool, tables, positions).compile()
+    q = shaped((batch, rows, heads, HEAD_DIM), jnp.bfloat16)
+    positions = shaped((batch, rows), jnp.int32)
+    compiled = jax.jit(pa.paged_attention_pallas).lower(
+        q, pool, pool, tables, positions).compile()
     text = compiled.as_text()
     assert 'custom_call_target="tpu_custom_call"' in text
     assert "%paged_attention" in text  # the name the trace readers select

@@ -8,6 +8,7 @@ streaming half-close orderings, and the aio fallback staying available.
 """
 
 import asyncio
+import os
 import threading
 
 import numpy as np
@@ -182,3 +183,16 @@ def test_aio_frontend_still_available():
         assert s.grpc_impl == "aio"
         with grpcclient.InferenceServerClient(s.grpc_url) as client:
             assert client.is_server_live()
+
+
+def test_default_grpc_frontend_is_native_where_it_is_built():
+    """``InProcessServer(grpc=True)`` takes the C++ front-end wherever
+    its extension is built: with ``build/`` made before the session's
+    first test, that is every worker from its first test, so which gRPC
+    server tier-1 covers is not the scheduler's choice."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if not os.path.exists(
+        os.path.join(repo, "build", "_native_frontend.so")
+    ):
+        pytest.skip("native build absent")
+    assert InProcessServer().grpc_impl == "native"

@@ -420,7 +420,7 @@ def test_pod_process_gauges_prune_on_replacement():
 # tp-sharded parity on the in-process mesh
 # ---------------------------------------------------------------------------
 
-KERNELS = ("standin", "fused_xla", "pallas_interpret")
+KERNELS = ("fused_xla", "pallas_interpret")
 
 #: two full blocks at block_size=8 — the shared prefix of the COW tests
 PREFIX = [9, 3, 7, 1, 5, 2, 8, 4, 6, 1, 2, 3, 4, 5, 6, 7]
@@ -518,8 +518,8 @@ def test_tp_paged_decode_parity_per_kernel(sharded_devices, monkeypatch, kernel)
 def test_tp_attention_twins_match_unsharded(sharded_devices):
     """``make_tp_attention`` — the shard_map wrap the engine applies
     under tp — equals the unsharded kernel call within 1e-5 for every
-    wrappable implementation AND its ``*_mq`` (speculative-verify)
-    twin, on ragged page layouts."""
+    served implementation, at one query row a sequence (decode) and at
+    several (speculative verify), on ragged page layouts."""
     from client_tpu.models import paged_attention as pa
     from client_tpu.parallel import sharding as mesh_sharding
 
@@ -534,26 +534,19 @@ def test_tp_attention_twins_match_unsharded(sharded_devices):
     tables[0, :1] = [1]
     tables[1, :2] = [2, 3]
     tables[2, :4] = [4, 5, 6, 7]
-    positions = np.array([5, 11, 25], np.int32)
-    q = rng.normal(size=(b, h, d)).astype(np.float32)
-    t = 3
-    q_mq = rng.normal(size=(b, t, h, d)).astype(np.float32)
-    pos_mq = (positions[:, None] + np.arange(t)[None, :]).astype(np.int32)
-    for name in ("fused_xla", "pallas_interpret"):
-        attn = pa.get_attention_impl(name)
-        reference = np.asarray(attn(q, k_pages, v_pages, tables, positions))
+    first = np.array([5, 11, 25], np.int32)
+    for name in KERNELS:
+        _, attn = pa.resolve_decode_attention(name, "cpu")
         wrapped = pa.make_tp_attention(attn, plan.mesh)
-        got = np.asarray(wrapped(q, k_pages, v_pages, tables, positions))
-        assert np.abs(got - reference).max() <= 1e-5, name
-        attn_mq = pa.get_attention_impl_mq(name)
-        reference_mq = np.asarray(
-            attn_mq(q_mq, k_pages, v_pages, tables, pos_mq)
-        )
-        wrapped_mq = pa.make_tp_attention(attn_mq, plan.mesh, multi_query=True)
-        got_mq = np.asarray(
-            wrapped_mq(q_mq, k_pages, v_pages, tables, pos_mq)
-        )
-        assert np.abs(got_mq - reference_mq).max() <= 1e-5, f"{name}_mq"
+        for t in (1, 3):
+            q = rng.normal(size=(b, t, h, d)).astype(np.float32)
+            positions = (first[:, None] + np.arange(t)[None, :]).astype(
+                np.int32
+            )
+            args = (q, k_pages, v_pages, tables, positions)
+            reference = np.asarray(attn(*args))
+            got = np.asarray(wrapped(*args))
+            assert np.abs(got - reference).max() <= 1e-5, (name, t)
 
 
 @pytest.mark.sharded

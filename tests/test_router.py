@@ -10,6 +10,8 @@ drain-in ramp — ISSUE 16's acceptance criteria.
 """
 
 import asyncio
+import contextlib
+import logging
 import os
 import signal
 import subprocess
@@ -23,6 +25,7 @@ import pytest
 from client_tpu.grpc import _wire as wire
 from client_tpu.grpc._generated import grpc_service_pb2 as pb
 from client_tpu.grpc._utils import set_parameter
+from client_tpu.testing import rerun_on_grpc_poller_breakdown
 from client_tpu.utils import InferenceServerException
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -203,6 +206,46 @@ def test_retry_grpc_poller_flake_retries_empty_runs_only():
         retry_grpc_poller_flake(run, lambda n: True, attempts=0)
 
 
+@pytest.mark.parametrize("breaks_down,attempts", [(True, 2), (False, 1)])
+def test_rerun_on_grpc_poller_breakdown_needs_the_breakdown(
+        breaks_down, attempts):
+    """A failed test runs again only where asyncio logged the poller's
+    breakdown while it ran; without that the first failure is the
+    test's, and a test that fails twice fails."""
+    calls = []
+
+    @rerun_on_grpc_poller_breakdown
+    def flaky(always=False):
+        calls.append(1)
+        if breaks_down:
+            try:
+                raise BlockingIOError(11, "Resource temporarily unavailable")
+            except BlockingIOError:
+                logging.getLogger("asyncio").error(
+                    "Exception in callback "
+                    "PollerCompletionQueue._handle_events()", exc_info=True)
+        assert len(calls) > 1 and not always
+        return "served"
+
+    def rerun_said():
+        if breaks_down:
+            return pytest.warns(UserWarning, match="poller broke down")
+        return contextlib.nullcontext()
+
+    with rerun_said():
+        if breaks_down:
+            assert flaky() == "served"
+        else:
+            with pytest.raises(AssertionError):
+                flaky()
+    assert len(calls) == attempts
+    calls.clear()
+    with rerun_said(), pytest.raises(AssertionError):
+        flaky(always=True)
+    assert len(calls) == attempts
+    assert not logging.getLogger("asyncio").handlers
+
+
 # ---------------------------------------------------------------------------
 # integration: traffic through a live router
 
@@ -312,6 +355,7 @@ def test_router_stream_decoupled_roundtrip():
 
 @pytest.mark.fleet
 @pytest.mark.chaos
+@rerun_on_grpc_poller_breakdown
 def test_router_backend_kill_zero_client_failures():
     """Chaos: a backend replica dies mid-run behind the router; the
     router benches it (readiness probe + UNAVAILABLE retry) and every

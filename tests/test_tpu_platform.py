@@ -100,12 +100,12 @@ def test_compiled_pallas_decode_matches_fused_xla(device, kv_heads, batch,
         rng, batch, kv_heads, n_blocks, rows=1
     )
     q = jnp.asarray(
-        rng.normal(size=(batch, HEADS, HEAD_DIM)), dtype=jnp.bfloat16
+        rng.normal(size=(batch, 1, HEADS, HEAD_DIM)), dtype=jnp.bfloat16
     )
-    args = (q, k_pages, v_pages, tables, positions)
+    args = (q, k_pages, v_pages, tables, positions[:, None])
     _assert_bf16_close(
         jax.jit(pa.paged_attention_pallas)(*args),
-        jax.jit(pa.paged_attention_fused_xla)(*args),
+        jax.jit(pa.paged_attention_xla)(*args),
         f"single-query KV={kv_heads} B={batch} NB={n_blocks}",
     )
 
@@ -137,8 +137,8 @@ def test_compiled_pallas_verify_matches_fused_xla(device, kv_heads, batch,
     )
     args = (q, k_pages, v_pages, tables, positions)
     _assert_bf16_close(
-        jax.jit(pa.paged_attention_pallas_mq)(*args),
-        jax.jit(pa.paged_attention_fused_xla_mq)(*args),
+        jax.jit(pa.paged_attention_pallas)(*args),
+        jax.jit(pa.paged_attention_xla)(*args),
         f"multi-query KV={kv_heads} B={batch} NB={n_blocks}",
     )
 
@@ -168,15 +168,10 @@ def test_compiled_pallas_at_the_benchmark_cells_shapes(device, rows):
     q = jnp.asarray(
         rng.normal(size=(batch, rows, HEADS, HEAD_DIM)), dtype=jnp.bfloat16
     )
-    if rows == 1:
-        args = (q[:, 0], k_pages, v_pages, tables, positions[:, 0])
-        pallas, fused = pa.paged_attention_pallas, pa.paged_attention_fused_xla
-    else:
-        args = (q, k_pages, v_pages, tables, positions)
-        pallas = pa.paged_attention_pallas_mq
-        fused = pa.paged_attention_fused_xla_mq
+    args = (q, k_pages, v_pages, tables, positions)
     _assert_bf16_close(
-        jax.jit(pallas)(*args), jax.jit(fused)(*args),
+        jax.jit(pa.paged_attention_pallas)(*args),
+        jax.jit(pa.paged_attention_xla)(*args),
         f"the cell's shapes, T={rows}",
     )
 
@@ -203,26 +198,13 @@ def test_tp_sharded_pallas_matches_unsharded_fused_xla(device):
         rng.normal(size=(batch, rows, HEADS, HEAD_DIM)), dtype=jnp.bfloat16
     )
     positions = (first[:, None] + np.arange(rows)[None, :]).astype(np.int32)
-    _assert_bf16_close(
-        jax.jit(pa.make_tp_attention(pa.paged_attention_pallas, mesh))(
-            q[:, 0], k_pages, v_pages, tables, first
-        ),
-        jax.jit(pa.paged_attention_fused_xla)(
-            q[:, 0], k_pages, v_pages, tables, first
-        ),
-        "tp=4 single-query",
-    )
-    _assert_bf16_close(
-        jax.jit(
-            pa.make_tp_attention(
-                pa.paged_attention_pallas_mq, mesh, multi_query=True
-            )
-        )(q, k_pages, v_pages, tables, positions),
-        jax.jit(pa.paged_attention_fused_xla_mq)(
-            q, k_pages, v_pages, tables, positions
-        ),
-        "tp=4 multi-query",
-    )
+    sharded = jax.jit(pa.make_tp_attention(pa.paged_attention_pallas, mesh))
+    for what, t in (("single-query", 1), ("multi-query", rows)):
+        args = (q[:, :t], k_pages, v_pages, tables, positions[:, :t])
+        _assert_bf16_close(
+            sharded(*args), jax.jit(pa.paged_attention_xla)(*args),
+            f"tp=4 {what}",
+        )
 
 
 def test_full_width_decode_logits_match_through_both_kernels(device):
@@ -265,7 +247,7 @@ def test_full_width_decode_logits_match_through_both_kernels(device):
         return fn(params, tokens, positions, tables, pages)[0]
 
     pallas = np.asarray(step(pa.paged_attention_pallas))
-    fused = np.asarray(step(pa.paged_attention_fused_xla))
+    fused = np.asarray(step(pa.paged_attention_xla))
     assert pallas.shape == (2, config.vocab_size)
     assert np.isfinite(pallas).all()
     # The two attention outputs differ by bf16 roundings (see
@@ -317,13 +299,13 @@ def test_compiled_pallas_at_the_mimo_cells_shapes(device, group):
         full[..., :real] = rng.normal(size=shape + (real,))
         return jnp.asarray(full, jnp.bfloat16)
 
-    q = rows((batch, heads), 256, 192)
+    q = rows((batch, 1, heads), 256, 192)
     k_pages = rows((blocks, BLOCK * kv_heads), 256, 192)
     v_pages = rows((blocks, BLOCK * kv_heads), 128, 128)
-    args = (q, k_pages, v_pages, tables.astype(np.int32), positions)
+    args = (q, k_pages, v_pages, tables.astype(np.int32), positions[:, None])
     _assert_bf16_close(
         jax.jit(lambda *a: pa.paged_attention_pallas(*a, **masking))(*args),
-        jax.jit(lambda *a: pa.paged_attention_fused_xla(*a, **masking))(*args),
+        jax.jit(lambda *a: pa.paged_attention_xla(*a, **masking))(*args),
         f"mimo's {group} group",
     )
 
