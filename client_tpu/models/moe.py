@@ -9,25 +9,34 @@ is another chip's, and on one chip the exchange that would bring it is
 simply not there. The shares' outputs add up to the whole layer's
 (``tests/test_mimo_v2.py``).
 
-The held experts' matmuls are grouped by expert: the (token, expert)
-pairs that land on a held expert are sorted by expert, each expert's
-rows padded to a whole number of row tiles, and one Pallas kernel
-(``name="moe_experts"``, the name a device trace shows) walks the tiles.
-The tile's expert rides scalar prefetch and picks the weight blocks, so
-an expert no token chose is never read, consecutive tiles of one expert
-reuse its block, and the tiles past the last used one re-name the last
-block fetched and do nothing. Which path runs is the load-time kernel
-choice's (``engine_model.Kernels.name``), never this module's: the
-kernel compiled, the kernel interpreted, or the plain XLA path
-(``jax.lax.ragged_dot`` over the same sorted pairs). There is no dense
-pass over all held experts in any of them: in a 2,048-token prefill that
-would be 16 times the routed FLOPs.
+Two Pallas kernels share the name ``moe_experts`` (what a device trace
+shows), and the call's row count alone picks one (:data:`_RESIDENT_ROWS`):
+
+- a decode batch (few rows) is not planned at all: every row stays
+  resident in VMEM, the kernel walks the held experts some row chose and
+  adds each expert's SwiGLU output at the row's routing weight for it
+  (zero where row and expert are no pair) into one float32 ``[T, d]``
+  (:func:`resident_experts`). What XLA prepares is a handful of
+  element-wise operations: no sort, no row gather, no pair gather.
+- a prefill's rows are grouped by expert: the (token, expert) pairs that
+  land on a held expert are sorted by expert, each expert's rows padded
+  to a whole number of row tiles, and the kernel walks the tiles
+  (:func:`grouped_experts`). A dense pass over all held experts would be
+  16 times the routed FLOPs in a 2,048-token prefill.
+
+In both the expert of a grid step rides scalar prefetch and picks the
+weight blocks, so an expert no token chose is never read, and a step
+with nothing to do re-names the block already there and costs no DMA.
+Whether a kernel runs compiled or interpreted, or the plain XLA path
+does (``jax.lax.ragged_dot`` over the sorted pairs), is the load-time
+kernel choice's (``engine_model.Kernels.name``), never this module's.
 """
 
 import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -38,7 +47,22 @@ _F_TILE = 512
 #: the three weight blocks twice (one in flight), rows, accumulator
 _VMEM_LIMIT = 48 << 20
 
-COUNTERS = ("moe_pairs", "moe_experts_touched", "moe_load_max")
+#: the most rows an expert-layer call may have to keep them all resident:
+#: the rows a weight tile takes in one pass of the MXU. Up to here an
+#: expert's matmuls are bound by bringing its weights in, not by the
+#: rows streamed past them, so the rows no pair names ride free.
+#: Measured on a v5e at the cell's sizes (PR 30; the whole layer, router's
+#: outputs in, ms a call, resident / planned in row tiles of 16 / a
+#: dense pass): 64 rows 1.13 / 1.20 / 1.30, 128 rows 1.20 / 1.36 / 1.38,
+#: the kernel itself level (1.006 against 1.012 at 64 rows);
+#: ``tests/test_tpu_platform.py`` asserts the order
+_RESIDENT_ROWS = 128
+#: rows of a planned tile: one tile should hold all of an expert's rows
+#: in a prefill, so its weights are read once
+_ROW_TILE = 128
+
+COUNTERS = ("moe_pairs", "moe_experts_touched", "moe_load_max",
+            "moe_resident_calls")
 
 
 def route(h, router, bias, top_k: int):
@@ -53,14 +77,6 @@ def route(h, router, bias, top_k: int):
     _, ids = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
     picked = jnp.take_along_axis(scores, ids, axis=-1)
     return ids.astype(jnp.int32), picked / picked.sum(axis=-1, keepdims=True)
-
-
-def row_tile(tokens: int, top_k: int) -> int:
-    """Rows of a tile: 16 (a bf16 sublane tile) for a decode batch,
-    where a held expert sees a handful of tokens, 128 for a prefill,
-    where one tile should hold all of an expert's rows so its weights
-    are read once."""
-    return 16 if tokens * top_k <= 1024 else 128
 
 
 def _held_pairs(ids, held):
@@ -184,6 +200,127 @@ def grouped_experts(x_rows, tile_expert, used, w_gate, w_up, w_down, *,
     )(tile_expert, used.reshape(1), x_rows, w_gate, w_up, w_down)
 
 
+def _walk(counts):
+    """What the resident kernel's step ``e`` does, one int32 a held
+    expert, for scalar prefetch: ``count + e`` where some row chose
+    expert ``e`` (compute it); else ``count + p`` for the last chosen
+    expert ``p`` before ``e``; else ``count - 1 - n`` for the first
+    chosen expert ``n`` after it (0 where no row chose any). One masked
+    maximum, so one device operation; :func:`_walk_block` reads it."""
+    count = counts.shape[0]
+    there, here = np.arange(count)[None, :], np.arange(count)[:, None]
+    # a table of numpy's, so the program holds it as a literal
+    named = np.where(there <= here, count + there, count - 1 - there)
+    return jnp.where(counts[None, :] > 0, named.astype(np.int32), 0).max(
+        axis=1)
+
+
+def _walk_block(walk, e, j, n_f: int):
+    """(expert block, f block) that step ``(e, j)`` names: its own where
+    expert ``e`` is chosen, else the block already there, which is the
+    last one of the chosen expert before it, or the first one of the
+    chosen expert after it: an expert no row chose costs no DMA."""
+    count = walk.shape[0]
+    k = walk[e]
+    chosen, after = k == count + e, k >= count
+    return (jnp.where(after, k - count, count - 1 - k),
+            jnp.where(chosen, j, jnp.where(after, n_f - 1, 0)))
+
+
+def _resident_kernel(walk_ref, x_ref, w_ref, gate_ref, up_ref, down_ref,
+                     o_ref):
+    """Grid step (expert, f): every row through one block of the expert's
+    hidden width, added into the output block (resident: its index never
+    changes, so it leaves VMEM once, after the last step) at each row's
+    weight for this expert."""
+    e, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when((e == 0) & (j == 0))
+    def _zero():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(walk_ref[e] == walk_ref.shape[0] + e)
+    def _expert():
+        x = x_ref[...]
+        gate = jnp.dot(x, gate_ref[0], preferred_element_type=jnp.float32)
+        up = jnp.dot(x, up_ref[0], preferred_element_type=jnp.float32)
+        mid = (jax.nn.silu(gate) * up).astype(x.dtype)
+        down = jnp.dot(mid, down_ref[0], preferred_element_type=jnp.float32)
+        w = w_ref[...]
+        mine = jax.lax.broadcasted_iota(jnp.int32, w.shape, 1) == e
+        o_ref[...] += down * jnp.where(mine, w, 0.0).sum(
+            axis=1, keepdims=True)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def resident_experts(x, w, counts, w_gate, w_up, w_down, *,
+                     interpret: bool = False):
+    """``x`` [T, d] with T a whole number of bf16 sublane tiles (16 rows)
+    and at most :data:`_RESIDENT_ROWS`; ``w`` [T, count] float32, row
+    ``t``'s routing weight for held expert ``e`` and zero where they are
+    no pair; ``counts`` [count], the pairs on each held expert. Returns
+    ``sum_e w[:, e] * swiglu_e(x)`` over the experts with a pair, [T, d]
+    float32. Jitted, so a model's layers share one lowering."""
+    rows, d = x.shape
+    count, _, f = w_gate.shape
+    tf = min(_F_TILE, f)
+    n_f = f // tf
+
+    def resident(e, j, walk):
+        return (0, 0)
+
+    def wide(e, j, walk):
+        block, f_block = _walk_block(walk, e, j, n_f)
+        return (block, 0, f_block)
+
+    def tall(e, j, walk):
+        block, f_block = _walk_block(walk, e, j, n_f)
+        return (block, f_block, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(count, n_f),
+        in_specs=[
+            pl.BlockSpec((rows, d), resident),
+            pl.BlockSpec((rows, count), resident),
+            pl.BlockSpec((1, d, tf), wide),
+            pl.BlockSpec((1, d, tf), wide),
+            pl.BlockSpec((1, tf, d), tall),
+        ],
+        out_specs=pl.BlockSpec((rows, d), resident),
+    )
+    return pl.pallas_call(
+        _resident_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((rows, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT,
+        ),
+        interpret=interpret,
+        name="moe_experts",
+    )(_walk(counts), x, w, w_gate, w_up, w_down)
+
+
+def _resident_layer(h, ids, weights, experts, held, interpret: bool):
+    """What XLA prepares for :func:`resident_experts` and its call: the
+    dense weight ``w[T, count] = sum_k where(ids[:, k] == first + e,
+    weights[:, k], 0)`` and the held experts' pairs, a compare and two
+    sums. Returns ``(out [T, d] float32, counts [count])``."""
+    tokens = h.shape[0]
+    first, count = held
+    hit = ids[..., None] == first + jnp.arange(count, dtype=ids.dtype)
+    counts = hit.sum(axis=(0, 1), dtype=jnp.int32)
+    w = jnp.where(hit, weights[..., None], 0.0).sum(axis=1)
+    # a batch bucket under a bf16 sublane tile is padded to one
+    pad = ((0, -tokens % 16), (0, 0))
+    out = resident_experts(
+        jnp.pad(h, pad), jnp.pad(w, pad), counts,
+        experts["w_gate"], experts["w_up"], experts["w_down"],
+        interpret=interpret)
+    return out[:tokens], counts
+
+
 def _experts_xla(h, flat, counts, experts, shape):
     """The plain path: the pairs (:func:`_held_pairs`) sorted by expert,
     those on no held expert last, go through three ``ragged_dot`` s over
@@ -209,28 +346,38 @@ def expert_layer(h, ids, weights, experts, held, *, kernel: str):
     :func:`route` over all experts, ``experts`` the held experts' stacked
     weights (``w_gate`` / ``w_up`` [count, d, f], ``w_down`` [count, f,
     d]), ``held = (first, count)``. ``kernel`` is the load-time choice:
-    ``pallas`` the ``moe_experts`` kernel, ``pallas_interpret`` the same
-    under the interpreter, anything else the plain XLA path. Returns
-    ``(out [T, d] float32, counters [3] int32)``: :data:`COUNTERS` for
+    ``pallas`` the ``moe_experts`` kernels, ``pallas_interpret`` the same
+    under the interpreter, anything else the plain XLA path. Under the
+    first two a call of at most :data:`_RESIDENT_ROWS` rows keeps its
+    rows resident and plans nothing; a longer one is planned. Returns
+    ``(out [T, d] float32, counters [4] int32)``: :data:`COUNTERS` for
     this call, which are the pairs on held experts, the held experts
-    some token chose, and the most pairs on one of them."""
-    on, flat, counts = _held_pairs(ids, held)
-    if kernel in ("pallas", "pallas_interpret"):
-        tm = row_tile(*ids.shape)
-        row_token, pair_row, tile_expert, used = _plan(
-            flat, counts, ids.shape, tm)
-        out_rows = grouped_experts(
-            h[row_token], tile_expert, used,
-            experts["w_gate"], experts["w_up"], experts["w_down"],
-            tm=tm, interpret=kernel == "pallas_interpret",
-        )
-        picked = out_rows[pair_row].astype(jnp.float32)
+    some token chose, the most pairs on one of them, and 1 if the call
+    took the resident path."""
+    pallas = kernel in ("pallas", "pallas_interpret")
+    resident = pallas and h.shape[0] <= _RESIDENT_ROWS
+    if resident:
+        out, counts = _resident_layer(
+            h, ids, weights, experts, held, kernel == "pallas_interpret")
     else:
-        picked = _experts_xla(h, flat, counts, experts, ids.shape)
-    # a gather by pair, not a scatter by row; `where`, not a product with
-    # a zero weight: the rows no pair names hold whatever was in memory
-    out = (jnp.where(on[..., None], picked, 0.0)
-           * weights[..., None]).sum(axis=1)
+        on, flat, counts = _held_pairs(ids, held)
+        if pallas:
+            row_token, pair_row, tile_expert, used = _plan(
+                flat, counts, ids.shape, _ROW_TILE)
+            out_rows = grouped_experts(
+                h[row_token], tile_expert, used,
+                experts["w_gate"], experts["w_up"], experts["w_down"],
+                tm=_ROW_TILE, interpret=kernel == "pallas_interpret",
+            )
+            picked = out_rows[pair_row].astype(jnp.float32)
+        else:
+            picked = _experts_xla(h, flat, counts, experts, ids.shape)
+        # a gather by pair, not a scatter by row; `where`, not a product
+        # with a zero weight: the rows no pair names hold whatever was in
+        # memory
+        out = (jnp.where(on[..., None], picked, 0.0)
+               * weights[..., None]).sum(axis=1)
     counters = jnp.stack(
-        [on.sum(), (counts > 0).sum(), counts.max()]).astype(jnp.int32)
+        [counts.sum(), (counts > 0).sum(), counts.max(), jnp.int32(resident)]
+    ).astype(jnp.int32)
     return out, counters

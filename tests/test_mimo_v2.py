@@ -158,42 +158,137 @@ def test_each_omission_alone_fails_the_comparison(toy, omission, monkeypatch):
     assert np.abs(served - ref).max() > 100 * TOLERANCE
 
 
-@pytest.mark.parametrize("kernel", ["fused_xla", "pallas_interpret"])
+# the expert layer's paths: the plain XLA one, and under the Pallas choice
+# the one the call's row count picks, or the planned one at every size
+PATHS = {"fused_xla": "fused_xla", "resident": "pallas_interpret",
+         "planned": "pallas_interpret"}
+
+
+def _expert_layer(path, monkeypatch):
+    """`moe.expert_layer` on ``path``. The planned path is steered here,
+    in the test, to the short calls the row count would keep resident."""
+    import functools
+
+    from client_tpu.models import moe
+
+    if path == "planned":
+        monkeypatch.setattr(moe, "_RESIDENT_ROWS", 0)
+    return functools.partial(moe.expert_layer, kernel=PATHS[path])
+
+
+def _to32(tree):
+    import jax
+    import jax.numpy as jnp
+
+    return jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), tree)
+
+
+@pytest.mark.parametrize("path", PATHS)
 @pytest.mark.parametrize("shares", [16, 4, 1])
 def test_shares_of_the_expert_layer_add_up_to_the_uncut_reference(
-        shares, kernel):
+        shares, path, monkeypatch):
     """The share test: each of ``shares`` chips holds 16 / shares experts
     of a layer, routes over all 16 and computes its own experts' part;
     the parts add up to the uncut reference's layer output."""
-    import jax
     import jax.numpy as jnp
 
     from benchmark.lib import reference_mimo, weights_mimo
     from client_tpu.models import moe
 
-    to32 = lambda tree: jax.tree_util.tree_map(  # noqa: E731
-        lambda a: a.astype(jnp.float32), tree)
-    whole = to32(weights_mimo.layer(SEED, 1, TOY, held_experts=(0, 16)))
+    expert_layer = _expert_layer(path, monkeypatch)
+    whole = _to32(weights_mimo.layer(SEED, 1, TOY, held_experts=(0, 16)))
     h = jnp.asarray(np.random.default_rng(1).normal(size=(40, 64)),
                     jnp.float32)
     ref = reference_mimo.expert_layer(h, whole, TOY, (0, 16))
     ids, weights = moe.route(h, whole["router"], whole["router_bias"], 4)
     count = 16 // shares
-    total, pairs = 0.0, 0
+    total, pairs, resident = 0.0, 0, 0
     for share in range(shares):
         held = (share * count, count)
-        mine = to32(weights_mimo.layer(SEED, 1, TOY, held_experts=held))
-        out, counters = moe.expert_layer(
-            h, ids, weights, mine["experts"], held, kernel=kernel)
+        mine = _to32(weights_mimo.layer(SEED, 1, TOY, held_experts=held))
+        out, counters = expert_layer(h, ids, weights, mine["experts"], held)
         total = total + out
         pairs += int(counters[0])
+        resident += int(counters[3])
     assert pairs == 40 * 4  # every pair lands on exactly one share
+    assert resident == (shares if path == "resident" else 0)
     assert np.abs(np.asarray(ref)).max() > 0.1
     assert np.abs(np.asarray(total) - np.asarray(ref)).max() <= TOLERANCE
 
 
-@pytest.mark.parametrize("kernel", ["fused_xla", "pallas_interpret"])
-def test_a_share_no_token_chose_gives_zero(kernel):
+@pytest.mark.parametrize("tokens", [1, 5, 64, 128, 129])
+def test_the_row_count_alone_picks_the_path_and_both_give_the_reference(
+        tokens, monkeypatch):
+    """Up to `moe._RESIDENT_ROWS` rows a call under the Pallas choice
+    keeps its rows resident, a longer one is planned; either gives what
+    the reference's loop over the held experts gives, and where both can
+    run the same call they count the same pairs, experts and load."""
+    import jax.numpy as jnp
+
+    from benchmark.lib import reference_mimo, weights_mimo
+    from client_tpu.models import moe
+
+    held = (4, 8)
+    mine = _to32(weights_mimo.layer(SEED, 2, TOY, held_experts=held))
+    h = jnp.asarray(np.random.default_rng(tokens).normal(size=(tokens, 64)),
+                    jnp.float32)
+    ref = np.asarray(reference_mimo.expert_layer(h, mine, TOY, held))
+    ids, weights = moe.route(h, mine["router"], mine["router_bias"], 4)
+    args = (h, ids, weights, mine["experts"], held)
+    out, counters = _expert_layer("resident", monkeypatch)(*args)
+    assert int(counters[3]) == (tokens <= 128)
+    assert np.abs(ref).max() > 0.05
+    assert np.abs(np.asarray(out) - ref).max() <= TOLERANCE
+    plain, plain_counters = _expert_layer("fused_xla", monkeypatch)(*args)
+    assert np.abs(np.asarray(plain) - ref).max() <= TOLERANCE
+    planned, planned_counters = _expert_layer("planned", monkeypatch)(*args)
+    assert int(planned_counters[3]) == 0
+    assert np.abs(np.asarray(planned) - ref).max() <= TOLERANCE
+    for other in (plain_counters, planned_counters):
+        assert np.asarray(other)[:3].tolist() == np.asarray(
+            counters)[:3].tolist()
+    on = (np.asarray(ids) >= 4) & (np.asarray(ids) < 12)
+    load = np.bincount(np.asarray(ids)[on] - 4, minlength=8)
+    assert np.asarray(counters)[:3].tolist() == [
+        on.sum(), (load > 0).sum(), load.max()]
+
+
+@pytest.mark.parametrize("path", ["resident", "planned"])
+def test_an_expert_no_lane_chose_is_never_computed(path, monkeypatch):
+    """The held experts no row chose are poisoned with NaN: the output
+    stays finite and equal, so neither kernel ran them (a product with a
+    zero weight would not have hidden a NaN)."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.lib import weights_mimo
+
+    held = (0, 16)
+    mine = _to32(weights_mimo.layer(SEED, 1, TOY, held_experts=held))
+    h = jnp.asarray(np.random.default_rng(5).normal(size=(3, 64)),
+                    jnp.float32)
+    # no row chose expert 0 (before the first chosen one), 3, 4, 7, 8 and
+    # 10 to 12 (between two) nor 14 and 15 (after the last): the walk
+    # names a block already there in each of the three ways
+    ids = jnp.asarray([[1, 2, 5, 6], [2, 5, 9, 13], [1, 6, 9, 13]], jnp.int32)
+    weights = jnp.asarray(
+        np.random.default_rng(6).uniform(0.1, 0.4, size=(3, 4)), jnp.float32)
+    chosen = np.zeros(16, bool)
+    chosen[np.asarray(ids).reshape(-1)] = True
+    poisoned = jax.tree_util.tree_map(
+        lambda a: jnp.where(chosen[:, None, None], a, jnp.nan),
+        mine["experts"])
+    expert_layer = _expert_layer(path, monkeypatch)
+    clean, _ = expert_layer(h, ids, weights, mine["experts"], held)
+    out, counters = expert_layer(h, ids, weights, poisoned, held)
+    assert int(counters[1]) == chosen.sum()
+    assert np.isfinite(np.asarray(out)).all()
+    assert np.abs(np.asarray(clean)).max() > 0.05
+    assert (np.asarray(out) == np.asarray(clean)).all()
+
+
+@pytest.mark.parametrize("path", PATHS)
+def test_a_share_no_token_chose_gives_zero(path, monkeypatch):
     import jax.numpy as jnp
 
     from benchmark.lib import weights_mimo
@@ -203,9 +298,11 @@ def test_a_share_no_token_chose_gives_zero(kernel):
     h = jnp.ones((5, 64), jnp.bfloat16)
     ids = jnp.asarray(np.random.default_rng(2).integers(4, 16, size=(5, 4)),
                       jnp.int32)
-    out, counters = moe.expert_layer(
-        h, ids, jnp.full((5, 4), 0.25), experts, (0, 4), kernel=kernel)
-    assert not np.asarray(out).any() and not np.asarray(counters).any()
+    out, counters = _expert_layer(path, monkeypatch)(
+        h, ids, jnp.full((5, 4), 0.25), experts, (0, 4))
+    assert out.shape == (5, 64) and not np.asarray(out).any()
+    assert moe.COUNTERS[3] == "moe_resident_calls"
+    assert np.asarray(counters).tolist() == [0, 0, 0, path == "resident"]
     with pytest.raises(ValueError, match="not a share"):
         mimo_v2.MimoV2Config.tiny(held=(12, 8))
 
@@ -272,6 +369,8 @@ def test_engine_serves_both_groups_and_matches_the_reference():
         assert stats["kv_blocks_in_use_by_group"] == [0, 0]  # all returned
         assert stats["window_blocks_whole"] > stats["window_blocks_unheld"] > 0
         assert stats["moe_pairs"] > 0 and stats["moe_load_max"] > 0
+        # off the TPU the load-time choice is the plain XLA path
+        assert stats["moe_resident_calls"] == 0
         assert 0 < stats["moe_experts_touched"] <= 16 * 3 * stats["steps"]
         params = jax.tree_util.tree_map(
             lambda a: a.astype(jnp.float32), model._params)

@@ -8,6 +8,8 @@ one process at a time, and every xdist worker imports this file), and
 all such tests live in this one file.
 """
 
+import functools
+
 import pytest
 
 pytestmark = pytest.mark.llm
@@ -89,12 +91,11 @@ def test_mosaic_compiles_unequal_rows_window_and_sink(one_chip, case):
     assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
 
 
-@pytest.mark.parametrize("tokens", [64, 512, 2048])
-def test_mosaic_compiles_the_expert_kernel(one_chip, tokens):
-    """Mosaic's verdict on ``moe_experts`` under the load-time choice
-    ``pallas`` at ``mimo_v2_flash.reason``'s sizes: a decode step's 64
-    lanes (row tiles of 16) and a 512- and a 2,048-token prefill (tiles
-    of 128), 16 held experts of 4096 x 2048 in bf16."""
+@functools.lru_cache(maxsize=None)
+def _expert_layer_with_its_router(one_chip, tokens):
+    """`moe.route` and `moe.expert_layer` under the load-time choice
+    ``pallas`` at ``mimo_v2_flash.reason``'s sizes (16 held of 256 experts
+    of 4096 x 2048 in bf16, 8 a token), compiled for the described v5e."""
     import jax
     import jax.numpy as jnp
 
@@ -105,18 +106,86 @@ def test_mosaic_compiles_the_expert_kernel(one_chip, tokens):
     def shaped(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
+    def layer(h, router, bias, experts):
+        ids, weights = moe.route(h, router, bias, top_k)
+        return moe.expert_layer(h, ids, weights, experts, held,
+                                kernel="pallas")
+
     experts = {"w_gate": shaped((16, d, f)), "w_up": shaped((16, d, f)),
                "w_down": shaped((16, f, d))}
-    compiled = jax.jit(
-        lambda h, ids, weights, experts: moe.expert_layer(
-            h, ids, weights, experts, held, kernel="pallas")
-    ).lower(shaped((tokens, d)), shaped((tokens, top_k), jnp.int32),
-            shaped((tokens, top_k), jnp.float32), experts).compile()
-    assert "%moe_experts" in compiled.as_text()
-    # the rows gathered by expert and their outputs, no dense pass
-    rows = moe.row_tile(tokens, top_k) * (
-        -(-tokens * top_k // moe.row_tile(tokens, top_k)) + 16)
-    assert compiled.memory_analysis().temp_size_in_bytes < 8 * rows * d
+    return jax.jit(layer).lower(
+        shaped((tokens, d)), shaped((d, 256)), shaped((256,), jnp.float32),
+        experts).compile()
+
+
+def _device_operations(compiled):
+    """[(opcode, name)] of the compiled program's entry computation in
+    the order it runs them, without what is no operation on the device
+    (parameters, literals, tuples and their elements, bitcasts)."""
+    import re
+
+    text = compiled.as_text()
+    assert "is_scheduled=true" in text
+    entry = text[text.index("\nENTRY "):]
+    ran = []
+    for line in entry.splitlines():
+        found = re.search(r"^\s*(?:ROOT )?(%\S+) = .*? ([a-z][a-z-]*)\((?:%|\))",
+                          line)
+        if found and found.group(2) not in (
+                "parameter", "constant", "tuple", "get-tuple-element",
+                "bitcast"):
+            ran.append((found.group(2), found.group(1)))
+    return ran
+
+
+@pytest.mark.parametrize("tokens", [1, 16, 64, 128, 512, 2048])
+def test_mosaic_compiles_the_expert_kernel(one_chip, tokens):
+    """Mosaic's verdict on both ``moe_experts`` kernels: a decode batch
+    (one lane, a sublane tile, the cell's 64 lanes, the 128 rows up to
+    which rows stay resident) takes the resident kernel and holds no
+    more than a few ``T x d`` buffers beside it; a 512- and a
+    2,048-token prefill are planned in row tiles of 128."""
+    from client_tpu.models import moe
+
+    compiled = _expert_layer_with_its_router(one_chip, tokens)
+    text, d = compiled.as_text(), 4096
+    assert "%moe_experts" in text
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    if tokens <= moe._RESIDENT_ROWS:
+        rows = -(-tokens // 16) * 16
+        assert f"f32[{rows},{d}]" in text  # the kernel's own output
+        assert f"bf16[{rows * 8}," not in text  # no row a pair
+        assert temp < 3 * 4 * rows * d
+    else:
+        # the rows gathered by expert and their outputs, no dense pass
+        rows = moe._ROW_TILE * (-(-tokens * 8 // moe._ROW_TILE) + 16)
+        assert f"bf16[{rows},{d}]" in text
+        assert temp < 8 * rows * d
+
+
+def test_the_decode_size_expert_layer_is_compiled_without_a_plan(one_chip):
+    """The finding of PR 30, held: at the cell's 64 lanes the compiled
+    layer has no loop, one sort (the router's ``top_k``) and, between
+    the router's matmul and the kernel, eighteen small device
+    operations (eleven of them the router's own ``top_k`` and
+    ``take_along_axis``) where the planned layer runs more than sixty,
+    and after the kernel none."""
+    opcodes = _device_operations(_expert_layer_with_its_router(one_chip, 64))
+    names = [name for _, name in opcodes]
+    kinds = [kind for kind, _ in opcodes]
+    assert "while" not in kinds and kinds.count("sort") == 1
+    (kernel,) = [i for i, name in enumerate(names)
+                 if name.startswith("%moe_experts")]
+    assert kinds[kernel] == "custom-call"
+    assert kernel == len(opcodes) - 1
+    router = kinds.index("sort") - 1  # what the sort reads: s + b
+    assert kinds[router] == "fusion"
+    between = [kind for kind in kinds[router + 1:kernel]
+               if kind not in ("copy-start", "copy-done")]  # prefetches
+    assert len(between) <= 18, opcodes[router + 1:kernel]
+    planned = _device_operations(_expert_layer_with_its_router(one_chip, 512))
+    assert len(planned) > 60
+    assert [kind for kind, _ in planned].count("sort") == 2
 
 
 @pytest.mark.parametrize("case", CASES)

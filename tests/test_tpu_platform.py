@@ -310,15 +310,42 @@ def test_compiled_pallas_at_the_mimo_cells_shapes(device, group):
     )
 
 
+def _ms_a_call(fn, *args, calls=20, repeats=3):
+    """Milliseconds a call of a jitted function, the best of ``repeats``
+    means over ``calls`` back-to-back calls (compiled before)."""
+    import time
+
+    import jax
+
+    jax.block_until_ready(fn(*args))
+    best = float("inf")
+    for _ in range(repeats):
+        began = time.perf_counter()
+        for _ in range(calls):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        best = min(best, (time.perf_counter() - began) / calls)
+    return 1e3 * best
+
+
 @pytest.mark.parametrize("tokens,kernel", [
-    (64, "pallas"), (512, "pallas"), (2048, "pallas"), (64, "fused_xla"),
+    (64, "pallas"), (128, "pallas"), (512, "pallas"), (2048, "pallas"),
+    (64, "fused_xla"),
 ])
-def test_expert_layer_matches_a_dense_pass(device, tokens, kernel):
+def test_expert_layer_matches_a_dense_pass(device, tokens, kernel,
+                                           monkeypatch):
     """``moe.expert_layer`` at the cell's sizes (16 held of 256 experts
-    of 4096 x 2048, 8 a token; a decode step's 64 lanes and a 512- and a
-    2,048-token prefill) through the compiled ``moe_experts`` kernel,
+    of 4096 x 2048, 8 a token; a decode step's 64 lanes, the 128 rows up
+    to which a call keeps its rows resident, and a 512- and a
+    2,048-token prefill) through the compiled ``moe_experts`` kernels,
     and a decode step through the plain XLA path, against every held
-    expert run over every token and kept where the router chose it."""
+    expert run over every token and kept where the router chose it.
+
+    Where the call is short enough to keep its rows resident, the whole
+    layer (router's outputs in, ``[T, d]`` out) is also timed: planned
+    as before (``_RESIDENT_ROWS`` 0, row tiles of 16) it must give the
+    same and take longer, and so must the dense pass. That is the
+    measured reason beside ``moe._RESIDENT_ROWS``."""
     import jax
     import jax.numpy as jnp
 
@@ -341,7 +368,7 @@ def test_expert_layer_matches_a_dense_pass(device, tokens, kernel):
     h = normal((tokens, d), 1.0)
     ids, weights = jax.jit(lambda h: moe.route(h, router, bias, 8))(h)
 
-    def dense(h, ids, weights):
+    def dense(h, ids, weights, experts):
         out = jnp.zeros(h.shape, jnp.float32)
         for e in range(held[1]):
             share = (weights * (ids == e)).sum(-1, keepdims=True)
@@ -354,14 +381,34 @@ def test_expert_layer_matches_a_dense_pass(device, tokens, kernel):
                 preferred_element_type=jnp.float32)
         return out
 
-    out, counters = jax.jit(lambda *a: moe.expert_layer(
-        *a, experts, held, kernel=kernel))(h, ids, weights)
+    def layer():
+        return jax.jit(lambda *a: moe.expert_layer(
+            *a, held, kernel=kernel))
+
+    args = (h, ids, weights, experts)
+    out, counters = layer()(*args)
     on = np.asarray(ids) < held[1]
+    resident = kernel == "pallas" and tokens <= moe._RESIDENT_ROWS
     assert int(counters[0]) == on.sum() > 0
+    assert int(counters[3]) == resident
+    wanted = jax.jit(dense)(*args)
     _assert_bf16_close(
-        out, jax.jit(dense)(h, ids, weights),
-        f"expert layer, {tokens} tokens, {kernel}",
-    )
+        out, wanted, f"expert layer, {tokens} tokens, {kernel}")
+    if not resident:
+        return
+    took = {"resident": _ms_a_call(layer(), *args),
+            "dense": _ms_a_call(jax.jit(dense), *args)}
+    monkeypatch.setattr(moe, "_RESIDENT_ROWS", 0)
+    monkeypatch.setattr(moe, "_ROW_TILE", 16)
+    planned, planned_counters = layer()(*args)
+    assert int(planned_counters[3]) == 0
+    assert (np.asarray(planned_counters[:3])
+            == np.asarray(counters[:3])).all()
+    _assert_bf16_close(planned, wanted, f"planned, {tokens} tokens")
+    took["planned"] = _ms_a_call(layer(), *args)
+    print(f"expert layer, {tokens} rows, ms a call: {took}")
+    assert took["resident"] < took["planned"], took
+    assert took["resident"] < took["dense"], took
 
 
 # ---------------------------------------------------------------------------
