@@ -173,14 +173,42 @@ class EngineConfig:
 
     def group_num_blocks(self) -> List[int]:
         """Physical blocks of each cache group's pool, in the groups'
-        order (one full group when none is declared)."""
+        order (one full group when none is declared). Refuses, with the
+        numbers, sizes under which a window group cannot do its work:
+        a ``max_seq_len`` whose page table has fewer columns than the
+        group's ring has blocks (the window never fills, and the ring is
+        memory no table row can name), and a full pool that cannot hold
+        what ``max_active`` rings hold (the full group keeps every block
+        a ring keeps and more, so lanes would be preempted before their
+        windows filled). Both are policy and not an invariant: a window
+        longer than ``max_seq_len`` is full attention under another
+        name, and a smaller full pool works while traffic stays short,
+        through preemption. What is forbidden is an oversubscribed pool
+        and a ring that no request can fill, so that a configuration
+        says what it will hold."""
         sizes = []
         for group in self.cache_groups or (None,):
             if group is None or group.window is None:
                 sizes.append(self.num_blocks)
-            else:
-                ring = window_ring_blocks(group.window, self.block_size)
-                sizes.append(1 + self.max_active * ring)
+                continue
+            ring = window_ring_blocks(group.window, self.block_size)
+            if ring > self.max_blocks_per_seq + 1:
+                raise ValueError(
+                    f"max_seq_len={self.max_seq_len} gives a page table of "
+                    f"{self.max_blocks_per_seq} columns, which cannot cover "
+                    f"a window of {group.window} tokens (a ring of {ring} "
+                    f"blocks of {self.block_size})"
+                )
+            if self.num_blocks - 1 < self.max_active * (ring - 1):
+                raise ValueError(
+                    f"num_blocks={self.num_blocks} holds "
+                    f"{self.num_blocks - 1} blocks of the full group, fewer "
+                    f"than the {self.max_active * (ring - 1)} that "
+                    f"max_active={self.max_active} windows of "
+                    f"{group.window} tokens fill ({ring - 1} blocks of "
+                    f"{self.block_size} each)"
+                )
+            sizes.append(1 + self.max_active * ring)
         return sizes
 
 
@@ -653,6 +681,14 @@ class LlmEngine:
         # those of them that the rings do not hold
         self.window_blocks_whole = 0
         self.window_blocks_unheld = 0
+        # tokens of context the lanes of every decode step attend over
+        # in a full layer (the whole context), and in a layer of each
+        # window group (what of it the window reaches): the K/V a step's
+        # attention has to read, a layer. One sum over all window groups:
+        # a reader that multiplies it by a layer count needs the model to
+        # have one window group (`kv_blocks_in_use_by_group` is a group's)
+        self.attn_tokens_full = 0
+        self.attn_tokens_window = 0
         # the model's own per-step counters (decode_fn's third value)
         self._step_counter_names = tuple(step_counters)
         self.model_counters: Dict[str, int] = dict.fromkeys(
@@ -1048,6 +1084,8 @@ class LlmEngine:
             "kv_blocks_in_use_by_group": self._blocks_in_use_by_group(),
             "window_blocks_whole": self.window_blocks_whole,
             "window_blocks_unheld": self.window_blocks_unheld,
+            "attn_tokens_full": self.attn_tokens_full,
+            "attn_tokens_window": self.attn_tokens_window,
             **self.model_counters,
             "block_size": self.allocator.block_size,
             "steps": self.steps,
@@ -1563,6 +1601,21 @@ class LlmEngine:
         speculative engine's step without drafts)."""
         self._consume(await self._dispatch(batch))
 
+    def _table_columns(self, batch: List[Sequence]) -> int:
+        """Page-table width of a step over ``batch``. Ragged: the decode
+        kernel's attention cost is proportional to the table width it
+        sees, so the table is cut to a bucket of the LONGEST live
+        sequence instead of always paying ``max_seq_len`` (bounded
+        recompiles; see :func:`block_bucket`). The last bucket under the
+        whole table takes the whole table: closed-loop streams that end
+        at ``max_seq_len`` one after another keep the widest lane within
+        a bucket or two of it, and a batch that dips under the edge for
+        a few steps (the next stream a few tokens late) would run, and
+        under load compile, a second program for 8 columns' saving."""
+        most = self.config.max_blocks_per_seq
+        nb = block_bucket(max(len(seq.blocks) for seq in batch))
+        return most if nb > 8 and nb + 8 >= most else min(nb, most)
+
     async def _dispatch(self, batch: List[Sequence],
                         flight: Optional[_Flight] = None) -> _Flight:
         """Build and dispatch the non-speculative decode step over
@@ -1577,14 +1630,7 @@ class LlmEngine:
         ahead = flight.lane_of if flight is not None else {}
         n = len(batch)
         bucket = pad_batch_bucket(n)
-        # ragged page-table width: the decode kernel's attention cost is
-        # proportional to the table width it sees, so slice it to a
-        # bucket of the LONGEST live sequence instead of always paying
-        # max_seq_len (bounded recompiles; see block_bucket)
-        nb = min(
-            block_bucket(max(len(seq.blocks) for seq in batch)),
-            self.config.max_blocks_per_seq,
-        )
+        nb = self._table_columns(batch)
         lane_map = np.full([bucket], -1, dtype=np.int32)
         host_tokens = np.zeros([bucket], dtype=np.int32)
         positions = np.zeros([bucket], dtype=np.int32)
@@ -1599,9 +1645,13 @@ class LlmEngine:
             positions[i] = position
             page_tables[i] = seq.page_table[:nb]
             self.attn_blocks_live += len(seq.blocks)
-            for _, ring, _ in self._windows:
+            self.attn_tokens_full += position + 1
+            for index, ring, _ in self._windows:
                 self.window_blocks_whole += len(seq.blocks)
                 self.window_blocks_unheld += max(0, len(seq.blocks) - ring)
+                self.attn_tokens_window += min(
+                    position + 1, self.config.cache_groups[index].window
+                )
             # COW invariant: the block this lane is about to write must
             # be exclusively owned (shared prefix blocks are read-only;
             # growth always lands in fresh blocks). A violation means
@@ -1772,10 +1822,7 @@ class LlmEngine:
             return
         bucket = pad_batch_bucket(n)
         t_width = min(pad_batch_bucket(k_max + 1), self.config.spec_k + 1)
-        nb = min(
-            block_bucket(max(len(seq.blocks) for seq in batch)),
-            self.config.max_blocks_per_seq,
-        )
+        nb = self._table_columns(batch)
         tokens = np.zeros([bucket, t_width], dtype=np.int32)
         positions = np.zeros([bucket, t_width], dtype=np.int32)
         lengths = np.zeros([bucket], dtype=np.int32)
@@ -1794,6 +1841,9 @@ class LlmEngine:
             lengths[i] = k_eff + 1
             page_tables[i] = seq.page_table[:nb]
             self.attn_blocks_live += len(seq.blocks)
+            # no window count to book: an engine with window groups is
+            # refused speculation when it is built
+            self.attn_tokens_full += seq.position + k_eff + 1
             # COW invariant over the WHOLE speculative write range: the
             # verify scatters K/V at position..position+k_eff, and none
             # of those blocks may be shared. Engine-fatal on violation,

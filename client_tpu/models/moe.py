@@ -7,7 +7,11 @@ per token), computes the part of the result that the held experts give,
 and puts nothing in place of the absent ones: what they would have added
 is another chip's, and on one chip the exchange that would bring it is
 simply not there. The shares' outputs add up to the whole layer's
-(``tests/test_mimo_v2.py``).
+(``tests/test_mimo_v2.py``). A model with a *shared* expert (one every
+token goes through, ``models/afmoe.py``) hands it to the layer too: every
+chip of the deployment computes it whole, so it is in each share's
+output and is counted once when the shares are summed
+(``tests/test_afmoe.py``).
 
 Two Pallas kernels share the name ``moe_experts`` (what a device trace
 shows), and the call's row count alone picks one (:data:`_RESIDENT_ROWS`):
@@ -41,8 +45,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 #: columns of an expert's hidden width one grid step brings in: gate, up
-#: and down blocks of 512 are 12 MB at d 4096 in bf16, long enough DMAs
-#: that the step's fixed cost is a few per cent of them
+#: and down blocks of 512 are 12 MB in bf16 at ``mimo_v2``'s d 4096 (four
+#: grid steps an expert of width 2,048) and 6 MB at ``afmoe``'s d 2048 (two
+#: an expert of width 1,024), long enough DMAs that the step's fixed cost
+#: is a few per cent of them
 _F_TILE = 512
 #: the three weight blocks twice (one in flight), rows, accumulator
 _VMEM_LIMIT = 48 << 20
@@ -51,10 +57,11 @@ _VMEM_LIMIT = 48 << 20
 #: the rows a weight tile takes in one pass of the MXU. Up to here an
 #: expert's matmuls are bound by bringing its weights in, not by the
 #: rows streamed past them, so the rows no pair names ride free.
-#: Measured on a v5e at the cell's sizes (PR 30; the whole layer, router's
-#: outputs in, ms a call, resident / planned in row tiles of 16 / a
-#: dense pass): 64 rows 1.13 / 1.20 / 1.30, 128 rows 1.20 / 1.36 / 1.38,
-#: the kernel itself level (1.006 against 1.012 at 64 rows);
+#: Measured on a v5e at ``mimo_v2_flash.reason``'s sizes (d 4096, width
+#: 2,048, 16 held; PR 30; the whole layer, router's outputs in, ms a
+#: call, resident / planned in row tiles of 16 / a dense pass): 64 rows
+#: 1.13 / 1.20 / 1.30, 128 rows 1.20 / 1.36 / 1.38, the kernel itself
+#: level (1.006 against 1.012 at 64 rows);
 #: ``tests/test_tpu_platform.py`` asserts the order
 _RESIDENT_ROWS = 128
 #: rows of a planned tile: one tile should hold all of an expert's rows
@@ -65,18 +72,23 @@ COUNTERS = ("moe_pairs", "moe_experts_touched", "moe_load_max",
             "moe_resident_calls")
 
 
-def route(h, router, bias, top_k: int):
+def route(h, router, bias, top_k: int, scale: float = 1.0):
     """``noaux_tc`` routing with sigmoid scores, one group: scores ``s =
     sigmoid(h @ router)`` in float32, the ``top_k`` of ``s + bias`` (the
     correction bias selects and does not weigh), weights ``s_e`` over
-    their sum. ``h`` [T, d] -> (ids [T, K] int32, weights [T, K] f32)."""
+    their sum, times the model's ``scale`` (``afmoe``'s ``route_scale``;
+    at 1 the program is the one it was without it). ``h`` [T, d] ->
+    (ids [T, K] int32, weights [T, K] f32)."""
     scores = jax.nn.sigmoid(jnp.dot(
         h.astype(jnp.float32), router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
     ))
     _, ids = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
     picked = jnp.take_along_axis(scores, ids, axis=-1)
-    return ids.astype(jnp.int32), picked / picked.sum(axis=-1, keepdims=True)
+    weights = picked / picked.sum(axis=-1, keepdims=True)
+    if scale != 1.0:
+        weights = weights * scale
+    return ids.astype(jnp.int32), weights
 
 
 def _held_pairs(ids, held):
@@ -339,8 +351,24 @@ def _experts_xla(h, flat, counts, experts, shape):
     return out_rows[jnp.argsort(order)].reshape(*shape, -1)
 
 
-def expert_layer(h, ids, weights, experts, held, *, kernel: str):
-    """The held experts' part of the layer's output.
+def shared_expert(h, shared):
+    """The expert every token goes through: ``h`` [T, d] through one
+    SwiGLU (``w_gate`` / ``w_up`` [d, f], ``w_down`` [f, d]) with the
+    kernels' arithmetic (operands as stored, float32 accumulation, the
+    hidden row rounded to the operands' type). Plain XLA under every
+    kernel choice: at decode size it is bound by its weights' bytes,
+    which XLA streams as it does a dense MLP's. Returns [T, d] float32."""
+    gate = jnp.dot(h, shared["w_gate"], preferred_element_type=jnp.float32)
+    up = jnp.dot(h, shared["w_up"], preferred_element_type=jnp.float32)
+    mid = (jax.nn.silu(gate) * up).astype(h.dtype)
+    return jnp.dot(mid, shared["w_down"], preferred_element_type=jnp.float32)
+
+
+def expert_layer(h, ids, weights, experts, held, *, kernel: str,
+                 shared=None):
+    """The held experts' part of the layer's output, and with ``shared``
+    (:func:`shared_expert`'s weights) the shared expert's whole output
+    added to it.
 
     ``h`` [T, d] (the normed input), ``ids`` / ``weights`` [T, K] from
     :func:`route` over all experts, ``experts`` the held experts' stacked
@@ -377,6 +405,8 @@ def expert_layer(h, ids, weights, experts, held, *, kernel: str):
         # memory
         out = (jnp.where(on[..., None], picked, 0.0)
                * weights[..., None]).sum(axis=1)
+    if shared is not None:
+        out = out + shared_expert(h, shared)
     counters = jnp.stack(
         [counts.sum(), (counts > 0).sum(), counts.max(), jnp.int32(resident)]
     ).astype(jnp.int32)

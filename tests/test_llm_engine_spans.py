@@ -235,6 +235,29 @@ def test_counters_count_what_was_submitted():
     engine.close()
 
 
+@pytest.mark.parametrize("block_size,max_seq_len,blocks,columns", [
+    # trinity_mini.reason8k: the widest lane a few tokens under 8,065
+    (16, 8192, 505, 512), (16, 8192, 504, 512), (16, 8192, 497, 512),
+    (16, 8192, 496, 496), (16, 8192, 33, 40),
+    # the cells that end at 2,048
+    (16, 2048, 128, 128), (16, 2048, 113, 128), (16, 2048, 112, 112),
+    # small tables keep their powers of two, and no table passes its end
+    (4, 64, 9, 16), (4, 64, 8, 8), (4, 64, 3, 4), (4, 16, 3, 4), (4, 8, 2, 2),
+])
+def test_the_last_bucket_under_the_whole_table_takes_the_whole_table(
+        block_size, max_seq_len, blocks, columns):
+    """Streams that end at ``max_seq_len`` one after another keep the
+    widest lane within a bucket of it: a dip of a few tokens under the
+    edge must not ask for a second decode program."""
+    import types
+
+    engine = _stub_engine(_TickingClock(), block_size=block_size,
+                          max_seq_len=max_seq_len)
+    batch = [types.SimpleNamespace(blocks=[1] * n) for n in (1, blocks)]
+    assert engine._table_columns(batch) == columns
+    engine.close()
+
+
 @pytest.mark.parametrize("speculative", [False, True],
                          ids=["decode", "verify"])
 def test_attn_block_counters_follow_the_tables_the_device_saw(speculative):

@@ -91,31 +91,41 @@ def test_mosaic_compiles_unequal_rows_window_and_sink(one_chip, case):
     assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
 
 
+# d, expert width, experts routed over, route scale, a shared expert?
+EXPERT_SIZES = {
+    "mimo": (4096, 2048, 256, 1.0, False),     # mimo_v2_flash.reason
+    "trinity": (2048, 1024, 128, 2.826, True),  # trinity_mini.reason8k
+}
+
+
 @functools.lru_cache(maxsize=None)
-def _expert_layer_with_its_router(one_chip, tokens):
+def _expert_layer_with_its_router(one_chip, tokens, model="mimo"):
     """`moe.route` and `moe.expert_layer` under the load-time choice
-    ``pallas`` at ``mimo_v2_flash.reason``'s sizes (16 held of 256 experts
-    of 4096 x 2048 in bf16, 8 a token), compiled for the described v5e."""
+    ``pallas`` at a cell's sizes (:data:`EXPERT_SIZES`; 16 held experts
+    in bf16, 8 a token), compiled for the described v5e."""
     import jax
     import jax.numpy as jnp
 
     from client_tpu.models import moe
 
-    d, f, held, top_k = 4096, 2048, (0, 16), 8
+    d, f, routed, scale, with_shared = EXPERT_SIZES[model]
+    held, top_k = (0, 16), 8
 
     def shaped(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    def layer(h, router, bias, experts):
-        ids, weights = moe.route(h, router, bias, top_k)
+    def layer(h, router, bias, experts, shared):
+        ids, weights = moe.route(h, router, bias, top_k, scale=scale)
         return moe.expert_layer(h, ids, weights, experts, held,
-                                kernel="pallas")
+                                kernel="pallas", shared=shared)
 
     experts = {"w_gate": shaped((16, d, f)), "w_up": shaped((16, d, f)),
                "w_down": shaped((16, f, d))}
+    shared = {"w_gate": shaped((d, f)), "w_up": shaped((d, f)),
+              "w_down": shaped((f, d))} if with_shared else None
     return jax.jit(layer).lower(
-        shaped((tokens, d)), shaped((d, 256)), shaped((256,), jnp.float32),
-        experts).compile()
+        shaped((tokens, d)), shaped((d, routed)),
+        shaped((routed,), jnp.float32), experts, shared).compile()
 
 
 def _device_operations(compiled):
@@ -138,29 +148,129 @@ def _device_operations(compiled):
     return ran
 
 
-@pytest.mark.parametrize("tokens", [1, 16, 64, 128, 512, 2048])
-def test_mosaic_compiles_the_expert_kernel(one_chip, tokens):
-    """Mosaic's verdict on both ``moe_experts`` kernels: a decode batch
-    (one lane, a sublane tile, the cell's 64 lanes, the 128 rows up to
-    which rows stay resident) takes the resident kernel and holds no
-    more than a few ``T x d`` buffers beside it; a 512- and a
-    2,048-token prefill are planned in row tiles of 128."""
+@pytest.mark.parametrize("model,tokens", [
+    ("mimo", 1), ("mimo", 16), ("mimo", 64), ("mimo", 128), ("mimo", 512),
+    ("mimo", 2048), ("trinity", 64), ("trinity", 512), ("trinity", 8192),
+])
+def test_mosaic_compiles_the_expert_kernel(one_chip, model, tokens):
+    """Mosaic's verdict on both ``moe_experts`` kernels at both cells'
+    sizes (:data:`EXPERT_SIZES`; at Trinity's an expert is two grid steps
+    of 6 MB where MiMo's takes four of 12, and the shared expert runs in
+    plain XLA beside the kernel): a decode batch (one lane, a sublane
+    tile, the cells' 64 lanes, the 128 rows up to which rows stay
+    resident) takes the resident kernel and holds no more than a few
+    ``T x d`` buffers beside it; a 512-token prefill and each cell's
+    longest (2,048 and 8,192 tokens) are planned in row tiles of 128."""
     from client_tpu.models import moe
 
-    compiled = _expert_layer_with_its_router(one_chip, tokens)
-    text, d = compiled.as_text(), 4096
+    compiled = _expert_layer_with_its_router(one_chip, tokens, model)
+    text, (d, f) = compiled.as_text(), EXPERT_SIZES[model][:2]
+    # float32 [rows, d] buffers a resident call may hold, and a planned
+    # one: MiMo's bounds as they were; the shared expert's rows come on
+    # top of them, and at 8,192 tokens each pair's row in float32 (1.1 GB,
+    # the prefill's largest scratch)
+    resident, planned = {"mimo": (3, 2), "trinity": (4, 5)}[model]
     assert "%moe_experts" in text
     temp = compiled.memory_analysis().temp_size_in_bytes
     if tokens <= moe._RESIDENT_ROWS:
         rows = -(-tokens // 16) * 16
         assert f"f32[{rows},{d}]" in text  # the kernel's own output
-        assert f"bf16[{rows * 8}," not in text  # no row a pair
-        assert temp < 3 * 4 * rows * d
+        pairs = text
+        if model == "trinity":
+            # XLA streams the shared expert's [2048, 1024] weights in
+            # slices of 512 rows, the one shape that 64 lanes x 8 meets
+            pairs = text.replace(f"bf16[512,{f}]", "")
+        assert f"bf16[{rows * 8}," not in pairs  # no row a pair
+        assert "while" not in text
+        assert temp < resident * 4 * rows * d
     else:
         # the rows gathered by expert and their outputs, no dense pass
         rows = moe._ROW_TILE * (-(-tokens * 8 // moe._ROW_TILE) + 16)
         assert f"bf16[{rows},{d}]" in text
-        assert temp < 8 * rows * d
+        assert temp < planned * 4 * rows * d
+
+
+TRINITY_ATTENTION = {
+    # pool blocks, window: trinity_mini.reason8k's two cache groups, 64
+    # lanes, 32 query heads over 4 KV heads of 128, a table of 512
+    "full": (20481, None),
+    "window": (1 + 64 * 129, 2048),
+}
+
+
+@pytest.mark.parametrize("group", TRINITY_ATTENTION)
+def test_mosaic_compiles_the_paged_kernel_at_trinitys_shapes(one_chip, group):
+    """KV 4 / D 128 in flat pools: tiles of 16 pages; a page table of
+    512 columns a lane (128 KB of scalar prefetch, four times MiMo's);
+    the window group's walk over a ring of 129 blocks."""
+    import jax
+    import jax.numpy as jnp
+
+    from client_tpu.models import paged_attention as pa
+
+    blocks, window = TRINITY_ATTENTION[group]
+    assert pa.pages_per_tile(BLOCK, 4, HEAD_DIM, jnp.bfloat16) == 16
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = shaped((blocks, BLOCK * 4, HEAD_DIM), jnp.bfloat16)
+    compiled = jax.jit(functools.partial(
+        pa.paged_attention_pallas, window=window, kv_heads=4)).lower(
+        shaped((64, 1, 32, HEAD_DIM), jnp.bfloat16), pool, pool,
+        shaped((64, 512), jnp.int32), shaped((64, 1), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "%paged_attention" in text
+    assert f"bf16[{blocks},{BLOCK * 4},{HEAD_DIM}]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
+
+
+def test_trinitys_longest_prefill_and_decode_fit_the_chip(one_chip):
+    """`afmoe`'s 8,192-token prefill and its 64-lane decode step compiled
+    whole for the described v5e at the cell's sizes (16 layers, 16 held
+    experts, 25,024 rows of vocabulary, pools of 20,481 and 8,257
+    blocks). The bound: 10.2 GB of arguments (4.23 of weights, 5.93 of
+    cache) and under 1.25 GB of scratch, 11.5 GB of the chip's 16; read
+    here at 10,162,077,184 and 1,131,382,784 B (the decode step:
+    43,803,648 B of scratch)."""
+    import jax
+    import jax.numpy as jnp
+
+    from client_tpu.models import afmoe, paged_attention
+    from client_tpu.models.engine_model import Kernels
+
+    config = afmoe.AfmoeConfig(
+        vocab_size=25024, layer_kinds=(1, 1, 1, 0) * 4, held=(0, 16))
+    kernels = Kernels("pallas", paged_attention.paged_attention_pallas)
+
+    def shaped(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    params = shaped(jax.eval_shape(
+        lambda: afmoe.init_params(jax.random.PRNGKey(0), config)))
+    pages = shaped(jax.eval_shape(
+        lambda: afmoe.init_pages(config, [20481, 1 + 64 * 129], BLOCK)))
+    ints = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.int32, sharding=one_chip)
+    prefill = jax.jit(
+        lambda p, t, tables, pages, last: afmoe.prefill_into_pages(
+            p, t, tables, pages, last, config, kernels),
+        donate_argnums=(3,)).lower(
+        params, ints(1, 8192), ints(2, 512), pages, ints()).compile()
+    memory = prefill.memory_analysis()
+    assert memory.argument_size_in_bytes < 10.2e9
+    assert memory.temp_size_in_bytes < 1.25e9
+    decode = jax.jit(
+        lambda p, t, at, tables, pages: afmoe.decode_step_paged(
+            p, t, at, tables, pages, config, kernels),
+        donate_argnums=(4,)).lower(
+        params, ints(64), ints(64), ints(2, 64, 512), pages).compile()
+    text = decode.as_text()
+    assert text.count("%paged_attention") >= 16
+    assert text.count("%moe_experts") >= 14
+    assert decode.memory_analysis().temp_size_in_bytes < 64e6
 
 
 def test_the_decode_size_expert_layer_is_compiled_without_a_plan(one_chip):

@@ -412,6 +412,163 @@ def test_expert_layer_matches_a_dense_pass(device, tokens, kernel,
 
 
 # ---------------------------------------------------------------------------
+# trinity_mini.reason8k's kernels and its whole step at the cell's shapes
+# ---------------------------------------------------------------------------
+
+
+def _device_normal(key, shape, scale, dtype=None):
+    import jax
+    import jax.numpy as jnp
+
+    return (jax.random.normal(key, shape, jnp.float32) * scale).astype(
+        dtype or jnp.bfloat16)
+
+
+@pytest.mark.parametrize("group", ["full", "window"])
+def test_compiled_pallas_at_the_trinity_cells_shapes(device, group):
+    """``trinity_mini.reason8k`` as the kernel sees it: 64 lanes, 32
+    query heads over 4 KV heads of 128 in flat pools, contexts staggered
+    over 512..8,192 behind a page table of 512 columns; the full group
+    over every block of a lane, the window group's window of 2,048 over
+    a ring of 129 blocks a lane behind the engine's own window tables.
+    Prints the kernel's ms a call beside plain XLA's."""
+    import jax
+
+    from client_tpu.llm.kv_cache import window_ring_blocks, window_tables
+    from client_tpu.models import paged_attention as pa
+
+    batch, heads, kv_heads, columns = 64, 32, 4, 512
+    positions = (511 + 120 * np.arange(batch)).astype(np.int32)
+    masking = {"kv_heads": kv_heads}
+    if group == "full":
+        owned = positions // BLOCK + 1
+        starts = 1 + np.concatenate([[0], np.cumsum(owned)[:-1]])
+        blocks = 1 + int(owned.sum())
+        tables = np.zeros((batch, columns), np.int32)
+        for lane in range(batch):
+            tables[lane, :owned[lane]] = starts[lane] + np.arange(owned[lane])
+    else:
+        ring = window_ring_blocks(2048, BLOCK)
+        assert ring == 129
+        blocks = 1 + batch * ring
+        tables = window_tables(
+            1 + np.arange(batch * ring).reshape(batch, ring),
+            positions // BLOCK, columns)
+        masking["window"] = 2048
+    keys = jax.random.split(jax.random.PRNGKey(31), 3)
+    q = _device_normal(keys[0], (batch, 1, heads, HEAD_DIM), 1.0)
+    k_pages = _device_normal(keys[1], (blocks, BLOCK * kv_heads, HEAD_DIM), 1.0)
+    v_pages = _device_normal(keys[2], (blocks, BLOCK * kv_heads, HEAD_DIM), 1.0)
+    args = (q, k_pages, v_pages, tables.astype(np.int32), positions[:, None])
+    kernel = jax.jit(lambda *a: pa.paged_attention_pallas(*a, **masking))
+    plain = jax.jit(lambda *a: pa.paged_attention_xla(*a, **masking))
+    _assert_bf16_close(kernel(*args), plain(*args), f"trinity's {group} group")
+    print(f"trinity {group} group, ms a call: pallas "
+          f"{_ms_a_call(kernel, *args):.3f}, xla {_ms_a_call(plain, *args):.3f}")
+
+
+@pytest.mark.parametrize("tokens,kernel", [
+    (64, "pallas"), (512, "pallas"), (8192, "pallas"), (64, "fused_xla"),
+])
+def test_trinitys_expert_layer_matches_a_dense_pass(device, tokens, kernel):
+    """``moe.expert_layer`` at the cell's sizes (16 held of 128 experts
+    of 2048 x 1024, 8 a token at ``route_scale`` 2.826, and the shared
+    expert): a decode step's 64 lanes resident, the 512- and the
+    8,192-token prefill planned, and the plain XLA path, against every
+    held expert run over every token and kept where the router chose it,
+    with the shared expert added once. Prints the layer's ms a call."""
+    import jax
+    import jax.numpy as jnp
+
+    from client_tpu.models import moe
+
+    d, f, held = 2048, 1024, (0, 16)
+    keys = iter(jax.random.split(jax.random.PRNGKey(tokens), 9))
+    swiglu = lambda *lead: {  # noqa: E731
+        "w_gate": _device_normal(next(keys), lead + (d, f), d ** -0.5),
+        "w_up": _device_normal(next(keys), lead + (d, f), d ** -0.5),
+        "w_down": _device_normal(next(keys), lead + (f, d), f ** -0.5)}
+    experts, shared = swiglu(16), swiglu()
+    router = _device_normal(next(keys), (d, 128), d ** -0.5)
+    bias = _device_normal(next(keys), (128,), 0.02, jnp.float32)
+    h = _device_normal(next(keys), (tokens, d), 1.0)
+    ids, weights = jax.jit(
+        lambda h: moe.route(h, router, bias, 8, scale=2.826))(h)
+
+    def one(h, w):
+        gate = jax.nn.silu(jnp.dot(
+            h, w["w_gate"], preferred_element_type=jnp.float32))
+        up = jnp.dot(h, w["w_up"], preferred_element_type=jnp.float32)
+        return jnp.dot((gate * up).astype(h.dtype), w["w_down"],
+                       preferred_element_type=jnp.float32)
+
+    def dense(h, ids, weights, experts, shared):
+        out = one(h, shared)
+        for e in range(held[1]):
+            share = (weights * (ids == e)).sum(-1, keepdims=True)
+            out = out + share * one(
+                h, {name: experts[name][e] for name in experts})
+        return out
+
+    layer = jax.jit(lambda h, ids, weights, experts, shared: moe.expert_layer(
+        h, ids, weights, experts, held, kernel=kernel, shared=shared))
+    args = (h, ids, weights, experts, shared)
+    out, counters = layer(*args)
+    assert int(counters[0]) == (np.asarray(ids) < held[1]).sum() > 0
+    assert int(counters[3]) == (kernel == "pallas" and tokens <= 128)
+    _assert_bf16_close(out, jax.jit(dense)(*args),
+                       f"trinity's expert layer, {tokens} tokens, {kernel}")
+    print(f"trinity expert layer, {tokens} rows, {kernel}, touched "
+          f"{int(counters[1])} of 16: {_ms_a_call(layer, *args):.3f} ms a call")
+
+
+def test_trinitys_decode_step_agrees_through_both_kernel_choices(device):
+    """`afmoe`'s whole decode step at the published widths (one period
+    of the layer pattern with both dense layers: two expert layers; 16
+    held experts) through the load-time choices ``pallas`` and
+    ``fused_xla``: the logits agree to a few bf16 steps, with contexts
+    on both sides of the window."""
+    import jax
+
+    from client_tpu.llm.kv_cache import window_ring_blocks, window_tables
+    from client_tpu.models import afmoe, paged_attention as pa
+    from client_tpu.models.engine_model import Kernels
+
+    config = afmoe.AfmoeConfig(vocab_size=4096, held=(0, 16))
+    params = afmoe.init_params(jax.random.PRNGKey(5), config)
+    lanes, columns = 4, 256
+    positions = np.array([17, 1500, 2047, 4000], np.int32)
+    ring = window_ring_blocks(config.window, BLOCK)
+    full = (1 + np.arange(lanes * columns)).reshape(lanes, columns)
+    tables = np.stack([full, window_tables(
+        1 + np.arange(lanes * ring).reshape(lanes, ring),
+        positions // BLOCK, columns)]).astype(np.int32)
+    keys = jax.random.split(jax.random.PRNGKey(6), 8)
+    pages = [
+        tuple(_device_normal(keys[2 * kind + side], (
+            1 + lanes * (ring if kind else columns),
+            BLOCK * config.n_kv_heads, config.head_dim), 1.0)
+            for side in (0, 1))
+        for kind in config.layer_kinds]
+    tokens = np.array([5, 6, 7, 8], np.int32)
+
+    def step(name, attn):
+        logits, _, counters = jax.jit(
+            lambda *a: afmoe.decode_step_paged(
+                *a, config, Kernels(name, attn)))(
+            params, tokens, positions, tables, pages)
+        return np.asarray(logits), np.asarray(counters)
+
+    kernel, counted = step("pallas", pa.paged_attention_pallas)
+    plain, plain_counted = step("fused_xla", pa.paged_attention_xla)
+    assert counted[3] == 2 and plain_counted[3] == 0
+    assert (counted[:3] == plain_counted[:3]).all()
+    assert np.isfinite(kernel).all() and np.abs(plain).max() > 1.0
+    worst = float(np.abs(kernel - plain).max())
+    assert worst <= 2.0 ** -4 * max(1.0, float(np.abs(plain).max())), worst
+
+
+# ---------------------------------------------------------------------------
 # the serving path on the device
 # ---------------------------------------------------------------------------
 
