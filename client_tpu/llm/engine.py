@@ -509,6 +509,10 @@ class LlmEngine:
     ``page_tables[G, B, NB]``. ``decode_fn`` may return a fourth value, an
     int32 vector of per-step counters named by ``step_counters``; it is
     read back with the ids and summed into ``stats()``.
+    ``attn_tile_pages`` is the paged kernel's tile in pages, a cache
+    group (``paged_attention.pages_per_tile`` of the group's pools,
+    which ``LlmEngineModel`` knows): with it ``stats()`` books the tile
+    stops a step's attention walks and those of them that are whole.
 
     **The step in flight.** A decode step whose live lanes are all greedy
     is left *in flight* when dispatched: its ids stay on the device. The
@@ -574,6 +578,7 @@ class LlmEngine:
         decode_multi_fn: Optional[Callable] = None,
         proposer: Any = None,
         step_counters: Any = (),
+        attn_tile_pages: Any = (),
     ):
         self.config = engine_config
         self.model_name = model_name
@@ -689,6 +694,14 @@ class LlmEngine:
         # have one window group (`kv_blocks_in_use_by_group` is a group's)
         self.attn_tokens_full = 0
         self.attn_tokens_window = 0
+        # tile stops the paged kernel makes over the tables of every
+        # decode and verify step (a layer of each cache group), and
+        # those of them it fetches with one copy a pool: the kernel's
+        # own rule (paged_attention.whole_tiles) on the tables as built
+        self._tile_pages = tuple(int(pages) for pages in attn_tile_pages)
+        self._group_blocks = sizes
+        self.attn_tiles_walked = 0
+        self.attn_tiles_whole = 0
         # the model's own per-step counters (decode_fn's third value)
         self._step_counter_names = tuple(step_counters)
         self.model_counters: Dict[str, int] = dict.fromkeys(
@@ -1086,6 +1099,8 @@ class LlmEngine:
             "window_blocks_unheld": self.window_blocks_unheld,
             "attn_tokens_full": self.attn_tokens_full,
             "attn_tokens_window": self.attn_tokens_window,
+            "attn_tiles_walked": self.attn_tiles_walked,
+            "attn_tiles_whole": self.attn_tiles_whole,
             **self.model_counters,
             "block_size": self.allocator.block_size,
             "steps": self.steps,
@@ -1159,6 +1174,30 @@ class LlmEngine:
                 rows.shape[1],
             )
         return tables.reshape((self._n_groups,) + full.shape)
+
+    def _book_tiles(self, tables: np.ndarray, positions: np.ndarray) -> None:
+        """Book the tile stops of a step over ``tables`` (what
+        :meth:`_group_tables` returned) whose live rows hold query
+        positions ``positions[n, T]``."""
+        if not self._tile_pages:
+            return
+        from client_tpu.models.paged_attention import (
+            count_tiles,
+            visible_slots,
+        )
+
+        n = len(positions)
+        if not self._windows:
+            tables = tables[None]
+        for index, group in enumerate(self.config.cache_groups or (None,)):
+            walked, whole = count_tiles(
+                tables[index, :n],
+                *visible_slots(positions, group and group.window),
+                self._tile_pages[index], self.allocator.block_size,
+                self._group_blocks[index],
+            )
+            self.attn_tiles_walked += walked
+            self.attn_tiles_whole += whole
 
     # -- step loop -----------------------------------------------------------
 
@@ -1663,11 +1702,12 @@ class LlmEngine:
                     f"block {seq.blocks[write_block]} with refcount "
                     f"{allocator.refcount(seq.blocks[write_block])}"
                 )
+        tables = self._group_tables(page_tables, batch, positions[:n])
+        self._book_tiles(tables, positions[:n, None])
         self._laps.enter("dispatch")
         ids, logits, self._pages, *counted = await self._run_device(
             self._decode, self._ids, lane_map, host_tokens, positions,
-            self._group_tables(page_tables, batch, positions[:n]),
-            self._pages,
+            tables, self._pages,
         )
         self._ids = ids
         if flight is not None:
@@ -1859,6 +1899,7 @@ class LlmEngine:
                         f"with refcount "
                         f"{allocator.refcount(seq.blocks[wb])}"
                     )
+        self._book_tiles(page_tables, positions[:n])
         laps = self._laps
         laps.enter("dispatch")
         logits, self._pages = await self._run_device(

@@ -10,6 +10,7 @@ token instead of N serial steps. Served through all streaming surfaces
 decoupled model.
 """
 
+import math
 import os
 from typing import Any, AsyncIterator, Dict, Optional
 
@@ -494,6 +495,19 @@ class LlmEngineModel(Model):
         # lifecycle layer before the swap)
         if self.engine is not None:
             self.engine.close()
+        # the paged kernel's tile in pages, a cache group, from the pools
+        # as they are stored (a tp shard holds 1/tp of a page's rows):
+        # what the engine needs to count the kernel's tile stops
+        tile_pages = [
+            paged_attention.pages_per_tile(
+                math.prod(k_pool.shape[1:-1]) // self.tp, 1,
+                max(k_pool.shape[-1], v_pool.shape[-1]), k_pool.dtype,
+            )
+            for k_pool, v_pool in (
+                pages[group.layers[0]]
+                for group in engine_config.cache_groups
+            )
+        ]
         self.engine = LlmEngine(
             prefill,
             decode,
@@ -503,6 +517,7 @@ class LlmEngineModel(Model):
             decode_multi_fn=decode_multi,
             proposer=proposer,
             step_counters=model.step_counters,
+            attn_tile_pages=tile_pages,
         )
         self._core = None  # rebind metrics/executor after a reload
         self._wire_recovery()

@@ -34,8 +34,13 @@ caller discards. ``masking`` is ``window``, ``sink``, ``scale`` and
   tile in flight while this one is folded into the online softmax, and
   stops at the sequence's own last tile: no ``[B, S]`` gather ever
   materializes and the padding of the table to its bucket is never
-  read. What a TPU serves. ``interpret=True`` runs the same kernel under
-  the Pallas interpreter, for CPU tests and rehearsals.
+  read. A tile whose live pages lie side by side in the pool (a window
+  group's ring, a prompt allocated in one go) comes by ONE copy of the
+  tile's pages from each pool, any other page by page; which, is read
+  off the table itself a tile at a time (:func:`whole_tiles`), and the
+  arithmetic on a tile does not know how it came. What a TPU serves.
+  ``interpret=True`` runs the same kernel under the Pallas interpreter,
+  for CPU tests and rehearsals.
 - :func:`paged_attention_xla`: one fused XLA computation over the
   gathered pages, the grouped-query einsum with no head repeat, at
   whatever (bucketed) table width the caller passes. What every other
@@ -56,6 +61,7 @@ from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -200,15 +206,93 @@ def pages_per_tile(block_size: int, kv_heads: int, head_dim: int,
     return max(1, _KV_VMEM_BUDGET // 4 // page_bytes)
 
 
+def visible_slots(positions, window):
+    """(first, length) a sequence: the slots ``[first, length)`` of its
+    context that the query rows at ``positions[B, T]`` see between them,
+    each its own position and, under a sliding ``window``, the ``window
+    - 1`` before. Where the kernel's walk starts and ends, and what
+    :func:`whole_tiles` calls live."""
+    lengths = positions.max(axis=1) + 1
+    if window is None:
+        return lengths * 0, lengths
+    first = positions.min(axis=1) - window + 1
+    return first * (first > 0), lengths
+
+
+def whole_tiles(page_tables, first_slots, lengths, pages: int,
+                block_size: int, num_pages: int):
+    """``[B, cdiv(NB, P)]``: the first pool page of every WHOLE tile of
+    ``page_tables[B, NB]``, -1 for a tile that is not (``P`` is
+    ``min(pages, NB)``, a tile the table columns ``[t*P, (t+1)*P)``).
+
+    A tile is whole when its LIVE columns hold consecutive pool pages
+    (column ``j`` page ``page0 + j % P``) and the ``P`` pages from
+    ``page0`` lie inside a pool of ``num_pages``. A column is live when
+    it holds a slot some query row of the sequence can see: from the
+    block of ``first_slots[b]`` to the block of slot ``lengths[b] - 1``
+    (:func:`visible_slots`). What a whole tile's
+    dead columns name is never read: the kernel reads the pages beside
+    the live ones in their place, and masks them by slot as it masks the
+    trash block. A tile with no live column is never walked, and reads
+    -1.
+
+    The one rule the kernel (which fetches a whole tile with one copy a
+    pool) and the engine's ``attn_tiles_whole`` share: plain array
+    operators only, so that ``jnp`` arrays under a trace and the numpy
+    tables the engine builds go through the same lines."""
+    nb = page_tables.shape[-1]
+    pages = min(pages, nb)
+    n_tiles = -(-nb // pages)
+    column = np.arange(n_tiles * pages, dtype=np.int32)
+    if nb % pages:
+        # a table narrower than a whole number of tiles: the spare
+        # columns of its last tile are nobody's live column
+        page_tables = page_tables[:, np.minimum(column, nb - 1)]
+    column = column.reshape(n_tiles, pages)
+    dead = ~(
+        (column >= (first_slots // block_size)[:, None, None])
+        & (column <= ((lengths - 1) // block_size)[:, None, None])
+        & (column < nb)
+    )
+    # the span's first page as each column has it; a dead column's is
+    # put out of every live one's reach, either side
+    page0 = page_tables.reshape(-1, n_tiles, pages) - column % pages
+    far = dead * np.int32(num_pages + pages)
+    low = (page0 + far).min(axis=-1)
+    high = (page0 - far).max(axis=-1)
+    whole = (low == high) & (low >= 0) & (low + pages <= num_pages)
+    return whole * (low + 1) - 1
+
+
+def count_tiles(page_tables, first_slots, lengths, pages: int,
+                block_size: int, num_pages: int) -> Tuple[int, int]:
+    """(tiles the kernel walks, those of them that are whole) for numpy
+    tables: what ``LlmEngine.stats()`` books as ``attn_tiles_walked``
+    and ``attn_tiles_whole``. The walk is the kernel's: from the tile of
+    a sequence's first visible slot to the tile of its last."""
+    tile_slots = min(pages, page_tables.shape[-1]) * block_size
+    walked = (lengths - 1) // tile_slots - first_slots // tile_slots + 1
+    whole = whole_tiles(
+        page_tables, first_slots, lengths, pages, block_size, num_pages)
+    return int(walked.sum()), int((whole >= 0).sum())
+
+
 def _rpa_kernel(kv, scale, window, has_sink, *refs):
     """Grid step ``b``: fold sequence ``b``'s live pages, one tile of
     ``P`` pages at a time, into the online softmax of all its query rows.
 
     The pools stay in HBM, viewed ``[N, bs*KV, D]`` (pool row ``t*KV +
     h`` is token ``t``, kv head ``h``; K rows are ``Dk`` wide and V rows
-    ``Dv``, which need not be equal). A tile is ``P`` page copies into
-    slot ``s`` of ``k_buf`` / ``v_buf`` (``[2, P, bs*KV, D]``), all on
-    ``sems[0|1, s]``; while a tile is folded the NEXT tile's copies are
+    ``Dv``, which need not be equal). A tile comes into slot ``s`` of
+    ``k_buf`` / ``v_buf`` (``[2, P, bs*KV, D]``) on ``sems[0|1, s]`` in
+    one of two ways, as ``whole_ref[b, tile]`` (:func:`whole_tiles`,
+    scalar-prefetched beside the table) says: a WHOLE tile, whose live
+    pages lie side by side in the pool, by one copy of ``P`` pages from
+    each pool (the entry is the first of them); any other (-1) by ``P``
+    page copies a pool, each with its table read. The wait is built
+    from the flag of the tile it waits for, read again by (sequence,
+    tile): a DMA semaphore counts in the copy's own size. While a tile
+    is folded the NEXT tile's copies are
     in flight in the other slot, and the next tile of a sequence's last
     tile is the next sequence's first, so only the very first tile of
     the call is waited for with nothing to do. ``slot_ref`` (SMEM)
@@ -233,8 +317,8 @@ def _rpa_kernel(kv, scale, window, has_sink, *refs):
     column holds each row's sink logit: it seeds the running maximum and
     a denominator of one, a key with no value row."""
     refs = list(refs)
-    tbl_ref, len_ref = refs[:2]
-    del refs[:2]
+    tbl_ref, whole_ref, len_ref = refs[:3]
+    del refs[:3]
     first_ref = refs.pop(0) if window is not None else None
     q_ref, pos_ref = refs[:2]
     del refs[:2]
@@ -270,26 +354,59 @@ def _rpa_kernel(kv, scale, window, has_sink, *refs):
             ),
         )
 
+    def tile_copies(slot, page0):
+        return (
+            pltpu.make_async_copy(
+                k_hbm.at[pl.ds(page0, pages)], k_buf.at[slot],
+                sems.at[0, slot]
+            ),
+            pltpu.make_async_copy(
+                v_hbm.at[pl.ds(page0, pages)], v_buf.at[slot],
+                sems.at[1, slot]
+            ),
+        )
+
     # the page loops are rolled: a copy traced once per site, not once
     # per page, keeps the program's trace (paid at every server start,
     # once per layer) as short as the kernel it replaces
 
+    def either_way(seq, tile, whole, by_page):
+        if k_hbm.shape[0] < pages:
+            return by_page()  # a pool that holds no span of P pages
+        page0 = whole_ref[seq, tile]
+        pl.when(page0 >= 0)(lambda: whole(page0))
+        pl.when(page0 < 0)(by_page)
+
     def start_tile(seq, tile, slot):
-        @pl.loop(0, pages)
-        def _start(j):
-            # a table narrower than a whole number of tiles: the last
-            # tile's spare pages re-read the last column, masked as
-            # slots past every position
-            column = jnp.minimum(tile * pages + j, nb - 1)
-            for copy in page_copies(slot, j, tbl_ref[seq, column]):
+        def whole(page0):
+            for copy in tile_copies(slot, page0):
                 copy.start()
 
-    def wait_tile(slot):
-        @pl.loop(0, pages)
-        def _wait(j):
-            # a wait takes its size from the copy, not its source
-            for copy in page_copies(slot, j, 0):
+        def by_page():
+            @pl.loop(0, pages)
+            def _start(j):
+                # a table narrower than a whole number of tiles: the
+                # last tile's spare pages re-read the last column,
+                # masked as slots past every position
+                column = jnp.minimum(tile * pages + j, nb - 1)
+                for copy in page_copies(slot, j, tbl_ref[seq, column]):
+                    copy.start()
+
+        either_way(seq, tile, whole, by_page)
+
+    def wait_tile(seq, tile, slot):
+        # a wait takes its size from the copy, not its source
+        def whole(_):
+            for copy in tile_copies(slot, 0):
                 copy.wait()
+
+        def by_page():
+            @pl.loop(0, pages)
+            def _wait(j):
+                for copy in page_copies(slot, j, 0):
+                    copy.wait()
+
+        either_way(seq, tile, whole, by_page)
 
     @pl.when(b == 0)
     def _first_tile():
@@ -317,7 +434,7 @@ def _rpa_kernel(kv, scale, window, has_sink, *refs):
             after = first_tile_of(jnp.minimum(b + 1, n_seqs - 1))
             start_tile(next_seq, jnp.where(last, after, i + 1), 1 - slot)
 
-        wait_tile(slot)
+        wait_tile(b, i, slot)
         k = k_buf[slot].reshape(tile_rows, -1)
         v = v_buf[slot].reshape(tile_rows, -1)
         s = jax.lax.dot_general(
@@ -364,12 +481,14 @@ def paged_attention_pallas(q, k_pages, v_pages, page_tables, positions,
                            sink=None, scale=None, kv_heads=None):
     """Flash-style ragged paged attention as a Pallas kernel.
 
-    One grid step per sequence. ``page_tables`` and each sequence's
-    length (its largest query position + 1) are scalar-prefetched; the
-    kernel copies pages ``page_tables[b, j]`` from the pools in HBM into
-    VMEM itself, :func:`pages_per_tile` at a time and one tile ahead of
-    the arithmetic, and stops at the sequence's own last tile — sequence
-    ``b`` never touches pages it does not own, the padding of the table
+    One grid step per sequence. ``page_tables``, :func:`whole_tiles` of
+    it and each sequence's length (its largest query position + 1) are
+    scalar-prefetched; the kernel copies pages ``page_tables[b, j]``
+    from the pools in HBM into VMEM itself, :func:`pages_per_tile` at a
+    time (a whole tile by one copy a pool, which also brings, and masks,
+    what lies beside its live pages) and one tile ahead of the
+    arithmetic, and stops at the sequence's own last tile — no row of
+    sequence ``b`` sees a slot it does not own, the padding of the table
     to its bucket costs nothing, no contiguous per-sequence view is ever
     materialized in HBM, and the T verify rows of a sequence share each
     tile (the pages cross HBM->VMEM once for all K+1 positions). Queries
@@ -405,9 +524,17 @@ def paged_attention_pallas(q, k_pages, v_pages, page_tables, positions,
     )
     positions = positions.astype(jnp.int32)
     row_positions = jnp.tile(jnp.repeat(positions, g, axis=1), (1, kv))
-    prefetch = [page_tables.astype(jnp.int32), positions.max(axis=1) + 1]
+    page_tables = page_tables.astype(jnp.int32)
+    first_slots, lengths = visible_slots(positions, window)
+    prefetch = [
+        page_tables,
+        whole_tiles(
+            page_tables, first_slots, lengths, pages, bs, n
+        ).astype(jnp.int32),
+        lengths,
+    ]
     if window is not None:
-        prefetch.append(jnp.maximum(positions.min(axis=1) - window + 1, 0))
+        prefetch.append(first_slots)
     row_block = lambda width: pl.BlockSpec(  # noqa: E731
         (1, rows, width), lambda i, *_: (i, 0, 0))
     inputs = [q_rows, row_positions[:, :, None]]

@@ -31,7 +31,8 @@ pytestmark = pytest.mark.llm
 
 VOCAB = 32
 COUNTERS = ("prefills", "admitted", "queue_wait_ns", "steps", "lane_steps",
-            "tokens_generated", "attn_blocks_live", "attn_blocks_bucket")
+            "tokens_generated", "attn_blocks_live", "attn_blocks_bucket",
+            "attn_tiles_walked", "attn_tiles_whole")
 
 
 class _TickingClock:
@@ -108,7 +109,7 @@ def _logits_row(token, position):
     return row
 
 
-def _stub_engine(clock, speculative=False, **overrides):
+def _stub_engine(clock, speculative=False, tile_pages=(), **overrides):
     def prefill(tokens, page_table, pages, last_index, start):
         return _logits_row(tokens[0, last_index], start + last_index)[None], pages
 
@@ -133,6 +134,7 @@ def _stub_engine(clock, speculative=False, **overrides):
         clock_ns=clock,
         decode_multi_fn=decode_multi if speculative else None,
         proposer=NgramProposer(k=3, ngram=2) if speculative else None,
+        attn_tile_pages=tile_pages,
     )
     # the laps read the engine's clock through a witness of their own
     engine._laps = LapSpans(engine._laps._names, clock_ns=_Boundaries(clock))
@@ -294,8 +296,149 @@ def test_attn_block_counters_follow_the_tables_the_device_saw(speculative):
     engine.close()
 
 
+def _tiles_by_hand(row, first, length, pages, block_size, pool):
+    """(tile stops walked, those of them whole) of one table row, column
+    by column: the loop :func:`paged_attention.whole_tiles` stands for."""
+    pages = min(pages, len(row))
+    tile_slots = pages * block_size
+    walked = whole = 0
+    for tile in range(first // tile_slots, (length - 1) // tile_slots + 1):
+        walked += 1
+        live = [c for c in range(tile * pages, min((tile + 1) * pages, len(row)))
+                if first // block_size <= c <= (length - 1) // block_size]
+        page0 = row[live[0]] - live[0] % pages
+        whole += (all(row[c] == page0 + c % pages for c in live)
+                  and 0 <= page0 <= pool - pages)
+    return walked, whole
+
+
+@pytest.mark.parametrize("speculative", [False, True],
+                         ids=["decode", "verify"])
+def test_attn_tile_counters_follow_the_tables_the_device_saw(speculative):
+    """``attn_tiles_walked`` is every tile stop the paged kernel makes
+    over the tables of a decode or verify step at the tile size the
+    engine was told (2 pages of 4 slots here), ``attn_tiles_whole``
+    those whose live columns hold consecutive pool blocks: three lanes
+    that grow a block at a time in turn fragment the pool, so both kinds
+    occur."""
+    engine = _stub_engine(
+        _TickingClock(), speculative=speculative, tile_pages=(2,))
+    expected = [0, 0]
+
+    def watched(call, table_at, positions_at):
+        def step(*args):
+            table = np.array(args[table_at])
+            positions = np.array(args[positions_at]).reshape(len(table), -1)
+            for row, at in zip(table, positions):
+                if row[0]:  # a live lane holds its first block
+                    walked, whole = _tiles_by_hand(
+                        row.tolist(), 0, int(at.max()) + 1, 2, 4, 33)
+                    expected[0] += walked
+                    expected[1] += whole
+            seen = engine.stats()
+            # booked where the table is built: this step is in already
+            assert [seen["attn_tiles_walked"],
+                    seen["attn_tiles_whole"]] == expected
+            return call(*args)
+        return step
+
+    engine._decode = watched(engine._decode, 4, 3)
+    if speculative:
+        engine._decode_multi = watched(engine._decode_multi, 3, 1)
+    out = _run_stub(engine, [[1, 2, 1, 2, 1, 2], [3, 3, 3, 3], [5]], 24)
+    assert [len(tokens) for tokens in out] == [24, 24, 24]
+    stats = engine.stats()
+    assert 0 < stats["attn_tiles_whole"] < stats["attn_tiles_walked"]
+    # an engine that was told no tile size counts none
+    silent = _stub_engine(_TickingClock(), speculative=speculative)
+    _run_stub(silent, [[1, 2, 3]], 4)
+    assert silent.stats()["attn_tiles_walked"] == 0
+    engine.close()
+    silent.close()
+
+
+def test_attn_tile_counters_sum_a_window_and_a_full_group():
+    """Hand-made tables of a full group (tiles of 2 pages) and a window
+    group (window of 10 slots over blocks of 4, tiles of 4 pages): each
+    group is walked from its own first visible slot at its own tile
+    size, against its own pool's size."""
+    from client_tpu.models.engine_model import FULL, WINDOW, CacheGroup
+
+    groups = (CacheGroup(FULL, (0,)), CacheGroup(WINDOW, (1,), window=10))
+    engine = _stub_engine(
+        _TickingClock(), tile_pages=(2, 4), cache_groups=groups,
+        prefix_sharing=False, max_seq_len=32, max_active=3)
+    assert engine._group_blocks == [33, 1 + 3 * 4]
+    tables = np.zeros((2, 4, 8), dtype=np.int32)
+    positions = np.array([[30], [17], [5]])
+    tables[0, 0] = [1, 2, 3, 4, 9, 10, 20, 21]  # whole, whole, whole, whole
+    tables[0, 1, :5] = [5, 6, 8, 7, 11]         # whole, not, whole (one live)
+    tables[0, 2, :2] = [12, 14]                 # not
+    # the window group's rows: the ring at the last columns, trash before
+    tables[1, 0, 5:] = [3, 4, 1]     # 21..30: tile 1, the ring's wrap inside
+    tables[1, 1, 2:5] = [5, 6, 7]    # 8..17: tiles 0 and 1, 5 at column 2
+    tables[1, 2, :2] = [9, 10]       # 0..5: tile 0, whole
+    engine._book_tiles(tables, positions)
+    stats = engine.stats()
+    assert stats["attn_tiles_walked"] == (4 + 3 + 1) + (1 + 2 + 1)
+    # lane 1's window: 5, 6 at columns 2, 3 start a span at page 3; 7 at
+    # column 4 one at 7, and 7 + 4 fits a pool of 13 blocks
+    assert stats["attn_tiles_whole"] == (4 + 2 + 0) + (0 + 2 + 1)
+    by_hand = [
+        _tiles_by_hand(tables[g, lane].tolist(), first, at + 1, pages, 4, pool)
+        for g, pages, pool, firsts in ((0, 2, 33, (0, 0, 0)),
+                                       (1, 4, 13, (21, 8, 0)))
+        for lane, (first, at) in enumerate(zip(firsts, (30, 17, 5)))
+    ]
+    assert [sum(column) for column in zip(*by_hand)] == [12, 9]
+    engine.close()
+
+
+def test_window_rings_stay_runs_of_consecutive_blocks():
+    """What the whole-tile copy rests on in a window group: a ring is
+    claimed whole at admission and returned whole, so whatever order
+    sequences are admitted, end, are preempted and come back in, every
+    ring the engine holds is ``base, base + 1, ...``."""
+    from client_tpu.models.engine_model import FULL, WINDOW, CacheGroup
+
+    groups = (CacheGroup(FULL, (0,)), CacheGroup(WINDOW, (1,), window=12))
+    engine = _stub_engine(
+        _TickingClock(), cache_groups=groups, prefix_sharing=False,
+        num_blocks=14, max_active=3, max_queue=16, max_seq_len=48)
+    rings = []
+
+    async def watch():
+        while True:
+            for seq in engine._running:
+                (ring,) = seq.rings
+                assert ring == list(range(ring[0], ring[0] + 4)), ring
+                rings.append(tuple(ring))
+            await asyncio.sleep(0)
+
+    async def run():
+        watcher = asyncio.ensure_future(watch())
+        rng = np.random.default_rng(7)
+        seqs = []
+        for n in rng.integers(2, 12, size=9):
+            seqs.append(engine.submit(
+                rng.integers(1, VOCAB, size=n).tolist(),
+                max_tokens=int(rng.integers(3, 30))))
+            await asyncio.sleep(0)
+        engine.release(seqs[1])  # a cancellation among the endings
+        await asyncio.gather(*[_collect(s) for s in seqs if s is not seqs[1]])
+        watcher.cancel()
+
+    asyncio.run(run())
+    stats = engine.stats()
+    assert stats["preemptions"] > 0 and stats["completed"] == 8
+    assert len(set(rings)) == 3  # the pool's three rings, over and over
+    assert stats["kv_blocks_in_use_by_group"] == [0, 0]
+    engine.close()
+
+
 def test_every_counter_is_in_stats_and_never_goes_back():
-    engine = _stub_engine(_TickingClock(), num_blocks=5, max_seq_len=16)
+    engine = _stub_engine(_TickingClock(), num_blocks=5, max_seq_len=16,
+                          tile_pages=(2,))
     seen = []
 
     async def watch():
@@ -336,7 +479,7 @@ def test_debug_state_serves_the_counters():
         decoupled = True
 
         def __init__(self):
-            self.engine = _stub_engine(_TickingClock())
+            self.engine = _stub_engine(_TickingClock(), tile_pages=(2,))
 
         def shutdown(self):
             self.engine.close()

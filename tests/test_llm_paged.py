@@ -125,6 +125,27 @@ def test_allocator_publish_skips_already_indexed():
 # ---------------------------------------------------------------------------
 
 
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_allocator_hands_whole_rings_back_as_runs(seed):
+    """A pool that only ever hands out and takes back whole rings (a
+    window group's) keeps every ring a run of consecutive blocks,
+    whatever order they are claimed and returned in: the LIFO free list
+    takes a ring back in reverse and so gives it out again in order."""
+    ring, rings = 9, 5
+    allocator = BlockAllocator(1 + ring * rings, 16)
+    rng = np.random.default_rng(seed)
+    held = {}
+    for step in range(200):
+        if held and (len(held) == rings or rng.random() < 0.5):
+            allocator.free(held.pop(int(rng.choice(list(held)))))
+        else:
+            held[step] = step
+            blocks = allocator.allocate(step, ring)
+            assert blocks == list(range(blocks[0], blocks[0] + ring))
+            assert (blocks[0] - 1) % ring == 0
+    assert allocator.blocks_in_use == ring * len(held)
+
+
 @pytest.fixture(scope="module")
 def tiny_llama():
     import jax
@@ -330,6 +351,202 @@ def test_pallas_window_sink_and_v_size_match_xla(
         # the sink is in the denominator: without it the rows differ
         bare = pa.paged_attention_xla(*args, **{**masking, "sink": None})
         assert np.abs(np.asarray(bare) - ref).max() > 1e-3
+
+
+# how a tile's pages lie in the pool (paged_attention.whole_tiles): the
+# kinds are the kernel's arguments, the layouts what a table can look
+# like to it
+TILE_KINDS = {
+    # kv, g, d, dv, rows, window, sink, flat pools
+    "plain": (8, 4, 128, 128, 1, None, False, False),
+    "window": (8, 4, 128, 128, 1, 72, False, False),
+    "window-sink": (8, 4, 128, 128, 1, 72, True, False),
+    "unequal-rows": (4, 4, 256, 128, 1, None, False, True),
+    "flat-kv4": (4, 8, 128, 128, 1, 150, False, True),
+    "verify-t4": (8, 4, 128, 128, 4, None, False, False),
+}
+TILE_LAYOUTS = (
+    "consecutive", "shuffled", "prompt-then-fragments", "wrap-inside-tile",
+    "trash-edges", "narrow", "off-pool",
+)
+
+
+def _placed_case(kind, layout):
+    """Three lanes of float32 contents and the table ``layout`` puts
+    them behind, for the kernel arguments of ``kind``; and the same
+    contents behind a twin table that takes the other way into VMEM (a
+    shuffled one, or the consecutive one for ``shuffled`` itself).
+    Every pool slot no row may see holds ``GARBAGE``, the pages beside
+    a lane's live ones included; columns wholly behind a window and past
+    a lane's last block hold the trash block, as the engine writes them.
+    Returns (q, positions, masking, pages, [(k, v, table), (k, v, table)])."""
+    from client_tpu.models import paged_attention as pa
+
+    kv, g, d, dv, t, window, sink, flat = TILE_KINDS[kind]
+    bs, b = 16, 3
+    rng = np.random.default_rng(
+        len(kind) * 100 + TILE_LAYOUTS.index(layout))
+    pages = pa.pages_per_tile(bs, kv, max(d, dv), np.float32)
+    assert pages >= 2
+    nb = max(1, pages - 1) if layout == "narrow" else 3 * pages - 1
+    if layout == "trash-edges":
+        # a last tile of one live column; under a window the first
+        # visible slot is the last of its block too
+        lengths = [(pages + 1) * bs - 15, 2 * pages * bs + 1,
+                   min(window or nb * bs, nb * bs) + bs - t + 1]
+    else:
+        lengths = [nb * bs, min(nb * bs, pages * bs + bs + 5),
+                   max(t, nb * bs - pages * bs - 3)]
+    lengths = [max(t, min(length, nb * bs)) for length in lengths]
+    room = nb + 2 * pages  # a lane's own stretch of the pool
+    n = 1 + pages + b * room
+    column = np.arange(nb)
+
+    def placed(how):
+        """[b, nb] pool pages of the lanes' logical blocks."""
+        base = 1 + pages + room * np.arange(b)[:, None]
+        if how == "shuffled":
+            return 1 + rng.permutation(n - 1)[: b * nb].reshape(b, nb)
+        if how == "prompt-then-fragments":
+            # the first tile as allocated in one go, the rest backwards
+            tail = base + nb - 1 - (column - pages)
+            return np.where(column < pages, base + column, tail)
+        if how == "wrap-inside-tile":
+            # a ring's wrap at column P + 1, inside the second tile
+            return base + (column + nb - pages - 1) % nb
+        if how == "off-pool":
+            # lane 0 from page 1 (a span from a first live column that
+            # is not its tile's first starts under the pool), lane 2's
+            # last blocks the pool's last pages
+            pages_of = base + column
+            pages_of[0] = 1 + column
+            pages_of[2] = n - (lengths[2] + bs - 1) // bs + column
+            return pages_of
+        return base + column
+
+    positions = np.stack([
+        max(0, length - t) + np.minimum(
+            np.arange(t), length - 1 - max(0, length - t))
+        for length in lengths
+    ]).astype(np.int32)
+    first = np.maximum(positions.min(axis=1) - (window or 1 << 30) + 1, 0)
+    live_k = rng.normal(size=(b, nb * bs, kv, d)).astype(np.float32)
+    live_v = rng.normal(size=(b, nb * bs, kv, dv)).astype(np.float32)
+    for lane, length in enumerate(lengths):
+        live_k[lane, length:] = live_k[lane, : first[lane]] = GARBAGE
+        live_v[lane, length:] = live_v[lane, : first[lane]] = -GARBAGE
+    held = ((column[None] >= (first // bs)[:, None])
+            & (column[None] <= ((np.asarray(lengths) - 1) // bs)[:, None]))
+    layouts = []
+    for how in (layout, "consecutive" if layout == "shuffled" else "shuffled"):
+        where = placed(how)
+        assert len(set(where[held])) == held.sum() and where[held].max() < n
+        k_pages = np.full((n, bs, kv, d), GARBAGE, np.float32)
+        v_pages = np.full((n, bs, kv, dv), -GARBAGE, np.float32)
+        k_pages[where[held]] = live_k.reshape(b, nb, bs, kv, d)[held]
+        v_pages[where[held]] = live_v.reshape(b, nb, bs, kv, dv)[held]
+        if flat:
+            k_pages = k_pages.reshape(n, bs * kv, d)
+            v_pages = v_pages.reshape(n, bs * kv, dv)
+        table = np.where(held, where, 0).astype(np.int32)
+        layouts.append((k_pages, v_pages, table))
+    q = rng.normal(size=(b, t, kv * g, d)).astype(np.float32)
+    masking = {"window": window}
+    if flat:
+        masking["kv_heads"] = kv
+    if d != dv:
+        q[..., 192:] = 0.0
+        masking["scale"] = 192 ** -0.5
+    if sink:
+        masking["sink"] = rng.normal(size=(kv * g,)).astype(np.float32) * 3
+    return q, positions, masking, pages, layouts
+
+
+@pytest.mark.parametrize("layout", TILE_LAYOUTS)
+@pytest.mark.parametrize("kind", TILE_KINDS)
+def test_pallas_fetches_whole_tiles_and_pages_to_the_same_bits(kind, layout):
+    """A tile whose live pages lie side by side comes into VMEM by one
+    copy a pool, any other page by page, and the arithmetic on a tile
+    does not know which: the same contents behind ``layout``'s table and
+    behind its twin give the same bits, and both XLA's result. The
+    pages a whole tile's copy reads beside the live ones hold garbage
+    (or another lane's rows), so a kernel that let a dead column's slot
+    through the mask fails both ways."""
+    from client_tpu.models import paged_attention as pa
+
+    q, positions, masking, pages, layouts = _placed_case(kind, layout)
+    bs = 16
+    first, lengths = pa.visible_slots(positions, masking["window"])
+    outs, wholes = [], []
+    for k_pages, v_pages, table in layouts:
+        args = (q, k_pages, v_pages, table, positions)
+        out = np.asarray(
+            pa.paged_attention_pallas(*args, interpret=True, **masking))
+        ref = np.asarray(pa.paged_attention_xla(*args, **masking))
+        assert out.shape == ref.shape and np.isfinite(out).all()
+        scale = np.maximum(1.0, np.abs(ref).max(axis=-1, keepdims=True))
+        assert (np.abs(out - ref) / scale).max() <= 3e-5
+        outs.append(out)
+        wholes.append(pa.count_tiles(
+            table, first, lengths, pages, bs, len(k_pages)))
+    assert (outs[0] == outs[1]).all()
+    # the layout is what its name says: both ways in were taken
+    (walked, whole), (_, twin_whole) = wholes
+    if layout in ("consecutive", "trash-edges", "narrow"):
+        assert whole == walked > twin_whole
+    elif layout == "shuffled":
+        assert whole < walked == twin_whole
+    else:
+        assert twin_whole <= whole < walked
+
+
+def test_whole_tiles_rule_on_hand_made_tables():
+    """:func:`paged_attention.whole_tiles`, the rule the kernel and the
+    engine's counter share, case by case in numpy; and the same lines
+    under ``jnp``."""
+    import jax.numpy as jnp
+
+    from client_tpu.models import paged_attention as pa
+
+    bs, pool = 16, 64
+    table = np.array([
+        [1, 2, 3, 4, 9, 8, 7, 6, 10, 11, 0, 0],      # run, reversed, run
+        [0, 0, 21, 22, 23, 24, 25, 26, 27, 0, 0, 0],  # a window's row
+        [0, 0, 0, 1, 2, 3, 0, 0, 0, 0, 0, 0],  # span from under the pool
+        [61, 62, 63, 0, 0, 0, 0, 0, 0, 0, 0, 0],   # span past the pool
+        [60, 61, 62, 0, 0, 0, 0, 0, 0, 0, 0, 0],   # the pool's last span
+        [5, 6, 50, 51, 7, 0, 0, 0, 0, 0, 0, 0],    # a break inside a tile
+    ], dtype=np.int32)
+    first = np.array([0, 2 * bs + 5, 3 * bs, 0, 0, 0])
+    lengths = np.array([10 * bs, 9 * bs, 6 * bs, 3 * bs, 3 * bs - 15,
+                        4 * bs + 1])
+    expected = [
+        [1, -1, 10],   # dead columns of the last tile are not compared
+        [19, 23, 27],  # 21 sits at column 2: the span starts at 19
+        [-1, 2, -1],   # 1 at column 3 would start at -2; tile 2 unwalked
+        [-1, -1, -1],  # 61..64 runs off a pool of 64 pages
+        [60, -1, -1],
+        [-1, 7, -1],   # one live column is whole wherever it lies
+    ]
+    whole = pa.whole_tiles(table, first, lengths, 4, bs, pool)
+    assert whole.tolist() == expected
+    assert pa.count_tiles(table, first, lengths, 4, bs, pool) == (12, 8)
+    traced = pa.whole_tiles(
+        jnp.asarray(table), jnp.asarray(first), jnp.asarray(lengths),
+        4, bs, pool)
+    assert np.asarray(traced).tolist() == expected
+    # a table narrower than a tile is one tile of its own width; one
+    # not a whole number of tiles has spare columns nobody compares
+    assert pa.whole_tiles(
+        table[:1, :3], first[:1], lengths[:1] * 0 + 3 * bs, 4, bs, pool
+    ).tolist() == [[1]]
+    assert pa.whole_tiles(
+        table[:1, :10], first[:1], lengths[:1], 4, bs, pool
+    ).tolist() == [[1, -1, 10]]
+    # the strictest pool: a span has to fit
+    assert pa.whole_tiles(
+        table[:1, :10], first[:1], lengths[:1], 4, bs, 13
+    ).tolist() == [[1, -1, -1]]
 
 
 def test_pages_per_tile_follows_the_shapes_alone():

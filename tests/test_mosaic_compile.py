@@ -225,6 +225,45 @@ def test_mosaic_compiles_the_paged_kernel_at_trinitys_shapes(one_chip, group):
     assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
 
 
+def test_the_compiled_kernel_holds_both_ways_of_fetching_a_tile(one_chip):
+    """Trinity's window group again, for what went into Mosaic: a tile
+    starts and is waited for either as one copy of 16 pages from each
+    pool or as 16 page copies from each, at both places a tile starts
+    (the call's first, and the next while this one is folded) and at
+    the one where it is waited for; the page loops stay rolled, so the
+    kernel holds each copy once a site."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from client_tpu.models import paged_attention as pa
+
+    blocks, window = TRINITY_ATTENTION["window"]
+    kernel = functools.partial(
+        pa.paged_attention_pallas, window=window, kv_heads=4)
+    shapes = [((64, 1, 32, HEAD_DIM), jnp.bfloat16),
+              ((blocks, BLOCK * 4, HEAD_DIM), jnp.bfloat16),
+              ((blocks, BLOCK * 4, HEAD_DIM), jnp.bfloat16),
+              ((64, 512), jnp.int32), ((64, 1), jnp.int32)]
+    jax.jit(kernel).lower(*[
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        for shape, dtype in shapes]).compile()
+    traced = str(jax.make_jaxpr(kernel)(*[
+        jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in shapes]))
+    starts = re.findall(r"dma_start\S* \w+\[([^\]]*)\] -> ", traced)
+    waits = re.findall(r"dma_wait \w+\[([^\]]*)\] ", traced)
+    # K and V at each site; a whole tile's source is a slice of 16 pages,
+    # a page's source one index
+    assert sorted(":" in source.split(",")[0] for source in starts) == (
+        [False] * 4 + [True] * 4), starts
+    assert sum("+16" in source for source in starts) == 4
+    # a wait is written on its destination: slot and page, or the slot
+    assert sorted(
+        target.split(",")[1] == ":" for target in waits
+    ) == [False] * 2 + [True] * 2, waits
+
+
 def test_trinitys_longest_prefill_and_decode_fit_the_chip(one_chip):
     """`afmoe`'s 8,192-token prefill and its 64-lane decode step compiled
     whole for the described v5e at the cell's sizes (16 layers, 16 held

@@ -424,18 +424,18 @@ def _device_normal(key, shape, scale, dtype=None):
         dtype or jnp.bfloat16)
 
 
-@pytest.mark.parametrize("group", ["full", "window"])
-def test_compiled_pallas_at_the_trinity_cells_shapes(device, group):
-    """``trinity_mini.reason8k`` as the kernel sees it: 64 lanes, 32
-    query heads over 4 KV heads of 128 in flat pools, contexts staggered
-    over 512..8,192 behind a page table of 512 columns; the full group
-    over every block of a lane, the window group's window of 2,048 over
-    a ring of 129 blocks a lane behind the engine's own window tables.
-    Prints the kernel's ms a call beside plain XLA's."""
+def _trinity_group(group):
+    """``trinity_mini.reason8k`` as the kernel sees one cache group: 64
+    lanes, 32 query heads over 4 KV heads of 128 in flat pools, contexts
+    staggered over 512..8,192 behind a page table of 512 columns; the
+    full group over every block of a lane, the window group's window of
+    2,048 over a ring of 129 blocks a lane behind the engine's own
+    window tables. Every lane's blocks are a run of consecutive pool
+    pages. Returns (q, k_pages, v_pages, tables, positions[B, 1]) and
+    the masking arguments."""
     import jax
 
     from client_tpu.llm.kv_cache import window_ring_blocks, window_tables
-    from client_tpu.models import paged_attention as pa
 
     batch, heads, kv_heads, columns = 64, 32, 4, 512
     positions = (511 + 120 * np.arange(batch)).astype(np.int32)
@@ -460,11 +460,66 @@ def test_compiled_pallas_at_the_trinity_cells_shapes(device, group):
     k_pages = _device_normal(keys[1], (blocks, BLOCK * kv_heads, HEAD_DIM), 1.0)
     v_pages = _device_normal(keys[2], (blocks, BLOCK * kv_heads, HEAD_DIM), 1.0)
     args = (q, k_pages, v_pages, tables.astype(np.int32), positions[:, None])
+    return args, masking
+
+
+@pytest.mark.parametrize("group", ["full", "window"])
+def test_compiled_pallas_at_the_trinity_cells_shapes(device, group):
+    """The kernel against plain XLA on :func:`_trinity_group`. Prints the
+    kernel's ms a call beside plain XLA's."""
+    import jax
+
+    from client_tpu.models import paged_attention as pa
+
+    args, masking = _trinity_group(group)
     kernel = jax.jit(lambda *a: pa.paged_attention_pallas(*a, **masking))
     plain = jax.jit(lambda *a: pa.paged_attention_xla(*a, **masking))
     _assert_bf16_close(kernel(*args), plain(*args), f"trinity's {group} group")
     print(f"trinity {group} group, ms a call: pallas "
           f"{_ms_a_call(kernel, *args):.3f}, xla {_ms_a_call(plain, *args):.3f}")
+
+
+def _shuffled_pool(rng, k_pages, v_pages, tables):
+    """The same contents behind a shuffled table: every pool page but
+    the trash block moves to a random place and the table follows, so
+    that no two columns of a tile hold neighbours."""
+    moved = np.concatenate([[0], 1 + rng.permutation(len(k_pages) - 1)])
+    back = np.argsort(moved)  # new_pool[moved[p]] = pool[p]
+    return k_pages[back], v_pages[back], moved[tables].astype(np.int32)
+
+
+@pytest.mark.parametrize("group", ["full", "window"])
+def test_a_whole_tile_is_one_copy_a_pool_at_the_trinity_cells_shapes(
+        device, group):
+    """The kernel's two ways of fetching a tile on :func:`_trinity_group`:
+    its table of consecutive pages (every tile whole but where a ring's
+    wrap falls inside one: one copy a pool) and the same contents behind
+    a shuffled table (page by page, 16 copies a pool, every tile but
+    those with one live column, which nothing can scatter). The
+    arithmetic on a tile does not know how it came, so the bits are
+    equal. Prints ms a call and us a tile stop for both paths."""
+    import jax
+
+    from client_tpu.models import paged_attention as pa
+
+    args, masking = _trinity_group(group)
+    q, k_pages, v_pages, tables, positions = args
+    shuffled = _shuffled_pool(
+        np.random.default_rng(33), k_pages, v_pages, tables)
+    kernel = jax.jit(lambda *a: pa.paged_attention_pallas(*a, **masking))
+    pages = pa.pages_per_tile(BLOCK, 4, HEAD_DIM, k_pages.dtype)
+    first, lengths = pa.visible_slots(positions, masking.get("window"))
+    outs = []
+    for layout, (k, v, table) in (("consecutive", (k_pages, v_pages, tables)),
+                                  ("shuffled", shuffled)):
+        walked, whole = pa.count_tiles(
+            table, first, lengths, pages, BLOCK, len(k_pages))
+        ms = _ms_a_call(kernel, q, k, v, table, positions)
+        outs.append(np.asarray(kernel(q, k, v, table, positions)))
+        print(f"trinity {group} group, {layout} table: {whole} of {walked} "
+              f"tile stops whole, {ms:.3f} ms a call, "
+              f"{1e3 * ms / walked:.3f} us a stop")
+    assert (outs[0] == outs[1]).all()
 
 
 @pytest.mark.parametrize("tokens,kernel", [
