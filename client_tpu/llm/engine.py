@@ -513,6 +513,10 @@ class LlmEngine:
     group (``paged_attention.pages_per_tile`` of the group's pools,
     which ``LlmEngineModel`` knows): with it ``stats()`` books the tile
     stops a step's attention walks and those of them that are whole.
+    ``kv_row_bytes`` is ``(stored, counted)`` bytes a cached token takes
+    in one layer of each cache group, which ``stats()`` serves as
+    ``kv_row_bytes_by_group`` for whoever turns the token counters into
+    bytes.
 
     **The step in flight.** A decode step whose live lanes are all greedy
     is left *in flight* when dispatched: its ids stay on the device. The
@@ -579,6 +583,7 @@ class LlmEngine:
         proposer: Any = None,
         step_counters: Any = (),
         attn_tile_pages: Any = (),
+        kv_row_bytes: Any = (),
     ):
         self.config = engine_config
         self.model_name = model_name
@@ -700,6 +705,10 @@ class LlmEngine:
         # own rule (paged_attention.whole_tiles) on the tables as built
         self._tile_pages = tuple(int(pages) for pages in attn_tile_pages)
         self._group_blocks = sizes
+        self._kv_row_bytes = [
+            {"stored": int(stored), "counted": int(counted)}
+            for stored, counted in kv_row_bytes
+        ]
         self.attn_tiles_walked = 0
         self.attn_tiles_whole = 0
         # the model's own per-step counters (decode_fn's third value)
@@ -1095,6 +1104,8 @@ class LlmEngine:
             "kv_blocks_shared": self.allocator.blocks_shared,
             # every cache group's blocks in use, in the groups' order
             "kv_blocks_in_use_by_group": self._blocks_in_use_by_group(),
+            # bytes a cached token takes in one layer of each group
+            "kv_row_bytes_by_group": self._kv_row_bytes,
             "window_blocks_whole": self.window_blocks_whole,
             "window_blocks_unheld": self.window_blocks_unheld,
             "attn_tokens_full": self.attn_tokens_full,

@@ -329,8 +329,8 @@ class LlmEngineModel(Model):
         )
 
     def _shard_pages(self, pages, plan):
-        """Shard every layer's (k_pages, v_pages) pool on the kv-head
-        axis — the tp partitioning of the paged cache itself."""
+        """Shard every pool of every layer on the kv-head axis — the tp
+        partitioning of the paged cache itself."""
         import jax
 
         from client_tpu.parallel import TP_AXIS
@@ -495,19 +495,32 @@ class LlmEngineModel(Model):
         # lifecycle layer before the swap)
         if self.engine is not None:
             self.engine.close()
-        # the paged kernel's tile in pages, a cache group, from the pools
-        # as they are stored (a tp shard holds 1/tp of a page's rows):
-        # what the engine needs to count the kernel's tile stops
+        # a layer's pools (K and V, or the one pool of a model whose
+        # values lie inside its key rows), a cache group, as they are
+        # stored (a tp shard holds 1/tp of a page's rows): from them the
+        # paged kernel's tile in pages, which the engine needs to count
+        # the kernel's tile stops, and the bytes a cached token takes
+        group_pools = [
+            jax.tree_util.tree_leaves(pages[group.layers[0]])
+            for group in engine_config.cache_groups
+        ]
         tile_pages = [
             paged_attention.pages_per_tile(
-                math.prod(k_pool.shape[1:-1]) // self.tp, 1,
-                max(k_pool.shape[-1], v_pool.shape[-1]), k_pool.dtype,
+                math.prod(pools[0].shape[1:-1]) // self.tp, 1,
+                max(pool.shape[-1] for pool in pools), pools[0].dtype,
+                len(pools),
             )
-            for k_pool, v_pool in (
-                pages[group.layers[0]]
-                for group in engine_config.cache_groups
-            )
+            for pools in group_pools
         ]
+        if model.kv_row_bytes is not None:
+            kv_row_bytes = model.kv_row_bytes(config)
+        else:  # counted as stored
+            stored = [
+                sum(math.prod(pool.shape[1:]) * pool.dtype.itemsize
+                    for pool in pools) // engine_config.block_size
+                for pools in group_pools
+            ]
+            kv_row_bytes = [(size, size) for size in stored]
         self.engine = LlmEngine(
             prefill,
             decode,
@@ -518,6 +531,7 @@ class LlmEngineModel(Model):
             proposer=proposer,
             step_counters=model.step_counters,
             attn_tile_pages=tile_pages,
+            kv_row_bytes=kv_row_bytes,
         )
         self._core = None  # rebind metrics/executor after a reload
         self._wire_recovery()

@@ -9,8 +9,9 @@ nothing under ``llm/`` branches on which model it is.
 A cache group is a set of layers whose K/V a sequence keeps in the same
 way: ``full`` layers keep every block of the context, ``window`` layers
 only the blocks a sliding window of ``window`` tokens can still see.
-Each group has a block pool of its own and a sequence has one
-page-table row a group; a model with one group gets its tables as
+Each group has block pools of its own (a layer's K and V, or the one
+pool of a model whose values lie inside its key rows) and a sequence
+has one page-table row a group; a model with one group gets its tables as
 ``[max_blocks]`` / ``[B, NB]``, a model with several as ``[G, ...]``, in
 the order of :attr:`EngineModel.cache_groups`.
 
@@ -82,6 +83,13 @@ class EngineModel:
     tokens[B, T], positions, lengths, tables, pages, config, kernels)``,
     ``param_specs(config)``: optional, see the module docstring.
     ``heads(config) -> (n_heads, n_kv_heads)``: what ``tp`` must divide.
+    ``init_pages`` returns one entry a layer, a pool or a tuple of pools
+    (``(k_pages, v_pages)``; a latent model's one pool holds its values
+    inside its key rows), which the engine hands back as it got them.
+    ``kv_row_bytes(config) -> [(stored, counted) per group]``: bytes a
+    cached token takes in one layer of each group, as its pools store
+    it and as the model reads it (rows padded to whole lanes count less
+    than they store); without it both are what the pools store.
     """
 
     name: str
@@ -94,6 +102,7 @@ class EngineModel:
     verify: Optional[Callable] = None
     param_specs: Optional[Callable] = None
     heads: Optional[Callable] = None
+    kv_row_bytes: Optional[Callable] = None
     step_counters: Tuple[str, ...] = ()
 
     def missing_for(self, *, speculation: bool, prefix_sharing: bool,

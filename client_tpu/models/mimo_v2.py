@@ -189,6 +189,20 @@ def init_pages(config: MimoV2Config, num_blocks, block_size: int):
     return pages
 
 
+def kv_row_bytes(config: MimoV2Config):
+    """(stored, counted) bytes a cached token takes in a layer of each
+    group: K rows are stored ``k_row`` wide and hold ``head_dim``."""
+    size = jnp.dtype(config.dtype).itemsize
+    out = []
+    for kind in (0, 1):
+        index = next((i for i, k in enumerate(config.layer_kinds)
+                      if k == kind), 0)
+        kv = config.kv_heads(index)
+        out.append((kv * (config.k_row + config.v_head_dim) * size,
+                    kv * (config.head_dim + config.v_head_dim) * size))
+    return out
+
+
 # -- building blocks ----------------------------------------------------------
 
 
@@ -358,5 +372,6 @@ ENGINE_MODEL = EngineModel(
     init_pages=init_pages,
     prefill=prefill_into_pages,
     decode=decode_step_paged,
+    kv_row_bytes=kv_row_bytes,
     step_counters=moe.COUNTERS,
 )

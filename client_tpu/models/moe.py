@@ -50,6 +50,11 @@ from jax.experimental.pallas import tpu as pltpu
 #: an expert of width 1,024), long enough DMAs that the step's fixed cost
 #: is a few per cent of them
 _F_TILE = 512
+#: what the three weight blocks may hold twice over (one in flight): 512
+#: columns at d 4096 in bf16. A wider model's tile narrows to fit it
+#: (:func:`_f_tile`): 256 columns at ``deepseek_v3``'s d 7168, blocks of
+#: 3.7 MB, eight grid steps an expert of width 2,048
+_WEIGHT_VMEM = 24 << 20
 #: the three weight blocks twice (one in flight), rows, accumulator
 _VMEM_LIMIT = 48 << 20
 
@@ -72,23 +77,56 @@ COUNTERS = ("moe_pairs", "moe_experts_touched", "moe_load_max",
             "moe_resident_calls")
 
 
-def route(h, router, bias, top_k: int, scale: float = 1.0):
-    """``noaux_tc`` routing with sigmoid scores, one group: scores ``s =
-    sigmoid(h @ router)`` in float32, the ``top_k`` of ``s + bias`` (the
-    correction bias selects and does not weigh), weights ``s_e`` over
-    their sum, times the model's ``scale`` (``afmoe``'s ``route_scale``;
-    at 1 the program is the one it was without it). ``h`` [T, d] ->
-    (ids [T, K] int32, weights [T, K] f32)."""
+def route(h, router, bias, top_k: int, scale: float = 1.0,
+          n_group: int = 1, topk_group: int = 1, eps: float = 0.0):
+    """``noaux_tc`` routing with sigmoid scores: scores ``s =
+    sigmoid(h @ router)`` in float32, the ``top_k`` of ``c = s + bias``
+    (the correction bias selects and does not weigh), weights ``s_e``
+    over their sum (plus ``eps``), times the model's ``scale``
+    (``afmoe``'s ``route_scale``, ``deepseek_v3``'s
+    ``routed_scaling_factor``). Group-limited (``n_group`` > 1): the
+    experts fall into ``n_group`` groups of consecutive ones, a group
+    scores the sum of its two largest ``c``, and the ``top_k`` are taken
+    among the ``topk_group`` best groups' experts (``c`` reads 0
+    elsewhere). At one group, a scale of 1 and no ``eps`` the program is
+    the one it was without them. ``h`` [T, d] -> (ids [T, K] int32,
+    weights [T, K] f32)."""
     scores = jax.nn.sigmoid(jnp.dot(
         h.astype(jnp.float32), router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
     ))
-    _, ids = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    biased = scores + bias.astype(jnp.float32)
+    if n_group > 1:
+        tokens, experts = biased.shape
+        grouped = biased.reshape(tokens, n_group, experts // n_group)
+        best_two = jax.lax.top_k(grouped, 2)[0].sum(axis=-1)
+        _, kept = jax.lax.top_k(best_two, topk_group)
+        keep = (kept[:, :, None] == jnp.arange(n_group)).any(axis=1)
+        biased = jnp.where(keep[:, :, None], grouped, 0.0).reshape(
+            tokens, experts)
+    _, ids = jax.lax.top_k(biased, top_k)
     picked = jnp.take_along_axis(scores, ids, axis=-1)
-    weights = picked / picked.sum(axis=-1, keepdims=True)
+    total = picked.sum(axis=-1, keepdims=True)
+    weights = picked / (total + eps if eps else total)
     if scale != 1.0:
         weights = weights * scale
     return ids.astype(jnp.int32), weights
+
+
+def _f_tile(d: int, f: int, dtype) -> int:
+    """Columns of an expert's hidden width ``f`` a grid step brings in:
+    :data:`_F_TILE`, or fewer where a model is so wide (``d``) that gate,
+    up and down blocks of that many columns, twice buffered, pass
+    :data:`_WEIGHT_VMEM`: then the most 128-lane columns that fit and
+    divide ``f``."""
+    fit = _WEIGHT_VMEM // (6 * d * jnp.dtype(dtype).itemsize)
+    tile = min(_F_TILE, f)
+    if fit >= tile:
+        return tile
+    fit = max(128, fit // 128 * 128)
+    while fit > 128 and f % fit:
+        fit -= 128
+    return fit
 
 
 def _held_pairs(ids, held):
@@ -173,7 +211,7 @@ def grouped_experts(x_rows, tile_expert, used, w_gate, w_up, w_down, *,
     Jitted, so a model's layers share one lowering."""
     rows, d = x_rows.shape
     f = w_gate.shape[-1]
-    tf = min(_F_TILE, f)
+    tf = _f_tile(d, f, w_gate.dtype)
     n_f = f // tf
     tiles = rows // tm
 
@@ -275,7 +313,7 @@ def resident_experts(x, w, counts, w_gate, w_up, w_down, *,
     float32. Jitted, so a model's layers share one lowering."""
     rows, d = x.shape
     count, _, f = w_gate.shape
-    tf = min(_F_TILE, f)
+    tf = _f_tile(d, f, w_gate.dtype)
     n_f = f // tf
 
     def resident(e, j, walk):

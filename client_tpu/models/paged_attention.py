@@ -26,6 +26,13 @@ have produced. Padding lanes and padding rows produce garbage the
 caller discards. ``masking`` is ``window``, ``sink``, ``scale`` and
 ``kv_heads`` (:func:`paged_attention_pallas`).
 
+A model whose values lie INSIDE its key rows (a latent cache: one row a
+token for all heads, the values its leading columns) passes ONE pool,
+``v_pages=None`` and ``v_width``, the width of the values: every
+function then reads each row once and takes ``v`` as the row's leading
+``v_width`` columns (``out[B, T, H, v_width]``). The arguments of the
+call pick the path, never a model's name.
+
 - :func:`paged_attention_pallas`: the flash-style Pallas kernel, one
   grid step per sequence. The page table and each sequence's length ride
   scalar prefetch; the pools stay in HBM and the kernel copies a TILE of
@@ -76,30 +83,36 @@ KERNELS = ("pallas", "pallas_interpret", "fused_xla")
 # ---------------------------------------------------------------------------
 
 
-def paged_attention_reference(q, k_pages, v_pages, page_tables, positions):
+def paged_attention_reference(q, k_pages, v_pages, page_tables, positions,
+                              *, v_width=None, scale=None):
     """Gather + repeat_kv + a ``[B, T, S]`` mask.
 
     The oracle the XLA and Pallas functions are pinned against: kept as
     dumb as possible (materialized head repeat, full-width softmax) and
-    served by nothing."""
+    served by nothing. With ``v_pages=None`` the values are the leading
+    ``v_width`` columns of ``k_pages``' rows."""
     b, t, h, d = q.shape
     _, bs, kv, _ = k_pages.shape
     n_rep = h // kv
     s = page_tables.shape[1] * bs
     k_ctx = k_pages[page_tables].reshape(b, s, kv, d)
-    v_ctx = v_pages[page_tables].reshape(b, s, kv, d)
+    if v_pages is None:
+        v_ctx = k_ctx[..., :v_width]
+    else:
+        v_ctx = v_pages[page_tables].reshape(b, s, kv, -1)
     k_rep = jnp.broadcast_to(
         k_ctx[:, :, :, None, :], (b, s, kv, n_rep, d)
     ).reshape(b, s, h, d)
     v_rep = jnp.broadcast_to(
-        v_ctx[:, :, :, None, :], (b, s, kv, n_rep, d)
-    ).reshape(b, s, h, d)
+        v_ctx[:, :, :, None, :], (b, s, kv, n_rep, v_ctx.shape[-1])
+    ).reshape(b, s, h, -1)
     qh = q.transpose(0, 2, 1, 3)  # [B, H, T, D]
     kh = k_rep.transpose(0, 2, 1, 3)  # [B, H, S, D]
     vh = v_rep.transpose(0, 2, 1, 3)
     scores = jnp.einsum(
         "bhtd,bhkd->bhtk", qh, kh, preferred_element_type=jnp.float32
-    ) / (d ** 0.5)
+    )
+    scores = scores * scale if scale else scores / (d ** 0.5)
     # per-position validity: query row t sees slot s iff s <= pos[b, t]
     valid = jnp.arange(s)[None, None, :] <= positions[:, :, None]  # [B, T, S]
     scores = jnp.where(valid[:, None, :, :], scores, NEG_INF)
@@ -113,15 +126,22 @@ def paged_attention_reference(q, k_pages, v_pages, page_tables, positions):
 # ---------------------------------------------------------------------------
 
 
-def _pool_shape(k_pages, v_pages, kv_heads):
+def _pool_shape(k_pages, v_pages, kv_heads, v_width=None):
     """(block size, kv heads, V row size) of pools ``[N, bs, KV, D]`` or,
     with ``kv_heads`` given, ``[N, bs*KV, D]`` (row ``t*KV + h``). The
     flat form is for models whose KV is under the 8 sublanes of a tile:
     ``[..., 4, D]`` is padded to 8 rows in HBM, twice the bytes, and
-    re-viewing it flat is then a copy of the pool."""
+    re-viewing it flat is then a copy of the pool. One pool
+    (``v_pages=None``) holds its values in the leading ``v_width``
+    columns of its rows."""
+    if (v_pages is None) != (v_width is not None):
+        raise ValueError(
+            "one pool whose values lie inside its rows takes v_pages=None "
+            "and v_width; two pools take neither")
+    dv = v_width if v_pages is None else v_pages.shape[-1]
     if kv_heads is None:
-        return k_pages.shape[1], k_pages.shape[2], v_pages.shape[-1]
-    return k_pages.shape[1] // kv_heads, kv_heads, v_pages.shape[-1]
+        return k_pages.shape[1], k_pages.shape[2], dv
+    return k_pages.shape[1] // kv_heads, kv_heads, dv
 
 
 def _window_valid(valid, slots, positions, window):
@@ -146,7 +166,7 @@ def _softmax_with_sink(scores, sink):
 
 def paged_attention_xla(q, k_pages, v_pages, page_tables, positions,
                         *, window=None, sink=None, scale=None,
-                        kv_heads=None):
+                        kv_heads=None, v_width=None):
     """One fused XLA computation over the gathered pages.
 
     Head layout matches ``_repeat_kv`` (head ``k*g + r`` reads kv head
@@ -160,13 +180,19 @@ def paged_attention_xla(q, k_pages, v_pages, page_tables, positions,
     ``[B, S, KV, D]`` gather layout in place (PERF.md PR-14). The
     query-position axis ``T`` rides along both einsums, so one call
     scores all K+1 verify positions against the same gathered pages
-    instead of gathering K+1 times."""
+    instead of gathering K+1 times. One pool (``v_pages=None``) is
+    gathered once, and the values are the context's leading ``v_width``
+    columns."""
     b, t, h, d = q.shape
-    bs, kv, dv = _pool_shape(k_pages, v_pages, kv_heads)
+    bs, kv, dv = _pool_shape(k_pages, v_pages, kv_heads, v_width)
     g = h // kv
     s = page_tables.shape[1] * bs
     k_ctx = k_pages[page_tables].reshape(b, s, kv, d).transpose(0, 2, 1, 3)
-    v_ctx = v_pages[page_tables].reshape(b, s, kv, dv).transpose(0, 2, 1, 3)
+    if v_pages is None:
+        v_ctx = k_ctx[..., :dv]
+    else:
+        v_ctx = v_pages[page_tables].reshape(b, s, kv, dv).transpose(
+            0, 2, 1, 3)
     qg = q.reshape(b, t, kv, g, d)
     scores = jnp.einsum(
         "btkgd,bksd->bkgts", qg, k_ctx, preferred_element_type=jnp.float32
@@ -190,20 +216,22 @@ def paged_attention_xla(q, k_pages, v_pages, page_tables, positions,
 # ---------------------------------------------------------------------------
 
 #: VMEM the kernel may hold in K/V page buffers: two slots (one being
-#: folded, one in flight) of one K tile and one V tile each
+#: folded, one in flight) of one tile of each pool
 _KV_VMEM_BUDGET = 1 << 20
 
 
 def pages_per_tile(block_size: int, kv_heads: int, head_dim: int,
-                   dtype) -> int:
-    """Pages the kernel brings into VMEM per tile: as many as fit a
-    quarter of :data:`_KV_VMEM_BUDGET` (K and V, two slots each). A
-    function of the pool's shapes alone, so one program serves every
+                   dtype, pools: int = 2) -> int:
+    """Pages the kernel brings into VMEM per tile: the largest power of
+    two that fits :data:`_KV_VMEM_BUDGET` over ``pools`` pools (K and V,
+    or one whose values lie inside its key rows) of two slots each. A
+    function of the pools' shapes alone, so one program serves every
     batch, and every model finds its own tile: 8 pages (128 tokens) at
     KV 8 / D 128 / bf16, 2 at KV 32, 32 for a KV 2 tensor-parallel
-    shard."""
+    shard, 16 (256 tokens) for one pool of 640-wide rows at KV 1."""
     page_bytes = block_size * kv_heads * head_dim * jnp.dtype(dtype).itemsize
-    return max(1, _KV_VMEM_BUDGET // 4 // page_bytes)
+    pages = max(1, _KV_VMEM_BUDGET // (2 * pools) // page_bytes)
+    return 1 << (pages.bit_length() - 1)
 
 
 def visible_slots(positions, window):
@@ -277,7 +305,7 @@ def count_tiles(page_tables, first_slots, lengths, pages: int,
     return int(walked.sum()), int((whole >= 0).sum())
 
 
-def _rpa_kernel(kv, scale, window, has_sink, *refs):
+def _rpa_kernel(kv, scale, window, has_sink, v_width, *refs):
     """Grid step ``b``: fold sequence ``b``'s live pages, one tile of
     ``P`` pages at a time, into the online softmax of all its query rows.
 
@@ -315,7 +343,12 @@ def _rpa_kernel(kv, scale, window, has_sink, *refs):
     SMEM, where the scalar-prefetched table and lengths live, only
     serves scalar loads. With ``has_sink`` a ``[KV*M, 1]`` float32
     column holds each row's sink logit: it seeds the running maximum and
-    a denominator of one, a key with no value row."""
+    a denominator of one, a key with no value row.
+
+    With ``v_width`` there is ONE pool, whose rows hold their values in
+    their leading ``v_width`` columns: one HBM ref, one buffer of two
+    slots, one copy a tile (or a page), and the second matmul runs
+    against the leading columns of the tile the first one read."""
     refs = list(refs)
     tbl_ref, whole_ref, len_ref = refs[:3]
     del refs[:3]
@@ -323,7 +356,11 @@ def _rpa_kernel(kv, scale, window, has_sink, *refs):
     q_ref, pos_ref = refs[:2]
     del refs[:2]
     sink_ref = refs.pop(0) if has_sink else None
-    k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, slot_ref = refs
+    n_pools = 1 if v_width else 2
+    hbm, o_ref, bufs = refs[:n_pools], refs[n_pools], refs[n_pools + 1:-2]
+    sems, slot_ref = refs[-2:]
+    k_hbm, k_buf = hbm[0], bufs[0]
+    dv = v_width or bufs[1].shape[-1]
     b = pl.program_id(0)
     n_seqs = pl.num_programs(0)
     nb = tbl_ref.shape[1]
@@ -345,26 +382,21 @@ def _rpa_kernel(kv, scale, window, has_sink, *refs):
     )
 
     def page_copies(slot, j, page):
-        return (
+        return [
             pltpu.make_async_copy(
-                k_hbm.at[page], k_buf.at[slot, j], sems.at[0, slot]
-            ),
-            pltpu.make_async_copy(
-                v_hbm.at[page], v_buf.at[slot, j], sems.at[1, slot]
-            ),
-        )
+                pool.at[page], buf.at[slot, j], sems.at[index, slot]
+            )
+            for index, (pool, buf) in enumerate(zip(hbm, bufs))
+        ]
 
     def tile_copies(slot, page0):
-        return (
+        return [
             pltpu.make_async_copy(
-                k_hbm.at[pl.ds(page0, pages)], k_buf.at[slot],
-                sems.at[0, slot]
-            ),
-            pltpu.make_async_copy(
-                v_hbm.at[pl.ds(page0, pages)], v_buf.at[slot],
-                sems.at[1, slot]
-            ),
-        )
+                pool.at[pl.ds(page0, pages)], buf.at[slot],
+                sems.at[index, slot]
+            )
+            for index, (pool, buf) in enumerate(zip(hbm, bufs))
+        ]
 
     # the page loops are rolled: a copy traced once per site, not once
     # per page, keeps the program's trace (paid at every server start,
@@ -420,7 +452,8 @@ def _rpa_kernel(kv, scale, window, has_sink, *refs):
     row_head = jax.lax.broadcasted_iota(
         jnp.int32, (rows, tile_rows), 0
     ) // (rows // kv)
-    own_head = column % kv == row_head
+    # at one kv head every column is the row's own
+    own_head = True if kv == 1 else column % kv == row_head
     slot_in_tile = column // kv
 
     def fold(i, carry):
@@ -436,7 +469,10 @@ def _rpa_kernel(kv, scale, window, has_sink, *refs):
 
         wait_tile(b, i, slot)
         k = k_buf[slot].reshape(tile_rows, -1)
-        v = v_buf[slot].reshape(tile_rows, -1)
+        if v_width:
+            v = k[:, :v_width]
+        else:
+            v = bufs[1][slot].reshape(tile_rows, -1)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -467,18 +503,20 @@ def _rpa_kernel(kv, scale, window, has_sink, *refs):
         l0 = jnp.ones((rows, 1), jnp.float32)
     _, l, acc = jax.lax.fori_loop(
         tile0, n_tiles, fold,
-        (m0, l0, jnp.zeros((rows, v_buf.shape[-1]), jnp.float32)),
+        (m0, l0, jnp.zeros((rows, dv), jnp.float32)),
     )
     slot_ref[0] = (first_slot + n_tiles - tile0) % 2
     o_ref[0] = (acc / l).astype(o_ref.dtype)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("interpret", "window", "scale", "kv_heads")
+    jax.jit,
+    static_argnames=("interpret", "window", "scale", "kv_heads", "v_width"),
 )
 def paged_attention_pallas(q, k_pages, v_pages, page_tables, positions,
                            *, interpret: bool = False, window=None,
-                           sink=None, scale=None, kv_heads=None):
+                           sink=None, scale=None, kv_heads=None,
+                           v_width=None):
     """Flash-style ragged paged attention as a Pallas kernel.
 
     One grid step per sequence. ``page_tables``, :func:`whole_tiles` of
@@ -506,7 +544,11 @@ def paged_attention_pallas(q, k_pages, v_pages, page_tables, positions,
     ``window`` slots up to its position, the walk starting at the tile
     of the sequence's earliest visible slot; ``sink`` (``[H]``) is one
     more logit a head in the softmax's denominator. With neither, the
-    program is the one it was without them.
+    program is the one it was without them. ``v_pages=None`` with
+    ``v_width`` (static) is the one-pool call of the module docstring: a
+    latent model's rows of 576 (512 of them the values) are stored 640
+    wide, at KV 1, and all its query heads are rows of one matmul
+    against the tile.
 
     Jitted, so that the layers of a model, which all call it with the
     same shapes, share one trace and one lowering of the kernel: a
@@ -514,11 +556,13 @@ def paged_attention_pallas(q, k_pages, v_pages, page_tables, positions,
     compile cache holds."""
     b, t, h, d = q.shape
     n = k_pages.shape[0]
-    bs, kv, dv = _pool_shape(k_pages, v_pages, kv_heads)
+    bs, kv, dv = _pool_shape(k_pages, v_pages, kv_heads, v_width)
+    pools = [k_pages] if v_pages is None else [k_pages, v_pages]
     g = h // kv
     rows = kv * t * g
     nb = page_tables.shape[1]
-    pages = min(pages_per_tile(bs, kv, max(d, dv), k_pages.dtype), nb)
+    pages = min(
+        pages_per_tile(bs, kv, max(d, dv), k_pages.dtype, len(pools)), nb)
     q_rows = (
         q.reshape(b, t, kv, g, d).transpose(0, 2, 1, 3, 4).reshape(b, rows, d)
     )
@@ -550,20 +594,21 @@ def paged_attention_pallas(q, k_pages, v_pages, page_tables, positions,
         num_scalar_prefetch=len(prefetch),
         grid=(b,),
         in_specs=in_specs + [
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY) for _ in pools
         ],
         out_specs=row_block(dv),
         scratch_shapes=[
-            pltpu.VMEM((2, pages, bs * kv, d), k_pages.dtype),
-            pltpu.VMEM((2, pages, bs * kv, dv), v_pages.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((2, pages, bs * kv, pool.shape[-1]), pool.dtype)
+            for pool in pools
+        ] + [
+            pltpu.SemaphoreType.DMA((len(pools), 2)),
             pltpu.SMEM((1,), jnp.int32),  # the slot the next tile is in
         ],
     )
     out = pl.pallas_call(
         functools.partial(
-            _rpa_kernel, kv, scale or d ** -0.5, window, sink is not None
+            _rpa_kernel, kv, scale or d ** -0.5, window, sink is not None,
+            v_width,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, rows, dv), q.dtype),
@@ -575,7 +620,7 @@ def paged_attention_pallas(q, k_pages, v_pages, page_tables, positions,
         name="paged_attention",
     )(
         *prefetch, *inputs,
-        k_pages.reshape(n, bs * kv, d), v_pages.reshape(n, bs * kv, dv),
+        *(pool.reshape(n, bs * kv, pool.shape[-1]) for pool in pools),
     )
     return (
         out.reshape(b, kv, t, g, dv).transpose(0, 2, 1, 3, 4)

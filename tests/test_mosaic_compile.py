@@ -95,7 +95,10 @@ def test_mosaic_compiles_unequal_rows_window_and_sink(one_chip, case):
 EXPERT_SIZES = {
     "mimo": (4096, 2048, 256, 1.0, False),     # mimo_v2_flash.reason
     "trinity": (2048, 1024, 128, 2.826, True),  # trinity_mini.reason8k
+    "gigachat": (7168, 2048, 256, 2.5, True),  # gigachat3_702b.reason8k_128
 }
+#: (n_group, topk_group) of the models whose routing is limited to groups
+EXPERT_GROUPS = {"gigachat": (8, 4)}
 
 
 @functools.lru_cache(maxsize=None)
@@ -114,8 +117,11 @@ def _expert_layer_with_its_router(one_chip, tokens, model="mimo"):
     def shaped(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
+    n_group, topk_group = EXPERT_GROUPS.get(model, (1, 1))
+
     def layer(h, router, bias, experts, shared):
-        ids, weights = moe.route(h, router, bias, top_k, scale=scale)
+        ids, weights = moe.route(h, router, bias, top_k, scale=scale,
+                                 n_group=n_group, topk_group=topk_group)
         return moe.expert_layer(h, ids, weights, experts, held,
                                 kernel="pallas", shared=shared)
 
@@ -151,6 +157,7 @@ def _device_operations(compiled):
 @pytest.mark.parametrize("model,tokens", [
     ("mimo", 1), ("mimo", 16), ("mimo", 64), ("mimo", 128), ("mimo", 512),
     ("mimo", 2048), ("trinity", 64), ("trinity", 512), ("trinity", 8192),
+    ("gigachat", 128), ("gigachat", 512), ("gigachat", 2048),
 ])
 def test_mosaic_compiles_the_expert_kernel(one_chip, model, tokens):
     """Mosaic's verdict on both ``moe_experts`` kernels at both cells'
@@ -160,7 +167,12 @@ def test_mosaic_compiles_the_expert_kernel(one_chip, model, tokens):
     tile, the cells' 64 lanes, the 128 rows up to which rows stay
     resident) takes the resident kernel and holds no more than a few
     ``T x d`` buffers beside it; a 512-token prefill and each cell's
-    longest (2,048 and 8,192 tokens) are planned in row tiles of 128."""
+    longest (2,048 and 8,192 tokens) are planned in row tiles of 128.
+    At GigaChat's d 7,168 the grid step's three weight blocks narrow to
+    256 columns (eight steps an expert, 44 MB twice buffered would not
+    fit beside the rows): the cell's 128 lanes resident, a 512-token
+    prefill and the 2,048 rows a longer prompt's feed-forward takes at
+    once planned, the router limited to 4 of 8 groups."""
     from client_tpu.models import moe
 
     compiled = _expert_layer_with_its_router(one_chip, tokens, model)
@@ -169,17 +181,19 @@ def test_mosaic_compiles_the_expert_kernel(one_chip, model, tokens):
     # one: MiMo's bounds as they were; the shared expert's rows come on
     # top of them, and at 8,192 tokens each pair's row in float32 (1.1 GB,
     # the prefill's largest scratch)
-    resident, planned = {"mimo": (3, 2), "trinity": (4, 5)}[model]
+    resident, planned = {"mimo": (3, 2), "trinity": (4, 5),
+                         "gigachat": (4, 5)}[model]
     assert "%moe_experts" in text
     temp = compiled.memory_analysis().temp_size_in_bytes
     if tokens <= moe._RESIDENT_ROWS:
         rows = -(-tokens // 16) * 16
         assert f"f32[{rows},{d}]" in text  # the kernel's own output
         pairs = text
-        if model == "trinity":
-            # XLA streams the shared expert's [2048, 1024] weights in
-            # slices of 512 rows, the one shape that 64 lanes x 8 meets
-            pairs = text.replace(f"bf16[512,{f}]", "")
+        if model != "mimo":
+            # XLA streams the shared expert's [d, f] weights in slices
+            # whose rows may equal lanes x 8 (512 for Trinity's 64 lanes,
+            # 1,024 for GigaChat's 128)
+            pairs = text.replace(f"bf16[{rows * 8},{f}]", "")
         assert f"bf16[{rows * 8}," not in pairs  # no row a pair
         assert "while" not in text
         assert temp < resident * 4 * rows * d
@@ -361,3 +375,96 @@ def test_mosaic_compiles_the_paged_attention_kernel(one_chip, case):
     # the pools reach the kernel as they lie in HBM: re-viewed, not copied
     assert f"bf16[{blocks},{BLOCK * kv_heads},{HEAD_DIM}]" in text
     assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
+
+
+GIGACHAT = dict(vocab_size=16032, n_layers=5, n_dense_layers=1, held=(0, 16))
+
+
+@pytest.mark.parametrize("lanes,columns", [(128, 512), (1, 8)])
+def test_mosaic_compiles_the_one_pool_latent_call(one_chip, lanes, columns):
+    """`gigachat3_702b.reason8k_128` as the kernel sees it: one pool of
+    640-wide rows at KV 1 (576 held: the latent's 512, which are also
+    the values, and the roped key's 64), 64 query rows a lane, tiles of
+    16 pages; ONE HBM operand and one VMEM buffer of two slots, where
+    two pools of such rows would be two of each."""
+    import jax
+    import jax.numpy as jnp
+
+    from client_tpu.models import paged_attention as pa
+
+    assert pa.pages_per_tile(BLOCK, 1, 640, jnp.bfloat16, 1) == 16
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    call = functools.partial(
+        pa.paged_attention_pallas, scale=0.14468, kv_heads=1, v_width=512)
+    compiled = jax.jit(lambda q, pool, tables, positions: call(
+        q, pool, None, tables, positions)).lower(
+        shaped((lanes, 1, 64, 640), jnp.bfloat16),
+        shaped((40961, BLOCK, 640), jnp.bfloat16),
+        shaped((lanes, columns), jnp.int32),
+        shaped((lanes, 1), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "%paged_attention" in text
+    assert text.count("bf16[40961,16,640]") >= 1
+    assert f"bf16[{lanes},64,512]" in text  # the latent output, V's width
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
+    traced = str(jax.make_jaxpr(call)(
+        jax.ShapeDtypeStruct((lanes, 1, 64, 640), jnp.bfloat16),
+        jax.ShapeDtypeStruct((40961, BLOCK, 640), jnp.bfloat16), None,
+        jax.ShapeDtypeStruct((lanes, columns), jnp.int32),
+        jax.ShapeDtypeStruct((lanes, 1), jnp.int32)))
+    # one copy a tile (or a page) at each of the two places a tile
+    # starts, where K and V pools make two
+    assert traced.count("dma_start") == 4
+    assert traced.count("dma_wait") == 2
+
+
+def test_gigachats_longest_prefill_and_decode_fit_the_chip(one_chip):
+    """`deepseek_v3`'s 8,192-token prefill and its 128-lane decode step
+    compiled whole for the described v5e at the cell's sizes (5 layers,
+    16 held experts, 16,032 rows of vocabulary, one pool of 40,961
+    blocks a layer). The bound: 12.8 GB of arguments (8.58 of weights,
+    4.19 of cache) and under 1.9 GB of scratch in the prefill, 14.7 GB
+    of the chip's 16; read here at 12,776,961,536 and 1,794,586,112 B
+    (the decode step: 16,064,000 B of scratch)."""
+    import jax
+    import jax.numpy as jnp
+
+    from client_tpu.models import deepseek_v3, paged_attention
+    from client_tpu.models.engine_model import Kernels
+
+    config = deepseek_v3.DeepseekV3Config(**GIGACHAT)
+    kernels = Kernels("pallas", paged_attention.paged_attention_pallas)
+
+    def shaped(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    params = shaped(jax.eval_shape(
+        lambda: deepseek_v3.init_params(jax.random.PRNGKey(0), config)))
+    pages = shaped(jax.eval_shape(
+        lambda: deepseek_v3.init_pages(config, [40961], BLOCK)))
+    ints = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.int32, sharding=one_chip)
+    prefill = jax.jit(
+        lambda p, t, table, pages, last: deepseek_v3.prefill_into_pages(
+            p, t, table, pages, last, config, kernels),
+        donate_argnums=(3,)).lower(
+        params, ints(1, 8192), ints(512), pages, ints()).compile()
+    memory = prefill.memory_analysis()
+    assert memory.argument_size_in_bytes < 12.8e9
+    assert memory.temp_size_in_bytes < 1.9e9
+    decode = jax.jit(
+        lambda p, t, at, tables, pages: deepseek_v3.decode_step_paged(
+            p, t, at, tables, pages, config, kernels),
+        donate_argnums=(4,)).lower(
+        params, ints(128), ints(128), ints(128, 512), pages).compile()
+    text = decode.as_text()
+    assert text.count("%paged_attention") >= 5
+    assert text.count("%moe_experts") >= 4
+    # the cache is never expanded: no per-head key or value of a context
+    assert "bf16[128,8192,64," not in text
+    assert decode.memory_analysis().temp_size_in_bytes < 64e6

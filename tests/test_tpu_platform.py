@@ -6,7 +6,8 @@ Covers what the hermetic CPU suite cannot see: the Pallas paged-attention
 kernels COMPILED by Mosaic (the CPU tier only interprets them) against
 the fused XLA reference at the shapes the server serves, a full-width
 decode step through both, ``mimo_v2_flash.reason``'s attention groups
-and expert layer at the cell's sizes, the client→server infer path
+and expert layer at the cell's sizes, ``gigachat3_702b.reason8k_128``'s
+one-pool latent call, expert layer and decode step, the client→server infer path
 executing on the real platform, and the tpu-shm staging round-trip.
 ``python chip_smoke.py`` runs this tier on the chip as one of its phases.
 """
@@ -618,6 +619,184 @@ def test_trinitys_decode_step_agrees_through_both_kernel_choices(device):
     plain, plain_counted = step("fused_xla", pa.paged_attention_xla)
     assert counted[3] == 2 and plain_counted[3] == 0
     assert (counted[:3] == plain_counted[:3]).all()
+    assert np.isfinite(kernel).all() and np.abs(plain).max() > 1.0
+    worst = float(np.abs(kernel - plain).max())
+    assert worst <= 2.0 ** -4 * max(1.0, float(np.abs(plain).max())), worst
+
+
+# ---------------------------------------------------------------------------
+# gigachat3_702b.reason8k_128's kernels and its whole step at the cell's shapes
+# ---------------------------------------------------------------------------
+
+
+def _latent_lanes():
+    """``gigachat3_702b.reason8k_128`` as the kernel sees a layer: 128
+    lanes, 64 query heads whose rows ``[q_lat 512 | q_rope 64 | zeros]``
+    all read ONE pool of 640-wide rows at KV 1, contexts staggered over
+    512..8,132 behind a page table of 512 columns, every lane's blocks a
+    run of consecutive pool pages. Returns (q, pool, tables,
+    positions[B, 1]) and the call's arguments."""
+    import jax
+
+    batch, heads, columns = 128, 64, 512
+    positions = (511 + 60 * np.arange(batch)).astype(np.int32)
+    owned = positions // BLOCK + 1
+    starts = 1 + np.concatenate([[0], np.cumsum(owned)[:-1]])
+    tables = np.zeros((batch, columns), np.int32)
+    for lane in range(batch):
+        tables[lane, :owned[lane]] = starts[lane] + np.arange(owned[lane])
+    keys = jax.random.split(jax.random.PRNGKey(41), 2)
+    q = _device_normal(keys[0], (batch, 1, heads, 640), 1.0)
+    pool = _device_normal(keys[1], (1 + int(owned.sum()), BLOCK, 640), 1.0)
+    asked = {"scale": 0.14468, "kv_heads": 1, "v_width": 512}
+    return (q, pool, tables, positions[:, None]), asked
+
+
+def test_the_latent_call_at_the_gigachat_cells_shapes(device):
+    """The one-pool call compiled by Mosaic against plain XLA on
+    :func:`_latent_lanes`, on its table of consecutive pages (whole
+    tiles: one copy each) and on the same contents behind a shuffled
+    table (16 page copies a tile): equal bits both ways, a few bf16
+    steps from XLA. Prints ms a call, us a tile stop, and the call's
+    share of the longer of its bytes (a row read once, counted at 576)
+    and its FLOPs (64 heads x (576 + 512) x 2 a cached token)."""
+    import jax
+
+    from client_tpu.models import paged_attention as pa
+
+    (q, pool, tables, positions), asked = _latent_lanes()
+    kernel = jax.jit(lambda q, pool, tables, positions: (
+        pa.paged_attention_pallas(q, pool, None, tables, positions, **asked)))
+    plain = jax.jit(lambda q, pool, tables, positions: (
+        pa.paged_attention_xla(q, pool, None, tables, positions, **asked)))
+    moved = np.concatenate([[0], 1 + np.random.default_rng(43).permutation(
+        len(pool) - 1)])
+    shuffled = (pool[np.argsort(moved)], moved[tables].astype(np.int32))
+    pages = pa.pages_per_tile(BLOCK, 1, 640, pool.dtype, 1)
+    first, lengths = pa.visible_slots(positions, None)
+    tokens = int(lengths.sum())
+    least_ms = 1e3 * max(tokens * 576 * 2 / 819e9,
+                         tokens * 64 * (576 + 512) * 2 / 197e12)
+    outs = []
+    for layout, (pages_, table) in (("consecutive", (pool, tables)),
+                                    ("shuffled", shuffled)):
+        walked, whole = pa.count_tiles(
+            table, first, lengths, pages, BLOCK, len(pool))
+        ms = _ms_a_call(kernel, q, pages_, table, positions)
+        outs.append(np.asarray(kernel(q, pages_, table, positions)))
+        print(f"gigachat latent call, {layout} table: {whole} of {walked} "
+              f"tile stops whole, {ms:.3f} ms a call, "
+              f"{1e3 * ms / walked:.3f} us a stop, "
+              f"{100 * least_ms / ms:.1f}% of max(bytes, FLOPs)")
+    assert (outs[0] == outs[1]).all()
+    _assert_bf16_close(outs[0], plain(q, pool, tables, positions),
+                       "gigachat's latent call")
+    print(f"gigachat latent call, plain XLA: "
+          f"{_ms_a_call(plain, q, pool, tables, positions, calls=5):.3f} "
+          f"ms a call")
+
+
+@pytest.mark.parametrize("tokens,kernel", [
+    (128, "pallas"), (512, "pallas"), (2048, "pallas"), (128, "fused_xla"),
+])
+def test_gigachats_expert_layer_matches_a_dense_pass(device, tokens, kernel):
+    """``moe.expert_layer`` at the cell's sizes (16 held of 256 experts of
+    7168 x 2048 routed in 8 groups of which 4 are kept, 8 a token at the
+    routed scale 2.5, and the shared expert): a decode step's 128 lanes
+    resident (grid steps of 256 columns), a 512-token prefill and a long
+    prompt's 2,048-row chunk planned, and the plain XLA path, against
+    every held expert run over every token and kept where the router
+    chose it, with the shared expert added once. Prints the layer's ms
+    a call and the touched experts' share of HBM's bandwidth."""
+    import jax
+    import jax.numpy as jnp
+
+    from client_tpu.models import moe
+
+    d, f, held = 7168, 2048, (0, 16)
+    keys = iter(jax.random.split(jax.random.PRNGKey(tokens), 9))
+    swiglu = lambda *lead: {  # noqa: E731
+        "w_gate": _device_normal(next(keys), lead + (d, f), d ** -0.5),
+        "w_up": _device_normal(next(keys), lead + (d, f), d ** -0.5),
+        "w_down": _device_normal(next(keys), lead + (f, d), f ** -0.5)}
+    experts, shared = swiglu(16), swiglu()
+    router = _device_normal(next(keys), (d, 256), d ** -0.5)
+    bias = _device_normal(next(keys), (256,), 0.02, jnp.float32)
+    h = _device_normal(next(keys), (tokens, d), 1.0)
+    ids, weights = jax.jit(lambda h: moe.route(
+        h, router, bias, 8, scale=2.5, n_group=8, topk_group=4,
+        eps=1e-20))(h)
+    groups = np.asarray(ids) // 32
+    assert all(len(set(row)) <= 4 for row in groups)
+
+    def one(h, w):
+        gate = jax.nn.silu(jnp.dot(
+            h, w["w_gate"], preferred_element_type=jnp.float32))
+        up = jnp.dot(h, w["w_up"], preferred_element_type=jnp.float32)
+        return jnp.dot((gate * up).astype(h.dtype), w["w_down"],
+                       preferred_element_type=jnp.float32)
+
+    def dense(h, ids, weights, experts, shared):
+        out = one(h, shared)
+        for e in range(held[1]):
+            share = (weights * (ids == e)).sum(-1, keepdims=True)
+            out = out + share * one(
+                h, {name: experts[name][e] for name in experts})
+        return out
+
+    layer = jax.jit(lambda h, ids, weights, experts, shared: moe.expert_layer(
+        h, ids, weights, experts, held, kernel=kernel, shared=shared))
+    args = (h, ids, weights, experts, shared)
+    out, counters = layer(*args)
+    assert int(counters[0]) == (np.asarray(ids) < held[1]).sum() > 0
+    assert int(counters[3]) == (kernel == "pallas" and tokens <= 128)
+    _assert_bf16_close(out, jax.jit(dense)(*args),
+                       f"gigachat's expert layer, {tokens} tokens, {kernel}")
+    ms = _ms_a_call(layer, *args)
+    streamed = (int(counters[1]) + 1) * 3 * d * f * 2  # touched + shared
+    print(f"gigachat expert layer, {tokens} rows, {kernel}, touched "
+          f"{int(counters[1])} of 16: {ms:.3f} ms a call, "
+          f"{100 * streamed / 819e9 / (ms / 1e3):.1f}% of HBM")
+
+
+def test_gigachats_decode_step_agrees_through_both_kernel_choices(device):
+    """`deepseek_v3`'s whole decode step at the published widths (the
+    dense layer and one expert layer; 16 held experts) through the
+    load-time choices ``pallas`` and ``fused_xla``, both reading the one
+    pool a layer: the logits agree to a few bf16 steps, with contexts
+    from inside one tile to the cell's longest."""
+    import jax
+
+    from client_tpu.models import deepseek_v3, paged_attention as pa
+    from client_tpu.models.engine_model import Kernels
+
+    config = deepseek_v3.DeepseekV3Config(
+        vocab_size=4096, n_layers=2, n_dense_layers=1, held=(0, 16))
+    params = deepseek_v3.init_params(jax.random.PRNGKey(5), config)
+    lanes, columns = 4, 512
+    positions = np.array([17, 1500, 4000, 8131], np.int32)
+    tables = (1 + np.arange(lanes * columns)).reshape(
+        lanes, columns).astype(np.int32)
+    keys = jax.random.split(jax.random.PRNGKey(6), config.n_layers)
+    # rows as the program stores them: zeros behind the 576 it holds
+    pages = [
+        _device_normal(key, (1 + lanes * columns, BLOCK, config.row_width),
+                       1.0).at[..., config.row:].set(0)
+        for key in keys]
+    tokens = np.array([5, 6, 7, 8], np.int32)
+
+    def step(name, attn):
+        logits, _, counters = jax.jit(
+            lambda *a: deepseek_v3.decode_step_paged(
+                *a, config, Kernels(name, attn)))(
+            params, tokens, positions, tables, pages)
+        return np.asarray(logits), np.asarray(counters)
+
+    kernel, counted = step("pallas", pa.paged_attention_pallas)
+    plain, plain_counted = step("fused_xla", pa.paged_attention_xla)
+    assert counted[3] == 1 and plain_counted[3] == 0
+    assert (counted[:3] == plain_counted[:3]).all()
+    assert counted[4] == plain_counted[4] <= lanes
     assert np.isfinite(kernel).all() and np.abs(plain).max() > 1.0
     worst = float(np.abs(kernel - plain).max())
     assert worst <= 2.0 ** -4 * max(1.0, float(np.abs(plain).max())), worst
