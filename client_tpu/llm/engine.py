@@ -54,6 +54,7 @@ this package), so deadline behavior is testable on fake clocks.
 
 import asyncio
 import time
+from operator import attrgetter
 from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
@@ -114,7 +115,10 @@ class EngineConfig:
     from the model. ``num_blocks`` sizes the full group's pool; a
     window group's is worked out: a ring for each of ``max_active``
     sequences, and the trash block, so a ring is there for whoever
-    ``max_active`` admits.
+    ``max_active`` admits. How many blocks a group's allocator hands
+    out at a time, and with it the ring's length, follows from the
+    paged kernel's tile and the table's width (:meth:`group_runs`):
+    shapes, not a setting.
     """
 
     __slots__ = (
@@ -171,10 +175,39 @@ class EngineConfig:
 
         return pad_batch_bucket(self.max_active)
 
-    def group_num_blocks(self) -> List[int]:
+    def group_runs(self, tile_pages=()) -> List[int]:
+        """Blocks each cache group's allocator hands out at a time
+        (``kv_cache.BlockAllocator``'s ``run``), in the groups' order:
+        the group's entry of ``tile_pages`` (the paged kernel's tile in
+        pages, ``paged_attention.pages_per_tile`` of its pools) where a
+        sequence's row holds more than one such tile (the tile is
+        smaller than the table's ``max_blocks_per_seq`` columns and, in
+        a window group, than the blocks its window can touch), else 1:
+        a row of one tile is whole only if the whole sequence is one
+        run, which is not attempted. Without ``tile_pages`` every run is
+        1 and the allocators work block for block."""
+        runs = []
+        for index, group in enumerate(self.cache_groups or (None,)):
+            most = self.max_blocks_per_seq
+            if group is not None and group.window is not None:
+                most = min(
+                    most, window_ring_blocks(group.window, self.block_size)
+                )
+            pages = int(tile_pages[index]) if index < len(tile_pages) else 1
+            runs.append(pages if 1 < pages < most else 1)
+        return runs
+
+    def group_num_blocks(self, tile_pages=()) -> List[int]:
         """Physical blocks of each cache group's pool, in the groups'
-        order (one full group when none is declared). Refuses, with the
-        numbers, sizes under which a window group cannot do its work:
+        order (one full group when none is declared). A window group's
+        ring is a whole number of its allocator's runs
+        (:meth:`group_runs` of ``tile_pages``: 129 blocks become 144 at
+        tiles of 16, 9 become 12 at tiles of 4), so that no tile of a
+        ring wraps; the full group's pool is ``num_blocks`` whatever the
+        run, and its sequences hold up to a run less one of it in
+        reserve each (``stats()["kv_blocks_reserved_by_group"]``).
+        Refuses, with the numbers, sizes under which a window group
+        cannot do its work:
         a ``max_seq_len`` whose page table has fewer columns than the
         group's ring has blocks (the window never fills, and the ring is
         memory no table row can name), and a full pool that cannot hold
@@ -187,10 +220,13 @@ class EngineConfig:
         and a ring that no request can fill, so that a configuration
         says what it will hold."""
         sizes = []
-        for group in self.cache_groups or (None,):
+        runs = self.group_runs(tile_pages)
+        for run, group in zip(runs, self.cache_groups or (None,)):
             if group is None or group.window is None:
                 sizes.append(self.num_blocks)
                 continue
+            # the checks reckon with the window's own blocks, not with
+            # what the ring is rounded up to
             ring = window_ring_blocks(group.window, self.block_size)
             if ring > self.max_blocks_per_seq + 1:
                 raise ValueError(
@@ -208,7 +244,10 @@ class EngineConfig:
                     f"{group.window} tokens fill ({ring - 1} blocks of "
                     f"{self.block_size} each)"
                 )
-            sizes.append(1 + self.max_active * ring)
+            sizes.append(
+                1 + self.max_active
+                * window_ring_blocks(group.window, self.block_size, run)
+            )
         return sizes
 
 
@@ -587,15 +626,16 @@ class LlmEngine:
     ):
         self.config = engine_config
         self.model_name = model_name
-        self.allocator = BlockAllocator(
-            engine_config.num_blocks, engine_config.block_size
-        )
         # cache groups: the full group is `self.allocator` and the
         # sequences' `blocks`; each window group has an allocator of its
         # own that hands out whole rings. `_windows` holds (index among
-        # the groups, ring length, allocator).
+        # the groups, ring length, allocator). Every allocator hands out
+        # runs of its group's tile where the table holds several tiles
+        # (`EngineConfig.group_runs`), so that the kernel finds them whole
+        self._tile_pages = tuple(int(pages) for pages in attn_tile_pages)
         groups = engine_config.cache_groups
-        sizes = engine_config.group_num_blocks()
+        runs = engine_config.group_runs(self._tile_pages)
+        sizes = engine_config.group_num_blocks(self._tile_pages)
         self._n_groups = max(1, len(groups))
         self._full_group = 0
         self._windows: List[tuple] = []
@@ -605,9 +645,17 @@ class LlmEngine:
                 continue
             self._windows.append((
                 index,
-                window_ring_blocks(group.window, engine_config.block_size),
-                BlockAllocator(sizes[index], engine_config.block_size),
+                window_ring_blocks(
+                    group.window, engine_config.block_size, runs[index]
+                ),
+                BlockAllocator(
+                    sizes[index], engine_config.block_size, runs[index]
+                ),
             ))
+        self.allocator = BlockAllocator(
+            engine_config.num_blocks, engine_config.block_size,
+            runs[self._full_group],
+        )
         if groups and len(groups) - len(self._windows) != 1:
             raise ValueError(
                 "the engine serves exactly one full cache group beside "
@@ -703,7 +751,6 @@ class LlmEngine:
         # decode and verify step (a layer of each cache group), and
         # those of them it fetches with one copy a pool: the kernel's
         # own rule (paged_attention.whole_tiles) on the tables as built
-        self._tile_pages = tuple(int(pages) for pages in attn_tile_pages)
         self._group_blocks = sizes
         self._kv_row_bytes = [
             {"stored": int(stored), "counted": int(counted)}
@@ -800,7 +847,9 @@ class LlmEngine:
             self.allocator.match_count(block_hashes),
             self._match_cap(len(prompt)),
         )
-        if self.allocator.blocks_for(total) - matched_now > self.allocator.capacity:
+        if self.allocator.demand(
+            self.allocator.blocks_for(total), matched_now
+        ) > self.allocator.capacity:
             raise InferenceServerException(
                 f"request needs {self.allocator.blocks_for(total)} KV "
                 f"blocks ({matched_now} shared) but the pool holds "
@@ -1103,7 +1152,12 @@ class LlmEngine:
             "kv_blocks_total": self.allocator.capacity,
             "kv_blocks_shared": self.allocator.blocks_shared,
             # every cache group's blocks in use, in the groups' order
-            "kv_blocks_in_use_by_group": self._blocks_in_use_by_group(),
+            "kv_blocks_in_use_by_group": self._blocks_by_group(
+                attrgetter("blocks_in_use")),
+            # and the blocks no admission can have that hold no
+            # reference: what sequences keep of their open runs
+            "kv_blocks_reserved_by_group": self._blocks_by_group(
+                attrgetter("blocks_reserved")),
             # bytes a cached token takes in one layer of each group
             "kv_row_bytes_by_group": self._kv_row_bytes,
             "window_blocks_whole": self.window_blocks_whole,
@@ -1158,11 +1212,13 @@ class LlmEngine:
             ring_allocator.free(seq.seq_id)
         seq.rings = []
 
-    def _blocks_in_use_by_group(self) -> List[int]:
-        in_use = [self.allocator.blocks_in_use] * self._n_groups
+    def _blocks_by_group(self, count: Callable) -> List[int]:
+        """``count`` of every cache group's allocator, in the groups'
+        order."""
+        counts = [count(self.allocator)] * self._n_groups
         for index, _, ring_allocator in self._windows:
-            in_use[index] = ring_allocator.blocks_in_use
-        return in_use
+            counts[index] = count(ring_allocator)
+        return counts
 
     def _group_tables(self, full: np.ndarray, seqs: List[Sequence],
                       last_positions) -> np.ndarray:
@@ -1339,14 +1395,16 @@ class LlmEngine:
             usable = min(
                 allocator.match_count(seq.block_hashes), cap, len(seq.block_hashes)
             )
+            # what the allocation takes of the pool: whole runs
+            demand = allocator.demand(need, usable)
             if self._flight is not None and (
-                need - usable > allocator.capacity
-                or need - usable <= allocator.free_blocks
+                demand > allocator.capacity
+                or demand <= allocator.free_blocks
             ):
                 self._consume(self._flight)
                 await self._admit()
                 return
-            if need - usable > allocator.capacity:
+            if demand > allocator.capacity:
                 # admitted on the strength of a shared prefix that has
                 # since been reclaimed (its sharers finished): the
                 # residual demand can never be satisfied — fail cleanly
@@ -1363,7 +1421,7 @@ class LlmEngine:
                     )
                 seq.fail(error)
                 continue
-            if need - usable > allocator.free_blocks:
+            if demand > allocator.free_blocks:
                 break
             self._waiting.remove([item])
             if seq.cancelled:
@@ -1619,8 +1677,8 @@ class LlmEngine:
                         # streamed), then grow from what that left
                         self._consume(flight)
                         return self._grow()
-                    if allocator.blocks_for(
-                        seq.position + 1
+                    if allocator.demand(
+                        allocator.blocks_for(seq.position + 1)
                     ) > allocator.capacity:
                         # the whole pool could not hold this context:
                         # possible only for a request admitted against a

@@ -35,6 +35,35 @@ ring's blocks at the last ``len(ring)`` logical columns, the trash block
 at every column wholly behind the window, which is therefore neither
 held nor read.
 
+Runs (``BlockAllocator(run=P)``): the paged kernel fetches a tile of
+``P`` table columns with one copy a pool when the tile's live columns
+hold ``P`` pool pages side by side (``paged_attention.whole_tiles``), so
+the allocator hands out the kernel's tile and not a block. The pool
+after the trash block is cut into aligned runs of ``P`` blocks (run
+``k`` is blocks ``1 + k * P .. k * P + P``; what does not fill a last
+run is never handed out and :attr:`capacity` leaves it out), the free
+pool is a LIFO stack of RUNS, and a sequence's own column ``j`` sits at
+``run_start + j % P`` of the run it holds for the tile ``j // P``: a
+prompt takes a run for each tile its fresh columns reach, growth takes
+the next block of the sequence's open run and a new run only where a
+column opens a tile, a rollback puts trailing blocks back into the open
+run, and a run returns to the pool when the last block of it that holds
+a reference drops, whoever held it. Columns matched from the index are
+referenced where they lie (in their publisher's runs); where the match
+ends inside a tile the fresh columns of that tile share it with them
+(they sit at ``j % P`` of a run of their own, whose leading ``matched %
+P`` blocks stay unused): one tile stop a sequence that is not whole.
+What admission may count on is whole runs (:attr:`free_blocks`,
+:meth:`demand`); :attr:`blocks_in_use` stays the blocks that hold a
+reference, and what lies in held runs without one (a sequence's open
+run beyond its newest block, at most ``P - 1``; a mixed tile's leading
+blocks; a run whose owner ended while the index still names a block of
+it) is :attr:`blocks_reserved`. ``run=1`` is the allocator block for
+block as it was before runs existed, the LIFO order included. A window
+group's ring is a whole number of runs (:func:`window_ring_blocks`
+rounds it up), so a ring tile never wraps. Which ``P`` a group gets is
+the engine's to read off its shapes (``EngineConfig.group_runs``).
+
 Pure bookkeeping: no clocks, no jax, single-owner (the engine's step
 loop) — no locks.
 """
@@ -55,12 +84,16 @@ TRASH_BLOCK = 0
 _CHAIN_SEED = b"kv-block-chain"
 
 
-def window_ring_blocks(window: int, block_size: int) -> int:
-    """Blocks a window of ``window`` tokens can touch at once: a span of
-    W slots starts anywhere in a block, so ``ceil(W / bs) + 1``: 9
-    blocks of 16 for ``mimo_v2``'s window of 128, 129 for ``afmoe``'s
-    of 2,048."""
-    return -(-int(window) // int(block_size)) + 1
+def window_ring_blocks(window: int, block_size: int, run: int = 1) -> int:
+    """Blocks of a window group's ring. A window of ``window`` tokens
+    can touch ``ceil(W / bs) + 1`` blocks at once (a span of W slots
+    starts anywhere in a block): 9 blocks of 16 for ``mimo_v2``'s window
+    of 128, 129 for ``afmoe``'s of 2,048. The ring is that, rounded up
+    to a whole number of the allocator's runs of ``run`` blocks (12 at
+    runs of 4, 144 at runs of 16), so that column ``j`` -> ring entry
+    ``j % R`` never wraps inside a tile of ``run`` columns."""
+    ring = -(-int(window) // int(block_size)) + 1
+    return -(-ring // int(run)) * int(run)
 
 
 def window_tables(rings, last_blocks, width: int) -> np.ndarray:
@@ -91,23 +124,34 @@ class BlockAllocator:
     """Fixed-size-block pool accounting for the paged KV cache.
 
     ``num_blocks`` counts PHYSICAL blocks including the reserved trash
-    block; :attr:`capacity` (= ``num_blocks - 1``) is what sequences can
-    actually hold. Blocks are identified by pool index; a block may be
-    referenced by several sequences at once (shared prefix), and returns
-    to the pool only when the last reference is freed.
+    block; :attr:`capacity` is what sequences can actually hold
+    (``num_blocks - 1`` at runs of 1). Blocks are identified by pool
+    index; a block may be referenced by several sequences at once
+    (shared prefix), and returns to the pool only when the last
+    reference is freed. ``run`` is the blocks the pool hands out at a
+    time (the module docstring's runs).
     """
 
-    def __init__(self, num_blocks: int, block_size: int):
+    def __init__(self, num_blocks: int, block_size: int, run: int = 1):
         if num_blocks < 2:
             raise ValueError("num_blocks must be >= 2 (block 0 is reserved)")
         if block_size < 1:
             raise ValueError("block_size must be >= 1")
+        if run < 1:
+            raise ValueError("run must be >= 1")
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
-        # LIFO free stack: recently-freed blocks are re-issued first
-        # (their pages are hot in cache)
-        self._free: List[int] = list(range(self.num_blocks - 1, 0, -1))
+        self.run = int(run)
+        # LIFO free stack of runs, each by its first block: recently
+        # freed ones are re-issued first (their pages are hot in cache)
+        self._free: List[int] = list(
+            range(1 + (self.capacity // self.run - 1) * self.run, 0, -self.run)
+        )
+        # first block of a held run -> blocks of it that hold a reference
+        self._live: Dict[int, int] = {}
         self._owned: Dict[object, List[int]] = {}
+        # the first column of a sequence that is its own (not matched)
+        self._fresh_from: Dict[object, int] = {}
         self._ref: Dict[int, int] = {}  # phys -> live reference count
         self._index: Dict[bytes, int] = {}  # chain digest -> phys
         self._hash_of: Dict[int, bytes] = {}  # phys -> its published digest
@@ -117,17 +161,26 @@ class BlockAllocator:
 
     @property
     def capacity(self) -> int:
-        """Allocatable blocks (the trash block excluded)."""
-        return self.num_blocks - 1
+        """Allocatable blocks: the whole runs after the trash block."""
+        return (self.num_blocks - 1) // self.run * self.run
 
     @property
     def free_blocks(self) -> int:
-        return len(self._free)
+        """Blocks of the runs no sequence holds: what an allocation can
+        be given (:meth:`demand` is what it would take of them)."""
+        return len(self._free) * self.run
 
     @property
     def blocks_in_use(self) -> int:
-        """Distinct PHYSICAL blocks allocated — sharing keeps this low."""
-        return self.capacity - len(self._free)
+        """Distinct PHYSICAL blocks that hold a reference — sharing
+        keeps this low."""
+        return len(self._ref)
+
+    @property
+    def blocks_reserved(self) -> int:
+        """Blocks of held runs that hold no reference: no admission can
+        have them until their run's last referenced block drops."""
+        return self.capacity - self.free_blocks - len(self._ref)
 
     @property
     def blocks_shared(self) -> int:
@@ -140,6 +193,16 @@ class BlockAllocator:
         """Blocks needed to hold ``n_tokens`` of context."""
         return (max(0, n_tokens) + self.block_size - 1) // self.block_size
 
+    def demand(self, n_blocks: int, n_matched: int = 0) -> int:
+        """Blocks of the free pool that a sequence of ``n_blocks``
+        columns takes when its first ``n_matched`` are referenced from
+        the index: a run for every tile its own columns reach
+        (``n_blocks - n_matched`` at runs of 1)."""
+        if n_blocks <= n_matched:
+            return 0
+        tiles = (n_blocks - 1) // self.run - n_matched // self.run + 1
+        return tiles * self.run
+
     def refcount(self, phys: int) -> int:
         """Live references to a physical block (0 = free/unallocated)."""
         return self._ref.get(phys, 0)
@@ -147,6 +210,36 @@ class BlockAllocator:
     def owned(self, seq_id) -> List[int]:
         """The sequence's block list (allocation order = logical order)."""
         return self._owned.get(seq_id, [])
+
+    def _claim(self, blocks: List[int], fresh_from: int) -> int:
+        """Append a fresh block for the next column of a sequence whose
+        own columns start at ``fresh_from``: inside a tile the block
+        after its last, where the column opens a tile (or is its first
+        own one) the block at ``column % run`` of a new run."""
+        column = len(blocks)
+        if column % self.run and column > fresh_from:
+            block = blocks[-1] + 1
+        elif self._free:
+            block = self._free.pop() + column % self.run
+        else:
+            raise CacheCapacityError(
+                f"KV cache exhausted: 0 of {self.capacity} blocks free"
+            )
+        self._ref[block] = 1
+        start = block - (block - 1) % self.run
+        self._live[start] = self._live.get(start, 0) + 1
+        blocks.append(block)
+        return block
+
+    def _reclaim(self, block: int) -> None:
+        """Drop the last reference to ``block``; its run goes back to
+        the pool with the last block of it that held one."""
+        del self._ref[block]
+        start = block - (block - 1) % self.run
+        self._live[start] -= 1
+        if not self._live[start]:
+            del self._live[start]
+            self._free.append(start)
 
     # -- prefix hashing / matching ------------------------------------------
 
@@ -213,23 +306,23 @@ class BlockAllocator:
             if phys is None:
                 break
             matched.append(phys)
-        need_new = n_blocks - len(matched)
-        if need_new > len(self._free):
+        need_new = self.demand(n_blocks, len(matched))
+        if need_new > self.free_blocks:
             raise CacheCapacityError(
                 f"KV cache exhausted: need {need_new} blocks "
-                f"({n_blocks} minus {len(matched)} shared), "
-                f"{len(self._free)} of {self.capacity} free"
+                f"({n_blocks} minus {len(matched)} shared, in runs of "
+                f"{self.run}), {self.free_blocks} of {self.capacity} free"
             )
         if prefix_hashes:
             self.prefix_queries += 1
             self.prefix_hits += len(matched)
         for phys in matched:
             self._ref[phys] += 1
-        fresh = [self._free.pop() for _ in range(need_new)]
-        for phys in fresh:
-            self._ref[phys] = 1
-        blocks = matched + fresh
+        blocks = list(matched)
+        while len(blocks) < n_blocks:
+            self._claim(blocks, len(matched))
         self._owned[seq_id] = blocks
+        self._fresh_from[seq_id] = len(matched)
         # a copy: callers keep their own page-table mirror, and a caller
         # appending to the returned list must not alias the ownership
         # record (a block listed twice would be freed twice)
@@ -237,24 +330,21 @@ class BlockAllocator:
 
     def extend(self, seq_id) -> int:
         """Claim ONE more block for a growing sequence (decode entering a
-        new block); raises :class:`CacheCapacityError` when the pool is
-        dry — the engine's preemption signal. Always a FRESH block with
-        refcount 1: growth never writes into shared storage."""
-        if seq_id not in self._owned:
+        new block): the next of its open run, or the first of a new run
+        where the column opens a tile; raises
+        :class:`CacheCapacityError` when that needs a run and the pool
+        is dry — the engine's preemption signal. Always a FRESH block
+        with refcount 1: growth never writes into shared storage."""
+        blocks = self._owned.get(seq_id)
+        if blocks is None:
             raise CacheCapacityError(f"sequence {seq_id!r} owns no blocks")
-        if not self._free:
-            raise CacheCapacityError(
-                f"KV cache exhausted: 0 of {self.capacity} blocks free"
-            )
-        block = self._free.pop()
-        self._ref[block] = 1
-        self._owned[seq_id].append(block)
-        return block
+        return self._claim(blocks, self._fresh_from[seq_id])
 
     def truncate(self, seq_id, keep: int) -> int:
         """Give back a sequence's TRAILING blocks beyond its first
         ``keep`` (speculative-decode rollback: lookahead blocks claimed
-        for draft-token writes that verification then rejected).
+        for draft-token writes that verification then rejected) into its
+        open run; a run left with no block goes back to the pool.
 
         Only ever legal on exclusively-owned tail blocks — growth never
         lands in shared storage, so a truncated block with ``refcount !=
@@ -277,29 +367,29 @@ class BlockAllocator:
                     f"published={phys in self._hash_of})"
                 )
         for phys in reversed(tail):
-            del self._ref[phys]
-            self._free.append(phys)
+            self._reclaim(phys)
         del blocks[keep:]
         return len(tail)
 
     def free(self, seq_id) -> int:
         """Drop a sequence's references (idempotent); returns the number
-        of blocks actually RECLAIMED into the pool. A block another
+        of blocks whose last reference dropped. A block another
         sequence still references survives with its index entry; the
-        last reference unpublishes and reclaims it."""
+        last reference unpublishes and reclaims it, and its run is free
+        again once no block of it holds a reference."""
         blocks = self._owned.pop(seq_id, None)
+        self._fresh_from.pop(seq_id, None)
         if not blocks:
             return 0
         reclaimed = 0
         for phys in reversed(blocks):
-            self._ref[phys] -= 1
-            if self._ref[phys] > 0:
+            if self._ref[phys] > 1:
+                self._ref[phys] -= 1
                 continue
-            del self._ref[phys]
             published = self._hash_of.pop(phys, None)
             if published is not None and self._index.get(published) == phys:
                 del self._index[published]
-            self._free.append(phys)
+            self._reclaim(phys)
             reclaimed += 1
         return reclaimed
 

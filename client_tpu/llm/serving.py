@@ -402,8 +402,31 @@ class LlmEngineModel(Model):
         prefill, decode, decode_multi = self._build_device_fns(
             params, config, engine_config, Kernels(name, attn), donate,
         )
+        # a layer's pools (K and V, or the one pool of a model whose
+        # values lie inside its key rows), a cache group, as they are
+        # stored (a tp shard holds 1/tp of a page's rows), read off the
+        # shapes before a pool exists: from them the paged kernel's tile
+        # in pages, which sizes the allocators' runs and the window
+        # groups' rings and lets the engine count the kernel's tile
+        # stops, and the bytes a cached token takes
+        shapes = jax.eval_shape(
+            lambda: model.init_pages(
+                config, [2] * n_groups, engine_config.block_size)
+        )
+        group_pools = [
+            jax.tree_util.tree_leaves(shapes[group.layers[0]])
+            for group in engine_config.cache_groups
+        ]
+        tile_pages = [
+            paged_attention.pages_per_tile(
+                math.prod(pools[0].shape[1:-1]) // self.tp, 1,
+                max(pool.shape[-1] for pool in pools), pools[0].dtype,
+                len(pools),
+            )
+            for pools in group_pools
+        ]
         pages = model.init_pages(
-            config, engine_config.group_num_blocks(),
+            config, engine_config.group_num_blocks(tile_pages),
             engine_config.block_size,
         )
         if plan is not None:
@@ -495,23 +518,6 @@ class LlmEngineModel(Model):
         # lifecycle layer before the swap)
         if self.engine is not None:
             self.engine.close()
-        # a layer's pools (K and V, or the one pool of a model whose
-        # values lie inside its key rows), a cache group, as they are
-        # stored (a tp shard holds 1/tp of a page's rows): from them the
-        # paged kernel's tile in pages, which the engine needs to count
-        # the kernel's tile stops, and the bytes a cached token takes
-        group_pools = [
-            jax.tree_util.tree_leaves(pages[group.layers[0]])
-            for group in engine_config.cache_groups
-        ]
-        tile_pages = [
-            paged_attention.pages_per_tile(
-                math.prod(pools[0].shape[1:-1]) // self.tp, 1,
-                max(pool.shape[-1] for pool in pools), pools[0].dtype,
-                len(pools),
-            )
-            for pools in group_pools
-        ]
         if model.kv_row_bytes is not None:
             kv_row_bytes = model.kv_row_bytes(config)
         else:  # counted as stored

@@ -424,6 +424,15 @@ def test_sizes_that_just_hold_the_windows_are_served():
         cache_groups=tuple(afmoe.cache_groups(afmoe.AfmoeConfig())))
     assert cell.group_num_blocks() == [20481, 1 + 64 * 129]
     assert cell.max_blocks_per_seq == 512
+    # told the kernel's tiles (16 pages in both groups' pools) the ring
+    # is 144 blocks, nine whole tiles, and the checks still reckon with
+    # the window's own 128
+    assert cell.group_runs((16, 16)) == [16, 16]
+    assert cell.group_num_blocks((16, 16)) == [20481, 1 + 64 * 144]
+    tight = EngineConfig(
+        block_size=16, num_blocks=1 + 64 * 128, max_active=64,
+        max_seq_len=8192, cache_groups=cell.cache_groups)
+    assert tight.group_num_blocks((16, 16)) == [8193, 9217]
 
 
 @pytest.mark.parametrize("features,engine,part", [

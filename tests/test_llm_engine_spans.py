@@ -318,9 +318,11 @@ def test_attn_tile_counters_follow_the_tables_the_device_saw(speculative):
     """``attn_tiles_walked`` is every tile stop the paged kernel makes
     over the tables of a decode or verify step at the tile size the
     engine was told (2 pages of 4 slots here), ``attn_tiles_whole``
-    those whose live columns hold consecutive pool blocks: three lanes
-    that grow a block at a time in turn fragment the pool, so both kinds
-    occur."""
+    those whose live columns hold consecutive pool blocks. Three lanes
+    grow a block at a time in turn, which fragmented the pool while the
+    allocator handed out single blocks; told the tile, it hands out runs
+    of 2, and every stop is whole. A fragmented table still reads
+    fragmented, through the same rule."""
     engine = _stub_engine(
         _TickingClock(), speculative=speculative, tile_pages=(2,))
     expected = [0, 0]
@@ -348,7 +350,12 @@ def test_attn_tile_counters_follow_the_tables_the_device_saw(speculative):
     out = _run_stub(engine, [[1, 2, 1, 2, 1, 2], [3, 3, 3, 3], [5]], 24)
     assert [len(tokens) for tokens in out] == [24, 24, 24]
     stats = engine.stats()
-    assert 0 < stats["attn_tiles_whole"] < stats["attn_tiles_walked"]
+    assert 0 < stats["attn_tiles_whole"] == stats["attn_tiles_walked"]
+    assert engine.allocator.run == 2
+    engine._book_tiles(  # lane 0's second tile, lane 1's first: not whole
+        np.array([[1, 2, 4, 3], [6, 5, 0, 0]]), np.array([[15], [6]]))
+    stats = engine.stats()
+    assert stats["attn_tiles_walked"] - stats["attn_tiles_whole"] == 2
     # an engine that was told no tile size counts none
     silent = _stub_engine(_TickingClock(), speculative=speculative)
     _run_stub(silent, [[1, 2, 3]], 4)
@@ -363,6 +370,7 @@ def test_attn_tile_counters_sum_a_window_and_a_full_group():
     group is walked from its own first visible slot at its own tile
     size, against its own pool's size."""
     from client_tpu.models.engine_model import FULL, WINDOW, CacheGroup
+    from client_tpu.models.paged_attention import count_tiles, visible_slots
 
     groups = (CacheGroup(FULL, (0,)), CacheGroup(WINDOW, (1,), window=10))
     engine = _stub_engine(
@@ -391,6 +399,160 @@ def test_attn_tile_counters_sum_a_window_and_a_full_group():
         for lane, (first, at) in enumerate(zip(firsts, (30, 17, 5)))
     ]
     assert [sum(column) for column in zip(*by_hand)] == [12, 9]
+    # the same three lanes in blocks the engine's own allocator hands
+    # out, a block at a time in turn: runs of 2 in the full group (the
+    # window group's tile is its whole ring, so its run is 1), and the
+    # full group's eight stops are whole where four of the hand-made
+    # ones were not
+    assert engine.allocator.run == 2 and engine._windows[0][2].run == 1
+    held = [engine.allocator.allocate(lane, 1) for lane in range(3)]
+    for column in range(1, 8):
+        for lane, most in enumerate((8, 5, 2)):
+            if column < most:
+                held[lane].append(engine.allocator.extend(lane))
+    made = np.zeros((3, 8), dtype=np.int32)
+    for lane, blocks in enumerate(held):
+        made[lane, : len(blocks)] = blocks
+    walked, whole = count_tiles(
+        made, *visible_slots(positions, None), 2, 4, 33)
+    assert walked == whole == 4 + 3 + 1
+    engine.close()
+
+
+def _watch_dispatch(engine, decoded):
+    """Call ``decoded(engine, batch, before)`` after every dispatched
+    decode step, ``before`` the tile counters as they stood before it."""
+    dispatch = engine._dispatch
+
+    async def watched(batch, flight=None):
+        before = (engine.attn_tiles_walked, engine.attn_tiles_whole)
+        out = await dispatch(batch, flight)
+        decoded(engine, batch, before)
+        return out
+
+    engine._dispatch = watched
+
+
+def _generations(tile_pages, decoded, **overrides):
+    """A stub engine of a full and a window group (window of 18 slots
+    over blocks of 4: 6 blocks at once), 8 lanes growing in turn through
+    three generations of requests, each ending at its own length, so
+    that completions and re-admissions interleave: the steady state of
+    a closed loop. ``decoded(engine, batch, before)`` sees every
+    dispatched step with the tile counters as they stood before it."""
+    from client_tpu.models.engine_model import FULL, WINDOW, CacheGroup
+
+    sizes = dict(
+        cache_groups=(CacheGroup(FULL, (0,)),
+                      CacheGroup(WINDOW, (1,), window=18)),
+        prefix_sharing=False, num_blocks=129, max_active=8, max_queue=32,
+        max_seq_len=64)
+    sizes.update(overrides)
+    engine = _stub_engine(_TickingClock(), tile_pages=tile_pages, **sizes)
+    _watch_dispatch(engine, decoded)
+    rng = np.random.default_rng(35)
+
+    async def run():
+        seqs = [
+            engine.submit(rng.integers(1, VOCAB, size=n).tolist(),
+                          max_tokens=int(rng.integers(12, 64 - n)))
+            for n in rng.integers(2, 22, size=24)
+        ]
+        return await asyncio.gather(*[_collect(s) for s in seqs])
+
+    out = asyncio.run(run())
+    stats = engine.stats()
+    assert stats["completed"] == 24 and stats["preemptions"] == 0
+    engine.close()
+    return engine, stats, [len(tokens) for tokens in out]
+
+
+def test_whole_tiles_are_the_allocators_promise_in_the_steady_state():
+    """Told the kernel's tile (4 pages a group, a table of 16 columns),
+    the allocators hand out runs of 4 and a ring of 8 (6 rounded up), and
+    EVERY tile stop of every step is whole, in the full group and in the
+    window group, with the pool as interleaved as three generations of
+    8 lanes leave it. Told nothing, the engine allocates block for block
+    as it did and the same traffic reads as fragmented as it did."""
+    from client_tpu.models.paged_attention import count_tiles, visible_slots
+
+    steps = []
+
+    def all_whole(engine, batch, before):
+        walked = engine.attn_tiles_walked - before[0]
+        assert walked == engine.attn_tiles_whole - before[1] > 0
+        steps.append(len(batch))
+        for seq in batch:
+            (ring,) = seq.rings
+            assert len(ring) == 8 and len(seq.blocks) <= 16
+        # what the open runs hold beyond their newest block: under a run
+        reserved = engine.stats()["kv_blocks_reserved_by_group"]
+        assert reserved[1] == 0 and 0 <= reserved[0] <= 3 * len(
+            engine._running)
+
+    engine, stats, lengths = _generations((4, 4), all_whole)
+    assert engine.allocator.run == 4 and engine._group_blocks == [129, 65]
+    assert max(steps) == 8 and len(steps) > 100
+    assert stats["attn_tiles_walked"] == stats["attn_tiles_whole"] > 1000
+    assert stats["kv_blocks_in_use_by_group"] == [0, 0]
+    assert stats["kv_blocks_reserved_by_group"] == [0, 0]
+
+    # the same traffic on an engine told nothing: runs of 1, a ring of 6,
+    # no tile booked, and the tables it builds read by the same rule
+    by_hand = [0, 0]
+
+    def fragmented(engine, batch, before):
+        positions = np.array(
+            [[seq.position + (engine._flight is not None
+                              and seq.seq_id in engine._flight.lane_of)]
+             for seq in batch])
+        full = np.array([seq.page_table[:16] for seq in batch])
+        tables = engine._group_tables(full, batch, positions[:, 0])
+        for table, window, pool in zip(tables, (None, 18), (129, 49)):
+            walked, whole = count_tiles(
+                table, *visible_slots(positions, window), 4, 4, pool)
+            by_hand[0] += walked
+            by_hand[1] += whole
+
+    plain, silent, same = _generations((), fragmented)
+    assert plain.allocator.run == 1 and plain._group_blocks == [129, 49]
+    assert same == lengths  # the streams do not depend on the allocator
+    assert silent["attn_tiles_walked"] == silent["attn_tiles_whole"] == 0
+    assert silent["kv_blocks_reserved_by_group"] == [0, 0]
+    # block for block the allocation before runs: these are the counts
+    # the commit before runs existed gives for the same traffic (45%)
+    assert by_hand == [2970, 1340]
+
+
+def test_a_shared_prefixs_mixed_tile_is_the_one_stop_not_whole():
+    """Prefix sharing over runs: a sequence admitted against 5 matched
+    blocks (tiles of 4) references them where they lie and puts its own
+    columns in runs of its own, so the tile the match ends in is its one
+    stop a step that is not whole; the publisher's are all whole."""
+    mixed_steps = []
+
+    def one_mixed_tile(engine, batch, before):
+        walked = engine.attn_tiles_walked - before[0]
+        whole = engine.attn_tiles_whole - before[1]
+        mixed = sum(1 for seq in batch if seq.shared_blocks % 4)
+        assert walked - whole == mixed
+        mixed_steps.append(mixed)
+
+    from client_tpu.models.engine_model import FULL, CacheGroup
+
+    engine = _stub_engine(
+        _TickingClock(), tile_pages=(4,), num_blocks=129, max_active=8,
+        max_queue=32, max_seq_len=64,
+        cache_groups=(CacheGroup(FULL, (0,)),))
+    _watch_dispatch(engine, one_mixed_tile)
+    prefix = list(range(1, 23))  # 5 full blocks of 4 and two tokens more
+    out = _run_stub(
+        engine, [prefix + [7 + lane] * lane for lane in range(6)], 30)
+    assert [len(tokens) for tokens in out] == [30] * 6
+    stats = engine.stats()
+    assert stats["prefix_cache_hits"] == 5 * 5  # five sharers of 5 blocks
+    assert max(mixed_steps) == 5 and stats["kv_blocks_in_use"] == 0
+    assert stats["kv_blocks_reserved_by_group"] == [0]
     engine.close()
 
 

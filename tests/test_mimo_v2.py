@@ -473,6 +473,10 @@ def test_window_tables_hold_the_ring_at_the_last_columns_only():
 
     assert kv_cache.window_ring_blocks(128, 16) == 9
     assert kv_cache.window_ring_blocks(24, 8) == 4
+    # a ring is a whole number of the allocator's runs: tiles of 4 pages
+    # in the cell's window pools, so 12 blocks and none wraps in a tile
+    assert kv_cache.window_ring_blocks(128, 16, 4) == 12
+    assert kv_cache.window_ring_blocks(24, 8, 4) == 4
     tables = kv_cache.window_tables([[5, 6, 7], [8, 9, 10]], [4, 1], 8)
     # lane 0 at block 4: columns 2..4 hold ring entries 2, 0, 1
     assert tables[0].tolist() == [0, 0, 7, 5, 6, 0, 0, 0]
@@ -495,3 +499,6 @@ def test_llama_declares_one_full_group_and_serves_as_before():
         speculation=True, prefix_sharing=True, tp=4) is None
     sizes = EngineConfig(num_blocks=65, cache_groups=(group,))
     assert sizes.group_num_blocks() == [65]
+    # the full pool is what was set, whatever the kernel's tile
+    assert sizes.group_num_blocks((8,)) == [65]
+    assert sizes.group_runs((8,)) == [8] and sizes.group_runs() == [1]

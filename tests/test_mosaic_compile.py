@@ -44,10 +44,11 @@ CASES = {
 
 MASKING_CASES = {
     # batch, heads, kv heads, table columns, pool blocks, window, sink:
-    # mimo_v2_flash.reason's two cache groups (K rows 192, V rows 128)
+    # mimo_v2_flash.reason's two cache groups (K rows 192, V rows 128;
+    # the window pool 64 rings of 12 blocks: 9 in whole tiles of 4)
     "mimo-full": (64, 64, 4, 128, 8193, None, False),
-    "mimo-window": (64, 64, 8, 128, 577, 128, True),
-    "mimo-window-one-lane": (1, 64, 8, 8, 577, 128, True),
+    "mimo-window": (64, 64, 8, 128, 769, 128, True),
+    "mimo-window-one-lane": (1, 64, 8, 8, 769, 128, True),
 }
 
 
@@ -208,7 +209,8 @@ TRINITY_ATTENTION = {
     # pool blocks, window: trinity_mini.reason8k's two cache groups, 64
     # lanes, 32 query heads over 4 KV heads of 128, a table of 512
     "full": (20481, None),
-    "window": (1 + 64 * 129, 2048),
+    # a ring of 144 blocks a lane: the window's 129 in whole tiles of 16
+    "window": (1 + 64 * 144, 2048),
 }
 
 
@@ -216,7 +218,7 @@ TRINITY_ATTENTION = {
 def test_mosaic_compiles_the_paged_kernel_at_trinitys_shapes(one_chip, group):
     """KV 4 / D 128 in flat pools: tiles of 16 pages; a page table of
     512 columns a lane (128 KB of scalar prefetch, four times MiMo's);
-    the window group's walk over a ring of 129 blocks."""
+    the window group's walk over a ring of 144 blocks (nine tiles)."""
     import jax
     import jax.numpy as jnp
 
@@ -281,11 +283,11 @@ def test_the_compiled_kernel_holds_both_ways_of_fetching_a_tile(one_chip):
 def test_trinitys_longest_prefill_and_decode_fit_the_chip(one_chip):
     """`afmoe`'s 8,192-token prefill and its 64-lane decode step compiled
     whole for the described v5e at the cell's sizes (16 layers, 16 held
-    experts, 25,024 rows of vocabulary, pools of 20,481 and 8,257
-    blocks). The bound: 10.2 GB of arguments (4.23 of weights, 5.93 of
-    cache) and under 1.25 GB of scratch, 11.5 GB of the chip's 16; read
-    here at 10,162,077,184 and 1,131,382,784 B (the decode step:
-    43,803,648 B of scratch)."""
+    experts, 25,024 rows of vocabulary, pools of 20,481 and 9,217
+    blocks: 64 rings of 144). The bound: 10.6 GB of arguments (4.23 of
+    weights, 6.31 of cache) and under 1.25 GB of scratch, 11.9 GB of the
+    chip's 16; read here at 10,539,564,544 and 1,131,382,784 B (the
+    decode step: 43,803,648 B of scratch)."""
     import jax
     import jax.numpy as jnp
 
@@ -304,7 +306,7 @@ def test_trinitys_longest_prefill_and_decode_fit_the_chip(one_chip):
     params = shaped(jax.eval_shape(
         lambda: afmoe.init_params(jax.random.PRNGKey(0), config)))
     pages = shaped(jax.eval_shape(
-        lambda: afmoe.init_pages(config, [20481, 1 + 64 * 129], BLOCK)))
+        lambda: afmoe.init_pages(config, [20481, 1 + 64 * 144], BLOCK)))
     ints = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
         shape, jnp.int32, sharding=one_chip)
     prefill = jax.jit(
@@ -313,7 +315,7 @@ def test_trinitys_longest_prefill_and_decode_fit_the_chip(one_chip):
         donate_argnums=(3,)).lower(
         params, ints(1, 8192), ints(2, 512), pages, ints()).compile()
     memory = prefill.memory_analysis()
-    assert memory.argument_size_in_bytes < 10.2e9
+    assert memory.argument_size_in_bytes < 10.6e9
     assert memory.temp_size_in_bytes < 1.25e9
     decode = jax.jit(
         lambda p, t, at, tables, pages: afmoe.decode_step_paged(
