@@ -44,6 +44,13 @@ paged-attention model functions (``models/llama.py``):
   annotations on a ``jax.profiler`` trace's host line (which the
   trace's device lines lead by a millisecond or two: see
   :class:`~client_tpu.observability.profiling.LapSpans`).
+- **a record a turn**: one iteration of the step loop is a turn, and the
+  laps book each to the steady turns or, at 250 ms or more, to the
+  stalls, each stall with a cause (the profiler, a compile, the
+  collector, or a sampled stack): ``stats()["steady_phase_ns"]`` over
+  ``["steady_steps"]`` is the loop's pace without its stalls, and the
+  last stalls are ``stall_log()`` (``/v2/debug/state``) and
+  ``llm_engine_stall`` log records.
 
 Single-owner concurrency: every public method runs on the serving event
 loop (the decoupled path executes models there); device calls hop to the
@@ -66,7 +73,7 @@ from client_tpu.llm.kv_cache import (
     window_ring_blocks,
     window_tables,
 )
-from client_tpu.observability.profiling import LapSpans
+from client_tpu.observability.profiling import WATCH, LapSpans
 from client_tpu.scheduling import (
     PriorityQueue,
     QueueFullError,
@@ -774,7 +781,8 @@ class LlmEngine:
         # divided by: prefill calls, first admissions with the time
         # their sequences spent queued
         self._laps = LapSpans(
-            {phase: f"engine.{phase}" for phase in PHASES}, clock_ns=clock_ns
+            {phase: f"engine.{phase}" for phase in PHASES}, clock_ns=clock_ns,
+            on_stall=self._log_stall,
         )
         self.prefills = 0
         self.admitted = 0
@@ -1196,12 +1204,30 @@ class LlmEngine:
                 self.spec_accepted / max(1, self.spec_proposed)
             ),
             # lap spans: ns per phase of the step loop (PHASES), which
-            # add up to its wall time not parked; all monotone
+            # add up to its wall time not parked; all monotone. Beside
+            # them the record of its turns: the same laps split into
+            # steady turns and stalls, the stalls by cause, and the
+            # process's collector, compile and profiler sums
             "phase_ns": dict(self._laps.ns),
+            **self._laps.record(),
             "prefills": self.prefills,
             "admitted": self.admitted,
             "queue_wait_ns": self.queue_wait_ns,
         }
+
+    def stall_log(self) -> List[Dict[str, Any]]:
+        """The step loop's last stalls, oldest first (see ``LapSpans``):
+        ``/v2/debug/state`` serves them as ``llm.<model>.stall_log``."""
+        return list(self._laps.stall_log)
+
+    def _log_stall(self, entry: Dict[str, Any]) -> None:
+        if self.logger is not None:
+            self.logger.warning(
+                "llm_engine_stall",
+                model=self.model_name,
+                rate_key=("llm_engine_stall", self.model_name),
+                **entry,
+            )
 
     # -- cache groups ---------------------------------------------------------
 
@@ -1292,6 +1318,7 @@ class LlmEngine:
 
     async def _run(self) -> None:
         laps = self._laps
+        WATCH.register(laps)
         try:
             while not self._closed:
                 if (
@@ -1299,11 +1326,13 @@ class LlmEngine:
                     and not len(self._waiting)
                     and self._flight is None
                 ):
-                    laps.park()
+                    laps.park(self.steps)
                     self._wake.clear()
                     await self._wake.wait()
                     continue
+                # one iteration is one turn of the laps' record
                 laps.enter("schedule")
+                laps.turn(self.steps)
                 self._prune()
                 await self._admit()
                 if self._running or self._flight is not None:
@@ -1326,7 +1355,8 @@ class LlmEngine:
         except Exception as e:  # noqa: BLE001 - engine must not die silently
             self._quarantine(e)
         finally:
-            laps.park()
+            laps.park(self.steps)
+            WATCH.unregister(laps)
 
     def _prune(self) -> None:
         """Drop cancelled sequences and expire waiting deadlines."""

@@ -30,7 +30,9 @@ Profiling (:mod:`client_tpu.observability.profiling`)
     collapsed-stack / speedscope exporters (``GET /v2/debug/profile``).
     The perf harness's ``--profile-server`` turns both into the
     "Wire-gap attribution" report. :class:`LapSpans` is the always-on
-    wall-clock counterpart for one loop (the LLM engine's step phases).
+    wall-clock counterpart for one loop (the LLM engine's step phases),
+    with a record of its turns: steady or a stall, a stall with a cause
+    (:class:`ProcessEvents`, :class:`StallWatch`).
 
 Logging (:mod:`client_tpu.observability.logging`)
     The real backend of the ``/v2/logging`` extension: a dependency-free
@@ -91,9 +93,12 @@ from client_tpu.observability.window import (
 )
 from client_tpu.observability.profiling import (
     STAGES,
+    STALL_NS,
     LapSpans,
+    ProcessEvents,
     ProfileResult,
     StageCpuAccounting,
+    StallWatch,
     WallProfiler,
     maybe_jax_trace,
     stage_scope,
@@ -121,6 +126,7 @@ __all__ = [
     "DEFAULT_LOG_SETTINGS",
     "NOOP_TRACE",
     "STAGES",
+    "STALL_NS",
     "TRACEPARENT_HEADER",
     "ClientMetrics",
     "ClientTrace",
@@ -134,12 +140,14 @@ __all__ = [
     "LapSpans",
     "LiveTelemetry",
     "MetricsRegistry",
+    "ProcessEvents",
     "ProfileResult",
     "ReplicaStats",
     "ServerTrace",
     "SloObjective",
     "Span",
     "StageCpuAccounting",
+    "StallWatch",
     "StructuredLogger",
     "TraceContext",
     "TraceManager",

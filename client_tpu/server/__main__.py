@@ -76,9 +76,12 @@ def main(argv=None) -> int:
         jax.config.update("jax_platforms", args.platform)
 
     from client_tpu.compile_cache import enable_compile_cache
+    from client_tpu.observability.profiling import PROCESS
 
-    # before the repository: model warmups are the first compilations
+    # before the repository: model warmups are the first compilations,
+    # and the process's record counts every one (setup.compile_s)
     enable_compile_cache()
+    PROCESS.listen()
 
     from client_tpu.server.core import ServerCore
     from client_tpu.server.model_repository import build_repository

@@ -1436,6 +1436,8 @@ class ServerCore:
         subsystem; cross-subsystem counts may be one request apart —
         taking one global lock across the hot path would cost more than
         the skew is worth)."""
+        from client_tpu.observability.profiling import PROCESS
+
         queues: Dict[str, Any] = {}
         for name, batcher in list(self._batchers.items()):
             queues[name] = {
@@ -1460,6 +1462,9 @@ class ServerCore:
             if callable(stats):
                 try:
                     doc = stats()
+                    stall_log = getattr(engine, "stall_log", None)
+                    if callable(stall_log):
+                        doc["stall_log"] = stall_log()
                     controller = getattr(model, "_recovery", None)
                     if controller is not None:
                         doc["recovery"] = controller.describe()
@@ -1491,6 +1496,9 @@ class ServerCore:
                 "completed": self.trace_manager.completed_count,
             },
             "profiling": self.profiling.config(),
+            # the last jax.profiler sessions: when each start and stop
+            # began and ended, on the clock of the laps and the stall log
+            "profiler_sessions": list(PROCESS.sessions),
             "flight_recorder": self.flight_recorder.stats(),
             # compact live-telemetry block: shortest-window rolling p99 +
             # SLO burn per model (the full document is GET /v2/debug/slo)

@@ -31,6 +31,7 @@ from typing import Any, Dict, Optional
 
 import numpy as np
 
+from client_tpu.observability.profiling import WATCH
 from client_tpu.server import _grpc_codec as codec
 from client_tpu.server import shm_ring as ring_codec
 from client_tpu.server.core import (
@@ -134,6 +135,8 @@ class NativeGrpcFrontend:
         self._pump = threading.Thread(
             target=self._pump_loop, name="ctpu-grpc-pump", daemon=True
         )
+        # it waits for requests inside the native call: no stall's stack
+        WATCH.idle_in(self._pump_loop)
         self._pump.start()
 
     def stop(self) -> None:

@@ -6,6 +6,9 @@
   ``phase_ns`` IS the clock's time from the loop's first boundary to its
   last, over plain decode, prefills with preemption, and speculation;
   the counters beside them count what was submitted and never go back;
+  the record of the turns adds up to the phases, and a second the
+  device double holds in one ``wait`` is a stall in the counters, in
+  ``/v2/debug/state`` and in the log;
 - the float32 tiny llama: ``/v2/debug/state`` serves the counters, a
   ``jax.profiler.trace`` holds ``engine.*`` events on one host line that
   agree with the counters, streams are bit-identical with and without a
@@ -17,6 +20,7 @@ import collections
 import glob
 import json
 import os
+import threading
 import urllib.request
 
 import numpy as np
@@ -137,7 +141,8 @@ def _stub_engine(clock, speculative=False, tile_pages=(), **overrides):
         attn_tile_pages=tile_pages,
     )
     # the laps read the engine's clock through a witness of their own
-    engine._laps = LapSpans(engine._laps._names, clock_ns=_Boundaries(clock))
+    engine._laps = LapSpans(engine._laps._names, clock_ns=_Boundaries(clock),
+                            on_stall=engine._log_stall)
     return engine
 
 
@@ -173,6 +178,14 @@ def _tiles(engine, stats=None):
     witness = engine._laps._clock_ns
     assert set(stats["phase_ns"]) == set(PHASES)
     assert sum(stats["phase_ns"].values()) == witness.last - witness.first > 0
+    # the record of the turns: closed turns only, so it trails the
+    # phases by the open turn and meets them where the loop is parked
+    closed = {p: stats["steady_phase_ns"][p] + stats["stall_phase_ns"][p]
+              for p in PHASES}
+    assert all(closed[p] <= stats["phase_ns"][p] for p in PHASES)
+    assert sum(closed.values()) == stats["loop_ns"]
+    if engine._laps._phase is None:
+        assert closed == stats["phase_ns"]
     return stats["phase_ns"]
 
 
@@ -656,10 +669,129 @@ def test_debug_state_serves_the_counters():
             f"http://127.0.0.1:{server.http_port}/v2/debug/state"
         ) as response:
             block = json.loads(response.read().decode())["llm"]["stub_llm"]
-    assert block == json.loads(json.dumps(model.engine.stats()))
+    assert block.pop("stall_log") == []
+    stats = json.loads(json.dumps(model.engine.stats()))
+    # the process's sums move on between two looks; the loop's do not
+    for name in ("gc_ns", "gc_collections", "compile"):
+        assert type(block.pop(name)) is type(stats.pop(name)), name
+    assert block == stats
     _tiles(model.engine, block)
     for name in COUNTERS:
         assert isinstance(block[name], int) and block[name] > 0, name
+
+
+class _Held:
+    """A device result whose ``block_until_ready`` holds the loop."""
+
+    def __init__(self, value, hold):
+        self.value, self.hold = value, hold
+
+    def block_until_ready(self):
+        self.hold()
+
+    def __array__(self, dtype=None, copy=None):
+        return self.value
+
+
+def test_a_second_held_in_one_wait_is_a_stall_served_and_logged():
+    """The device double holds step 5's result for a second of the fake
+    clock: one turn is a stall of cause ``other`` with the second in
+    ``wait``, its step is not a steady step, the entry is in
+    ``/v2/debug/state`` and in one log record; an engine on a fake clock
+    never starts the watch."""
+    from client_tpu.observability import StructuredLogger
+    from client_tpu.server.core import ServerCore
+    from client_tpu.server.model_repository import Model, ModelRepository
+    from client_tpu.testing import InProcessServer
+
+    clock = _TickingClock()
+    engine = _stub_engine(clock)
+    records = []
+    engine.logger = StructuredLogger(sink=records.append)
+    decode, calls = engine._decode, []
+
+    def held_once(*args):
+        ids, *rest = decode(*args)
+        calls.append(engine._laps in profiling.WATCH._loops)
+        if len(calls) == 5:
+            ids = _Held(ids, lambda: setattr(clock, "now", clock.now + S))
+        return (ids, *rest)
+
+    S = 1_000_000_000
+    engine._decode = held_once
+    out = _run_stub(engine, [[1, 2, 3], [4, 5]], 12)
+    assert [len(tokens) for tokens in out] == [12, 12]
+    assert len(calls) > 5 and not any(calls)  # never watched
+    stats = engine.stats()
+    _tiles(engine, stats)
+    assert stats["stalls"] == {"profiler": 0, "compile": 0, "gc": 0,
+                               "other": 1}
+    assert S <= stats["stall_phase_ns"]["wait"] < S + 10_000
+    assert S <= stats["stall_ns"]["other"] < S + 100_000
+    assert stats["steady_steps"] == stats["steps"] - 1 > 5
+    assert "turns" not in stats and "profiler_sessions" not in stats
+    (entry,) = engine.stall_log()
+    assert entry["cause"] == "other" and entry["phase"] == "wait"
+    assert entry["steps"] == 1 and entry["wall_ns"] == stats["stall_ns"]["other"]
+    assert entry["phase_ns"]["wait"] == stats["stall_phase_ns"]["wait"]
+    assert entry["watch_late_ns"] is None and entry["stacks"] == ""
+    assert entry["watch_cpu_ns"] is None
+    (record,) = [r for r in records if r["event"] == "llm_engine_stall"]
+    assert record["model"] == "stub" and record["severity"] == "WARNING"
+    assert {k: record[k] for k in entry} == entry
+
+    class StubLlm(Model):
+        name = "stub_llm"
+        decoupled = True
+
+        def shutdown(self):
+            engine.close()
+
+    model = StubLlm()
+    model.engine = engine
+    repository = ModelRepository()
+    core = ServerCore(repository)
+    repository.add_model(model)
+    with InProcessServer(core=core, builtin_models=False) as server:
+        with urllib.request.urlopen(
+            f"http://127.0.0.1:{server.http_port}/v2/debug/state"
+        ) as response:
+            state = json.loads(response.read().decode())
+    assert state["llm"]["stub_llm"]["stall_log"] == [entry]
+    assert state["llm"]["stub_llm"]["stalls"]["other"] == 1
+    assert state["profiler_sessions"] == json.loads(
+        json.dumps(list(profiling.PROCESS.sessions)))
+
+
+def test_one_watch_thread_a_process_and_none_after_the_last_engine():
+    """Two engines on the process's own clock share one watch thread,
+    which ends once both loops have ended."""
+    import time
+
+    def watchers():
+        return [t for t in threading.enumerate() if t.name == "stall-watch"]
+
+    engines = [_stub_engine(time.monotonic_ns) for _ in range(2)]
+    for engine in engines:  # on the real clock, as a served engine is
+        engine._laps = LapSpans(engine._laps._names)
+    seen = []
+
+    async def run():
+        seqs = [e.submit([1, 2, 3], max_tokens=6) for e in engines]
+        first = asyncio.ensure_future(_collect(seqs[0]))
+        await _collect(seqs[1])
+        await first
+        seen.append((len(watchers()), len(profiling.WATCH._loops)))
+        for engine in engines:
+            engine.close()
+        await asyncio.sleep(0)
+
+    asyncio.run(run())
+    assert seen == [(1, 2)]
+    assert not profiling.WATCH._loops
+    for thread in watchers():
+        thread.join(timeout=10)
+    assert not watchers() and profiling.WATCH._thread is None
 
 
 # -- the real engine on the float32 tiny llama ---------------------------------

@@ -22,10 +22,17 @@ import pytest
 import client_tpu.grpc as grpcclient
 import client_tpu.http as httpclient
 from client_tpu.observability.metrics import histogram_totals, parse_exposition
+from client_tpu.observability import profiling
 from client_tpu.observability.profiling import (
+    CAUSES,
+    PROCESS,
     STAGES,
+    STALL_NS,
+    LapSpans,
+    ProcessEvents,
     ProfileResult,
     StageCpuAccounting,
+    StallWatch,
     WallProfiler,
     maybe_jax_trace,
     stage_scope,
@@ -411,6 +418,379 @@ def test_maybe_jax_trace_noop_paths(tmp_path):
         pass
     with maybe_jax_trace(str(tmp_path / "trace")):
         pass  # jax profiler capture (or a silent skip) must not raise
+
+
+# ---------------------------------------------------------------------------
+# a record a turn: steady turns and stalls, a stall's cause, the watch
+
+
+class _SetClock:
+    """A clock a test sets: ``clock.at`` is what the next read returns."""
+
+    def __init__(self, at=1_000):
+        self.at = at
+
+    def __call__(self):
+        return self.at
+
+
+def _laps(clock, process=None, **more):
+    return LapSpans({"a": "loop.a", "b": "loop.b", "c": "loop.c"},
+                    clock_ns=clock, process=process or ProcessEvents(clock),
+                    **more)
+
+
+def _one_turn(laps, clock, a_ns, b_ns, steps):
+    """A turn of ``a_ns`` in phase a and ``b_ns`` in phase b, closed by
+    the next turn's first boundary."""
+    laps.enter("a")
+    laps.turn(steps)
+    clock.at += a_ns
+    laps.enter("b")
+    clock.at += b_ns
+    laps.enter("c")  # the turn's last lap has no length
+    laps.enter("a")
+    laps.turn(steps)
+
+
+@pytest.mark.parametrize("wall_ns,stalled", [
+    (STALL_NS - 1, False), (STALL_NS, True), (STALL_NS + 1, True),
+    (1_000, False), (40 * STALL_NS, True),
+])
+def test_turn_classifies_at_stall_ns(wall_ns, stalled):
+    clock = _SetClock()
+    seen = []
+    laps = _laps(clock, on_stall=seen.append)
+    _one_turn(laps, clock, 400, 600, steps=0)  # a steady turn before it
+    laps.enter("a")
+    clock.at += wall_ns - 7
+    laps.enter("b")
+    clock.at += 7
+    laps.enter("a")
+    laps.turn(3)
+    record = laps.record()
+    assert record["loop_ns"] == 1_000 + wall_ns
+    assert record["stalls"]["other"] == int(stalled)
+    assert sum(record["stalls"].values()) == len(seen) == int(stalled)
+    assert len(laps.stall_log) == int(stalled)
+    if stalled:
+        assert record["stall_phase_ns"] == {"a": wall_ns - 7, "b": 7, "c": 0}
+        assert record["steady_phase_ns"] == {"a": 400, "b": 600, "c": 0}
+        assert record["stall_ns"]["other"] == wall_ns
+        assert record["steady_steps"] == 0  # a stall's steps are not steady
+        (entry,) = seen
+        assert entry is laps.stall_log[-1]
+        assert entry == {
+            "at_ns": clock.at, "wall_ns": wall_ns, "cause": "other",
+            "phase": "a", "phase_ns": {"a": wall_ns - 7, "b": 7}, "steps": 3,
+            "gc_ns": 0, "compile_ns": 0, "watch_late_ns": None,
+            "watch_cpu_ns": None, "stacks": ""}
+        json.dumps(entry)
+    else:
+        assert record["steady_phase_ns"] == {
+            "a": 400 + wall_ns - 7, "b": 607, "c": 0}
+        assert record["stall_phase_ns"] == {"a": 0, "b": 0, "c": 0}
+        assert record["steady_steps"] == 3
+    assert set(record["stalls"]) == set(record["stall_ns"]) == set(CAUSES)
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_steady_plus_stall_is_ns_after_any_sequence(seed):
+    rng = np.random.default_rng(seed)
+    clock = _SetClock()
+    laps = _laps(clock)
+    steps = parked = 0
+    last = {"loop_ns": 0, "steady_steps": 0}
+    turns = 0
+    for _ in range(600):
+        # mostly short laps, now and then one past a stall's length
+        clock.at += int(rng.choice([1, 50, 30_000, STALL_NS // 3, STALL_NS]))
+        move = rng.integers(0, 10)
+        if move < 6:
+            laps.enter("abc"[rng.integers(0, 3)])
+            continue
+        steps += int(rng.integers(0, 3))
+        if move < 9:
+            laps.turn(steps)
+        else:
+            laps.park(steps)
+            parked += 1
+            clock.at += int(rng.integers(0, 2 * STALL_NS))  # in no phase
+        # a turn boundary: the record adds up, phase for phase
+        record = laps.record()
+        for phase, total in laps.ns.items():
+            assert (record["steady_phase_ns"][phase]
+                    + record["stall_phase_ns"][phase]) == total, phase
+        assert record["loop_ns"] == sum(laps.ns.values())
+        assert sum(record["stall_ns"].values()) == sum(
+            record["stall_phase_ns"].values())
+        turns += 1
+        assert sum(record["stalls"].values()) <= turns
+        assert record["steady_steps"] <= steps
+        for name, before in last.items():
+            assert record[name] >= before, name
+            last[name] = record[name]
+    assert parked and sum(laps.stalls.values()) and laps.steady_steps
+    assert len(laps.stall_log) <= 16
+
+
+@pytest.mark.parametrize("profiler,compile_ns,gc_ns,cause", [
+    (True, STALL_NS, STALL_NS, "profiler"),
+    (True, 0, 0, "profiler"),
+    (False, STALL_NS // 2, STALL_NS, "compile"),
+    (False, STALL_NS // 2 - 1, STALL_NS // 2, "gc"),
+    (False, STALL_NS // 2 - 1, STALL_NS // 2 - 1, "other"),
+    (False, 0, 0, "other"),
+])
+def test_a_stalls_cause_is_the_first_that_fits(profiler, compile_ns, gc_ns,
+                                               cause):
+    clock = _SetClock()
+    process = ProcessEvents(clock)
+    laps = _laps(clock, process=process)
+    _one_turn(laps, clock, 5, 5, steps=1)
+    # what was booked before the turn opened is not the turn's
+    process.on_duration(profiling._BACKEND_COMPILE, 3.0)
+    process.on_gc("start", {"generation": 2})
+    clock.at += STALL_NS - 20
+    process.on_gc("stop", {"generation": 2})
+    laps.enter("b")
+    laps.turn(1)
+    assert not any(laps.stalls.values())
+    opened = clock.at
+    clock.at += 1
+    if profiler:
+        session = process.open_session()
+        with process.profiler_hold(session, 0):
+            clock.at += 10
+    process.on_duration(profiling._BACKEND_COMPILE, compile_ns // 2 / 1e9)
+    process.on_duration("/jax/core/compile/jaxpr_trace_duration",
+                        (compile_ns - compile_ns // 2) / 1e9)
+    process.on_duration("/jax/core/something_else_duration", 9.0)
+    if gc_ns:
+        process.on_gc("start", {"generation": 1})
+        clock.at += gc_ns
+        process.on_gc("stop", {"generation": 1})
+    laps.enter("a")
+    clock.at = opened + STALL_NS  # the turn's wall, exactly
+    laps.enter("b")
+    laps.turn(2)
+    assert laps.stalls == dict(dict.fromkeys(CAUSES, 0), **{cause: 1})
+    entry = laps.stall_log[-1]
+    assert entry["cause"] == cause and entry["wall_ns"] == STALL_NS
+    assert entry["compile_ns"] == pytest.approx(compile_ns, abs=2)
+    assert entry["gc_ns"] == gc_ns
+    assert len(process.sessions) == int(profiler)
+
+
+def test_the_profilers_stop_still_going_on_is_a_cause():
+    clock = _SetClock()
+    process = ProcessEvents(clock)
+    laps = _laps(clock, process=process)
+    session = process.open_session()
+    with process.profiler_hold(session, 0):
+        clock.at += 10
+    clock.at += 10 * STALL_NS
+    _one_turn(laps, clock, STALL_NS, 5, steps=0)  # between start and stop
+    assert laps.stalls["other"] == 1
+    session["monotonic_ns"][2] = clock.at  # the stop has begun and not ended
+    _one_turn(laps, clock, STALL_NS, 5, steps=0)
+    assert laps.stalls["profiler"] == 1
+    assert not process.in_profiler(0, session["monotonic_ns"][0] - 1)
+
+
+def _frame(*labels):
+    """A chain of fake frames, outermost first; returns the innermost."""
+    frame = None
+    for label in labels:
+        filename, name = label.split(":")
+        code = type("code", (), {"co_filename": f"/x/{filename}",
+                                 "co_name": name})
+        frame = type("frame", (), {"f_code": code, "f_back": frame})
+    return frame
+
+
+def test_the_watch_puts_the_stack_and_its_lateness_in_the_log():
+    clock = _SetClock()
+    frames = {
+        1: _frame("engine.py:_run", "engine.py:_consume", "array.py:_value"),
+        2: _frame("thread.py:_worker"),  # idle: counted, not shown
+        3: _frame("pool.py:work", "native.py:held"),
+        4: _frame("threading.py:_bootstrap", "threading.py:wait"),
+    }
+    spawned = []
+    watch = StallWatch(clock_ns=clock, frames=lambda: frames,
+                       spawn=spawned.append)
+    laps = _laps(clock)
+    other = _laps(_SetClock())  # on another clock: not watched
+    watch.register(laps)
+    watch.register(other)
+    assert list(watch._loops) == [laps] and len(spawned) == 1
+    watch._loops[laps] = 1  # the loop's thread, as if it had registered
+    laps.enter("a")
+    laps.turn(0)
+    clock.at += STALL_NS - 1
+    watch.check(clock.at, late_ns=11)
+    assert laps._sampled is None  # not yet a stall's length in one phase
+    clock.at += 1
+    watch.check(clock.at, late_ns=22, cpu_ns=5)
+    first = laps._sampled
+    clock.at += STALL_NS
+    watch.check(clock.at, late_ns=33)
+    assert laps._sampled is first  # ONE sample a lap
+    laps.enter("b")
+    laps.enter("a")
+    laps.turn(0)
+    (entry,) = laps.stall_log
+    assert entry["cause"] == "other" and entry["watch_late_ns"] == 22
+    assert entry["watch_cpu_ns"] == 5
+    assert entry["stacks"].splitlines() == [
+        "thread-1;engine.py:_run;engine.py:_consume;array.py:_value 1",
+        "thread-3;pool.py:work;native.py:held 1",
+        "(idle threads) 2",
+    ]
+    # the next stall has no sample of its own: the old one is not reused
+    _one_turn(laps, clock, STALL_NS // 2, STALL_NS // 2, steps=0)
+    assert laps.stall_log[-1]["stacks"] == ""
+    assert laps.stall_log[-1]["watch_late_ns"] is None
+    # parked, a loop is never sampled; and 2 KB is all a sample may take
+    laps.park()
+    clock.at += 3 * STALL_NS
+    watch.check(clock.at)
+    assert laps._sampled is first
+    frames[1] = _frame(*[f"deep{i}.py:f{i}" for i in range(40)])
+    frames.update({10 + i: _frame(f"m{i}.py:{'f' * 90}") for i in range(40)})
+    laps.enter("a")
+    clock.at += STALL_NS
+    watch.check(clock.at)
+    text = laps._sampled[3]
+    assert len(text) <= 2048 and text.endswith(" 1\n(idle threads) 2\n")
+    assert text.startswith("thread-1;deep16.py:f16;")  # the innermost 24
+    watch.unregister(laps)
+    watch.unregister(laps)
+    assert not watch._loops and watch._wake.is_set()
+
+
+def test_a_loop_registered_after_the_last_left_finds_the_wake_cleared():
+    """The last loop leaves and sets the wake; another registers before
+    the thread has seen the empty set (an engine restarted after a
+    quarantine): the thread lives on, and its waits must wait again."""
+    clock = _SetClock()
+    spawned = []
+    watch = StallWatch(clock_ns=clock,
+                       spawn=lambda run: spawned.append(run) or "alive")
+    first, second = _laps(clock), _laps(clock)
+    watch.register(first)
+    watch.unregister(first)
+    assert watch._wake.is_set()
+    watch.register(second)  # the thread of the first is still there
+    assert len(spawned) == 1 and not watch._wake.is_set()
+    watch.unregister(second)
+
+
+def test_whoever_starts_a_waiting_thread_names_its_idle_leaf():
+    clock = _SetClock()
+
+    def _pump_loop():
+        """Stands for a thread's loop that waits inside a native call."""
+
+    frames = {
+        1: _frame("engine.py:_run"),
+        2: _frame("threading.py:run", "test_profiling.py:_pump_loop"),
+    }
+    watch = StallWatch(clock_ns=clock, frames=lambda: frames,
+                       spawn=lambda run: None)
+    assert "(idle threads)" not in watch._stacks(1)
+    watch.idle_in(_pump_loop)
+    assert watch._stacks(1).splitlines() == [
+        "thread-1;engine.py:_run 1", "(idle threads) 1"]
+    # the process's own watch knows the standard library's and what the
+    # native front-end handed it, no server file's name by itself
+    assert not any("native_frontend" in leaf for leaf in profiling._IDLE_LEAVES)
+
+
+def test_the_gc_hook_books_a_collection():
+    import gc
+
+    clock = _SetClock()
+    events = ProcessEvents(clock)
+    events.on_gc("stop", {"generation": 0})  # a stop without its start
+    for generation, took in ((0, 30), (2, 5_000), (0, 12)):
+        events.on_gc("start", {"generation": generation})
+        clock.at += took
+        events.on_gc("stop", {"generation": generation, "collected": 1})
+    assert events.gc_ns == [42, 0, 5_000]
+    assert events.gc_collections == [2, 0, 1]
+    assert (events.gc_total_ns, events.compile_total_ns) == (5_042, 0)
+    # the process's own hears the collector itself
+    PROCESS.listen()
+    PROCESS.listen()
+    assert gc.callbacks.count(PROCESS.on_gc) == 1
+    before = PROCESS.record()
+    gc.collect()
+    after = PROCESS.record()
+    assert after["gc_collections"][2] == before["gc_collections"][2] + 1
+    assert after["gc_ns"][2] > before["gc_ns"][2]
+
+
+def test_the_compile_listener_books_a_fresh_jit_once():
+    import jax
+
+    PROCESS.listen()
+    salt = float(np.random.default_rng().integers(1 << 30))
+    fresh = jax.jit(lambda x: x * 3 + salt)
+    x = np.ones([3], dtype=np.float32)
+    before = PROCESS.record()["compile"]
+    fresh(x).block_until_ready()
+    first = PROCESS.record()["compile"]
+    assert first["backend_count"] == before["backend_count"] + 1
+    assert first["backend_ns"] > before["backend_ns"]
+    assert first["trace_ns"] > before["trace_ns"]
+    fresh(x).block_until_ready()
+    assert PROCESS.record()["compile"] == first  # nothing compiles twice
+    events = ProcessEvents(_SetClock())
+    events.on_event("/jax/compilation_cache/cache_hits")
+    events.on_event("/jax/compilation_cache/cache_misses")
+    assert events.compile["cache_hits"] == 1
+
+
+@pytest.mark.parametrize("fails", [False, True], ids=["sound", "raises"])
+def test_maybe_jax_trace_leaves_four_instants_on_the_laps_clock(
+        monkeypatch, tmp_path, fails):
+    import contextlib
+
+    import jax
+
+    clock = _SetClock(at=500)
+    events = ProcessEvents(clock)
+
+    @contextlib.contextmanager
+    def held(log_dir):
+        clock.at += 2_000  # the start holds the process
+        try:
+            yield
+        finally:
+            clock.at += 6_000  # and so does the stop
+
+    monkeypatch.setattr(jax.profiler, "trace", held)
+    with maybe_jax_trace(None, process=events):
+        pass
+    assert not events.sessions
+    try:
+        with maybe_jax_trace(str(tmp_path), process=events):
+            clock.at += 100
+            if fails:
+                raise KeyError("inside the window")
+    except KeyError:
+        assert fails
+    else:
+        assert not fails
+    (session,) = events.sessions
+    assert session == {"monotonic_ns": [500, 2_500, 2_600, 8_600]}
+    assert "profiler_sessions" not in events.record()  # numbers a metric reads
+    assert events.in_profiler(0, 500) and events.in_profiler(2_500, 2_599)
+    assert not events.in_profiler(2_501, 2_599)
+    assert events.in_profiler(8_600, 9_000) and not events.in_profiler(8_601, 9_000)
+    json.dumps(list(events.sessions))
 
 
 # ---------------------------------------------------------------------------
