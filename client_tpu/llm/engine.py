@@ -558,7 +558,8 @@ class LlmEngine:
     ``attn_tile_pages`` is the paged kernel's tile in pages, a cache
     group (``paged_attention.pages_per_tile`` of the group's pools,
     which ``LlmEngineModel`` knows): with it ``stats()`` books the tile
-    stops a step's attention walks and those of them that are whole.
+    stops a step's attention walks, those of them that are whole, the
+    slots they fetch and those of them that are live.
     ``kv_row_bytes`` is ``(stored, counted)`` bytes a cached token takes
     in one layer of each cache group, which ``stats()`` serves as
     ``kv_row_bytes_by_group`` for whoever turns the token counters into
@@ -757,7 +758,10 @@ class LlmEngine:
         # tile stops the paged kernel makes over the tables of every
         # decode and verify step (a layer of each cache group), and
         # those of them it fetches with one copy a pool: the kernel's
-        # own rule (paged_attention.whole_tiles) on the tables as built
+        # own rule (paged_attention.whole_tiles) on the tables as built;
+        # the slots those stops bring in (a tile is copied whole, dead
+        # slots behind a lane's last token and before its window with
+        # it) and those of them some query row can see
         self._group_blocks = sizes
         self._kv_row_bytes = [
             {"stored": int(stored), "counted": int(counted)}
@@ -765,6 +769,8 @@ class LlmEngine:
         ]
         self.attn_tiles_walked = 0
         self.attn_tiles_whole = 0
+        self.attn_slots_fetched = 0
+        self.attn_slots_live = 0
         # the model's own per-step counters (decode_fn's third value)
         self._step_counter_names = tuple(step_counters)
         self.model_counters: Dict[str, int] = dict.fromkeys(
@@ -1174,6 +1180,8 @@ class LlmEngine:
             "attn_tokens_window": self.attn_tokens_window,
             "attn_tiles_walked": self.attn_tiles_walked,
             "attn_tiles_whole": self.attn_tiles_whole,
+            "attn_slots_fetched": self.attn_slots_fetched,
+            "attn_slots_live": self.attn_slots_live,
             **self.model_counters,
             "block_size": self.allocator.block_size,
             "steps": self.steps,
@@ -1282,15 +1290,21 @@ class LlmEngine:
         n = len(positions)
         if not self._windows:
             tables = tables[None]
+        block_size = self.allocator.block_size
         for index, group in enumerate(self.config.cache_groups or (None,)):
+            first_slots, lengths = visible_slots(
+                positions, group and group.window)
+            pages = self._tile_pages[index]
             walked, whole = count_tiles(
-                tables[index, :n],
-                *visible_slots(positions, group and group.window),
-                self._tile_pages[index], self.allocator.block_size,
+                tables[index, :n], first_slots, lengths, pages, block_size,
                 self._group_blocks[index],
             )
             self.attn_tiles_walked += walked
             self.attn_tiles_whole += whole
+            # a table narrower than a tile is one tile of its own width
+            self.attn_slots_fetched += walked * block_size * min(
+                pages, tables.shape[-1])
+            self.attn_slots_live += int((lengths - first_slots).sum())
 
     # -- step loop -----------------------------------------------------------
 

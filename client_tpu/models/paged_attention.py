@@ -36,8 +36,10 @@ call pick the path, never a model's name.
 - :func:`paged_attention_pallas`: the flash-style Pallas kernel, one
   grid step per sequence. The page table and each sequence's length ride
   scalar prefetch; the pools stay in HBM and the kernel copies a TILE of
-  pages (as many as a fixed VMEM budget holds for the pool's shapes: 8
-  at KV 8 / D 128 / bf16) into one of two VMEM slots itself, the next
+  pages (as many as a fixed VMEM budget holds for the pools' shapes: 8
+  at KV 8 / D 128 / bf16; a one-pool call's as many as give its score
+  block the columns that tile has, 64 at rows of 640:
+  :func:`pages_per_tile`) into one of two VMEM slots itself, the next
   tile in flight while this one is folded into the online softmax, and
   stops at the sequence's own last tile: no ``[B, S]`` gather ever
   materializes and the padding of the table to its bucket is never
@@ -218,6 +220,13 @@ def paged_attention_xla(q, k_pages, v_pages, page_tables, positions,
 #: VMEM the kernel may hold in K/V page buffers: two slots (one being
 #: folded, one in flight) of one tile of each pool
 _KV_VMEM_BUDGET = 1 << 20
+#: the (token, kv head) row the budget was set for, D 128 in bf16: two
+#: slots of K and V tiles of such rows hold 1,024 of them, which is the
+#: score block's width there
+_BUDGET_ROW_BYTES = 128 * 2
+#: the most a ONE-POOL call's two slots may take of the 16 MB of VMEM
+#: Mosaic gives a kernel, where the rule below lengthens its tile
+_ONE_POOL_VMEM_BUDGET = 4 << 20
 
 
 def pages_per_tile(block_size: int, kv_heads: int, head_dim: int,
@@ -228,9 +237,30 @@ def pages_per_tile(block_size: int, kv_heads: int, head_dim: int,
     function of the pools' shapes alone, so one program serves every
     batch, and every model finds its own tile: 8 pages (128 tokens) at
     KV 8 / D 128 / bf16, 2 at KV 32, 32 for a KV 2 tensor-parallel
-    shard, 16 (256 tokens) for one pool of 640-wide rows at KV 1."""
+    shard.
+
+    A ONE-POOL call gets a longer tile than those bytes hold. What a
+    tile stop costs beyond its bytes (the copy's start and its latency
+    with one copy in flight, the flag, the wait, the rescale of the
+    accumulator) is paid once a score block and so spread over the
+    block's columns, the tile's rows (pages x rows a page). One pool
+    holds a token's row for ALL heads, so under the same bytes its tile
+    has a quarter of the columns a two-pool tile at KV 8 has (256
+    against 1,024 at rows of 640), and its stop's fixed part is as long
+    as its bytes. Its tile is therefore lengthened to the columns the
+    budget gives the shapes it was set for (1,024 of
+    :data:`_BUDGET_ROW_BYTES`), two slots of it staying under
+    :data:`_ONE_POOL_VMEM_BUDGET`: 64 pages (1,024 tokens, 1.25 MB a
+    slot) for one pool of 640-wide bf16 rows at KV 1, where the budget
+    alone gives 16. Past those columns the fold itself, not the copy,
+    sets a stop's time and the dead slots behind a lane's last token
+    grow (PERF.md, PR 37: 32 / 64 / 128 pages on the chip)."""
     page_bytes = block_size * kv_heads * head_dim * jnp.dtype(dtype).itemsize
     pages = max(1, _KV_VMEM_BUDGET // (2 * pools) // page_bytes)
+    if pools == 1:
+        columns = _KV_VMEM_BUDGET // (2 * 2) // _BUDGET_ROW_BYTES
+        pages = max(pages, min(columns // (block_size * kv_heads),
+                               _ONE_POOL_VMEM_BUDGET // 2 // page_bytes))
     return 1 << (pages.bit_length() - 1)
 
 

@@ -55,7 +55,9 @@ LANES = ((21, 61), (5, 45), (60, 100))
 def small_tiles():
     """Tiles of :data:`TILE_PAGES` pages for every kernel call of this
     file at blocks of :data:`BLOCK` (the toy's rows are 128 float32
-    wide: a page is 4 KiB), so that its contexts lie over several tiles.
+    wide: a page is 4 KiB), so that its contexts lie over several tiles;
+    the columns a one-pool tile is lengthened to shrink with the budget
+    (32 here: the same 4 pages).
     The kernel is jitted: the cut holds for shapes first traced under
     it, which are this file's alone."""
     from client_tpu.models import paged_attention as pa
@@ -306,19 +308,26 @@ def test_the_one_pool_call_reads_each_row_once_under_every_function():
         pa.paged_attention_xla(q, pool, None, tables, positions, kv_heads=1)
 
 
-def test_one_pool_takes_a_tile_twice_as_long_as_two(monkeypatch):
-    """Two slots of one pool where there were two of two: the tile is a
-    power of two of pages, 16 (256 tokens) at the latent cache's rows of
-    640 where K and V pools of such rows would take 8."""
+def test_one_pool_takes_the_score_columns_of_a_two_pool_tile(monkeypatch):
+    """Two slots of one pool where there were two of two, and a tile
+    lengthened to the score block's columns the budget gives K and V at
+    KV 8 / D 128: 64 pages (1,024 tokens) at the latent cache's rows of
+    640, where the bytes alone hold 16 and K and V pools of such rows
+    would take 8; the two-pool tiles are what they were."""
     import jax.numpy as jnp
 
     from client_tpu.models import paged_attention as pa
 
     # the shipped budget, whatever the module's fixture set
     monkeypatch.setattr(pa, "_KV_VMEM_BUDGET", 1 << 20)
-    assert pa.pages_per_tile(16, 1, 640, jnp.bfloat16, 1) == 16
+    assert pa.pages_per_tile(16, 1, 640, jnp.bfloat16, 1) == 64
     assert pa.pages_per_tile(16, 1, 640, jnp.bfloat16) == 8
     assert pa.pages_per_tile(16, 8, 128, jnp.bfloat16) == 8
+    assert pa.pages_per_tile(16, 32, 128, jnp.bfloat16) == 2
+    assert pa.pages_per_tile(16, 2, 128, jnp.bfloat16) == 32
+    # MiMo's two groups as `LlmEngineModel` asks for them
+    assert pa.pages_per_tile(16 * 4, 1, 256, jnp.bfloat16) == 8
+    assert pa.pages_per_tile(16 * 8, 1, 256, jnp.bfloat16) == 4
 
 
 # -- one case a departure: the reference with it left out is far away ----------

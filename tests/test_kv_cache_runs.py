@@ -456,5 +456,70 @@ def test_the_run_is_read_off_the_shapes():
     plain = EngineConfig(block_size=16, num_blocks=2049, max_seq_len=2048)
     assert plain.group_runs() == [1] and plain.group_runs((8,)) == [8]
     assert plain.group_num_blocks((8,)) == [2049]
+    # GigaChat's one full group at the one-pool call's tile of 64 pages:
+    # the table row holds eight of them, the pool 640 runs and a block
+    latent = EngineConfig(block_size=16, num_blocks=40961, max_active=128,
+                          max_seq_len=8192)
+    assert latent.group_runs((64,)) == [64]
+    assert latent.group_num_blocks((64,)) == [40961]
+    assert BlockAllocator(40961, 16, 64).capacity == 640 * 64
+    assert latent.group_runs((512,)) == [1]  # a tile as wide as the row
     with pytest.raises(ValueError, match="run must be"):
         BlockAllocator(9, 4, 0)
+
+
+@pytest.mark.parametrize("run,reserved_most", [(64, 4000), (32, 1984)])
+def test_gigachats_block_traffic_fits_the_pool_in_long_runs(
+        run, reserved_most):
+    """``gigachat3_702b.reason8k_128``'s block traffic through the
+    allocator alone at the one-pool call's tile (64 pages; 32, the next
+    shorter, beside it): 128 sequences over a pool of 40,961 blocks of
+    16, prompts of 512 + 60 i tokens, a token a step each to 8,192, then
+    a fresh prompt of 512 in the lane, so a completion every 60 steps.
+    Nothing runs dry (no ``CacheCapacityError``: the engine would
+    preempt), an admission always finds its runs, a sequence keeps at
+    most a run less one in reserve, and every tile stop of the tables
+    as the engine would write them is whole. Counters only."""
+    lanes, pool, block, columns, last = 128, 40961, 16, 512, 8192
+    alloc = BlockAllocator(pool, block, run)
+    assert alloc.capacity == (pool - 1) // run * run
+    held, at = {}, {}
+    fresh = iter(range(1 << 30))
+
+    def admit(lane, prompt):
+        need = alloc.blocks_for(prompt + 1)
+        assert alloc.demand(need) <= alloc.free_blocks, (lane, prompt)
+        at[lane] = [next(fresh), prompt]  # id, the next position written
+        held[lane] = alloc.allocate(at[lane][0], need)
+
+    for lane in range(lanes):
+        admit(lane, 512 + 60 * lane)
+    worst = walked = whole = completions = 0
+    for step in range(3000):
+        for lane in range(lanes):
+            if at[lane][1] == last:
+                alloc.free(at[lane][0])
+                completions += 1
+                admit(lane, 512)
+            seq, position = at[lane]
+            while position // block >= len(held[lane]):
+                held[lane].append(alloc.extend(seq))
+            at[lane][1] = position + 1
+        worst = max(worst, alloc.blocks_reserved)
+        assert alloc.blocks_reserved == sum(
+            -len(blocks) % run for blocks in held.values())
+        if step % 50 == 0:
+            tables = np.zeros((lanes, columns), np.int32)
+            for lane, blocks in held.items():
+                tables[lane, :len(blocks)] = blocks
+            positions = np.array([[at[lane][1] - 1] for lane in range(lanes)])
+            stops = count_tiles(
+                tables, *visible_slots(positions, None), run, block, pool)
+            walked += stops[0]
+            whole += stops[1]
+    assert completions == 2999 // 60  # one every 60 steps from step 60
+    assert walked == whole > 0
+    assert worst == reserved_most <= lanes * (run - 1)
+    assert alloc.free_blocks + alloc.blocks_in_use + alloc.blocks_reserved \
+        == alloc.capacity
+

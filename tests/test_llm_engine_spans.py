@@ -36,7 +36,8 @@ pytestmark = pytest.mark.llm
 VOCAB = 32
 COUNTERS = ("prefills", "admitted", "queue_wait_ns", "steps", "lane_steps",
             "tokens_generated", "attn_blocks_live", "attn_blocks_bucket",
-            "attn_tiles_walked", "attn_tiles_whole")
+            "attn_tiles_walked", "attn_tiles_whole", "attn_slots_fetched",
+            "attn_slots_live")
 
 
 class _TickingClock:
@@ -430,6 +431,90 @@ def test_attn_tile_counters_sum_a_window_and_a_full_group():
         made, *visible_slots(positions, None), 2, 4, 33)
     assert walked == whole == 4 + 3 + 1
     engine.close()
+
+
+@pytest.mark.parametrize("windowed", [False, True], ids=["full", "windowed"])
+def test_attn_slot_counters_against_a_hand_count(windowed):
+    """``attn_slots_fetched`` is what the tile stops of a step bring
+    into VMEM (a tile is copied whole: the stops walked times the slots
+    of a tile), ``attn_slots_live`` those of them a query row can see
+    (each lane's ``length - first_slot``): three lanes at positions 30,
+    17 and 5 behind a full group's tiles of 2 pages of 4 slots and, with
+    it, a window group's (window 10, tiles of 4 pages), counted by
+    hand. A table narrower than a tile fetches tiles of its own width."""
+    from client_tpu.models.engine_model import FULL, WINDOW, CacheGroup
+
+    groups = (CacheGroup(FULL, (0,)), CacheGroup(WINDOW, (1,), window=10))
+    engine = _stub_engine(
+        _TickingClock(), tile_pages=(2, 4) if windowed else (2,),
+        cache_groups=groups if windowed else (), prefix_sharing=False,
+        max_seq_len=32, max_active=3)
+    positions = np.array([[30], [17], [5]])
+    full = np.zeros((3, 8), dtype=np.int32)
+    full[0] = [1, 2, 3, 4, 9, 10, 20, 21]
+    full[1, :5] = [5, 6, 8, 7, 11]
+    full[2, :2] = [12, 14]
+    # the full group: tiles of 8 slots, lengths 31, 18 and 6 from slot 0
+    fetched, live = (4 + 3 + 1) * 8, 31 + 18 + 6
+    tables = full
+    if windowed:
+        # the window group: tiles of 16 slots, each lane's last 10 slots
+        # (lane 2 has only 6): 21..30 in tile 1, 8..17 in tiles 0 and 1
+        tables = np.zeros((2, 3, 8), dtype=np.int32)
+        tables[0] = full
+        tables[1, 0, 5:] = [3, 4, 1]
+        tables[1, 1, 2:5] = [5, 6, 7]
+        tables[1, 2, :2] = [9, 10]
+        fetched += (1 + 2 + 1) * 16
+        live += 10 + 10 + 6
+    engine._book_tiles(tables, positions)
+    stats = engine.stats()
+    assert stats["attn_slots_fetched"] == fetched
+    assert stats["attn_slots_live"] == live
+    assert stats["attn_tiles_walked"] == 8 + 4 * windowed
+    # two live lanes behind a table of one column: a tile is that column
+    engine._book_tiles(tables[..., :2, :1], np.array([[3], [0]]))
+    after = engine.stats()
+    groups_booked = 1 + windowed
+    assert after["attn_slots_fetched"] - fetched == groups_booked * 2 * 4
+    assert after["attn_slots_live"] - live == groups_booked * (4 + 1)
+    silent = _stub_engine(_TickingClock())
+    silent._book_tiles(full, positions)
+    assert silent.stats()["attn_slots_fetched"] == 0
+    assert silent.stats()["attn_slots_live"] == 0
+    engine.close()
+    silent.close()
+
+
+def test_the_benchmark_reads_the_live_share_of_the_fetched_slots():
+    """``attn.tile_slots_live_share`` is a metric file over the reader
+    the benchmark has (``counters:delta_ratio``): the window's delta of
+    ``attn_slots_live`` over that of ``attn_slots_fetched`` in per cent,
+    listed for all four cells; a parent whose ``stats()`` has no such
+    counter reads nothing, and the line leaves the metric out."""
+    import types
+
+    from benchmark import run as harness
+
+    engine = _stub_engine(_TickingClock(), tile_pages=(2,))
+    before = {"engine": json.loads(json.dumps(engine.stats()))}
+    engine._book_tiles(  # three tiles of 8 slots for 19 and 3 live slots
+        np.array([[1, 2, 3, 4], [5, 6, 0, 0]]), np.array([[18], [2]]))
+    after = {"engine": json.loads(json.dumps(engine.stats()))}
+    engine.close()
+    run = types.SimpleNamespace(before=before, after=after)
+    value, unit = harness.read_metric(run, "attn.tile_slots_live_share")
+    assert unit == "%" and value == pytest.approx(100 * 22 / 32)
+    for snapshot in (before, after):
+        del snapshot["engine"]["attn_slots_fetched"]
+        del snapshot["engine"]["attn_slots_live"]
+    assert harness.read_metric(run, "attn.tile_slots_live_share")[0] is None
+    with open(os.path.join(harness.ROOT, "BENCHMARK.json")) as f:
+        benchmark = json.load(f)
+    entry = benchmark["per_layer"][-1]
+    assert entry["name"] == "attn.tile_slots_live_share"
+    assert entry["workloads"] == [w["name"] for w in benchmark["workloads"]]
+    assert entry["layer"] == "kernels" and entry["moves"] == "out_tokens_per_s"
 
 
 def _watch_dispatch(engine, decoded):

@@ -500,6 +500,79 @@ def test_pallas_fetches_whole_tiles_and_pages_to_the_same_bits(kind, layout):
         assert twin_whole <= whole < walked
 
 
+ONE_POOL_CASES = {
+    # table columns, lane lengths in slots, pool pages (None: a stretch
+    # of its own a lane): lengths that end one slot before, on and one
+    # past the long tile's edge (1,024 slots), past its second, at 1 and
+    # at the table's end; a table narrower than one tile; a pool that
+    # holds fewer pages than a tile (every tile page by page)
+    "tile-edges": (160, (1023, 1024, 1025, 2049, 1, 2560), None),
+    "narrow-table": (24, (384, 100, 1), None),
+    "small-pool": (64, (208, 16, 200), 40),
+}
+
+
+@pytest.mark.parametrize("case", ONE_POOL_CASES)
+def test_the_one_pool_call_folds_its_long_tile(case):
+    """The one-pool call (values inside the key rows, KV 1) at the tile
+    ``pages_per_tile`` gives one pool, 64 pages of 16 slots, against
+    the oracle: one score block a stop whatever the tile's length, the
+    dead slots a whole tile's copy brings masked (they hold garbage),
+    and the same contents behind a shuffled table (the page-by-page
+    path, one rolled loop over the tile's 64 pages) to the same bits."""
+    from client_tpu.models import paged_attention as pa
+
+    nb, lengths, n = ONE_POOL_CASES[case]
+    bs, width, dv, heads = 16, 128, 32, 4
+    pages = pa.pages_per_tile(bs, 1, width, np.float32, 1)
+    assert pages == 64 and pages * bs == 1024
+    lanes = len(lengths)
+    owned = [-(-length // bs) for length in lengths]
+    n = n or 1 + lanes * nb
+    assert (nb < pages) == (case == "narrow-table")
+    assert (n < min(pages, nb)) == (case == "small-pool")
+    rng = np.random.default_rng(len(case))
+    starts = 1 + np.concatenate([[0], np.cumsum(owned)[:-1]])
+    moved = np.concatenate([[0], 1 + rng.permutation(n - 1)])
+    consecutive = np.zeros((lanes, nb), np.int32)
+    pool = np.full((n, bs, width), GARBAGE, np.float32)
+    for lane, length in enumerate(lengths):
+        blocks = starts[lane] + np.arange(owned[lane])
+        consecutive[lane, :owned[lane]] = blocks
+        rows = rng.normal(size=(owned[lane] * bs, width)).astype(np.float32)
+        rows[length:] = GARBAGE
+        pool[blocks] = rows.reshape(owned[lane], bs, width)
+    shuffled = np.where(consecutive > 0, moved[consecutive], 0).astype(
+        np.int32)
+    shuffled_pool = pool[np.argsort(moved)]
+    positions = (np.asarray(lengths, np.int32) - 1)[:, None]
+    q = rng.normal(size=(lanes, 1, heads, width)).astype(np.float32)
+    asked = dict(scale=0.2, kv_heads=1, v_width=dv)
+    ref = np.asarray(pa.paged_attention_reference(
+        q, pool[:, :, None], None, consecutive, positions,
+        scale=0.2, v_width=dv))
+    first, seen = pa.visible_slots(positions, None)
+    outs = []
+    for pool_, table in ((pool, consecutive), (shuffled_pool, shuffled)):
+        out = np.asarray(pa.paged_attention_pallas(
+            q, pool_, None, table, positions, interpret=True, **asked))
+        assert out.shape == ref.shape == (lanes, 1, heads, dv)
+        scale = np.maximum(1.0, np.abs(ref).max(axis=-1, keepdims=True))
+        assert (np.abs(out - ref) / scale).max() <= 1e-5
+        outs.append(out)
+        walked, whole = pa.count_tiles(table, first, seen, pages, bs, n)
+        assert walked == sum(-(-length // (min(pages, nb) * bs))
+                             for length in lengths)
+        if case == "small-pool":
+            assert whole == 0  # no span of a tile's pages lies in it
+        elif table is consecutive:
+            # the lane whose span runs off the pool's end goes by page
+            assert walked - 1 <= whole <= walked
+        else:
+            assert whole < walked
+    assert (outs[0] == outs[1]).all()
+
+
 def test_whole_tiles_rule_on_hand_made_tables():
     """:func:`paged_attention.whole_tiles`, the rule the kernel and the
     engine's counter share, case by case in numpy; and the same lines
@@ -560,6 +633,30 @@ def test_pages_per_tile_follows_the_shapes_alone():
     assert pa.pages_per_tile(16, 8, 128, jnp.bfloat16) == 8
     assert pa.pages_per_tile(16, 32, 128, jnp.bfloat16) == 2
     assert pa.pages_per_tile(16, 2, 128, jnp.bfloat16) == 32
+    # the served models' two-pool tiles, as `LlmEngineModel` asks for
+    # them (a page's rows flat, the wider pool's row): MiMo's full and
+    # window groups (K rows of 256 at KV 4 and KV 8), Trinity's (KV 4)
+    assert pa.pages_per_tile(16 * 4, 1, 256, jnp.bfloat16, 2) == 8
+    assert pa.pages_per_tile(16 * 8, 1, 256, jnp.bfloat16, 2) == 4
+    assert pa.pages_per_tile(16 * 4, 1, 128, jnp.bfloat16, 2) == 16
+    # one pool (a latent cache's rows of 640 at KV 1): the budget alone
+    # holds 16 pages, 256 columns of the score block; the tile is
+    # lengthened to the 1,024 columns the budget gives K and V at KV 8
+    assert pa.pages_per_tile(16, 1, 640, jnp.bfloat16, 1) == 64
+    assert pa.pages_per_tile(16, 1, 640, jnp.bfloat16, 2) == 8
+    for bs, kv, d, dtype in [(16, 1, 640, jnp.bfloat16),
+                             (16, 1, 128, jnp.float32),
+                             (32, 1, 640, jnp.bfloat16),
+                             (16, 2, 256, jnp.bfloat16),
+                             (16, 1, 4096, jnp.bfloat16)]:
+        pages = pa.pages_per_tile(bs, kv, d, dtype, 1)
+        scratch = 2 * pages * bs * kv * d * jnp.dtype(dtype).itemsize
+        assert pages >= pa.pages_per_tile(bs, kv, d, dtype, 2)
+        assert pages * bs * kv <= 1024
+        assert scratch <= pa._ONE_POOL_VMEM_BUDGET
+        # short of the columns only where the bytes stop it
+        assert pages * bs * kv == 1024 or 2 * scratch > (
+            pa._ONE_POOL_VMEM_BUDGET)
     for bs, kv, d, dtype in [(16, 32, 128, jnp.bfloat16),
                              (16, 32, 128, jnp.float32),
                              (32, 8, 128, jnp.bfloat16),

@@ -382,19 +382,22 @@ def test_mosaic_compiles_the_paged_attention_kernel(one_chip, case):
 GIGACHAT = dict(vocab_size=16032, n_layers=5, n_dense_layers=1, held=(0, 16))
 
 
-@pytest.mark.parametrize("lanes,columns", [(128, 512), (1, 8)])
+@pytest.mark.parametrize("lanes,columns", [(128, 512), (1, 8), (8, 96)])
 def test_mosaic_compiles_the_one_pool_latent_call(one_chip, lanes, columns):
     """`gigachat3_702b.reason8k_128` as the kernel sees it: one pool of
     640-wide rows at KV 1 (576 held: the latent's 512, which are also
     the values, and the roped key's 64), 64 query rows a lane, tiles of
-    16 pages; ONE HBM operand and one VMEM buffer of two slots, where
-    two pools of such rows would be two of each."""
+    64 pages (a `[64, 1024]` score block a stop, two slots of 1.25 MB;
+    a table narrower than a tile is one tile, one of 96 columns a tile
+    and a half); ONE HBM operand and one VMEM buffer of two slots,
+    where two pools of such rows would be two of each."""
     import jax
     import jax.numpy as jnp
 
     from client_tpu.models import paged_attention as pa
 
-    assert pa.pages_per_tile(BLOCK, 1, 640, jnp.bfloat16, 1) == 16
+    assert pa.pages_per_tile(BLOCK, 1, 640, jnp.bfloat16, 1) == 64
+    assert pa.pages_per_tile(BLOCK, 8, 128, jnp.bfloat16) == 8
 
     def shaped(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)

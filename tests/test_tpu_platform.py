@@ -633,38 +633,48 @@ def _latent_lanes():
     """``gigachat3_702b.reason8k_128`` as the kernel sees a layer: 128
     lanes, 64 query heads whose rows ``[q_lat 512 | q_rope 64 | zeros]``
     all read ONE pool of 640-wide rows at KV 1, contexts staggered over
-    512..8,132 behind a page table of 512 columns, every lane's blocks a
-    run of consecutive pool pages. Returns (q, pool, tables,
-    positions[B, 1]) and the call's arguments."""
+    512..8,132 behind a page table of 512 columns, every lane's blocks
+    consecutive pool pages in whole runs of the kernel's tile, as the
+    engine's allocator hands them out. Returns (q, pool, tables,
+    positions[B, 1]), the call's arguments and the tile in pages."""
     import jax
+    import jax.numpy as jnp
+
+    from client_tpu.models import paged_attention as pa
 
     batch, heads, columns = 128, 64, 512
+    tile = pa.pages_per_tile(BLOCK, 1, 640, jnp.bfloat16, 1)
     positions = (511 + 60 * np.arange(batch)).astype(np.int32)
     owned = positions // BLOCK + 1
-    starts = 1 + np.concatenate([[0], np.cumsum(owned)[:-1]])
+    held = -(-owned // tile) * tile
+    starts = 1 + np.concatenate([[0], np.cumsum(held)[:-1]])
     tables = np.zeros((batch, columns), np.int32)
     for lane in range(batch):
         tables[lane, :owned[lane]] = starts[lane] + np.arange(owned[lane])
     keys = jax.random.split(jax.random.PRNGKey(41), 2)
     q = _device_normal(keys[0], (batch, 1, heads, 640), 1.0)
-    pool = _device_normal(keys[1], (1 + int(owned.sum()), BLOCK, 640), 1.0)
+    pool = _device_normal(keys[1], (1 + int(held.sum()), BLOCK, 640), 1.0)
     asked = {"scale": 0.14468, "kv_heads": 1, "v_width": 512}
-    return (q, pool, tables, positions[:, None]), asked
+    return (q, pool, tables, positions[:, None]), asked, tile
 
 
 def test_the_latent_call_at_the_gigachat_cells_shapes(device):
     """The one-pool call compiled by Mosaic against plain XLA on
     :func:`_latent_lanes`, on its table of consecutive pages (whole
     tiles: one copy each) and on the same contents behind a shuffled
-    table (16 page copies a tile): equal bits both ways, a few bf16
-    steps from XLA. Prints ms a call, us a tile stop, and the call's
-    share of the longer of its bytes (a row read once, counted at 576)
-    and its FLOPs (64 heads x (576 + 512) x 2 a cached token)."""
+    table (64 page copies a tile): equal bits both ways, a few bf16
+    steps from XLA. Prints ms a call, us a tile stop, ns a cached token
+    (stops of different lengths do not compare; a token does), the live
+    share of the slots the stops fetch, and the call's share of the
+    longer of its bytes (a row read once, counted at 576) and its FLOPs
+    (64 heads x (576 + 512) x 2 a cached token)."""
     import jax
 
     from client_tpu.models import paged_attention as pa
 
-    (q, pool, tables, positions), asked = _latent_lanes()
+    (q, pool, tables, positions), asked, pages = _latent_lanes()
+    # the tile the call runs at: 1,024 cached tokens a stop
+    assert pages == 64
     kernel = jax.jit(lambda q, pool, tables, positions: (
         pa.paged_attention_pallas(q, pool, None, tables, positions, **asked)))
     plain = jax.jit(lambda q, pool, tables, positions: (
@@ -672,7 +682,6 @@ def test_the_latent_call_at_the_gigachat_cells_shapes(device):
     moved = np.concatenate([[0], 1 + np.random.default_rng(43).permutation(
         len(pool) - 1)])
     shuffled = (pool[np.argsort(moved)], moved[tables].astype(np.int32))
-    pages = pa.pages_per_tile(BLOCK, 1, 640, pool.dtype, 1)
     first, lengths = pa.visible_slots(positions, None)
     tokens = int(lengths.sum())
     least_ms = 1e3 * max(tokens * 576 * 2 / 819e9,
@@ -682,11 +691,16 @@ def test_the_latent_call_at_the_gigachat_cells_shapes(device):
                                     ("shuffled", shuffled)):
         walked, whole = pa.count_tiles(
             table, first, lengths, pages, BLOCK, len(pool))
+        if layout == "consecutive":
+            assert whole == walked  # runs of the tile: every stop whole
         ms = _ms_a_call(kernel, q, pages_, table, positions)
         outs.append(np.asarray(kernel(q, pages_, table, positions)))
-        print(f"gigachat latent call, {layout} table: {whole} of {walked} "
-              f"tile stops whole, {ms:.3f} ms a call, "
-              f"{1e3 * ms / walked:.3f} us a stop, "
+        print(f"gigachat latent call, tiles of {pages} pages, {layout} "
+              f"table: {whole} of {walked} tile stops whole, "
+              f"{ms:.3f} ms a call, {1e3 * ms / walked:.3f} us a stop, "
+              f"{1e6 * ms / tokens:.3f} ns a cached token, "
+              f"{100 * tokens / (walked * pages * BLOCK):.1f}% of the "
+              f"fetched slots live, "
               f"{100 * least_ms / ms:.1f}% of max(bytes, FLOPs)")
     assert (outs[0] == outs[1]).all()
     _assert_bf16_close(outs[0], plain(q, pool, tables, positions),
