@@ -73,6 +73,7 @@ from client_tpu.llm.kv_cache import (
     window_ring_blocks,
     window_tables,
 )
+from client_tpu.models.engine_model import STATE
 from client_tpu.observability.profiling import WATCH, LapSpans
 from client_tpu.scheduling import (
     PriorityQueue,
@@ -122,7 +123,16 @@ class EngineConfig:
     from the model. ``num_blocks`` sizes the full group's pool; a
     window group's is worked out: a ring for each of ``max_active``
     sequences, and the trash block, so a ring is there for whoever
-    ``max_active`` admits. How many blocks a group's allocator hands
+    ``max_active`` admits. A ``state`` group's pool is counted in slots
+    and not in blocks: ``1 + max_active`` of them, slot 0 the trash slot,
+    one a sequence from admission until it ends or is preempted, never
+    more as it grows; its row of the tables holds the slot in column 0.
+    Prefix sharing and speculation are refused beside it
+    (``LlmEngine``), as beside a window group: a state holds the whole
+    prefix in one slot, so no block of it can be shared, and a draft
+    that is turned down cannot be taken out of it again (the allocator's
+    ``truncate`` rolls back blocks, never a state).
+    How many blocks a group's allocator hands
     out at a time, and with it the ring's length, follows from the
     paged kernel's tile and the table's width (:meth:`group_runs`):
     shapes, not a setting.
@@ -192,9 +202,13 @@ class EngineConfig:
         a window group, than the blocks its window can touch), else 1:
         a row of one tile is whole only if the whole sequence is one
         run, which is not attempted. Without ``tile_pages`` every run is
-        1 and the allocators work block for block."""
+        1 and the allocators work block for block. A state group's
+        slots go one at a time."""
         runs = []
         for index, group in enumerate(self.cache_groups or (None,)):
+            if group is not None and group.kind == STATE:
+                runs.append(1)
+                continue
             most = self.max_blocks_per_seq
             if group is not None and group.window is not None:
                 most = min(
@@ -212,7 +226,8 @@ class EngineConfig:
         tiles of 16, 9 become 12 at tiles of 4), so that no tile of a
         ring wraps; the full group's pool is ``num_blocks`` whatever the
         run, and its sequences hold up to a run less one of it in
-        reserve each (``stats()["kv_blocks_reserved_by_group"]``).
+        reserve each (``stats()["kv_blocks_reserved_by_group"]``). A
+        state group's pool is ``1 + max_active`` slots.
         Refuses, with the numbers, sizes under which a window group
         cannot do its work:
         a ``max_seq_len`` whose page table has fewer columns than the
@@ -229,6 +244,9 @@ class EngineConfig:
         sizes = []
         runs = self.group_runs(tile_pages)
         for run, group in zip(runs, self.cache_groups or (None,)):
+            if group is not None and group.kind == STATE:
+                sizes.append(1 + self.max_active)
+                continue
             if group is None or group.window is None:
                 sizes.append(self.num_blocks)
                 continue
@@ -431,6 +449,7 @@ class Sequence:
         "state",
         "blocks",
         "rings",
+        "slots",
         "page_table",
         "last_token",
         "position",
@@ -464,6 +483,8 @@ class Sequence:
         # one fixed ring of blocks for each WINDOW cache group, held
         # from admission to free (kv_cache.window_tables)
         self.rings: List[List[int]] = []
+        # the slot held in each STATE cache group, as long as the rings
+        self.slots: List[int] = []
         self.page_table = np.zeros([max_blocks], dtype=np.int32)
         self.last_token = 0
         self.position = 0
@@ -550,7 +571,7 @@ class LlmEngine:
     in which a live lane samples). :func:`decode_fn_from_logits` builds
     such a callable in numpy from a plain logits function. A
     model with several cache groups (``engine_config.cache_groups``: one
-    full group, the rest window groups) gets one table row a group,
+    full group, the rest window or state groups) gets one table row a group,
     stacked in the groups' order: ``page_table[G, max_blocks]`` and
     ``page_tables[G, B, NB]``. ``decode_fn`` may return a fourth value, an
     int32 vector of per-step counters named by ``step_counters``; it is
@@ -639,7 +660,9 @@ class LlmEngine:
         # own that hands out whole rings. `_windows` holds (index among
         # the groups, ring length, allocator). Every allocator hands out
         # runs of its group's tile where the table holds several tiles
-        # (`EngineConfig.group_runs`), so that the kernel finds them whole
+        # (`EngineConfig.group_runs`), so that the kernel finds them whole.
+        # A state group's allocator hands out slots, one a sequence:
+        # `_states` holds (index among the groups, allocator)
         self._tile_pages = tuple(int(pages) for pages in attn_tile_pages)
         groups = engine_config.cache_groups
         runs = engine_config.group_runs(self._tile_pages)
@@ -647,7 +670,11 @@ class LlmEngine:
         self._n_groups = max(1, len(groups))
         self._full_group = 0
         self._windows: List[tuple] = []
+        self._states: List[tuple] = []
         for index, group in enumerate(groups):
+            if group.kind == STATE:
+                self._states.append((index, BlockAllocator(sizes[index], 1)))
+                continue
             if group.window is None:
                 self._full_group = index
                 continue
@@ -664,10 +691,25 @@ class LlmEngine:
             engine_config.num_blocks, engine_config.block_size,
             runs[self._full_group],
         )
-        if groups and len(groups) - len(self._windows) != 1:
+        if groups and (
+            len(groups) - len(self._windows) - len(self._states) != 1
+        ):
             raise ValueError(
                 "the engine serves exactly one full cache group beside "
-                f"any window groups, got {[g.kind for g in groups]}"
+                "any window and state groups, got "
+                f"{[g.kind for g in groups]}"
+            )
+        if self._states and (
+            engine_config.prefix_sharing or engine_config.spec_k
+        ):
+            raise ValueError(
+                "a state cache group (one slot of "
+                f"{1 + engine_config.max_active} a sequence) is served "
+                f"without prefix sharing (prefix_sharing="
+                f"{engine_config.prefix_sharing}) and without speculation "
+                f"(spec_k={engine_config.spec_k}): a slot holds the whole "
+                "prefix, so no block of it can be shared, and `truncate` "
+                "cannot take a refused draft out of it"
             )
         if self._windows and (
             engine_config.prefix_sharing or engine_config.spec_k
@@ -1174,6 +1216,14 @@ class LlmEngine:
                 attrgetter("blocks_reserved")),
             # bytes a cached token takes in one layer of each group
             "kv_row_bytes_by_group": self._kv_row_bytes,
+            # state groups: slots that hold a sequence's recurrent state
+            # (all state groups together), and the bytes they hold in
+            # all of a group's layers (0 for a group of another kind)
+            "state_slots_in_use": sum(
+                slot_allocator.blocks_in_use
+                for _, slot_allocator in self._states
+            ),
+            "state_bytes_by_group": self._state_bytes_by_group(),
             "window_blocks_whole": self.window_blocks_whole,
             "window_blocks_unheld": self.window_blocks_unheld,
             "attn_tokens_full": self.attn_tokens_full,
@@ -1245,6 +1295,9 @@ class LlmEngine:
         for _, _, ring_allocator in self._windows:
             ring_allocator.free(seq.seq_id)
         seq.rings = []
+        for _, slot_allocator in self._states:
+            slot_allocator.free(seq.seq_id)
+        seq.slots = []
 
     def _blocks_by_group(self, count: Callable) -> List[int]:
         """``count`` of every cache group's allocator, in the groups'
@@ -1252,7 +1305,22 @@ class LlmEngine:
         counts = [count(self.allocator)] * self._n_groups
         for index, _, ring_allocator in self._windows:
             counts[index] = count(ring_allocator)
+        for index, slot_allocator in self._states:
+            counts[index] = count(slot_allocator)
         return counts
+
+    def _state_bytes_by_group(self) -> List[int]:
+        """Bytes the held slots of every state group take, over the
+        group's layers, in the groups' order (0 for another kind)."""
+        held = [0] * self._n_groups
+        for index, slot_allocator in self._states:
+            if index < len(self._kv_row_bytes):
+                held[index] = (
+                    slot_allocator.blocks_in_use
+                    * self._kv_row_bytes[index]["stored"]
+                    * len(self.config.cache_groups[index].layers)
+                )
+        return held
 
     def _group_tables(self, full: np.ndarray, seqs: List[Sequence],
                       last_positions) -> np.ndarray:
@@ -1260,8 +1328,10 @@ class LlmEngine:
         for a one-group model, else one such array a group, stacked in
         the groups' order. A window group's rows are written from the
         sequences' rings for the block of each ``last_positions`` entry
-        (the newest position the call reads or writes)."""
-        if not self._windows:
+        (the newest position the call reads or writes); a state group's
+        hold each sequence's slot in column 0 (a padding row the trash
+        slot) and nothing else."""
+        if self._n_groups == 1:
             return full
         rows = full.reshape(-1, full.shape[-1])
         tables = np.zeros((self._n_groups,) + rows.shape, dtype=np.int32)
@@ -1274,12 +1344,17 @@ class LlmEngine:
                 [seq.rings[window] for seq in seqs], last_blocks,
                 rows.shape[1],
             )
+        for state, (index, _) in enumerate(self._states):
+            tables[index, : len(seqs), 0] = [
+                seq.slots[state] for seq in seqs
+            ]
         return tables.reshape((self._n_groups,) + full.shape)
 
     def _book_tiles(self, tables: np.ndarray, positions: np.ndarray) -> None:
         """Book the tile stops of a step over ``tables`` (what
         :meth:`_group_tables` returned) whose live rows hold query
-        positions ``positions[n, T]``."""
+        positions ``positions[n, T]``. A state group has no tiles: its
+        layers' cost a step is per lane, not per cached token."""
         if not self._tile_pages:
             return
         from client_tpu.models.paged_attention import (
@@ -1288,10 +1363,12 @@ class LlmEngine:
         )
 
         n = len(positions)
-        if not self._windows:
+        if self._n_groups == 1:
             tables = tables[None]
         block_size = self.allocator.block_size
         for index, group in enumerate(self.config.cache_groups or (None,)):
+            if group is not None and group.kind == STATE:
+                continue
             first_slots, lengths = visible_slots(
                 positions, group and group.window)
             pages = self._tile_pages[index]
@@ -1484,6 +1561,11 @@ class LlmEngine:
             seq.rings = [
                 ring_allocator.allocate(seq.seq_id, ring)
                 for _, ring, ring_allocator in self._windows
+            ]
+            # and a slot of every state group; the prefill writes it whole
+            seq.slots = [
+                slot_allocator.allocate(seq.seq_id, 1)[0]
+                for _, slot_allocator in self._states
             ]
             # visible to _fail_all while the prefill await is in flight:
             # the sequence owns blocks but is in neither queue nor batch.

@@ -33,7 +33,13 @@ whole on free; logical block ``j`` lives in ring entry ``j % len(ring)``,
 and :func:`window_tables` writes the row a window layer is handed: the
 ring's blocks at the last ``len(ring)`` logical columns, the trash block
 at every column wholly behind the window, which is therefore neither
-held nor read.
+held nor read. A state group (a linear-attention layer's recurrent
+state) holds no blocks at all but ONE SLOT a sequence, whatever its
+length: its allocator is a ``BlockAllocator(1 + max_active, 1)`` whose
+"blocks" are slots of one, claimed at admission (``allocate(seq, 1)``),
+never extended, returned on free; slot 0 is the trash slot, as block 0
+is the trash block, and the group's table row holds the slot in column
+0. Nothing of a slot is hashed, shared or truncated.
 
 Runs (``BlockAllocator(run=P)``): the paged kernel fetches a tile of
 ``P`` table columns with one copy a pool when the tile's live columns

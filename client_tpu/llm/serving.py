@@ -345,7 +345,7 @@ class LlmEngineModel(Model):
         import jax
 
         from client_tpu.models import paged_attention
-        from client_tpu.models.engine_model import Kernels
+        from client_tpu.models.engine_model import STATE, Kernels
 
         config, model = self._config, self._model
         engine_config = self.engine_config
@@ -417,13 +417,14 @@ class LlmEngineModel(Model):
             jax.tree_util.tree_leaves(shapes[group.layers[0]])
             for group in engine_config.cache_groups
         ]
+        # (a state group's pools are slots and have no tile: 1)
         tile_pages = [
-            paged_attention.pages_per_tile(
+            1 if group.kind == STATE else paged_attention.pages_per_tile(
                 math.prod(pools[0].shape[1:-1]) // self.tp, 1,
                 max(pool.shape[-1] for pool in pools), pools[0].dtype,
                 len(pools),
             )
-            for pools in group_pools
+            for group, pools in zip(engine_config.cache_groups, group_pools)
         ]
         pages = model.init_pages(
             config, engine_config.group_num_blocks(tile_pages),
@@ -520,11 +521,13 @@ class LlmEngineModel(Model):
             self.engine.close()
         if model.kv_row_bytes is not None:
             kv_row_bytes = model.kv_row_bytes(config)
-        else:  # counted as stored
+        else:  # counted as stored (a state group's entry is a slot's)
             stored = [
                 sum(math.prod(pool.shape[1:]) * pool.dtype.itemsize
-                    for pool in pools) // engine_config.block_size
-                for pools in group_pools
+                    for pool in pools)
+                // (1 if group.kind == STATE else engine_config.block_size)
+                for group, pools in zip(
+                    engine_config.cache_groups, group_pools)
             ]
             kv_row_bytes = [(size, size) for size in stored]
         self.engine = LlmEngine(

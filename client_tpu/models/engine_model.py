@@ -6,14 +6,26 @@ call, and the cache groups the model's layers fall into. The engine,
 its allocator and its front-ends know nothing else of a model, and
 nothing under ``llm/`` branches on which model it is.
 
-A cache group is a set of layers whose K/V a sequence keeps in the same
-way: ``full`` layers keep every block of the context, ``window`` layers
-only the blocks a sliding window of ``window`` tokens can still see.
-Each group has block pools of its own (a layer's K and V, or the one
-pool of a model whose values lie inside its key rows) and a sequence
-has one page-table row a group; a model with one group gets its tables as
-``[max_blocks]`` / ``[B, NB]``, a model with several as ``[G, ...]``, in
-the order of :attr:`EngineModel.cache_groups`.
+A cache group is a set of layers whose cache a sequence keeps in the
+same way: ``full`` layers keep every block of the context, ``window``
+layers only the blocks a sliding window of ``window`` tokens can still
+see, ``state`` layers no K/V at all but a recurrent state of fixed size
+(a linear-attention layer's, ``models/qwen3_next.py``): ONE slot of the
+group's pools a sequence, whatever its length.
+Each group has pools of its own (a layer's K and V, the one pool of a
+model whose values lie inside its key rows, a state layer's state and
+convolution pools, whose leading size is slots and not blocks) and a
+sequence has one page-table row a group; a model with one group gets its
+tables as ``[max_blocks]`` / ``[B, NB]``, a model with several as
+``[G, ...]``, in the order of :attr:`EngineModel.cache_groups`. A
+``state`` group's row holds the sequence's slot in column 0 and nothing
+else: slot 0 is the trash slot, which the padding rows of a batch
+bucket and the warm-up probes name. A slot is claimed at admission,
+given back when the sequence ends or is preempted, and what it holds is
+written whole by the prefill (a resumed sequence's re-prefill too), so a
+new owner finds nothing of the last one. Nothing of a state can be
+shared, rolled back or looked ahead into: prefix sharing and speculation
+are refused at load beside a ``state`` group, as beside a ``window`` one.
 
 Which kernels run is chosen once, at load (``llm/serving.py``, from
 ``CLIENT_TPU_LLM_KERNEL`` or the platform), and handed to every program
@@ -29,15 +41,16 @@ refused the feature at load, by the name of the missing part.
 import dataclasses
 from typing import Any, Callable, Optional, Sequence, Tuple
 
-FULL, WINDOW = "full", "window"
+FULL, WINDOW, STATE = "full", "window", "state"
 
 
 @dataclasses.dataclass(frozen=True)
 class CacheGroup:
-    """``kind`` :data:`FULL` or :data:`WINDOW`; ``layers`` the indices of
-    the model's layers in the group; ``window`` the tokens a
-    :data:`WINDOW` layer's query sees, itself included (the engine holds
-    ``kv_cache.window_ring_blocks`` blocks a sequence for it)."""
+    """``kind`` :data:`FULL`, :data:`WINDOW` or :data:`STATE`; ``layers``
+    the indices of the model's layers in the group; ``window`` the tokens
+    a :data:`WINDOW` layer's query sees, itself included (the engine holds
+    ``kv_cache.window_ring_blocks`` blocks a sequence for it). A
+    :data:`STATE` group's sequence holds one slot of ``1 + max_active``."""
 
     kind: str
     layers: Tuple[int, ...]
@@ -85,11 +98,14 @@ class EngineModel:
     ``heads(config) -> (n_heads, n_kv_heads)``: what ``tp`` must divide.
     ``init_pages`` returns one entry a layer, a pool or a tuple of pools
     (``(k_pages, v_pages)``; a latent model's one pool holds its values
-    inside its key rows), which the engine hands back as it got them.
+    inside its key rows; a state layer's ``(state_pool, conv_pool)``,
+    ``num_blocks`` of its group being slots), which the engine hands
+    back as it got them.
     ``kv_row_bytes(config) -> [(stored, counted) per group]``: bytes a
     cached token takes in one layer of each group, as its pools store
     it and as the model reads it (rows padded to whole lanes count less
-    than they store); without it both are what the pools store.
+    than they store); without it both are what the pools store. A state
+    group's entry is the bytes of one SLOT in one layer.
     """
 
     name: str

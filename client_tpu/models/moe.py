@@ -78,24 +78,35 @@ COUNTERS = ("moe_pairs", "moe_experts_touched", "moe_load_max",
 
 
 def route(h, router, bias, top_k: int, scale: float = 1.0,
-          n_group: int = 1, topk_group: int = 1, eps: float = 0.0):
-    """``noaux_tc`` routing with sigmoid scores: scores ``s =
-    sigmoid(h @ router)`` in float32, the ``top_k`` of ``c = s + bias``
-    (the correction bias selects and does not weigh), weights ``s_e``
-    over their sum (plus ``eps``), times the model's ``scale``
-    (``afmoe``'s ``route_scale``, ``deepseek_v3``'s
+          n_group: int = 1, topk_group: int = 1, eps: float = 0.0,
+          score: str = "sigmoid"):
+    """Routing over all experts in float32. ``score`` (static) names the
+    scores: ``"sigmoid"`` is ``noaux_tc`` routing, ``s = sigmoid(h @
+    router)``, the ``top_k`` of ``c = s + bias`` (the correction bias
+    selects and does not weigh); ``"softmax"`` (``qwen3_next``) is ``s =
+    softmax(h @ router)`` over ALL the experts, and a model without a
+    correction bias passes ``bias=None`` (``c = s``). Either way the
+    weights are ``s_e`` over the chosen ones' sum (plus ``eps``), which
+    for softmax scores is ``norm_topk_prob``, times the model's
+    ``scale`` (``afmoe``'s ``route_scale``, ``deepseek_v3``'s
     ``routed_scaling_factor``). Group-limited (``n_group`` > 1): the
     experts fall into ``n_group`` groups of consecutive ones, a group
     scores the sum of its two largest ``c``, and the ``top_k`` are taken
     among the ``topk_group`` best groups' experts (``c`` reads 0
-    elsewhere). At one group, a scale of 1 and no ``eps`` the program is
-    the one it was without them. ``h`` [T, d] -> (ids [T, K] int32,
-    weights [T, K] f32)."""
-    scores = jax.nn.sigmoid(jnp.dot(
+    elsewhere). At one group, a scale of 1, no ``eps`` and sigmoid
+    scores the program is the one it was without them. ``h`` [T, d] ->
+    (ids [T, K] int32, weights [T, K] f32)."""
+    if score not in ("sigmoid", "softmax"):
+        raise ValueError(f"score={score!r}: 'sigmoid' or 'softmax'")
+    logits = jnp.dot(
         h.astype(jnp.float32), router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
-    ))
-    biased = scores + bias.astype(jnp.float32)
+    )
+    if score == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        scores = jax.nn.sigmoid(logits)
+    biased = scores if bias is None else scores + bias.astype(jnp.float32)
     if n_group > 1:
         tokens, experts = biased.shape
         grouped = biased.reshape(tokens, n_group, experts // n_group)
@@ -395,17 +406,24 @@ def shared_expert(h, shared):
     kernels' arithmetic (operands as stored, float32 accumulation, the
     hidden row rounded to the operands' type). Plain XLA under every
     kernel choice: at decode size it is bound by its weights' bytes,
-    which XLA streams as it does a dense MLP's. Returns [T, d] float32."""
+    which XLA streams as it does a dense MLP's. A shared expert with a
+    gate of its own (``w_sg`` [d], ``qwen3_next``) is weighed a token by
+    ``sigmoid(h @ w_sg)`` in float32. Returns [T, d] float32."""
     gate = jnp.dot(h, shared["w_gate"], preferred_element_type=jnp.float32)
     up = jnp.dot(h, shared["w_up"], preferred_element_type=jnp.float32)
     mid = (jax.nn.silu(gate) * up).astype(h.dtype)
-    return jnp.dot(mid, shared["w_down"], preferred_element_type=jnp.float32)
+    out = jnp.dot(mid, shared["w_down"], preferred_element_type=jnp.float32)
+    if "w_sg" in shared:
+        out = out * jax.nn.sigmoid(jnp.dot(
+            h, shared["w_sg"][:, None], preferred_element_type=jnp.float32))
+    return out
 
 
 def expert_layer(h, ids, weights, experts, held, *, kernel: str,
                  shared=None):
     """The held experts' part of the layer's output, and with ``shared``
-    (:func:`shared_expert`'s weights) the shared expert's whole output
+    (:func:`shared_expert`'s weights, its scalar gate ``w_sg`` among
+    them where the model has one) the shared expert's whole output
     added to it.
 
     ``h`` [T, d] (the normed input), ``ids`` / ``weights`` [T, K] from

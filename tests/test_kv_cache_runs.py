@@ -16,7 +16,7 @@ from client_tpu.llm.kv_cache import (
     window_ring_blocks,
     window_tables,
 )
-from client_tpu.models.engine_model import FULL, WINDOW, CacheGroup
+from client_tpu.models.engine_model import FULL, STATE, WINDOW, CacheGroup
 from client_tpu.models.paged_attention import count_tiles, visible_slots
 from client_tpu.utils import InferenceServerException
 
@@ -523,3 +523,29 @@ def test_gigachats_block_traffic_fits_the_pool_in_long_runs(
     assert alloc.free_blocks + alloc.blocks_in_use + alloc.blocks_reserved \
         == alloc.capacity
 
+
+
+@pytest.mark.parametrize("max_active,tile_pages", [
+    (128, (16, 1)), (128, ()), (8, (4, 1)), (1, (16, 16))])
+def test_a_state_group_is_counted_in_slots_not_blocks(max_active, tile_pages):
+    """``EngineConfig`` beside a state group: its pool is ``1 +
+    max_active`` slots whatever the block size, the table's width or the
+    tile the full group runs at, its slots go one at a time, and the
+    full group's pool and runs are what they are without it."""
+    groups = (CacheGroup(FULL, (3, 7)), CacheGroup(STATE, (0, 1, 2, 4, 5, 6)))
+    cell = EngineConfig(block_size=16, num_blocks=16385,
+                        max_active=max_active, max_seq_len=2048,
+                        cache_groups=groups)
+    alone = EngineConfig(block_size=16, num_blocks=16385,
+                         max_active=max_active, max_seq_len=2048)
+    assert cell.group_runs(tile_pages)[1] == 1
+    assert cell.group_runs(tile_pages)[0] == alone.group_runs(tile_pages)[0]
+    assert cell.group_num_blocks(tile_pages) == [16385, 1 + max_active]
+    # a slot allocator: one block a sequence, the trash slot never
+    slots = BlockAllocator(1 + max_active, 1)
+    held = [slots.allocate(seq, 1)[0] for seq in range(max_active)]
+    assert sorted(held) == list(range(1, 1 + max_active))
+    with pytest.raises(CacheCapacityError):
+        slots.allocate("one too many", 1)
+    slots.free(0)
+    assert slots.allocate("next", 1) == [held[0]]

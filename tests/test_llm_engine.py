@@ -841,3 +841,139 @@ def test_genai_perf_drives_engine_end_to_end(llm_server, tmp_path, capsys):
     assert report["output_token_throughput_per_s"] == pytest.approx(
         summary["tokens_per_sec"], rel=0.01
     )
+
+
+# ---------------------------------------------------------------------------
+# a state cache group: one slot a sequence beside the full group's blocks
+# ---------------------------------------------------------------------------
+
+
+def _state_engine(clock, seen, **overrides):
+    """A stub engine over a full group and a state group whose device
+    functions record the tables they are handed (``seen``)."""
+    from client_tpu.models.engine_model import FULL, STATE, CacheGroup
+
+    def prefill(tokens, page_table, pages, last_index, start):
+        seen.append(("prefill", np.array(page_table)))
+        logits = np.zeros([1, VOCAB], dtype=np.float32)
+        logits[0, (int(tokens.sum()) + start) % VOCAB] = 1.0
+        return logits, pages
+
+    holder = {}
+
+    def decode(tokens, positions, page_tables, pages):
+        seen.append(("decode", np.array(page_tables)))
+        seen.append(("stats", holder["engine"].stats()))
+        n = tokens.shape[0]
+        logits = np.zeros([n, VOCAB], dtype=np.float32)
+        for i in range(n):
+            logits[i, int(tokens[i] + positions[i]) % VOCAB] = 1.0
+        return logits, pages
+
+    defaults = dict(
+        block_size=4, num_blocks=17, max_active=3, max_queue=8,
+        max_seq_len=32, prefix_sharing=False,
+        cache_groups=(CacheGroup(FULL, (1,)), CacheGroup(STATE, (0, 2))),
+    )
+    defaults.update(overrides)
+    holder["engine"] = LlmEngine(
+        prefill,
+        decode_fn_from_logits(decode),
+        pages=object(),
+        engine_config=EngineConfig(**defaults),
+        model_name="stub",
+        clock_ns=clock,
+        attn_tile_pages=(2, 1),
+        kv_row_bytes=((64, 64), (1000, 1000)),
+    )
+    return holder["engine"]
+
+
+def test_a_state_group_gives_every_sequence_one_slot_for_its_whole_life():
+    """Five sequences over three slots: a table's state row holds the
+    slot in column 0 and zeros elsewhere (a padding lane the trash
+    slot), live lanes hold distinct slots that never change while they
+    run, the slots are given back, the tile counters see the full group
+    alone, and ``stats()`` serves the slots held and their bytes."""
+    seen = []
+    engine = _state_engine(_FakeClock(), seen)
+
+    async def run():
+        seqs = [engine.submit([1 + i, 2, 3], max_tokens=9) for i in range(5)]
+        return [await _collect(s) for s in seqs]
+
+    out = asyncio.run(run())
+    assert all(len(tokens) == 9 for tokens in out)
+    running = [entry for kind, entry in seen if kind == "stats"]
+    assert max(s["state_slots_in_use"] for s in running) == 3
+    for mid in running:
+        assert mid["state_slots_in_use"] == mid["active_sequences"]
+        assert mid["kv_blocks_in_use_by_group"][1] == mid["active_sequences"]
+        assert mid["state_bytes_by_group"] == [
+            0, mid["state_slots_in_use"] * 1000 * 2]
+    for kind, tables in seen:
+        if kind == "stats":
+            continue
+        assert tables.shape[0] == 2
+        state = tables[1]
+        assert not state[..., 1:].any()
+        if kind == "prefill":
+            assert 1 <= state[0] <= 3
+        else:
+            live = state[:, 0][state[:, 0] != 0]
+            assert len(set(live.tolist())) == len(live) and live.max() <= 3
+    stats = engine.stats()
+    assert stats["state_slots_in_use"] == 0
+    assert stats["kv_blocks_in_use_by_group"] == [0, 0]
+    assert stats["state_bytes_by_group"] == [0, 0]
+    assert stats["completed"] == 5
+    # only the full group is walked: at most a tile a column pair a lane
+    assert 0 < stats["attn_tiles_walked"] <= 2 * stats["lane_steps"]
+    assert stats["attn_blocks_live"] > 0 and stats["window_blocks_whole"] == 0
+
+
+def test_a_preempted_sequence_gives_its_slot_back_and_takes_one_to_resume():
+    """A full pool too small for three sequences: the victim's slot is
+    free while it waits (the slots held are the sequences running, at
+    every step), it is re-prefilled into a slot when it resumes, and
+    nothing is held at the end. (That the resumed stream is the
+    undisturbed one takes a model whose prefill and decode agree:
+    ``tests/test_qwen3_next.py``.)"""
+    seen = []
+    tight = _state_engine(_FakeClock(), seen, num_blocks=8)
+
+    async def run(engine):
+        seqs = [engine.submit([1 + i, 2, 3, 4], max_tokens=14)
+                for i in range(3)]
+        return [await _collect(s) for s in seqs]
+
+    resumed = asyncio.run(run(tight))
+    assert [len(tokens) for tokens in resumed] == [14, 14, 14]
+    assert tight.stats()["preemptions"] >= 1
+    running = [entry for kind, entry in seen if kind == "stats"]
+    assert all(s["state_slots_in_use"] == s["active_sequences"]
+               for s in running)
+    assert min(s["state_slots_in_use"] for s in running) < 3
+    prefills = sum(kind == "prefill" for kind, _ in seen)
+    assert prefills == 3 + tight.stats()["preemptions"]
+    assert tight.stats()["state_slots_in_use"] == 0
+
+
+@pytest.mark.parametrize("setting,named", [
+    (dict(prefix_sharing=True), "prefix_sharing=True"),
+    (dict(spec_k=2), "spec_k=2"),
+])
+def test_a_state_group_refuses_sharing_and_speculation_by_name(setting, named):
+    with pytest.raises(ValueError, match="state cache group") as refused:
+        _state_engine(_FakeClock(), [], **setting)
+    assert named in str(refused.value) and "truncate" in str(refused.value)
+    assert "one slot of 4" in str(refused.value)
+
+
+def test_two_full_groups_beside_a_state_group_are_refused():
+    from client_tpu.models.engine_model import FULL, STATE, CacheGroup
+
+    with pytest.raises(ValueError, match="exactly one full cache group"):
+        _state_engine(_FakeClock(), [], cache_groups=(
+            CacheGroup(FULL, (0,)), CacheGroup(FULL, (1,)),
+            CacheGroup(STATE, (2,))))

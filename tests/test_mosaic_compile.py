@@ -9,6 +9,7 @@ all such tests live in this one file.
 """
 
 import functools
+import re
 
 import pytest
 
@@ -473,3 +474,97 @@ def test_gigachats_longest_prefill_and_decode_fit_the_chip(one_chip):
     # the cache is never expanded: no per-head key or value of a context
     assert "bf16[128,8192,64," not in text
     assert decode.memory_analysis().temp_size_in_bytes < 64e6
+
+
+QWEN3_NEXT = dict(vocab_size=18992, n_layers=16, held=(0, 32),
+                  max_seq_len=2048)
+
+
+@pytest.mark.parametrize("lanes,slots", [(128, 129), (1, 129), (8, 9)])
+def test_mosaic_compiles_the_state_update_kernel(one_chip, lanes, slots):
+    """``gated_delta_step`` at Qwen3-Next's widths (16 key heads and 32
+    value heads of 128, a float32 state of 2 MB a lane) for the described
+    v5e: the pool is aliased input to output and the compiled program
+    holds no copy of it and no scratch beside it."""
+    import jax
+    import jax.numpy as jnp
+
+    from client_tpu.models import gated_delta
+
+    def shaped(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = (slots, 32, 128, 128)
+    compiled = jax.jit(
+        lambda q, k, v, g, beta, at, pool: gated_delta.gated_delta_step(
+            q, k, v, g, beta, at, pool, kernel="pallas"),
+        donate_argnums=(6,)).lower(
+        shaped((lanes, 16, 128)), shaped((lanes, 16, 128)),
+        shaped((lanes, 32, 128)), shaped((lanes, 32)), shaped((lanes, 32)),
+        shaped((lanes,), jnp.int32), shaped(pool)).compile()
+    text = compiled.as_text()
+    assert "%gated_delta_step" in text and "tpu_custom_call" in text
+    memory = compiled.memory_analysis()
+    pool_bytes = slots * 32 * 128 * 128 * 4
+    assert memory.alias_size_in_bytes >= pool_bytes
+    assert memory.temp_size_in_bytes < pool_bytes // 8
+    assert not [line for line in text.splitlines()
+                if " copy(" in line and f"f32[{slots},32,128,128]" in line]
+
+
+def test_qwen3_nexts_longest_prefill_and_decode_fit_the_chip(one_chip):
+    """`qwen3_next`'s 2,048-token prefill and its 128-lane decode step
+    compiled whole for the described v5e at the cell's sizes (16 layers,
+    32 held experts, 18,992 rows of vocabulary, a full group of 16,385
+    blocks, a state group of 129 slots). The bound: 10.1 GB of arguments
+    (4.54 of weights, 2.15 of K/V, 3.32 of states) and under 1 GB of
+    scratch in the prefill, 11 GB of the chip's 16; read here at
+    10,012,945,408 B of arguments, 248,639,488 B of scratch in the decode
+    step and 910,672,896 B in the prefill. No whole state pool (277 MB a
+    layer) is copied: every one is updated where it lies."""
+    import jax
+    import jax.numpy as jnp
+
+    from client_tpu.models import paged_attention, qwen3_next
+    from client_tpu.models.engine_model import Kernels
+
+    config = qwen3_next.Qwen3NextConfig(**QWEN3_NEXT)
+    kernels = Kernels("pallas", paged_attention.paged_attention_pallas)
+
+    def shaped(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    params = shaped(jax.eval_shape(
+        lambda: qwen3_next.init_params(jax.random.PRNGKey(0), config)))
+    pages = shaped(jax.eval_shape(
+        lambda: qwen3_next.init_pages(config, [16385, 129], BLOCK)))
+    ints = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.int32, sharding=one_chip)
+    decode = jax.jit(
+        lambda p, t, at, tables, pages: qwen3_next.decode_step_paged(
+            p, t, at, tables, pages, config, kernels),
+        donate_argnums=(4,)).lower(
+        params, ints(128), ints(128), ints(2, 128, 128), pages).compile()
+    memory = decode.memory_analysis()
+    assert memory.argument_size_in_bytes < 10.1e9
+    assert memory.temp_size_in_bytes < 300e6
+    text = decode.as_text()
+    assert text.count("%gated_delta_step") >= 12
+    assert text.count("%paged_attention") >= 4
+    assert text.count("%moe_experts") >= 16
+    pool = re.compile(r"= f32\[129,32,128,128\]\S* (copy|dynamic-update-slice|"
+                      r"scatter|broadcast)\(")
+    assert not [line for line in text.splitlines() if pool.search(line)]
+    prefill = jax.jit(
+        lambda p, t, table, pages, last: qwen3_next.prefill_into_pages(
+            p, t, table, pages, last, config, kernels),
+        donate_argnums=(3,)).lower(
+        params, ints(1, 2048), ints(2, 128), pages, ints()).compile()
+    memory = prefill.memory_analysis()
+    assert memory.argument_size_in_bytes < 10.1e9
+    assert memory.temp_size_in_bytes < 1.0e9
+    copied = re.compile(r"= f32\[129,32,128,128\]\S* copy\(")
+    assert not [line for line in prefill.as_text().splitlines()
+                if copied.search(line)]

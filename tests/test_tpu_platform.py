@@ -7,7 +7,9 @@ kernels COMPILED by Mosaic (the CPU tier only interprets them) against
 the fused XLA reference at the shapes the server serves, a full-width
 decode step through both, ``mimo_v2_flash.reason``'s attention groups
 and expert layer at the cell's sizes, ``gigachat3_702b.reason8k_128``'s
-one-pool latent call, expert layer and decode step, the client→server infer path
+one-pool latent call, expert layer and decode step,
+``qwen3_next_80b.reason2k_128``'s state-update kernel, paged call and
+decode step, the client→server infer path
 executing on the real platform, and the tpu-shm staging round-trip.
 ``python chip_smoke.py`` runs this tier on the chip as one of its phases.
 """
@@ -814,6 +816,194 @@ def test_gigachats_decode_step_agrees_through_both_kernel_choices(device):
     assert np.isfinite(kernel).all() and np.abs(plain).max() > 1.0
     worst = float(np.abs(kernel - plain).max())
     assert worst <= 2.0 ** -4 * max(1.0, float(np.abs(plain).max())), worst
+
+
+# ---------------------------------------------------------------------------
+# qwen3_next_80b.reason2k_128: the state-update kernel, the paged kernel at
+# KV 2 / D 256, the decode step
+# ---------------------------------------------------------------------------
+
+
+def _delta_lanes(lanes=128, slots=129, seed=21):
+    """A decode step's inputs of one DeltaNet layer at the published
+    widths (16 key heads and 32 value heads of 128): unit q and k, decays
+    between 0.74 and 0.9996, a pool of states of unit size whose slot 0
+    is zero, every fourth lane a batch bucket's padding."""
+    import jax
+    import jax.numpy as jnp
+
+    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
+    unit = lambda x: x * jax.lax.rsqrt(  # noqa: E731
+        jnp.square(x).sum(-1, keepdims=True) + 1e-6)
+    q = unit(jax.random.normal(keys[0], (lanes, 16, 128))) * 128 ** -0.5
+    k = unit(jax.random.normal(keys[1], (lanes, 16, 128)))
+    v = jax.random.normal(keys[2], (lanes, 32, 128))
+    g = -jnp.exp(jax.random.uniform(
+        keys[3], (lanes, 32), minval=np.log(4e-4), maxval=np.log(0.3)))
+    beta = jax.random.uniform(keys[4], (lanes, 32), minval=0.05, maxval=0.95)
+    pool = jax.random.normal(keys[5], (slots, 32, 128, 128)).at[0].set(0.0)
+    order = 1 + np.random.default_rng(seed).permutation(slots - 1)[:lanes]
+    order[::4] = 0
+    return q, k, v, g, beta, jnp.asarray(order, jnp.int32), pool
+
+
+def test_gated_delta_step_compiled_matches_the_gather_and_scatter(device):
+    """The state-update kernel compiled by Mosaic against the plain XLA
+    form at the cell's shapes (128 lanes over a pool of 129 slots, a
+    quarter of the lanes padding): float32 both ways, sums in another
+    order, so outputs and states agree to 1e-5 of their size (a state in
+    bf16 would be 4e-3 off); the slots of no lane are left bit for bit,
+    the trash slot holds zeros. Prints us a live lane and the share of
+    HBM's bandwidth of the states moved in and out."""
+    import jax
+
+    from client_tpu.models import gated_delta
+
+    q, k, v, g, beta, slots, pool = _delta_lanes()
+    kernel = jax.jit(lambda *a: gated_delta.gated_delta_step(
+        *a, kernel="pallas"))
+    plain = jax.jit(lambda *a: gated_delta.gated_delta_step(
+        *a, kernel="fused_xla"))
+    out, new = kernel(q, k, v, g, beta, slots, pool)
+    ref_out, ref_new = plain(q, k, v, g, beta, slots, pool)
+    out, new, ref_out, ref_new = map(np.asarray, (out, new, ref_out, ref_new))
+    assert np.isfinite(out).all() and np.abs(ref_out).max() > 0.1
+    assert np.abs(out - ref_out).max() <= 1e-5 * np.abs(ref_out).max()
+    assert np.abs(new - ref_new).max() <= 1e-5 * np.abs(ref_new).max()
+    live = np.asarray(slots)[np.asarray(slots) != 0]
+    untouched = np.setdiff1d(np.arange(1, len(new)), live)
+    assert (new[untouched] == np.asarray(pool)[untouched]).all()
+    assert not new[0].any() and not out[np.asarray(slots) == 0].any()
+    moved = len(live) * 2 * 32 * 128 * 128 * 4
+    for name, fn in (("kernel", kernel), ("plain XLA", plain)):
+        ms = _ms_a_call(fn, q, k, v, g, beta, slots, pool, calls=10)
+        print(f"gated_delta_step, {len(live)} live of 128 lanes, {name} "
+              f"(pool not donated: a copy of it rides along): {ms:.3f} ms "
+              f"a call, {1e3 * ms / len(live):.2f} us a live lane, "
+              f"{100 * moved / 819e9 / (ms / 1e3):.1f}% of HBM")
+
+
+def test_gated_delta_step_in_place_is_timed_with_the_pool_donated(device):
+    """The same call with its pool donated, as the decode program has it:
+    no copy of the pool, the step's own time. Prints ms a call, us a live
+    lane and the states' share of HBM's bandwidth."""
+    import time
+
+    import jax
+
+    from client_tpu.models import gated_delta
+
+    q, k, v, g, beta, slots, pool = _delta_lanes()
+    live = int((np.asarray(slots) != 0).sum())
+    step = jax.jit(
+        lambda q, k, v, g, beta, slots, pool: gated_delta.gated_delta_step(
+            q, k, v, g, beta, slots, pool, kernel="pallas"),
+        donate_argnums=(6,))
+    text = step.lower(q, k, v, g, beta, slots, pool).compile().as_text()
+    assert not [line for line in text.splitlines()
+                if " copy(" in line and "f32[129,32,128,128]" in line]
+    out, pool = step(q, k, v, g, beta, slots, pool)
+    jax.block_until_ready(pool)
+    best = float("inf")
+    for _ in range(3):
+        began = time.perf_counter()
+        for _ in range(20):
+            out, pool = step(q, k, v, g, beta, slots, pool)
+        jax.block_until_ready(pool)
+        best = min(best, (time.perf_counter() - began) / 20)
+    assert np.isfinite(np.asarray(out)).all()
+    moved = live * 2 * 32 * 128 * 128 * 4
+    print(f"gated_delta_step in place, {live} live of 128 lanes: "
+          f"{1e3 * best:.3f} ms a call, {1e6 * best / live:.2f} us a live "
+          f"lane, {100 * moved / 819e9 / best:.1f}% of HBM")
+
+
+def test_compiled_pallas_at_the_qwen3_next_cells_shapes(device):
+    """The paged kernel at KV 2 / D 256 (tiles of 16 pages, 8 query rows
+    a KV head), 128 lanes over contexts to 2,048, against plain XLA."""
+    import jax
+
+    from client_tpu.models import paged_attention as pa
+
+    rng = np.random.default_rng(31)
+    lanes, columns, kv, dim, heads = 128, 128, 2, 256, 16
+    assert pa.pages_per_tile(BLOCK * kv, 1, dim, np.dtype("bfloat16"), 2) == 16
+    keys = jax.random.split(jax.random.PRNGKey(31), 3)
+    pools = [_device_normal(key, (1 + lanes * columns, BLOCK * kv, dim), 1.0)
+             for key in keys[:2]]
+    tables = (1 + np.arange(lanes * columns)).reshape(
+        lanes, columns).astype(np.int32)
+    positions = rng.integers(0, columns * BLOCK, size=(lanes, 1)).astype(
+        np.int32)
+    positions[0] = columns * BLOCK - 1
+    live = positions // BLOCK + 1
+    tables = np.where(np.arange(columns)[None] < live, tables, 0).astype(
+        np.int32)
+    q = _device_normal(keys[2], (lanes, 1, heads, dim), 1.0)
+    kernel = jax.jit(lambda *a: pa.paged_attention_pallas(*a, kv_heads=kv))
+    plain = jax.jit(lambda *a: pa.paged_attention_xla(*a, kv_heads=kv))
+    args = (q, *pools, tables, positions)
+    _assert_bf16_close(kernel(*args), plain(*args),
+                       "qwen3_next's gated full attention")
+    ms = _ms_a_call(kernel, *args)
+    tokens = int((positions + 1).sum())
+    print(f"qwen3_next paged call, KV 2 / D 256, {tokens} cached tokens: "
+          f"{ms:.3f} ms a call, "
+          f"{100 * tokens * 2048 / 819e9 / (ms / 1e3):.1f}% of HBM")
+
+
+def test_qwen3_nexts_decode_step_agrees_through_both_kernel_choices(device):
+    """`qwen3_next`'s whole decode step at the published widths (one
+    period: three DeltaNet layers and a gated full-attention layer; 32
+    held experts of 512) after a prefill, through the load-time choices
+    ``pallas`` and ``fused_xla``: the logits agree to a few bf16 steps
+    and the states the two leave agree in float32."""
+    import jax
+
+    from client_tpu.models import paged_attention as pa, qwen3_next
+    from client_tpu.models.engine_model import Kernels
+
+    config = qwen3_next.Qwen3NextConfig(
+        vocab_size=4096, n_layers=4, held=(0, 32))
+    params = qwen3_next.init_params(jax.random.PRNGKey(5), config)
+    lanes, columns = 4, 128
+    prompts = [17, 300, 64, 511]
+
+    def run(name, attn):
+        kernels = Kernels(name, attn)
+        pages = qwen3_next.init_pages(
+            config, [1 + lanes * columns, 1 + lanes], BLOCK)
+        tables = np.zeros((2, lanes, columns), np.int32)
+        tables[0] = (1 + np.arange(lanes * columns)).reshape(lanes, columns)
+        tables[1, :, 0] = 1 + np.arange(lanes)
+        prefill = jax.jit(lambda *a: qwen3_next.prefill_into_pages(
+            *a, config, kernels))
+        for lane, prompt in enumerate(prompts):
+            tokens = np.zeros((1, 512), np.int32)
+            tokens[0, :prompt] = np.random.default_rng(lane).integers(
+                1, 4096, size=prompt)
+            _, pages = prefill(params, tokens, tables[:, lane], pages,
+                               prompt - 1)
+        decode = jax.jit(lambda *a: qwen3_next.decode_step_paged(
+            *a, config, kernels))
+        rows = []
+        for step in range(3):
+            logits, pages, counters = decode(
+                params, np.array([5, 6, 7, 8], np.int32) + step,
+                np.asarray(prompts, np.int32) + step, tables, pages)
+            rows.append(np.asarray(logits))
+        return np.stack(rows), np.asarray(counters), np.asarray(pages[0][0])
+
+    kernel, counted, state = run("pallas", pa.paged_attention_pallas)
+    plain, plain_counted, plain_state = run("fused_xla", pa.paged_attention_xla)
+    assert counted[3] == 4 and plain_counted[3] == 0
+    assert counted[4] == plain_counted[4] == 3 * lanes
+    assert np.isfinite(kernel).all() and np.abs(plain).max() > 1.0
+    worst = float(np.abs(kernel - plain).max())
+    assert worst <= 2.0 ** -4 * max(1.0, float(np.abs(plain).max())), worst
+    assert np.abs(state - plain_state).max() <= 2.0 ** -6 * np.abs(
+        plain_state).max()
+    assert not state[0].any()
 
 
 # ---------------------------------------------------------------------------
