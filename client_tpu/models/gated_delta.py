@@ -23,10 +23,12 @@ caller, ``g <= 0`` the head's log decay and ``beta`` in (0, 1). Key head
   bucket, the warm-up probes) write zeros there and read out zeros.
 - :func:`chunked_gated_delta`, a whole prompt in chunks of 64 tokens
   (the WY form of the published implementation's chunked rule): inside a
-  chunk the ``u`` of all its tokens come from one triangular solve, across
-  chunks the state is carried by a scan. Plain ``jax.numpy`` under every
-  kernel choice, float32 at ``highest`` matmul precision: the state a
-  prefill leaves is the one the recurrence would have left.
+  chunk the ``u`` of all its tokens come from the chunk's unit-lower-
+  triangular system, applied as its inverse by block recursion
+  (:func:`_solve_unit_lower`: batched matmuls, no row-by-row solve),
+  across chunks the state is carried by a scan. Plain ``jax.numpy`` under
+  every kernel choice, float32 at ``highest`` matmul precision: the state
+  a prefill leaves is the one the recurrence would have left.
 
 Nothing is stored narrower than float32: a state in bf16 is a different
 result (``tests/test_qwen3_next.py`` holds that it fails the comparison).
@@ -159,6 +161,41 @@ def gated_delta_step(q, k, v, g, beta, slots, state_pool, *, kernel: str):
     return jnp.where(live, out, 0.0), state_pool.at[slots].set(state)
 
 
+def _solve_unit_lower(lower, rhs):
+    """``lower^-1 @ rhs`` for unit-lower-triangular ``lower`` [..., C, C],
+    ``C`` a power of two, and ``rhs`` [..., C, N]: the inverse is built by
+    block recursion and applied with one product, so nothing walks the
+    rows one after another. ``[[A, 0], [X, B]]`` has the inverse
+    ``[[A^-1, 0], [-B^-1 X A^-1, B^-1]]``. ``inv`` holds the inverses of
+    the diagonal blocks of one size and zeros elsewhere (a lone row's is
+    1), and a level doubles that size with two products over the whole
+    matrix, all its blocks at once: ``cross`` is what ``lower`` holds
+    below the diagonal blocks inside the blocks of twice the size, so
+    ``inv @ cross @ inv`` is ``B^-1 X A^-1`` in each such place and zero
+    in every other. Exact in exact arithmetic, and no power of the
+    strictly lower part is ever formed: on a chunk of nearly collinear
+    keys those grow like binomial coefficients and cancel, which is what
+    rules out the product form ``(I - N)(I + N^2)(I + N^4)...``
+    (``tests/test_qwen3_next.py``)."""
+    size = lower.shape[-1]
+    if size & (size - 1):
+        raise ValueError(f"a chunk of {size} tokens is no power of two")
+    row, col = jnp.arange(size)[:, None], jnp.arange(size)[None, :]
+
+    def cross(block):
+        return jnp.where((row // (2 * block) == col // (2 * block))
+                         & (row // block > col // block), lower, 0.0)
+
+    inv = jnp.eye(size, dtype=lower.dtype) - cross(1)
+    block = 2
+    while block < size:
+        inv = inv - jnp.matmul(
+            jnp.matmul(inv, cross(block), precision=HIGHEST), inv,
+            precision=HIGHEST)
+        block *= 2
+    return jnp.matmul(inv, rhs, precision=HIGHEST)
+
+
 def chunked_gated_delta(q, k, v, g, beta, chunk: int = CHUNK):
     """A whole sequence from a zero state. ``q`` / ``k`` [L, Hk, Dk]
     (normalised, ``q`` scaled), ``v`` [L, Hv, Dv], ``g`` / ``beta`` [L,
@@ -186,10 +223,8 @@ def chunked_gated_delta(q, k, v, g, beta, chunk: int = CHUNK):
     k_beta, v_beta = k * beta[..., None], v * beta[..., None]
     inner = jnp.einsum("hnid,hnjd->hnij", k_beta, k, precision=HIGHEST)
     lower = jnp.where(row > col, inner * decay, 0.0) + jnp.eye(chunk)
-    solved = jax.scipy.linalg.solve_triangular(
-        lower, jnp.concatenate(
-            [v_beta, k_beta * jnp.exp(summed)[..., None]], axis=-1),
-        lower=True, unit_diagonal=True)
+    solved = _solve_unit_lower(lower, jnp.concatenate(
+        [v_beta, k_beta * jnp.exp(summed)[..., None]], axis=-1))
     own, carried = solved[..., :dv], solved[..., dv:]
     among = jnp.einsum("hnid,hnjd->hnij", q, k, precision=HIGHEST) * decay
 

@@ -512,16 +512,10 @@ def test_mosaic_compiles_the_state_update_kernel(one_chip, lanes, slots):
                 if " copy(" in line and f"f32[{slots},32,128,128]" in line]
 
 
-def test_qwen3_nexts_longest_prefill_and_decode_fit_the_chip(one_chip):
-    """`qwen3_next`'s 2,048-token prefill and its 128-lane decode step
-    compiled whole for the described v5e at the cell's sizes (16 layers,
-    32 held experts, 18,992 rows of vocabulary, a full group of 16,385
-    blocks, a state group of 129 slots). The bound: 10.1 GB of arguments
-    (4.54 of weights, 2.15 of K/V, 3.32 of states) and under 1 GB of
-    scratch in the prefill, 11 GB of the chip's 16; read here at
-    10,012,945,408 B of arguments, 248,639,488 B of scratch in the decode
-    step and 910,672,896 B in the prefill. No whole state pool (277 MB a
-    layer) is copied: every one is updated where it lies."""
+def _qwen3_next_at_the_cells_sizes(one_chip):
+    """(lowered decode step of ``lanes``, lowered prefill of ``tokens``)
+    of `qwen3_next` at the cell's sizes for the described v5e, as two
+    functions of those sizes."""
     import jax
     import jax.numpy as jnp
 
@@ -542,11 +536,36 @@ def test_qwen3_nexts_longest_prefill_and_decode_fit_the_chip(one_chip):
         lambda: qwen3_next.init_pages(config, [16385, 129], BLOCK)))
     ints = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
         shape, jnp.int32, sharding=one_chip)
-    decode = jax.jit(
-        lambda p, t, at, tables, pages: qwen3_next.decode_step_paged(
-            p, t, at, tables, pages, config, kernels),
-        donate_argnums=(4,)).lower(
-        params, ints(128), ints(128), ints(2, 128, 128), pages).compile()
+
+    def decode(lanes):
+        return jax.jit(
+            lambda p, t, at, tables, pages: qwen3_next.decode_step_paged(
+                p, t, at, tables, pages, config, kernels),
+            donate_argnums=(4,)).lower(
+            params, ints(lanes), ints(lanes), ints(2, lanes, 128), pages)
+
+    def prefill(tokens):
+        return jax.jit(
+            lambda p, t, table, pages, last: qwen3_next.prefill_into_pages(
+                p, t, table, pages, last, config, kernels),
+            donate_argnums=(3,)).lower(
+            params, ints(1, tokens), ints(2, 128), pages, ints())
+
+    return decode, prefill
+
+
+def test_qwen3_nexts_longest_prefill_and_decode_fit_the_chip(one_chip):
+    """`qwen3_next`'s 2,048-token prefill and its 128-lane decode step
+    compiled whole for the described v5e at the cell's sizes (16 layers,
+    32 held experts, 18,992 rows of vocabulary, a full group of 16,385
+    blocks, a state group of 129 slots). The bound: 10.1 GB of arguments
+    (4.54 of weights, 2.15 of K/V, 3.32 of states) and under 1 GB of
+    scratch in the prefill, 11 GB of the chip's 16; read here at
+    10,012,945,408 B of arguments, 248,639,488 B of scratch in the decode
+    step and 909,801,984 B in the prefill. No whole state pool (277 MB a
+    layer) is copied: every one is updated where it lies."""
+    decode, prefill = _qwen3_next_at_the_cells_sizes(one_chip)
+    decode = decode(128).compile()
     memory = decode.memory_analysis()
     assert memory.argument_size_in_bytes < 10.1e9
     assert memory.temp_size_in_bytes < 300e6
@@ -557,14 +576,33 @@ def test_qwen3_nexts_longest_prefill_and_decode_fit_the_chip(one_chip):
     pool = re.compile(r"= f32\[129,32,128,128\]\S* (copy|dynamic-update-slice|"
                       r"scatter|broadcast)\(")
     assert not [line for line in text.splitlines() if pool.search(line)]
-    prefill = jax.jit(
-        lambda p, t, table, pages, last: qwen3_next.prefill_into_pages(
-            p, t, table, pages, last, config, kernels),
-        donate_argnums=(3,)).lower(
-        params, ints(1, 2048), ints(2, 128), pages, ints()).compile()
+    prefill = prefill(2048).compile()
     memory = prefill.memory_analysis()
     assert memory.argument_size_in_bytes < 10.1e9
     assert memory.temp_size_in_bytes < 1.0e9
     copied = re.compile(r"= f32\[129,32,128,128\]\S* copy\(")
     assert not [line for line in prefill.as_text().splitlines()
                 if copied.search(line)]
+
+
+def test_qwen3_nexts_prefill_holds_no_triangular_solve(one_chip):
+    """The cell's 512-token prefill compiled whole for the described
+    v5e: the chunked rule inverts its chunks with batched products, so
+    the program holds no ``triangular-solve`` and none of the custom
+    calls XLA expands one into (``InvertDiagBlocksLowerTriangular``,
+    which walks a block's rows one after another: 0.69 ms a layer on the
+    chip). Its only custom calls on the device are the named Mosaic
+    kernels; the four others are the compiler's own bookkeeping of
+    buffers and gather indices and run nothing."""
+    _, prefill = _qwen3_next_at_the_cells_sizes(one_chip)
+    text = prefill(512).compile().as_text()
+    assert " triangular-solve(" not in text
+    calls = [line for line in text.splitlines() if " custom-call(" in line]
+    targets = {re.search(r'custom_call_target="([^"]+)"', line).group(1)
+               for line in calls}
+    assert targets - {"AllocateBuffer", "ConcatBitcast",
+                      "AssumeGatherIndicesInBound",
+                      "GatherScatterIndicesBitpacked"} == {"tpu_custom_call"}
+    kernels = {re.search(r"(%[A-Za-z_]+)[.\d]* = ", line).group(1)
+               for line in calls if '"tpu_custom_call"' in line}
+    assert kernels == {"%moe_experts"}

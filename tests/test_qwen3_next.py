@@ -250,17 +250,60 @@ def _token_by_token(q, k, v, g, beta):
     return np.stack(outs), np.asarray(state)
 
 
-@pytest.mark.parametrize("length,bucket", [
-    (150, 150), (64, 64), (1, 8), (37, 64), (100, 256), (129, 130)])
-def test_the_chunked_rule_equals_the_recurrence(length, bucket):
+#: head counts and sizes of the rule's comparisons: the toy's, and the
+#: published widths with the toy's heads and with the published 16 : 32
+RULE_SHAPES = {
+    "toy": dict(key_heads=2, heads=4, dk=16, dv=16),
+    "toy-heads-128": dict(key_heads=2, heads=4, dk=128, dv=128),
+    "published": dict(key_heads=16, heads=32, dk=128, dv=128),
+}
+
+
+def _adverse_inputs(kind, length, **shape):
+    """Prompts on which a chunk's triangular system is as hard as a
+    prompt can make it. ``collinear``: every key within 5% noise of one
+    direction (a prompt that all but repeats a token), ``beta`` 0.9-0.999
+    and a hundredth of the seeded decays, so the entries under the
+    diagonal are near 1. ``repeated``: one key (and one query) for every
+    token. ``padded``: ``beta = g = 0`` over the whole second chunk."""
+    q, k, v, g, beta = _rule_inputs(length, **shape)
+    rng = np.random.default_rng(7)
+    if kind == "collinear":
+        k = rng.normal(size=(1,) + k.shape[1:]) + 0.05 * rng.normal(
+            size=k.shape)
+        k = k / np.sqrt((k * k).sum(-1, keepdims=True))
+        beta = rng.uniform(0.9, 0.999, size=beta.shape)
+        g = 0.01 * g
+    elif kind == "repeated":
+        q, k = np.repeat(q[:1], length, 0), np.repeat(k[:1], length, 0)
+    elif kind == "padded":
+        real = ((np.arange(length) < 64) | (np.arange(length) >= 128))[:, None]
+        g, beta = g * real, beta * real
+    return [x.astype(np.float32) for x in (q, k, v, g, beta)]
+
+
+@pytest.mark.parametrize("kind,shape,length,bucket", [
+    ("seeded", "toy", 150, 150), ("seeded", "toy", 64, 64),
+    ("seeded", "toy", 1, 8), ("seeded", "toy", 37, 64),
+    ("seeded", "toy", 100, 256), ("seeded", "toy", 129, 130),
+    ("seeded", "published", 150, 150),
+    ("collinear", "toy-heads-128", 150, 150),
+    ("collinear", "published", 150, 150),
+    ("repeated", "toy-heads-128", 150, 150),
+    ("repeated", "published", 150, 150),
+    ("padded", "toy-heads-128", 150, 150),
+    ("padded", "published", 150, 150)])
+def test_the_chunked_rule_equals_the_recurrence(kind, shape, length, bucket):
     """Lengths that are no whole number of chunks of 64, and a prompt
     padded to its bucket with ``beta = 0`` and ``g = 0`` past its end
     (the padding's q, k and v are whatever the projections of the
     padding tokens gave, not zeros): outputs up to the last token and the
-    final state are the recurrence's."""
+    final state are the recurrence's. So they are on the adverse prompts
+    (:func:`_adverse_inputs`) at the published head size, where the
+    chunk's system is inverted on numbers near 1."""
     from client_tpu.models import gated_delta
 
-    q, k, v, g, beta = _rule_inputs(bucket)
+    q, k, v, g, beta = _adverse_inputs(kind, bucket, **RULE_SHAPES[shape])
     real = (np.arange(bucket) < length)[:, None]
     g, beta = g * real, beta * real
     out, state = gated_delta.chunked_gated_delta(q, k, v, g, beta)
@@ -269,6 +312,69 @@ def test_the_chunked_rule_equals_the_recurrence(length, bucket):
     assert np.abs(ref_out).max() > 0.01
     assert np.abs(np.asarray(out)[:length] - ref_out).max() <= 1e-5
     assert np.abs(np.asarray(state) - ref_state).max() <= 1e-5
+
+
+def _chunk_systems(kind, monkeypatch):
+    """The unit-lower-triangular systems ``chunked_gated_delta`` itself
+    forms on a prompt of three chunks, and their right-hand sides:
+    ``lower`` [H, 3, 64, 64] and ``rhs`` [H, 3, 64, Dv + Dk], float32."""
+    from client_tpu.models import gated_delta
+
+    seen = []
+    monkeypatch.setattr(gated_delta, "_solve_unit_lower",
+                        lambda lower, rhs: seen.append((lower, rhs)) or rhs)
+    gated_delta.chunked_gated_delta(
+        *_adverse_inputs(kind, 192, **RULE_SHAPES["toy-heads-128"]))
+    monkeypatch.undo()
+    return seen[0]
+
+
+def _product_form(lower, rhs):
+    """``(I - N)(I + N^2)(I + N^4)...(I + N^32)`` for ``lower = I + N``,
+    the inverse that is NOT used: exact in exact arithmetic as well."""
+    import jax.numpy as jnp
+
+    from client_tpu.models.gated_delta import HIGHEST
+
+    eye = jnp.eye(lower.shape[-1], dtype=lower.dtype)
+    power = lower - eye
+    inv = eye - power
+    for _ in range(5):
+        power = jnp.matmul(power, power, precision=HIGHEST)
+        inv = jnp.matmul(inv, eye + power, precision=HIGHEST)
+    return jnp.matmul(inv, rhs, precision=HIGHEST)
+
+
+@pytest.mark.parametrize("kind", ["seeded", "collinear"])
+def test_the_block_inverse_errs_no_more_than_the_triangular_solve(
+        kind, monkeypatch):
+    """``gated_delta._solve_unit_lower`` (the inverse by block recursion,
+    applied with one product) against a float64 ``numpy.linalg.solve`` of
+    the same systems: its error is within 4 x that of
+    ``jax.scipy.linalg.solve_triangular``, which it replaced, on the
+    seeded chunks and on chunks of nearly collinear keys. The plain
+    product form passes on the first and, on the second, is off by
+    more than a million (its powers grow like binomial coefficients and
+    cancel): the reason it is not the inverse used."""
+    from jax.scipy.linalg import solve_triangular
+
+    from client_tpu.models import gated_delta
+
+    lower, rhs = _chunk_systems(kind, monkeypatch)
+    exact = np.linalg.solve(np.asarray(lower, np.float64),
+                            np.asarray(rhs, np.float64))
+
+    def err(solved):
+        return float(np.abs(np.asarray(solved) - exact).max())
+
+    solve = err(solve_triangular(lower, rhs, lower=True, unit_diagonal=True))
+    assert 0 < solve < 1e-5
+    assert err(gated_delta._solve_unit_lower(lower, rhs)) <= 4 * solve
+    product = err(_product_form(lower, rhs))
+    if kind == "seeded":
+        assert product <= 4 * solve
+    else:
+        assert product > 1e6
 
 
 @pytest.mark.parametrize("kernel", ["fused_xla", "pallas_interpret"])
