@@ -10,8 +10,11 @@ A cache group is a set of layers whose cache a sequence keeps in the
 same way: ``full`` layers keep every block of the context, ``window``
 layers only the blocks a sliding window of ``window`` tokens can still
 see, ``state`` layers no K/V at all but a recurrent state of fixed size
-(a linear-attention layer's, ``models/qwen3_next.py``): ONE slot of the
-group's pools a sequence, whatever its length.
+(a linear-attention layer's matrix a head, ``models/qwen3_next.py``; a
+state-space layer's diagonal state a channel, ``models/jamba.py``): ONE
+slot of the group's pools a sequence, whatever its length. What a slot
+holds and how a step turns it is the model's own: the engine hands out
+slots and counts their bytes (``kv_row_bytes``), no more.
 Each group has pools of its own (a layer's K and V, the one pool of a
 model whose values lie inside its key rows, a state layer's state and
 convolution pools, whose leading size is slots and not blocks) and a

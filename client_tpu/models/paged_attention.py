@@ -237,7 +237,7 @@ def pages_per_tile(block_size: int, kv_heads: int, head_dim: int,
     function of the pools' shapes alone, so one program serves every
     batch, and every model finds its own tile: 8 pages (128 tokens) at
     KV 8 / D 128 / bf16, 2 at KV 32, 32 for a KV 2 tensor-parallel
-    shard.
+    shard, 64 at KV 1 / D 128 (a multi-query model's 4 KB pages).
 
     A ONE-POOL call gets a longer tile than those bytes hold. What a
     tile stop costs beyond its bytes (the copy's start and its latency

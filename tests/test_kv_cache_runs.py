@@ -549,3 +549,53 @@ def test_a_state_group_is_counted_in_slots_not_blocks(max_active, tile_pages):
         slots.allocate("one too many", 1)
     slots.free(0)
     assert slots.allocate("next", 1) == [held[0]]
+
+
+@pytest.mark.parametrize("pool", [65537, 40961])
+def test_jambas_cell_holds_a_slot_a_lane_and_its_blocks_in_runs_of_64(pool):
+    """``jamba2_3b.reason8k_128`` through the allocators alone: a full
+    group of two attention layers whose tile at KV 1 / D 128 is 64 pages
+    (a table row of 512 columns holds eight) beside a state group of 26
+    layers. The configuration's pool of 65,537 blocks is 128 lanes of
+    8,192 tokens and the trash block, so nothing can run dry whatever the
+    traffic; GigaChat's 40,961 under the same mix would do too (the
+    cell's steady state holds 128 x 4,352 tokens in whole runs). 128
+    sequences of 512 + 60 i tokens grow a token a step to 8,192 and are
+    followed by a fresh prompt of 512 in the lane: an admission always
+    finds its runs AND its slot, the slots held are the lanes running,
+    and a slot freed is the next one claimed."""
+    groups = (CacheGroup(FULL, (7, 21)),
+              CacheGroup(STATE, tuple(i for i in range(28) if i % 14 != 7)))
+    cell = EngineConfig(block_size=16, num_blocks=pool, max_active=128,
+                        max_seq_len=8192, cache_groups=groups)
+    assert cell.group_runs((64, 1)) == [64, 1]
+    assert cell.group_num_blocks((64, 1)) == [pool, 129]
+    blocks, slots = BlockAllocator(pool, 16, 64), BlockAllocator(129, 1)
+    at, fresh = {}, iter(range(1 << 30))
+
+    def admit(lane, prompt):
+        need = blocks.blocks_for(prompt + 1)
+        assert blocks.demand(need) <= blocks.free_blocks, (lane, prompt)
+        at[lane] = [next(fresh), prompt]
+        blocks.allocate(at[lane][0], need)
+        (slot,) = slots.allocate(at[lane][0], 1)
+        assert 1 <= slot <= 128
+        return slot
+
+    held = {lane: admit(lane, 512 + 60 * lane) for lane in range(128)}
+    assert sorted(held.values()) == list(range(1, 129))
+    completions = 0
+    for _ in range(1200):
+        for lane in range(128):
+            if at[lane][1] == 8192:
+                blocks.free(at[lane][0])
+                slots.free(at[lane][0])
+                completions += 1
+                assert admit(lane, 512) == held[lane]
+            seq, position = at[lane]
+            if position % 16 == 0:
+                blocks.extend(seq)
+            at[lane][1] = position + 1
+        assert slots.blocks_in_use == 128 and slots.free_blocks == 0
+    assert completions >= 19
+    assert blocks.blocks_in_use + blocks.blocks_reserved <= blocks.capacity

@@ -848,9 +848,11 @@ def test_genai_perf_drives_engine_end_to_end(llm_server, tmp_path, capsys):
 # ---------------------------------------------------------------------------
 
 
-def _state_engine(clock, seen, **overrides):
+def _state_engine(clock, seen, row_bytes=((64, 64), (1000, 1000)),
+                  **overrides):
     """A stub engine over a full group and a state group whose device
-    functions record the tables they are handed (``seen``)."""
+    functions record the tables they are handed (``seen``);
+    ``row_bytes`` is the model's ``kv_row_bytes``."""
     from client_tpu.models.engine_model import FULL, STATE, CacheGroup
 
     def prefill(tokens, page_table, pages, last_index, start):
@@ -884,7 +886,7 @@ def _state_engine(clock, seen, **overrides):
         model_name="stub",
         clock_ns=clock,
         attn_tile_pages=(2, 1),
-        kv_row_bytes=((64, 64), (1000, 1000)),
+        kv_row_bytes=row_bytes,
     )
     return holder["engine"]
 
@@ -977,3 +979,37 @@ def test_two_full_groups_beside_a_state_group_are_refused():
         _state_engine(_FakeClock(), [], cache_groups=(
             CacheGroup(FULL, (0,)), CacheGroup(FULL, (1,)),
             CacheGroup(STATE, (2,))))
+
+
+@pytest.mark.parametrize("layers,slot", [
+    # qwen3_next_80b: 12 DeltaNet layers of 2,146,304 B a slot to 4 full
+    (((3, 7, 11, 15), tuple(i for i in range(16) if i % 4 != 3)), 2146304),
+    # jamba2_3b: 26 Mamba layers of 358,400 B a slot to 2 attention layers
+    (((7, 21), tuple(i for i in range(28) if i % 14 != 7)), 358400),
+])
+def test_state_bytes_follow_the_models_slot_and_its_layers(layers, slot):
+    """Two state-group models of different shapes behind the one engine:
+    what ``stats()`` serves of a state group is the slots held times the
+    model's own bytes a slot times the group's layers, whichever model
+    gave them; nothing under ``llm/`` holds a state layer's shape."""
+    from client_tpu.models.engine_model import FULL, STATE, CacheGroup
+
+    seen = []
+    engine = _state_engine(
+        _FakeClock(), seen, row_bytes=((512, 512), (slot, slot)),
+        cache_groups=(CacheGroup(FULL, layers[0]),
+                      CacheGroup(STATE, layers[1])))
+
+    async def run():
+        seqs = [engine.submit([1 + i, 2, 3], max_tokens=6) for i in range(4)]
+        return [await _collect(s) for s in seqs]
+
+    assert all(len(tokens) == 6 for tokens in asyncio.run(run()))
+    running = [entry for kind, entry in seen if kind == "stats"]
+    assert max(s["state_slots_in_use"] for s in running) == 3
+    for mid in running:
+        assert mid["state_bytes_by_group"] == [
+            0, mid["state_slots_in_use"] * slot * len(layers[1])]
+        assert mid["kv_row_bytes_by_group"][1] == {
+            "stored": slot, "counted": slot}
+    assert engine.stats()["state_bytes_by_group"] == [0, 0]

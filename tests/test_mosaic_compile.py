@@ -606,3 +606,127 @@ def test_qwen3_nexts_prefill_holds_no_triangular_solve(one_chip):
     kernels = {re.search(r"(%[A-Za-z_]+)[.\d]* = ", line).group(1)
                for line in calls if '"tpu_custom_call"' in line}
     assert kernels == {"%moe_experts"}
+
+
+JAMBA_POOL = 65537  # blocks: 128 lanes of 8,192 tokens and the trash block
+
+
+@pytest.mark.parametrize("lanes,slots", [(128, 129), (1, 129), (8, 9)])
+def test_mosaic_compiles_the_selective_scan_kernel(one_chip, lanes, slots):
+    """``selective_scan_step`` at Jamba2-3B's widths (5,120 channels of 16
+    states, a float32 state of 327,680 B a lane) for the described v5e:
+    the pool is aliased input to output and the compiled program holds no
+    copy of it and no scratch beside it."""
+    import jax
+    import jax.numpy as jnp
+
+    from client_tpu.models import selective_scan
+
+    def shaped(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows, vectors = shaped((lanes, 5120)), shaped((lanes, 16))
+    compiled = jax.jit(
+        lambda u, delta, b, c, z, a, d_skip, at, pool:
+        selective_scan.selective_scan_step(
+            u, delta, b, c, z, a, d_skip, at, pool, kernel="pallas"),
+        donate_argnums=(8,)).lower(
+        rows, rows, vectors, vectors, rows, shaped((16, 5120)),
+        shaped((5120,)), shaped((lanes,), jnp.int32),
+        shaped((slots, 16, 5120))).compile()
+    text = compiled.as_text()
+    assert "%selective_scan_step" in text and "tpu_custom_call" in text
+    memory = compiled.memory_analysis()
+    pool_bytes = slots * 16 * 5120 * 4
+    assert memory.alias_size_in_bytes >= pool_bytes
+    assert memory.temp_size_in_bytes < max(pool_bytes // 8, 1 << 20)
+    assert not [line for line in text.splitlines()
+                if " copy(" in line and f"f32[{slots},16,5120]" in line]
+
+
+@pytest.mark.parametrize("lanes,columns", [(128, 512), (1, 8)])
+def test_mosaic_compiles_the_paged_kernel_at_jambas_shapes(
+        one_chip, lanes, columns):
+    """`jamba2_3b.reason8k_128` as the paged kernel sees it: ONE KV head
+    of 128 under 20 query heads (20 query rows a lane, no head mask), flat
+    pools whose page of 16 tokens is 4 KB, tiles of 64 pages (1,024
+    tokens, a `[20, 1024]` score block a stop; a table narrower than a
+    tile is one tile)."""
+    import jax
+    import jax.numpy as jnp
+
+    from client_tpu.models import paged_attention as pa
+
+    assert pa.pages_per_tile(BLOCK, 1, HEAD_DIM, jnp.bfloat16, 2) == 64
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = shaped((JAMBA_POOL, BLOCK, HEAD_DIM), jnp.bfloat16)
+    compiled = jax.jit(functools.partial(
+        pa.paged_attention_pallas, kv_heads=1)).lower(
+        shaped((lanes, 1, 20, HEAD_DIM), jnp.bfloat16), pool, pool,
+        shaped((lanes, columns), jnp.int32),
+        shaped((lanes, 1), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "%paged_attention" in text
+    assert f"bf16[{JAMBA_POOL},{BLOCK},{HEAD_DIM}]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
+
+
+def test_jambas_longest_prefill_and_decode_fit_the_chip(one_chip):
+    """`jamba`'s 8,192-token prefill and its 128-lane decode step compiled
+    whole for the described v5e at the cell's sizes: all 28 layers, all
+    65,536 rows, a full group of 65,537 blocks, a state group of 129
+    slots. The bound: 8.4 GB of arguments (6.06 of weights, 1.07 of K/V,
+    1.20 of states) and under 3.3 GB of scratch in the prefill, 11.7 GB
+    of the chip's 16; read here at 8,345,503,744 B of arguments,
+    193,160,704 B of scratch in the decode step and 3,042,985,984 B in
+    the prefill. No whole state pool (42 MB a layer) and no convolution
+    pool (4 MB) is copied: every one is updated where it lies."""
+    import jax
+    import jax.numpy as jnp
+
+    from client_tpu.models import jamba, paged_attention
+    from client_tpu.models.engine_model import Kernels
+
+    config = jamba.JambaConfig()
+    kernels = Kernels("pallas", paged_attention.paged_attention_pallas)
+
+    def shaped(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    params = shaped(jax.eval_shape(
+        lambda: jamba.init_params(jax.random.PRNGKey(0), config)))
+    pages = shaped(jax.eval_shape(
+        lambda: jamba.init_pages(config, [JAMBA_POOL, 129], BLOCK)))
+    ints = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.int32, sharding=one_chip)
+    decode = jax.jit(
+        lambda p, t, at, tables, pages: jamba.decode_step_paged(
+            p, t, at, tables, pages, config, kernels),
+        donate_argnums=(4,)).lower(
+        params, ints(128), ints(128), ints(2, 128, 512), pages).compile()
+    memory = decode.memory_analysis()
+    assert memory.argument_size_in_bytes < 8.4e9
+    assert memory.temp_size_in_bytes < 250e6
+    text = decode.as_text()
+    assert text.count("%selective_scan_step") >= 26
+    assert text.count("%paged_attention") >= 2
+    pool = re.compile(
+        r"= (f32\[129,16,5120\]|bf16\[129,15360\])\S* "
+        r"(copy|dynamic-update-slice|broadcast)\(")
+    assert not [line for line in text.splitlines() if pool.search(line)]
+    prefill = jax.jit(
+        lambda p, t, table, pages, last: jamba.prefill_into_pages(
+            p, t, table, pages, last, config, kernels),
+        donate_argnums=(3,)).lower(
+        params, ints(1, 8192), ints(2, 512), pages, ints()).compile()
+    memory = prefill.memory_analysis()
+    assert memory.argument_size_in_bytes < 8.4e9
+    assert memory.temp_size_in_bytes < 3.3e9
+    copied = re.compile(r"= (f32\[129,16,5120\]|bf16\[129,15360\])\S* copy\(")
+    assert not [line for line in prefill.as_text().splitlines()
+                if copied.search(line)]

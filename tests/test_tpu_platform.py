@@ -9,7 +9,8 @@ decode step through both, ``mimo_v2_flash.reason``'s attention groups
 and expert layer at the cell's sizes, ``gigachat3_702b.reason8k_128``'s
 one-pool latent call, expert layer and decode step,
 ``qwen3_next_80b.reason2k_128``'s state-update kernel, chunked rule,
-paged call and decode step, the client→server infer path
+paged call and decode step, ``jamba2_3b.reason8k_128``'s scan kernel,
+chunked scan, paged call and decode step, the client→server infer path
 executing on the real platform, and the tpu-shm staging round-trip.
 ``python chip_smoke.py`` runs this tier on the chip as one of its phases.
 """
@@ -1035,6 +1036,254 @@ def test_qwen3_nexts_decode_step_agrees_through_both_kernel_choices(device):
     plain, plain_counted, plain_state = run("fused_xla", pa.paged_attention_xla)
     assert counted[3] == 4 and plain_counted[3] == 0
     assert counted[4] == plain_counted[4] == 3 * lanes
+    assert np.isfinite(kernel).all() and np.abs(plain).max() > 1.0
+    worst = float(np.abs(kernel - plain).max())
+    assert worst <= 2.0 ** -4 * max(1.0, float(np.abs(plain).max())), worst
+    assert np.abs(state - plain_state).max() <= 2.0 ** -6 * np.abs(
+        plain_state).max()
+    assert not state[0].any()
+
+
+# ---------------------------------------------------------------------------
+# jamba2_3b.reason8k_128: the selective scan's decode kernel and its prefill
+# in chunks, the paged kernel at KV 1 / D 128, the decode step
+# ---------------------------------------------------------------------------
+
+
+def _scan_lanes(lanes=128, slots=129, seed=23):
+    """A decode step's inputs of one Mamba layer at the published widths
+    (5,120 channels of 16 states): steps of 0.001-0.2, ``A = -1 .. -16``,
+    a pool of states of unit size whose slot 0 is zero, every fourth lane
+    a batch bucket's padding. ``(u, delta, b, c, z, a, d_skip, slots,
+    pool)``."""
+    import jax
+    import jax.numpy as jnp
+
+    keys = jax.random.split(jax.random.PRNGKey(seed), 7)
+    channels, states = 5120, 16
+    u = jax.random.normal(keys[0], (lanes, channels))
+    z = jax.random.normal(keys[1], (lanes, channels))
+    delta = jnp.exp(jax.random.uniform(
+        keys[2], (lanes, channels), minval=np.log(1e-3), maxval=np.log(0.2)))
+    b = jax.random.normal(keys[3], (lanes, states))
+    c = jax.random.normal(keys[4], (lanes, states))
+    a = -jnp.broadcast_to(
+        jnp.arange(1.0, states + 1)[:, None], (states, channels))
+    d_skip = 1 + 0.1 * jax.random.normal(keys[5], (channels,))
+    pool = jax.random.normal(keys[6], (slots, states, channels)).at[0].set(0.0)
+    order = 1 + np.random.default_rng(seed).permutation(slots - 1)[:lanes]
+    order[::4] = 0
+    return u, delta, b, c, z, a, d_skip, jnp.asarray(order, jnp.int32), pool
+
+
+SCAN_STATE_BYTES = 16 * 5120 * 4
+
+
+def test_selective_scan_step_compiled_matches_the_gather_and_scatter(device):
+    """The scan's decode kernel compiled by Mosaic against the plain XLA
+    form at the cell's shapes (128 lanes over a pool of 129 slots, a
+    quarter of the lanes padding): float32 both ways, sums in another
+    order and the chip's own exponential, so outputs and states agree to
+    1e-5 of their size (a state in bf16 would be 4e-3 off); the slots of
+    no lane are left bit for bit, the trash slot holds zeros. Prints us a
+    live lane and the share of HBM's bandwidth of the states moved in and
+    out."""
+    import jax
+
+    from client_tpu.models import selective_scan
+
+    args = _scan_lanes()
+    slots, pool = args[-2:]
+    kernel = jax.jit(lambda *a: selective_scan.selective_scan_step(
+        *a, kernel="pallas"))
+    plain = jax.jit(lambda *a: selective_scan.selective_scan_step(
+        *a, kernel="fused_xla"))
+    out, new = kernel(*args)
+    ref_out, ref_new = plain(*args)
+    out, new, ref_out, ref_new = map(np.asarray, (out, new, ref_out, ref_new))
+    assert np.isfinite(out).all() and np.abs(ref_out).max() > 0.1
+    assert np.abs(out - ref_out).max() <= 1e-5 * np.abs(ref_out).max()
+    assert np.abs(new - ref_new).max() <= 1e-5 * np.abs(ref_new).max()
+    live = np.asarray(slots)[np.asarray(slots) != 0]
+    untouched = np.setdiff1d(np.arange(1, len(new)), live)
+    assert (new[untouched] == np.asarray(pool)[untouched]).all()
+    assert not new[0].any() and not out[np.asarray(slots) == 0].any()
+    moved = len(live) * 2 * SCAN_STATE_BYTES
+    for name, fn in (("kernel", kernel), ("plain XLA", plain)):
+        ms = _ms_a_call(fn, *args, calls=10)
+        print(f"selective_scan_step, {len(live)} live of 128 lanes, {name} "
+              f"(pool not donated: a copy of it rides along): {ms:.3f} ms "
+              f"a call, {1e3 * ms / len(live):.2f} us a live lane, "
+              f"{100 * moved / 819e9 / (ms / 1e3):.1f}% of HBM")
+
+
+def test_selective_scan_step_in_place_is_timed_with_the_pool_donated(device):
+    """The same call with its pool donated, as the decode program has it:
+    no copy of the pool. Prints ms a call, us a lane and the states' share
+    of HBM's bandwidth; a lone call of 0.14 ms of kernel is bound by its
+    dispatch from the host (0.34 ms a call, my chip run, PR 40), so the
+    kernel's own time is the cell's trace's (`ssm.step_roofline`)."""
+    import time
+
+    import jax
+
+    from client_tpu.models import selective_scan
+
+    *rows, slots, pool = _scan_lanes()
+    live = int((np.asarray(slots) != 0).sum())
+    step = jax.jit(
+        lambda u, delta, b, c, z, a, d_skip, slots, pool:
+        selective_scan.selective_scan_step(
+            u, delta, b, c, z, a, d_skip, slots, pool, kernel="pallas"),
+        donate_argnums=(8,))
+    text = step.lower(*rows, slots, pool).compile().as_text()
+    assert not [line for line in text.splitlines()
+                if " copy(" in line and "f32[129,16,5120]" in line]
+    out, pool = step(*rows, slots, pool)
+    jax.block_until_ready(pool)
+    best = float("inf")
+    for _ in range(3):
+        began = time.perf_counter()
+        for _ in range(20):
+            out, pool = step(*rows, slots, pool)
+        jax.block_until_ready(pool)
+        best = min(best, (time.perf_counter() - began) / 20)
+    assert np.isfinite(np.asarray(out)).all()
+    moved = live * 2 * SCAN_STATE_BYTES
+    print(f"selective_scan_step in place, {live} live of 128 lanes: "
+          f"{1e3 * best:.3f} ms a call, {1e6 * best / 128:.2f} us a lane, "
+          f"{100 * moved / 819e9 / best:.1f}% of HBM")
+
+
+def _associative_chunk(state, decay, drive, c):
+    """A chunk by ``lax.associative_scan`` over its tokens: the form
+    `selective_scan._scan_chunk` is timed against."""
+    import jax
+
+    def combine(left, right):
+        return left[0] * right[0], right[0] * left[1] + right[1]
+
+    carried, driven = jax.lax.associative_scan(combine, (decay, drive))
+    h = driven + carried * state
+    return h[-1], (h * c[:, :, None]).sum(axis=1)
+
+
+@pytest.mark.parametrize("tokens", [512, 8192])
+def test_the_chunked_scan_walks_a_chunk_faster_than_an_associative_scan(
+        device, monkeypatch, tokens):
+    """``chunked_selective_scan`` alone at the cell's shapes (a prompt of
+    512 tokens, and the longest re-prefill's 8,192; 5,120 channels of 16
+    states) with a chunk's tokens walked one after another, as the
+    program has them, against the same function with
+    ``lax.associative_scan`` inside a chunk: float32 both ways, so outputs
+    and the state left agree to 1e-5 of their size, and the walk is the
+    faster (0.54 against 1.25 ms at 512 tokens, 6.5 against 19.5 at
+    8,192: my chip run, PR 40; the associative scan makes log2(64) passes
+    over a chunk's `[64, 16, 5120]` arrays). Prints both times."""
+    import jax
+
+    from client_tpu.models import selective_scan
+
+    u, delta, b, c, z, a, d_skip = _scan_lanes(lanes=tokens, slots=2)[:7]
+    args = (u, delta, b, c, z, a, d_skip)
+    walked = jax.jit(lambda *a: selective_scan.chunked_selective_scan(*a))
+    out, state = walked(*args)
+    walked_ms = _ms_a_call(walked, *args, calls=5)
+    monkeypatch.setattr(selective_scan, "_scan_chunk", _associative_chunk)
+    scanned = jax.jit(lambda *a: selective_scan.chunked_selective_scan(*a))
+    ref_out, ref_state = scanned(*args)
+    scanned_ms = _ms_a_call(scanned, *args, calls=5)
+    assert np.isfinite(np.asarray(out)).all()
+    assert np.abs(np.asarray(ref_state)).max() > 0.01
+    scale = float(np.abs(np.asarray(ref_out)).max())
+    assert np.abs(np.asarray(out) - np.asarray(ref_out)).max() <= 1e-5 * scale
+    assert np.abs(np.asarray(state) - np.asarray(ref_state)).max() <= 1e-5
+    print(f"chunked_selective_scan, {tokens} tokens, 5,120 channels of 16 "
+          f"states: {walked_ms:.3f} ms a call with a chunk's tokens walked, "
+          f"{scanned_ms:.3f} ms by associative_scan inside a chunk")
+    assert walked_ms < scanned_ms
+
+
+def test_compiled_pallas_at_the_jamba_cells_shapes(device):
+    """The paged kernel at KV 1 / D 128 (tiles of 64 pages, 20 query rows
+    a lane, no head mask), 128 lanes over contexts to 8,192, against
+    plain XLA."""
+    import jax
+
+    from client_tpu.models import paged_attention as pa
+
+    rng = np.random.default_rng(37)
+    lanes, columns, kv, dim, heads = 128, 512, 1, 128, 20
+    assert pa.pages_per_tile(BLOCK * kv, 1, dim, np.dtype("bfloat16"), 2) == 64
+    keys = jax.random.split(jax.random.PRNGKey(37), 3)
+    pools = [_device_normal(key, (1 + lanes * columns, BLOCK * kv, dim), 1.0)
+             for key in keys[:2]]
+    tables = (1 + np.arange(lanes * columns)).reshape(
+        lanes, columns).astype(np.int32)
+    positions = rng.integers(0, columns * BLOCK, size=(lanes, 1)).astype(
+        np.int32)
+    positions[0] = columns * BLOCK - 1
+    live = positions // BLOCK + 1
+    tables = np.where(np.arange(columns)[None] < live, tables, 0).astype(
+        np.int32)
+    q = _device_normal(keys[2], (lanes, 1, heads, dim), 1.0)
+    kernel = jax.jit(lambda *a: pa.paged_attention_pallas(*a, kv_heads=kv))
+    plain = jax.jit(lambda *a: pa.paged_attention_xla(*a, kv_heads=kv))
+    args = (q, *pools, tables, positions)
+    _assert_bf16_close(kernel(*args), plain(*args),
+                       "jamba's multi-query attention")
+    ms = _ms_a_call(kernel, *args)
+    tokens = int((positions + 1).sum())
+    print(f"jamba paged call, KV 1 / D 128, {tokens} cached tokens: "
+          f"{ms:.3f} ms a call, "
+          f"{100 * tokens * 512 / 819e9 / (ms / 1e3):.1f}% of HBM")
+
+
+def test_jambas_decode_step_agrees_through_both_kernel_choices(device):
+    """`jamba`'s whole decode step at the published widths (four layers:
+    three Mamba layers and a multi-query attention layer) after a
+    prefill, through the load-time choices ``pallas`` and ``fused_xla``:
+    the logits agree to a few bf16 steps and the states the two leave
+    agree in float32."""
+    import jax
+
+    from client_tpu.models import jamba, paged_attention as pa
+    from client_tpu.models.engine_model import Kernels
+
+    config = jamba.JambaConfig(
+        vocab_size=4096, n_layers=4, attn_period=4, attn_offset=2)
+    params = jamba.init_params(jax.random.PRNGKey(5), config)
+    lanes, columns = 4, 128
+    prompts = [17, 300, 64, 511]
+
+    def run(name, attn):
+        kernels = Kernels(name, attn)
+        pages = jamba.init_pages(
+            config, [1 + lanes * columns, 1 + lanes], BLOCK)
+        tables = np.zeros((2, lanes, columns), np.int32)
+        tables[0] = (1 + np.arange(lanes * columns)).reshape(lanes, columns)
+        tables[1, :, 0] = 1 + np.arange(lanes)
+        prefill = jax.jit(lambda *a: jamba.prefill_into_pages(
+            *a, config, kernels))
+        for lane, prompt in enumerate(prompts):
+            tokens = np.zeros((1, 512), np.int32)
+            tokens[0, :prompt] = np.random.default_rng(lane).integers(
+                1, 4096, size=prompt)
+            _, pages = prefill(params, tokens, tables[:, lane], pages,
+                               prompt - 1)
+        decode = jax.jit(lambda *a: jamba.decode_step_paged(
+            *a, config, kernels))
+        rows = []
+        for step in range(3):
+            logits, pages, counters = decode(
+                params, np.array([5, 6, 7, 8], np.int32) + step,
+                np.asarray(prompts, np.int32) + step, tables, pages)
+            rows.append(np.asarray(logits))
+        return np.stack(rows), np.asarray(counters), np.asarray(pages[0][0])
+
+    kernel, counted, state = run("pallas", pa.paged_attention_pallas)
+    plain, plain_counted, plain_state = run("fused_xla", pa.paged_attention_xla)
+    assert counted[0] == plain_counted[0] == 3 * lanes
     assert np.isfinite(kernel).all() and np.abs(plain).max() > 1.0
     worst = float(np.abs(kernel - plain).max())
     assert worst <= 2.0 ** -4 * max(1.0, float(np.abs(plain).max())), worst
