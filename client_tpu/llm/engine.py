@@ -36,7 +36,10 @@ paged-attention model functions (``models/llama.py``):
   loop builds step k+1 from what it knows without them (positions + 1,
   blocks grown for them, lanes that end at k left out, a map from each
   lane to its lane in k), dispatches it, and only then reads, books and
-  streams k's tokens: the host's work runs under the device's. See
+  streams k's tokens: the host's work runs under the device's. An
+  admission does not drain that queue: the prompt's prefill is
+  dispatched behind the step in flight, un-waited, the loop runs ahead
+  across it, and the new lane is booked when its logits arrive. See
   :class:`LlmEngine` for when the step in flight is consumed first.
 - **lap spans**: the step loop's wall time is tiled by named phase
   (:data:`PHASES`; one clock read at each phase boundary), as monotone
@@ -278,18 +281,23 @@ class EngineConfig:
 
 #: The step loop's phases (``stats()["phase_ns"]`` keys, ``engine.<phase>``
 #: trace annotations). They tile the loop's un-parked wall time:
-#: ``schedule`` prune, admission without its prefills, block growth and
+#: ``schedule`` prune, admission without its prefills (a new lane's
+#: first token is drawn and streamed here), block growth and
 #: preemption, building the step's arrays, the COW check, ``_publish``;
-#: ``prefill`` each ``_prefill_one`` (dispatch, device, read-back);
+#: ``prefill`` each ``_prefill_one``: building a prompt's arrays and the
+#: device call until it returns un-waited arrays (not the device's
+#: time: the prefill is waited for where its admission is completed);
 #: ``propose`` the speculative drafts; ``dispatch`` the device call until
 #: it returns un-waited arrays; ``wait`` blocked on the result of the
 #: step being consumed, which for a step that ran ahead is what is left
 #: of the device's time once the host's own work is done (no longer the
-#: device's step); ``readback`` the copy to the host of that step's ids
-#: and counters, or of its logits where a lane samples; ``sample``
-#: ``_sample_rows`` (on an all-greedy step it only passes the program's
-#: argmax through); ``emit`` booking and streaming the tokens, metrics hooks;
-#: ``yield`` the ``asyncio.sleep(0)``: everything else on the event loop.
+#: device's step), and on the logits of an admission being completed;
+#: ``readback`` the copy to the host of that step's ids and counters,
+#: or of its logits where a lane samples, and of a prefill's logits
+#: row; ``sample`` ``_sample_rows`` (on an all-greedy step it only passes
+#: the program's argmax through); ``emit`` booking and streaming the
+#: tokens, metrics hooks; ``yield`` the ``asyncio.sleep(0)``: everything
+#: else on the event loop.
 PHASES = (
     "schedule", "prefill", "propose", "dispatch", "wait", "readback",
     "sample", "emit", "yield",
@@ -307,6 +315,28 @@ def _wait_ready(result: Any) -> None:
     wait = getattr(result, "block_until_ready", None)
     if wait is not None:
         wait()
+
+
+def _start_copy(result: Any) -> None:
+    """Start the copy to the host of a device call's result that the
+    host will read: it then begins when the program ends, not when the
+    loop gets round to asking for it. Plain numpy has nothing to copy."""
+    start_copy = getattr(result, "copy_to_host_async", None)
+    if start_copy is not None:
+        start_copy()
+
+
+class _Admission:
+    """One dispatched prefill whose sequence is not booked yet: it holds
+    its blocks, rings and slots and is neither waiting nor running.
+    ``logits`` is the prefill's ``[1, V]`` result as the un-waited
+    device array it is."""
+
+    __slots__ = ("seq", "logits")
+
+    def __init__(self, seq, logits=None):
+        self.seq: "Sequence" = seq
+        self.logits = logits
 
 
 class _Flight:
@@ -594,14 +624,13 @@ class LlmEngine:
     it), dispatches it with ``prev_ids`` = the ids in flight, and only
     then waits for, books and streams the step in flight
     (``stats()["steps_ahead"]`` counts the steps dispatched so). At most
-    one step is unconsumed at any time. The loop decides from its own
-    state, never from a setting, and consumes the step in flight
-    *first* — so that everything below sees ``generated`` and the block
-    lists as a loop that never ran ahead would — before it
+    one decode step **and any number of prefills** are unconsumed at any
+    time. The loop decides from its own state, never from a setting, and
+    consumes the step in flight *first* — so that everything below sees
+    ``generated`` and the block lists as a loop that never ran ahead
+    would — before it
 
-    - admits a waiting request or fails one that can never fit
-      (``_admit``: the re-prefill of ``prompt + generated`` after a
-      preemption or an :meth:`adopt`, and ``allocator.publish``);
+    - fails a waiting request that can never fit (``_admit``);
     - preempts a victim or fails a sequence because the pool is dry
       (``_grow``);
     - parks.
@@ -614,6 +643,29 @@ class LlmEngine:
     token of it was streamed, so a survivor's re-prefilled stream is
     what it would have been. A device failure therefore surfaces up to
     one step later, at the next dispatch or at the wait for the ids.
+
+    **An admission pending.** A waiting request that fits as the books
+    stand (its blocks are free; running lanes and pending admissions
+    together are under ``max_active``) is admitted without consuming
+    anything: ``_admit`` allocates its blocks, rings and slots and
+    dispatches its prefill *behind* the step in flight, un-waited (the
+    pools chain through ``pages``, so the device runs the step, then the
+    prefill, in order; ``stats()["prefills_behind"]`` counts the prefills
+    dispatched so), and publishes the prompt's blocks to the prefix
+    index at once: whoever matches them is dispatched later and so runs
+    behind the prefill. What consuming the step could have freed (a lane
+    that ends at it) is seen one iteration later. The sequence is then an
+    *admission pending* (``_admitting``, in admission order): neither
+    waiting nor running, no lane of the step that ``_step`` dispatches
+    next, which therefore runs ahead like any other. The top of the next
+    ``_admit`` completes it: waits for the logits, draws the first token
+    from them on the host (same key as ever), streams it, and the
+    sequence joins the running lanes with ``last_token`` from the host
+    (``lane_map`` -1), or ends there. A speculative engine completes an
+    admission in the iteration that dispatched it. A pending sequence
+    that is cancelled gives back what it holds when its turn to be
+    completed comes, and streams nothing; ``close``, a failure and
+    ``_quarantine`` see it as they see a running lane.
     ``metrics`` implements the ServerMetrics LLM hooks (set_kv_blocks /
     set_llm_sequences / observe_llm_step / observe_llm_preemption /
     observe_prefix_hits / observe_rejection / observe_llm_speculation);
@@ -737,10 +789,11 @@ class LlmEngine:
         self._executor = executor
         self._waiting = PriorityQueue(levels=engine_config.priority_levels)
         self._running: List[Sequence] = []
-        # the one sequence mid-prefill in _admit: it owns blocks but is
-        # in neither _waiting nor _running, so shutdown/failure cleanup
-        # must cover it explicitly
-        self._admitting: Optional[Sequence] = None
+        # the admissions pending, in admission order: each sequence's
+        # prefill is dispatched and it owns blocks, but it is in neither
+        # _waiting nor _running, so shutdown/failure cleanup must cover
+        # them explicitly
+        self._admitting: List[_Admission] = []
         self._seq_counter = 0
         self._task: Optional[asyncio.Task] = None
         self._wake: Optional[asyncio.Event] = None
@@ -769,6 +822,9 @@ class LlmEngine:
         self._flight: Optional[_Flight] = None
         self._ids: Any = np.zeros([engine_config.ids_width], dtype=np.int32)
         self.steps_ahead = 0
+        # prefills dispatched behind a decode step in flight (which was
+        # not consumed for them)
+        self.prefills_behind = 0
         # decode-step emissions only (prefill first-tokens excluded) and
         # the lane-steps that produced them (one per live lane per
         # step): step_tokens / lane_steps is the tokens-per-step A/B
@@ -1029,14 +1085,15 @@ class LlmEngine:
         )
 
     def _fail_all(self, error: BaseException) -> None:
-        """Free and fail every live sequence — running, waiting, and the
-        one possibly mid-prefill — so no consumer hangs and no KV block
-        leaks. Idempotent (free is; fail on a done sequence is inert)."""
+        """Free and fail every live sequence — running, waiting, and
+        those whose admission is pending — so no consumer hangs and no
+        KV block leaks. Idempotent (free is; fail on a done sequence is
+        inert)."""
         self._flight = None  # dropped unbooked: nothing of it streamed
-        if self._admitting is not None:
-            self._free_blocks(self._admitting)
-            self._admitting.fail(error)
-            self._admitting = None
+        for pending in self._admitting:
+            self._free_blocks(pending.seq)
+            pending.seq.fail(error)
+        self._admitting.clear()
         for seq in self._running:
             self._free_blocks(seq)
             seq.fail(error)
@@ -1095,9 +1152,9 @@ class LlmEngine:
             else:
                 seq.fail(error)
 
-        if self._admitting is not None:
-            triage(self._admitting)
-            self._admitting = None
+        for pending in self._admitting:
+            triage(pending.seq)
+        self._admitting.clear()
         for seq in self._running:
             triage(seq)
         self._running.clear()
@@ -1269,6 +1326,8 @@ class LlmEngine:
             "phase_ns": dict(self._laps.ns),
             **self._laps.record(),
             "prefills": self.prefills,
+            # those of them dispatched behind a decode step in flight
+            "prefills_behind": self.prefills_behind,
             "admitted": self.admitted,
             "queue_wait_ns": self.queue_wait_ns,
         }
@@ -1415,6 +1474,7 @@ class LlmEngine:
                 if (
                     not self._running
                     and not len(self._waiting)
+                    and not self._admitting
                     and self._flight is None
                 ):
                     laps.park(self.steps)
@@ -1480,8 +1540,8 @@ class LlmEngine:
         return max(0, (context_len - 1) // self.allocator.block_size)
 
     async def _admit(self) -> None:
-        """Prefill waiting sequences into the running batch, in
-        (priority, arrival) order, while the block pool and the
+        """Book the admissions pending, then admit waiting sequences:
+        in (priority, arrival) order, while the block pool and the
         ``max_active`` bound allow. The first blocker stops admission —
         a full cache queues behind it rather than skipping ahead (no
         starvation of large prompts). Prompt blocks already in the shared
@@ -1489,14 +1549,23 @@ class LlmEngine:
         NEW blocks only) and their prefill is skipped: TTFT is one
         partial prefill of the unshared suffix.
 
-        With a step in flight the scan only looks: before the first
-        request is admitted or failed the step is consumed, and the scan
-        starts again over what that booked (a lane that ended, its
-        blocks freed)."""
+        A request that fits is admitted as the books stand: its prefill
+        is dispatched behind the step in flight, if there is one, and
+        waited for by the next call (or by this one in a speculative
+        engine). Only a request that can never fit has the step in
+        flight consumed before it is failed, and the scan starts again
+        over what that booked."""
+        self._complete_admissions()
+        await self._admit_waiting()
+
+    async def _admit_waiting(self) -> None:
         allocator = self.allocator
         for item in self._waiting.scan():
             seq: Sequence = item.value
-            if len(self._running) >= self.config.max_active:
+            if (
+                len(self._running) + len(self._admitting)
+                >= self.config.max_active
+            ):
                 break
             context = seq.context
             # +1: the first decode step writes the freshly-sampled
@@ -1518,14 +1587,11 @@ class LlmEngine:
             )
             # what the allocation takes of the pool: whole runs
             demand = allocator.demand(need, usable)
-            if self._flight is not None and (
-                demand > allocator.capacity
-                or demand <= allocator.free_blocks
-            ):
-                self._consume(self._flight)
-                await self._admit()
-                return
             if demand > allocator.capacity:
+                if self._flight is not None:
+                    self._consume(self._flight)
+                    await self._admit_waiting()
+                    return
                 # admitted on the strength of a shared prefix that has
                 # since been reclaimed (its sharers finished): the
                 # residual demand can never be satisfied — fail cleanly
@@ -1567,33 +1633,59 @@ class LlmEngine:
                 slot_allocator.allocate(seq.seq_id, 1)[0]
                 for _, slot_allocator in self._states
             ]
-            # visible to _fail_all while the prefill await is in flight:
-            # the sequence owns blocks but is in neither queue nor batch.
-            # Deliberately NOT cleared in a finally — on cancellation or
-            # device failure it must still be set when the _run handlers
-            # reclaim it; only a successful prefill clears it here.
-            self._admitting = seq
+            # visible to _fail_all and _quarantine from here on: the
+            # sequence owns blocks but is in neither queue nor batch.
+            # Deliberately NOT dropped in a finally — on cancellation or
+            # device failure it must still be listed when the _run
+            # handlers reclaim it; only its completion takes it off.
+            pending = _Admission(seq)
+            self._admitting.append(pending)
             now_ns = self._laps.enter("prefill")
             if seq.submitted_ns is not None:
                 self.queue_wait_ns += now_ns - seq.submitted_ns
                 self.admitted += 1
                 seq.submitted_ns = None
-            logits = await self._prefill_one(
+            pending.logits = await self._prefill_one(
                 seq, context, matched * allocator.block_size
             )
             self._laps.enter("schedule")
-            # the sequence's full prompt blocks (matched + just
-            # prefilled) now hold valid K/V — publish them for the next
-            # identical prefix
+            # the sequence's full prompt blocks (matched + the ones the
+            # dispatched prefill writes) are published for the next
+            # identical prefix now: whatever reads them is dispatched
+            # later, and the pools order it behind the prefill
             if self.config.prefix_sharing:
                 allocator.publish(seq.seq_id, seq.block_hashes)
-            self._admitting = None
             if matched and self.metrics is not None:
                 self.metrics.observe_prefix_hits(self.model_name, matched)
+            if self._speculative:
+                # no step is ever in flight: nothing to run ahead of
+                self._complete_admissions()
+
+    def _complete_admissions(self) -> None:
+        """Book every admission pending, in admission order: wait for
+        the prefill's logits, draw the sequence's first token from them,
+        stream it, and let the sequence join the running lanes (or end
+        there). A sequence cancelled meanwhile gives back what it holds
+        and streams nothing; its prefill is not waited for."""
+        laps = self._laps
+        while self._admitting:
+            pending = self._admitting[0]
+            seq = pending.seq
+            if seq.cancelled:
+                self._free_blocks(seq)
+                seq.state = _DONE
+                del self._admitting[0]
+                continue
+            laps.enter("wait")
+            _wait_ready(pending.logits)
+            laps.enter("readback")
+            logits = np.asarray(pending.logits)[0]
+            laps.enter("schedule")
+            del self._admitting[0]
             token = self._sample(seq, logits)
+            seq.position = len(seq.prompt) + len(seq.generated)
             seq.generated.append(token)
             seq.last_token = token
-            seq.position = len(context)
             final = len(seq.generated) >= seq.max_tokens
             seq.emit(token, final)
             self.tokens_generated += 1
@@ -1606,10 +1698,12 @@ class LlmEngine:
                 self._running.append(seq)
 
     async def _prefill_one(self, seq: Sequence, context: List[int],
-                           start: int) -> np.ndarray:
-        """Prefill ``context[start:]`` (``start`` = matched shared
-        blocks, always block-aligned and < len(context)) and return the
-        last real token's logits row."""
+                           start: int) -> Any:
+        """Dispatch the prefill of ``context[start:]`` (``start`` =
+        matched shared blocks, always block-aligned and < len(context))
+        and return its logits ``[1, V]``, the last real token's row, as
+        the un-waited device array they are, their copy to the host
+        started."""
         from client_tpu.server.models import pad_batch_bucket
 
         suffix = context[start:]
@@ -1622,6 +1716,8 @@ class LlmEngine:
         tokens = np.zeros([1, bucket], dtype=np.int32)
         tokens[0, : len(suffix)] = suffix
         self.prefills += 1
+        if self._flight is not None:
+            self.prefills_behind += 1
         # A failing device call is ENGINE-fatal, not sequence-fatal: the
         # inputs were engine-constructed (request validation happened at
         # submit) and the donated page pool may be gone — let it
@@ -1635,7 +1731,8 @@ class LlmEngine:
             len(suffix) - 1,
             start,
         )
-        return np.asarray(logits)[0]
+        _start_copy(logits)
+        return logits
 
     def _sample(self, seq: Sequence, logits: np.ndarray) -> int:
         """Next token from a logits row (the single-row prefill path);
@@ -1908,11 +2005,7 @@ class LlmEngine:
         if flight is not None:
             self.steps_ahead += 1
         for result in (ids, *counted):
-            # what the host will read: its copy starts when the program
-            # ends, not when the loop gets round to asking for it
-            start_copy = getattr(result, "copy_to_host_async", None)
-            if start_copy is not None:
-                start_copy()
+            _start_copy(result)
         return _Flight(batch, ids, logits, counted)
 
     def _consume(self, flight: _Flight) -> None:

@@ -617,9 +617,11 @@ def test_whole_tiles_are_the_allocators_promise_in_the_steady_state():
     assert same == lengths  # the streams do not depend on the allocator
     assert silent["attn_tiles_walked"] == silent["attn_tiles_whole"] == 0
     assert silent["kv_blocks_reserved_by_group"] == [0, 0]
-    # block for block the allocation before runs: these are the counts
-    # the commit before runs existed gives for the same traffic (45%)
-    assert by_hand == [2970, 1340]
+    # block for block the allocation before runs: as fragmented as the
+    # commit before runs existed left the same traffic (45% whole then;
+    # 46% since a lane joins the batch a step after its prefill was
+    # dispatched, which moves who takes which block)
+    assert by_hand == [2970, 1371]
 
 
 def test_a_shared_prefixs_mixed_tile_is_the_one_stop_not_whole():
@@ -962,8 +964,12 @@ def test_a_profiler_trace_holds_the_spans_and_changes_no_token(
     counted = _delta(before, after)
     assert set(spans) == {f"engine.{p}" for p in PHASES if counted[p]}
     assert sum(spans.values()) == pytest.approx(sum(counted.values()), rel=0.05)
+    # `prefill` is four dispatches of a third of a millisecond since the
+    # prefill is waited for under `wait`: an annotation's own few tens of
+    # microseconds an event get the absolute allowance
     for phase in ("prefill", "dispatch", "wait"):
-        assert spans[f"engine.{phase}"] == pytest.approx(counted[phase], rel=0.05)
+        assert spans[f"engine.{phase}"] == pytest.approx(
+            counted[phase], rel=0.05, abs=200_000)
     assert programs == {"llm_prefill", "llm_prefill_suffix", "llm_decode",
                         "llm_verify"}
 

@@ -1235,6 +1235,48 @@ def test_admission_counts_new_blocks_only():
     assert run(prefix_sharing=True) >= 3
 
 
+def test_sharers_admitted_while_the_first_is_pending_match_its_blocks():
+    """Four requests with one 8-block prefix arrive together: all four
+    prefills are dispatched in one admission pass, the first's blocks
+    published when it was dispatched, so the three behind it reference
+    them while it is still pending: 24 hits of a demand of 32, as when
+    each prefill was waited for before the next."""
+    prefix = list(range(32))  # 8 full blocks @ block_size 4
+    clock = _FakeClock()
+
+    async def go():
+        engine = _consistent_stub_engine(
+            clock, num_blocks=33, max_active=6, max_queue=16)
+        reference = _consistent_stub_engine(
+            clock, num_blocks=33, max_active=6, max_queue=16,
+            prefix_sharing=False)
+        prompts = [prefix + [100 + i, 200 + i] for i in range(4)]
+        seqs = [engine.submit(p, max_tokens=6) for p in prompts]
+        tasks = [asyncio.ensure_future(_collect(s)) for s in seqs]
+        while not engine._admitting:
+            await asyncio.sleep(0)
+        assert [p.seq for p in engine._admitting] == seqs
+        assert [s.shared_blocks for s in seqs] == [0, 8, 8, 8]
+        pending = engine.stats()
+        assert pending["active_sequences"] == pending["waiting_sequences"] == 0
+        assert pending["kv_blocks_shared"] == 8
+        assert pending["prefix_cache_hits"] == 24
+        results = await asyncio.gather(*tasks)
+        unshared = await asyncio.gather(*[
+            _collect(reference.submit(p, max_tokens=6)) for p in prompts])
+        stats = engine.stats()
+        engine.close()
+        reference.close()
+        return results, unshared, stats
+
+    results, unshared, stats = asyncio.run(go())
+    assert results == unshared and all(len(r) == 6 for r in results)
+    assert stats["prefix_cache_hits"] == 24
+    assert stats["prefix_block_demand"] == 32
+    assert stats["prefills"] == 4 and stats["prefills_behind"] == 0
+    assert stats["kv_blocks_in_use"] == 0
+
+
 def test_submit_accepts_post_match_demand_and_fails_cleanly_when_gone():
     """submit() recomputes the capacity fast-fail against post-match
     demand (a prompt mostly covered by a live shared prefix is not

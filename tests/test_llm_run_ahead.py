@@ -6,8 +6,10 @@ then are k's ids read, booked and streamed. The double here is a model
 that really keeps a paged cache (a token a slot, read back through the
 page tables a call is handed), so a wrong lane map, a wrong position or
 a block written by the wrong owner changes the tokens that follow; its
-``ids`` record when the host reads them. The last two tests run the
-jitted programs themselves, on the tiny Llama.
+``ids`` record when the host reads them, and so do a prefill's logits:
+an admission is dispatched behind the step in flight and booked an
+iteration later, when its logits arrive (section 7 on). The tests of
+``jit_llm_decode`` run the jitted programs themselves, on the tiny Llama.
 
 CPU, toy sizes.
 """
@@ -85,17 +87,52 @@ class _DeviceIds:
         return self.values
 
 
+class _DeviceLogits:
+    """A prefill's logits as the engine gets them: on the "device" until
+    the host waits for them or copies them, both of which are recorded
+    with the prefill's number. ``lost`` fails the wait, as a device that
+    died under the program does."""
+
+    def __init__(self, values, call, events, lost=False):
+        self.values = values
+        self.call = call
+        self.events = events
+        self.lost = lost
+
+    def copy_to_host_async(self):
+        self.events.append(("logits_copy", self.call))
+
+    def block_until_ready(self):
+        self.events.append(("logits_wait", self.call))
+        if self.lost:
+            raise RuntimeError(f"device lost under prefill {self.call}")
+        return self
+
+    def __array__(self, dtype=None, copy=None):
+        self.events.append(("logits_read", self.call))
+        return self.values
+
+
 class _PagedModel:
     """Device functions over a paged cache of token values. Written to
     the engine's contract directly (no ``decode_fn_from_logits``): the
     previous ids are read where they are, never through the host."""
 
-    def __init__(self, num_blocks, block_size, fail_at_call=None):
+    def __init__(self, num_blocks, block_size, fail_at_call=None,
+                 full_group=None):
         self.block_size = block_size
         self.pages = np.full([num_blocks, block_size], -1, dtype=np.int64)
         self.events = []
         self.calls = 0
+        self.prefills = 0
         self.fail_at_call = fail_at_call
+        # the prefill call that raises, and the one whose logits never
+        # arrive (the wait for them raises)
+        self.fail_at_prefill = None
+        self.lose_logits_of = None
+        # the row of stacked group tables that is the full group's (a
+        # model of several cache groups keeps only that one here)
+        self.full_group = full_group
         self.allocator = None  # set once the engine exists
 
     def _slot(self, table, position):
@@ -118,11 +155,20 @@ class _PagedModel:
         return out
 
     def prefill(self, tokens, page_table, pages, last_index, start):
-        self.events.append(("prefill", None))
+        self.prefills += 1
+        call = self.prefills
+        self.events.append(("prefill", call))
+        if call == self.fail_at_prefill:
+            raise RuntimeError(f"device lost at prefill call {call}")
+        if self.full_group is not None:
+            page_table = page_table[self.full_group]
         for j in range(last_index + 1):
             self._write(page_table, start + j, int(tokens[0, j]))
         context = self._context(page_table, start + last_index)
-        return _logits_row(context)[None], pages
+        logits = _DeviceLogits(
+            _logits_row(context)[None], call, self.events,
+            lost=call == self.lose_logits_of)
+        return logits, pages
 
     def decode(self, prev_ids, lane_map, host_tokens, positions,
                page_tables, pages):
@@ -132,6 +178,8 @@ class _PagedModel:
         if self.fail_at_call is not None and step >= self.fail_at_call:
             raise RuntimeError(f"device lost at decode call {step}")
         prev = prev_ids.values if isinstance(prev_ids, _DeviceIds) else prev_ids
+        if self.full_group is not None:
+            page_tables = page_tables[self.full_group]
         bucket = lane_map.shape[0]
         logits = np.zeros([bucket, VOCAB], dtype=np.float32)
         ids = np.zeros([len(prev)], dtype=np.int32)
@@ -150,14 +198,43 @@ class _PagedModel:
             ids[lane] = int(logits[lane].argmax())
         return _DeviceIds(ids, step, self.events), logits, pages
 
+    def verify(self, tokens, positions, lengths, page_tables, pages):
+        """The speculative engine's multi-query step: each lane's rows
+        written and read in order, its logits read at once."""
+        self.calls += 1
+        self.events.append(("verify", self.calls))
+        logits = np.zeros(tokens.shape + (VOCAB,), dtype=np.float32)
+        for lane in range(tokens.shape[0]):
+            table = page_tables[lane]
+            for t in range(int(lengths[lane])):
+                position = int(positions[lane, t])
+                self._write(table, position, int(tokens[lane, t]))
+                logits[lane, t] = _logits_row(self._context(table, position))
+        return logits, pages
 
-def _engine(model=None, clock=None, **overrides):
+
+class _TrueProposer:
+    """Drafts the model's own continuation: every draft is accepted."""
+
+    def propose(self, context, k):
+        context = list(context)
+        for _ in range(k):
+            context.append(_next_token(context))
+        return context[-k:]
+
+
+def _engine(model=None, clock=None, speculative=False, **overrides):
     defaults = dict(block_size=4, num_blocks=65, max_active=4, max_queue=16,
                     max_seq_len=64)
     defaults.update(overrides)
     config = EngineConfig(**defaults)
-    model = model or _PagedModel(config.num_blocks, config.block_size)
+    groups = [group.kind for group in config.cache_groups]
+    model = model or _PagedModel(
+        config.num_blocks, config.block_size,
+        full_group=groups.index("full") if groups else None)
     kwargs = {"clock_ns": clock} if clock is not None else {}
+    if speculative:
+        kwargs.update(decode_multi_fn=model.verify, proposer=_TrueProposer())
     engine = LlmEngine(
         model.prefill, model.decode, pages=object(), engine_config=config,
         model_name="paged", **kwargs,
@@ -530,6 +607,513 @@ def test_phases_tile_the_loop_with_a_step_in_flight(ending):
     engine.close()
 
 
+# -- (7) an admission does not drain the device --------------------------------
+
+
+async def _until(condition):
+    while not condition():
+        await asyncio.sleep(0)
+
+
+def test_prefill_goes_behind_the_step_in_flight_and_the_loop_runs_ahead():
+    """With step k dispatched and unread a request arrives: its prefill
+    is dispatched before k's ids are read, step k+1 (without the new
+    lane) before the prefill's logits are, and the lane joins step k+2
+    with its token from the host."""
+    engine, model = _engine()
+    lanes = {}
+    decode = model.decode
+
+    def watched_decode(prev_ids, lane_map, host_tokens, *rest):
+        lanes[model.calls + 1] = lane_map.tolist()
+        return decode(prev_ids, lane_map, host_tokens, *rest)
+
+    engine._decode = watched_decode
+
+    async def run():
+        first = engine.submit([3, 1, 4], max_tokens=12)
+        task = asyncio.ensure_future(_collect(first))
+        await _until(lambda: engine.steps >= 3 and engine._flight is not None)
+        second = engine.submit([1, 5, 9], max_tokens=6)
+        out = [await task, await _collect(second)]
+        await _settle()
+        return out
+
+    out = asyncio.run(run())
+    assert out == [_alone([3, 1, 4], 12), _alone([1, 5, 9], 6)]
+    events = model.events
+    at = {event: index for index, event in enumerate(events)}
+    prefill = at[("prefill", 2)]
+    in_flight = max(step for kind, step in events[:prefill]
+                    if kind == "dispatch")
+    # the copy of the logits starts with the dispatch
+    assert events[prefill + 1] == ("logits_copy", 2)
+    # behind the step in flight: that step's ids are still unread
+    assert prefill < at[("wait", in_flight)] < at[("read", in_flight)]
+    # the next step is dispatched across the prefill, without the lane
+    assert prefill < at[("dispatch", in_flight + 1)] < at[("logits_wait", 2)]
+    assert at[("logits_wait", 2)] < at[("logits_read", 2)]
+    assert lanes[in_flight + 1] == [0]
+    # and booked from the host's token in the step after
+    assert at[("logits_read", 2)] < at[("dispatch", in_flight + 2)]
+    assert lanes[in_flight + 2] == [0, -1]
+    assert lanes[in_flight + 3] == [0, 1]
+    stats = engine.stats()
+    assert stats["prefills"] == 2 and stats["prefills_behind"] == 1
+    assert stats["steps_ahead"] == stats["steps"] - 1
+    engine.close()
+
+
+def _draws(kind, index):
+    sampled = kind == "sampled" or (kind == "mixed" and index % 2)
+    if not sampled:
+        return None
+    return {"temperature": 1.0, "top_k": 12, "seed": 100 + index}
+
+
+@pytest.mark.parametrize("draws", ["greedy", "sampled", "mixed"])
+@pytest.mark.parametrize("num_blocks", [65, 12], ids=["roomy", "dry_pool"])
+def test_streams_with_admissions_mid_run_equal_each_sequence_decoded_alone(
+        draws, num_blocks):
+    """Eight unequal sequences over four lanes, three there from the
+    start and one more every other step: admissions fall while steps
+    are in flight, lanes end under them, and at 11 usable blocks the
+    pool runs dry and preempts. Greedy streams are each sequence's own
+    alone; a sampled one keeps its seed's draws."""
+    engine, model = _engine(num_blocks=num_blocks, max_queue=16)
+
+    async def run():
+        seqs = [engine.submit(p, max_tokens=m, parameters=_draws(draws, i))
+                for i, (p, m) in enumerate(_TRAFFIC[:3])]
+        tasks = [asyncio.ensure_future(_collect(s)) for s in seqs]
+        for i, (p, m) in enumerate(_TRAFFIC[3:], start=3):
+            after = engine.steps + 2
+            await _until(lambda: engine.steps >= after)
+            seq = engine.submit(p, max_tokens=m, parameters=_draws(draws, i))
+            tasks.append(asyncio.ensure_future(_collect(seq)))
+        out = await asyncio.gather(*tasks)
+        await _settle()
+        return out
+
+    out = asyncio.run(run())
+    for index, ((prompt, max_tokens), tokens) in enumerate(zip(_TRAFFIC, out)):
+        parameters = _draws(draws, index)
+        want = _alone(prompt, max_tokens) if parameters is None else (
+            _alone_sampled(prompt, max_tokens, parameters["temperature"],
+                           parameters["top_k"], parameters["seed"]))
+        assert tokens == want, index
+    stats = engine.stats()
+    assert stats["completed"] == len(_TRAFFIC)
+    assert stats["prefills"] == len(_TRAFFIC) + stats["preemptions"]
+    assert (stats["preemptions"] > 0) == (num_blocks == 12)
+    if draws == "greedy":
+        assert stats["prefills_behind"] >= 5
+        assert stats["steps_ahead"] > stats["steps"] * 3 // 4
+    elif draws == "sampled":
+        # a sampled lane lives from the first step to the last: every
+        # step is consumed where it was dispatched, no prefill finds one
+        assert stats["prefills_behind"] == stats["steps_ahead"] == 0
+    assert stats["kv_blocks_in_use"] == 0 and engine._flight is None
+    assert engine._admitting == []
+    engine.close()
+
+
+def _grouped_engine(**overrides):
+    """An engine over a full, a window and a state cache group: a
+    sequence holds blocks, a ring and a slot."""
+    from client_tpu.models.engine_model import (
+        FULL, STATE, WINDOW, CacheGroup)
+
+    return _engine(
+        cache_groups=(CacheGroup(WINDOW, (0,), window=8),
+                      CacheGroup(FULL, (1,)), CacheGroup(STATE, (2,))),
+        prefix_sharing=False, **overrides)
+
+
+def test_cancelled_while_its_admission_is_pending_frees_all_and_streams_nothing():
+    engine, model = _grouped_engine()
+    held = {}
+
+    async def run():
+        first = engine.submit([3, 1, 4], max_tokens=12)
+        task = asyncio.ensure_future(_collect(first))
+        await _until(lambda: engine.steps >= 2 and engine._flight is not None)
+        alone = engine.stats()
+        second = engine.submit([1, 5, 9, 2, 6], max_tokens=6)
+        await _until(lambda: engine._admitting)
+        assert [p.seq for p in engine._admitting] == [second]
+        pending = engine.stats()
+        held["blocks"] = [
+            now - before for now, before in zip(
+                pending["kv_blocks_in_use_by_group"],
+                alone["kv_blocks_in_use_by_group"])]
+        held["slots"] = (pending["state_slots_in_use"],
+                         alone["state_slots_in_use"])
+        assert second.blocks and second.rings[0] and second.slots
+        # neither waiting nor running while it is pending
+        assert pending["waiting_sequences"] == 0
+        assert pending["active_sequences"] == 1
+        engine.release(second)
+        await _settle()
+        freed = engine.stats()
+        assert engine._admitting == [] and second.state == "done"
+        assert freed["state_slots_in_use"] == 1
+        assert freed["kv_blocks_in_use_by_group"][0] == (
+            alone["kv_blocks_in_use_by_group"][0])
+        out = await task
+        await _settle()
+        return out, second
+
+    out, second = asyncio.run(run())
+    assert out == _alone([3, 1, 4], 12)
+    # two blocks of the full group, a ring of three, one slot
+    assert held == {"blocks": [3, 2, 1], "slots": (2, 1)}
+    assert second.generated == [] and second.rings == [] == second.slots
+    assert second._out.get_nowait()[0] == "end" and second._out.empty()
+    # its prefill ran (the blocks were its own), its logits were never read
+    assert ("prefill", 2) in model.events
+    assert ("logits_wait", 2) not in model.events
+    assert ("logits_read", 2) not in model.events
+    stats = engine.stats()
+    assert stats["cancelled"] == 1 and stats["completed"] == 1
+    assert stats["tokens_generated"] == 12
+    assert stats["kv_blocks_in_use_by_group"] == [0, 0, 0]
+    assert stats["state_slots_in_use"] == 0
+    engine.close()
+
+
+@pytest.mark.parametrize("fails", ["at_dispatch", "at_the_logits"])
+def test_failed_prefill_behind_a_step_quarantines_and_survivors_resume(fails):
+    """The prefill raises (or its logits never arrive) with a decode
+    step in flight: the engine is quarantined, the pending sequence has
+    streamed nothing and holds nothing, and all three streams resume on
+    a successor as if nothing had happened."""
+    engine, model = _engine()
+    fatal = []
+    engine.on_fatal = fatal.append
+    if fails == "at_dispatch":
+        model.fail_at_prefill = 3
+    else:
+        model.lose_logits_of = 3
+    prompts = [([3, 1, 4], 14), ([1, 5], 11), ([9, 2, 6, 5], 9)]
+    streamed = [[] for _ in prompts]
+
+    async def run():
+        seqs = [engine.submit(p, max_tokens=m) for p, m in prompts[:2]]
+        tasks = [asyncio.ensure_future(_collect(s, into))
+                 for s, into in zip(seqs, streamed)]
+        await _until(lambda: engine.steps >= 3 and engine._flight is not None)
+        seqs.append(engine.submit(*prompts[2][:1], max_tokens=prompts[2][1]))
+        tasks.append(asyncio.ensure_future(_collect(seqs[2], streamed[2])))
+        await _until(lambda: fatal)
+        await _settle()
+        assert engine.recovering and engine._flight is None
+        assert engine._admitting == []
+        assert engine.stats()["kv_blocks_in_use"] == 0
+        assert engine.stats()["recovery_survivors"] == 3
+        # nothing of the pending sequence, and of the others what was
+        # booked: the step in flight (and, where the logits failed, the
+        # step dispatched across the prefill) was dropped unread
+        assert streamed[2] == [] and seqs[2].generated == []
+        for seq, got in zip(seqs[:2], streamed):
+            assert got == seq.generated
+        at_failure = [len(s) for s in streamed]
+        successor, _ = _engine()
+        successor.adopt(engine.detach_survivors())
+        out = await asyncio.gather(*tasks)
+        await _settle()
+        assert successor.stats()["kv_blocks_in_use"] == 0
+        successor.close()
+        return out, at_failure
+
+    out, at_failure = asyncio.run(run())
+    assert out == [_alone(p, m) for p, m in prompts]
+    assert isinstance(fatal[0], RuntimeError)
+    dispatched = max(step for kind, step in model.events if kind == "dispatch")
+    reads = [step for kind, step in model.events if kind == "read"]
+    # the newest step was in flight, and never read
+    assert reads == list(range(1, dispatched))
+    after_prefill = model.events[model.events.index(("prefill", 3)):]
+    across = [step for kind, step in after_prefill if kind == "dispatch"]
+    if fails == "at_dispatch":
+        assert "prefill call 3" in str(fatal[0])
+        assert across == []
+    else:
+        # the failure surfaced an iteration later, at the wait: a step
+        # went across the prefill and the one before it was booked
+        assert "under prefill 3" in str(fatal[0])
+        assert across == [dispatched]
+        assert ("read", dispatched - 1) in after_prefill
+        assert ("logits_read", 3) not in model.events
+    assert at_failure[0] == 1 + len(reads)
+    assert engine.stats()["prefills_behind"] == 1
+    engine.close()
+
+
+def test_two_pending_admissions_complete_in_admission_order():
+    engine, model = _engine()
+    first_tokens = []
+
+    async def collect(name, seq):
+        out = []
+        async for token in _each_token(seq):
+            if not out:
+                first_tokens.append(name)
+            out.append(token)
+        return out
+
+    async def run():
+        first = engine.submit([3, 1, 4], max_tokens=12)
+        tasks = [asyncio.ensure_future(collect("a", first))]
+        await _until(lambda: engine.steps >= 2 and engine._flight is not None)
+        second = engine.submit([1, 5, 9], max_tokens=5)
+        third = engine.submit([2, 6], max_tokens=7)
+        tasks += [asyncio.ensure_future(collect("b", second)),
+                  asyncio.ensure_future(collect("c", third))]
+        await _until(lambda: engine._admitting)
+        assert [p.seq for p in engine._admitting] == [second, third]
+        assert engine.stats()["active_sequences"] == 1
+        out = await asyncio.gather(*tasks)
+        await _settle()
+        return out
+
+    out = asyncio.run(run())
+    assert out == [_alone([3, 1, 4], 12), _alone([1, 5, 9], 5),
+                   _alone([2, 6], 7)]
+    assert first_tokens == ["a", "b", "c"]
+    events = model.events
+    at = {event: index for index, event in enumerate(events)}
+    # both prefills, then the step across them, then their logits in turn
+    assert at[("prefill", 2)] < at[("prefill", 3)] < at[("logits_wait", 2)]
+    between = [e for e in events[at[("prefill", 3)]:at[("logits_wait", 2)]]
+               if e[0] == "dispatch"]
+    assert len(between) == 1
+    assert (at[("logits_wait", 2)] < at[("logits_read", 2)]
+            < at[("logits_wait", 3)] < at[("logits_read", 3)])
+    stats = engine.stats()
+    assert stats["prefills_behind"] == 2
+    assert stats["steps_ahead"] == stats["steps"] - 1
+    engine.close()
+
+
+def test_a_speculative_engine_books_each_admission_where_it_dispatched_it():
+    """No step is ever in flight under speculation, so the order of
+    calls is what it always was: a prefill, its logits, the next
+    prefill, its logits, and only then the step."""
+    engine, model = _engine(speculative=True, spec_k=3)
+    assert engine.stats()["speculative"]
+
+    async def run():
+        seqs = [engine.submit([3, 1, 4], max_tokens=12),
+                engine.submit([1, 5], max_tokens=9)]
+        tasks = [asyncio.ensure_future(_collect(s)) for s in seqs]
+        await _until(lambda: engine.steps >= 2)
+        assert engine._admitting == [] and engine._flight is None
+        tasks.append(asyncio.ensure_future(
+            _collect(engine.submit([9, 2, 6, 5], max_tokens=7))))
+        out = await asyncio.gather(*tasks)
+        await _settle()
+        return out
+
+    out = asyncio.run(run())
+    assert out == [_alone([3, 1, 4], 12), _alone([1, 5], 9),
+                   _alone([9, 2, 6, 5], 7)]
+    events = model.events
+    calls = [(index, event) for index, event in enumerate(events)
+             if event[0] in ("prefill", "dispatch", "verify")]
+    assert [event for _, event in calls[:3]] == [
+        ("prefill", 1), ("prefill", 2), ("verify", 1)]
+    for (index, (kind, call)), (after, _) in zip(calls, calls[1:]):
+        if kind == "prefill":
+            assert events[index + 1:index + 4] == [
+                ("logits_copy", call), ("logits_wait", call),
+                ("logits_read", call)]
+            assert after > index + 3
+    stats = engine.stats()
+    assert stats["prefills"] == 3 and stats["spec_steps"] > 0
+    assert stats["prefills_behind"] == stats["steps_ahead"] == 0
+    assert stats["tokens_per_step"] > 2
+    engine.close()
+
+
+@pytest.mark.parametrize("draws", ["greedy", "sampled"])
+def test_steps_ahead_and_prefills_behind_count_what_happened(draws):
+    engine, model = _engine()
+    sampled = _draws(draws, 0)
+
+    async def run():
+        first = engine.submit([3, 1, 4], max_tokens=12, parameters=sampled)
+        task = asyncio.ensure_future(_collect(first))
+        await _until(lambda: engine.steps >= 3)
+        await asyncio.gather(
+            task, _collect(engine.submit([1, 5, 9], max_tokens=6)))
+        await _settle()
+        middle = engine.stats()
+        assert engine._flight is None and not engine._running
+        # an idle engine: nothing in flight to go behind
+        await _collect(engine.submit([2, 6], max_tokens=4))
+        await _settle()
+        return middle
+
+    middle = asyncio.run(run())
+    stats = engine.stats()
+    assert stats["prefills"] == 3 and middle["prefills"] == 2
+    if draws == "greedy":
+        assert middle["prefills_behind"] == stats["prefills_behind"] == 1
+        # every step but the first of each busy spell ran ahead
+        assert middle["steps_ahead"] == middle["steps"] - 1
+        assert stats["steps_ahead"] == stats["steps"] - 2
+    else:
+        # the sampled lane's steps are consumed where they are
+        # dispatched: the second prefill finds no step in flight. Once
+        # the lane has ended, the greedy ones' steps run ahead again
+        assert stats["prefills_behind"] == 0
+        assert 0 < stats["steps_ahead"] < stats["steps"] - 11
+    assert stats["steps"] == 11 + 3
+    engine.close()
+
+
+def test_a_prefix_published_at_dispatch_is_matched_while_its_writer_is_pending():
+    """Two requests with one prefix arrive together behind a running
+    lane: the second references the first's blocks while the first's
+    prefill is still unread, and reads what that prefill wrote."""
+    engine, model = _engine()
+    prefix = [7, 3, 7, 3, 8, 1, 8, 1]  # two full blocks
+
+    async def run():
+        first = engine.submit([3, 1, 4], max_tokens=12)
+        tasks = [asyncio.ensure_future(_collect(first))]
+        await _until(lambda: engine.steps >= 2 and engine._flight is not None)
+        writer = engine.submit(prefix + [5], max_tokens=6)
+        reader = engine.submit(prefix + [9, 2], max_tokens=5)
+        tasks += [asyncio.ensure_future(_collect(s)) for s in (writer, reader)]
+        await _until(lambda: engine._admitting)
+        assert [p.seq for p in engine._admitting] == [writer, reader]
+        assert reader.shared_blocks == 2 and writer.shared_blocks == 0
+        assert reader.blocks[:2] == writer.blocks[:2]
+        assert engine.stats()["kv_blocks_shared"] == 2
+        out = await asyncio.gather(*tasks)
+        await _settle()
+        return out
+
+    out = asyncio.run(run())
+    assert out == [_alone([3, 1, 4], 12), _alone(prefix + [5], 6),
+                   _alone(prefix + [9, 2], 5)]
+    stats = engine.stats()
+    assert stats["prefix_cache_hits"] == 2
+    assert stats["prefix_block_demand"] == 4
+    assert stats["prefills_behind"] == 2
+    assert stats["kv_blocks_in_use"] == 0
+    engine.close()
+
+
+@pytest.mark.parametrize("ending", ["park", "close", "cancel"])
+def test_phases_tile_the_loop_with_an_admission_pending(ending):
+    engine = _witnessed_engine()
+    pending_at = []
+
+    async def run():
+        first = engine.submit([3, 1, 4], max_tokens=12)
+        tasks = [asyncio.ensure_future(_collect(first))]
+        await _until(lambda: engine.steps >= 2 and engine._flight is not None)
+        second = engine.submit([1, 5, 9], max_tokens=6)
+        tasks.append(asyncio.ensure_future(_collect(second)))
+        await _until(lambda: engine._admitting)
+        # not parked, a step in flight and a prefill unread: the phases
+        # add up to the last boundary
+        pending_at.append(_tiles(engine))
+        if ending == "close":
+            engine.close()
+        elif ending == "cancel":
+            engine.release(second)
+        out = await asyncio.gather(*tasks, return_exceptions=True)
+        await _settle()
+        return out
+
+    out = asyncio.run(run())
+    stats = _tiles(engine)
+    assert pending_at and engine._flight is None and engine._admitting == []
+    before = pending_at[0]["phase_ns"]
+    assert before["prefill"] > 0
+    if ending == "park":
+        assert out == [_alone([3, 1, 4], 12), _alone([1, 5, 9], 6)]
+        # the second prefill was waited for and read under these two
+        assert stats["phase_ns"]["wait"] > before["wait"]
+        assert stats["phase_ns"]["readback"] > before["readback"]
+        assert stats["prefills_behind"] == 1
+    elif ending == "close":
+        assert all(isinstance(o, Exception) for o in out)
+        assert stats["steps"] == pending_at[0]["steps"]
+        assert stats["tokens_generated"] == pending_at[0]["tokens_generated"]
+    else:
+        assert out == [_alone([3, 1, 4], 12), []]
+        assert stats["cancelled"] == 1
+    assert stats["kv_blocks_in_use"] == 0
+    assert stats["phase_ns"]["prefill"] == before["prefill"]
+    # parked: a later look finds nothing moved
+    assert engine.stats()["phase_ns"] == stats["phase_ns"]
+    engine.close()
+
+
+def test_the_benchmark_reads_the_share_of_prefills_that_went_behind_a_step():
+    """``engine.admits_behind_share`` is a metric file over a reader the
+    benchmark has (``counters:delta_ratio``) and two keys ``stats()``
+    really serves; ``BENCHMARK.json`` lists it for all six cells; a
+    parent without the counter reads nothing and leaves it out."""
+    import json
+    import os
+    import types
+
+    from benchmark import run as harness
+
+    with open(os.path.join(harness.ROOT, "benchmark", "metrics",
+                           "engine.admits_behind_share.json")) as f:
+        metric = json.load(f)
+    assert metric["reader"] == "counters:delta_ratio"
+    assert metric["params"]["scale"] == 100
+    engine, model = _engine()
+    snapshots = []
+
+    async def run():
+        first = engine.submit([3, 1, 4], max_tokens=14)
+        task = asyncio.ensure_future(_collect(first))
+        await _until(lambda: engine.steps >= 2 and engine._flight is not None)
+        snapshots.append({"engine": json.loads(json.dumps(engine.stats()))})
+        for prompt in ([1, 5, 9], [2, 6]):
+            await _collect(engine.submit(prompt, max_tokens=3))
+        await task
+        await _settle()
+        # and one onto the idle engine: three prefills, two of them behind
+        await _collect(engine.submit([7, 7], max_tokens=2))
+        await _settle()
+        snapshots.append({"engine": json.loads(json.dumps(engine.stats()))})
+
+    asyncio.run(run())
+    before, after = snapshots
+    for key in ("numerator", "denominator"):
+        source, name = metric["params"][key].split(":")
+        assert source == "engine" and name in after["engine"]
+        assert isinstance(after["engine"][name], int)
+    window = types.SimpleNamespace(before=before, after=after)
+    value, unit = harness.read_metric(window, "engine.admits_behind_share")
+    assert unit == "%" and value == pytest.approx(100 * 2 / 3)
+    for snapshot in (before, after):
+        del snapshot["engine"]["prefills_behind"]
+    assert harness.read_metric(window, "engine.admits_behind_share")[0] is None
+    with open(os.path.join(harness.ROOT, "BENCHMARK.json")) as f:
+        benchmark = json.load(f)
+    (entry,) = [m for m in benchmark["per_layer"]
+                if m["name"] == "engine.admits_behind_share"]
+    assert benchmark["per_layer"][-1] == entry
+    cells = [w["name"] for w in benchmark["workloads"]]
+    assert entry["workloads"] == cells and len(cells) == 6
+    (ahead,) = [m for m in benchmark["per_layer"]
+                if m["name"] == "engine.steps_ahead_share"]
+    for key in ("unit", "better", "source", "layer", "moves"):
+        assert entry[key] == metric[key] == ahead[key]
+    engine.close()
+
+
 # -- the seam to the device: jit_llm_decode -----------------------------------
 
 
@@ -631,6 +1215,46 @@ def test_engine_over_the_jitted_programs_runs_ahead_and_matches_alone(
     assert held["steps"] - after["steps"] == 26
     assert held["steps_ahead"] == after["steps_ahead"]
     assert held["kv_blocks_in_use"] == held["kv_blocks_shared"]
+
+
+def test_jitted_prefills_go_behind_the_step_in_flight_and_match_alone(
+        tiny_model):
+    """The real programs with requests arriving mid-run: each prefill
+    is dispatched behind a jitted step in flight, its logits an
+    un-waited device array whose copy was started, and every stream is
+    the prompt's own alone."""
+    engine = tiny_model.engine
+    prompts = [[5, 9, 17, 3], [1, 2, 3], [40, 41, 42, 43, 44], [7, 8]]
+    lengths = [14, 9, 6, 8]
+
+    async def alone(i):
+        out = await _collect(engine.submit(prompts[i], max_tokens=lengths[i]))
+        await _settle()
+        return out
+
+    async def run():
+        want = [await alone(i) for i in range(len(prompts))]
+        before = engine.stats()
+        tasks = [asyncio.ensure_future(
+            _collect(engine.submit(prompts[0], max_tokens=lengths[0])))]
+        for i in range(1, len(prompts)):
+            after = engine.steps + 2
+            await _until(lambda: engine.steps >= after
+                         and engine._flight is not None)
+            tasks.append(asyncio.ensure_future(
+                _collect(engine.submit(prompts[i], max_tokens=lengths[i]))))
+        got = await asyncio.gather(*tasks)
+        await _settle()
+        return want, got, before, engine.stats()
+
+    want, got, before, after = asyncio.run(run())
+    assert got == want
+    assert after["prefills"] - before["prefills"] == 4
+    assert after["prefills_behind"] - before["prefills_behind"] == 3
+    steps = after["steps"] - before["steps"]
+    assert after["steps_ahead"] - before["steps_ahead"] == steps - 1
+    assert after["kv_blocks_in_use"] == after["kv_blocks_shared"]
+    assert engine._admitting == []
 
 
 def test_every_streamed_token_comes_out_of_sample_rows():
