@@ -36,7 +36,15 @@ shared expert a SwiGLU times ``sigmoid(m @ w_sg)`` (``models/moe.py``).
 Two cache groups (``models/engine_model.py``): the full group of the
 gated-attention layers (flat K and V pools, ``[N, bs*KV, D]``) and the
 ``state`` group of the DeltaNet layers, whose pools are ``(state [slots,
-Hv, Dk, Dv] float32, conv [slots, taps - 1, channels])``: a sequence
+Hv, Dk, Dv] float32, conv [slots, taps - 1, heads, lanes])``, a slot's
+three convolution inputs oldest first, each folded into the rows of its
+q, k and v heads (``Qwen3NextConfig.conv_lanes``), so that a slot owns
+whole tiles of the pool as the TPU stores it and a gather or a scatter
+by slot moves those and nothing else (a ``[slots, 3, channels]`` pool
+lies in HBM with its slots second to last and is copied whole before
+every gather and after every scatter; one row of ``3 * channels`` a slot
+shares each tile among 16 slots, and a scatter by slot then writes a
+sixteenth of every tile it touches): a sequence
 holds one slot whatever its length, ``tables[1][..., 0]``. A prefill
 writes the slot whole (the chunked rule's final state; the last three
 convolution inputs up to ``last_index``), a decode step turns it in
@@ -45,6 +53,7 @@ place. Slot 0 is the trash slot and holds zeros. Rotary pairs are (2i,
 """
 
 import dataclasses
+import math
 from typing import Any, Dict, Tuple
 
 import jax
@@ -111,6 +120,14 @@ class Qwen3NextConfig:
     def conv_dim(self) -> int:
         return (2 * self.lin_key_heads * self.lin_key_dim
                 + self.lin_value_heads * self.lin_value_dim)
+
+    @property
+    def conv_lanes(self) -> int:
+        """The width the convolution's channels are folded by where they
+        are stored and convolved, ``[.., conv_dim // lanes, lanes]``: the
+        widest that leaves every q, k and v head whole rows (128 at the
+        published sizes, a head a row)."""
+        return math.gcd(self.lin_key_dim, self.lin_value_dim)
 
     @property
     def value_dim(self) -> int:
@@ -227,7 +244,9 @@ def cache_groups(config: Qwen3NextConfig):
 def init_pages(config: Qwen3NextConfig, num_blocks, block_size: int):
     """In layer order: a full layer's flat ``(k_pages, v_pages)`` of
     ``num_blocks[0]`` blocks, a DeltaNet layer's ``(state_pool,
-    conv_pool)`` of ``num_blocks[1]`` SLOTS, the state in float32."""
+    conv_pool)`` of ``num_blocks[1]`` SLOTS, the state in float32, a
+    slot's convolution inputs ``[conv_kernel - 1, conv_dim // lanes,
+    lanes]``, oldest first, each folded by ``config.conv_lanes``."""
     rows = block_size * config.n_kv_heads
     pages = []
     for kind in config.layer_kinds:
@@ -237,7 +256,8 @@ def init_pages(config: Qwen3NextConfig, num_blocks, block_size: int):
                            config.lin_key_dim, config.lin_value_dim),
                           jnp.float32),
                 jnp.zeros((num_blocks[1], config.conv_kernel - 1,
-                           config.conv_dim), config.dtype),
+                           config.conv_dim // config.conv_lanes,
+                           config.conv_lanes), config.dtype),
             ))
         else:
             pages.append(tuple(
@@ -302,8 +322,9 @@ def _join_attention(layer, out, gate):
 
 
 def _delta_inputs(layer, normed, config: Qwen3NextConfig):
-    """``normed`` [T, d] -> the convolution's input [T, channels] (q, k
-    and v before it), z [T, Hv, Dv], beta and g [T, Hv] in float32."""
+    """``normed`` [T, d] -> the convolution's input [T, channels //
+    lanes, lanes] (q, k and v before it, folded by ``config.conv_lanes``),
+    z [T, Hv, Dv], beta and g [T, Hv] in float32."""
     heads = config.lin_value_heads
     mixed = jnp.dot(normed, layer["w_qkvz"])
     ba = jnp.dot(normed, layer["w_ba"],
@@ -312,26 +333,33 @@ def _delta_inputs(layer, normed, config: Qwen3NextConfig):
     beta = jax.nn.sigmoid(ba[:, :heads])
     g = -jnp.exp(layer["A_log"]) * jax.nn.softplus(
         ba[:, heads:] + layer["dt_bias"])
-    return mixed[:, :config.conv_dim], z, beta, g
+    inputs = mixed[:, :config.conv_dim].reshape(
+        -1, config.conv_dim // config.conv_lanes, config.conv_lanes)
+    return inputs, z, beta, g
 
 
 def _convolved(layer, taps, dtype):
-    """``taps`` [kernel, T, channels], tap ``j`` the input ``kernel - 1 -
-    j`` tokens back: silu of the depthwise sum, float32 inside."""
-    weights = layer["conv_w"].astype(jnp.float32)[:, None, :]
-    return jax.nn.silu((taps.astype(jnp.float32) * weights).sum(axis=0)
-                       ).astype(dtype)
+    """``taps``: the convolution's ``kernel`` inputs [T, channels // lanes,
+    lanes] each, tap ``j`` the input ``kernel - 1 - j`` tokens back: silu
+    of the depthwise sum, oldest first, float32 inside."""
+    weights = layer["conv_w"].astype(jnp.float32).reshape(
+        -1, *taps[0].shape[1:])
+    summed = sum(tap.astype(jnp.float32) * weights[j]
+                 for j, tap in enumerate(taps))
+    return jax.nn.silu(summed).astype(dtype)
 
 
 def _delta_heads(conv, config: Qwen3NextConfig):
-    """The convolution's output [T, channels] -> q and k [T, Hk, Dk]
-    L2-normalised (q scaled by ``Dk ** -0.5``) and v [T, Hv, Dv], all
-    float32."""
+    """The convolution's output [T, channels // lanes, lanes] -> q and k
+    [T, Hk, Dk] L2-normalised (q scaled by ``Dk ** -0.5``) and v [T, Hv,
+    Dv], all float32: whole rows each, and where ``lanes`` is the head
+    size a head a row."""
     hk, dk = config.lin_key_heads, config.lin_key_dim
+    rows = hk * dk // config.conv_lanes
     conv = conv.astype(jnp.float32)
-    q = conv[:, :hk * dk].reshape(-1, hk, dk)
-    k = conv[:, hk * dk:2 * hk * dk].reshape(-1, hk, dk)
-    v = conv[:, 2 * hk * dk:].reshape(
+    q = conv[:, :rows].reshape(-1, hk, dk)
+    k = conv[:, rows:2 * rows].reshape(-1, hk, dk)
+    v = conv[:, 2 * rows:].reshape(
         -1, config.lin_value_heads, config.lin_value_dim)
     unit = lambda x: x * jax.lax.rsqrt(
         jnp.square(x).sum(axis=-1, keepdims=True) + L2_EPS)
@@ -377,8 +405,9 @@ def prefill_into_pages(params, tokens, page_tables, pages, last_index,
     attends on the prompt in plain XLA; a DeltaNet layer runs the chunked
     rule with the padding masked (``beta = 0``, ``g = 0`` past
     ``last_index``) and writes the final state and the last convolution
-    inputs up to ``last_index`` WHOLE into the slot (zeros into the trash
-    slot), under every kernel choice. Returns (logits of the last token
+    inputs up to ``last_index`` (oldest first, zeros before the prompt's
+    start) WHOLE into the slot (zeros into the trash slot), under every
+    kernel choice. Returns (logits of the last token
     [1, V], pages)."""
     length = tokens.shape[1]
     kv = config.n_kv_heads
@@ -395,9 +424,9 @@ def prefill_into_pages(params, tokens, page_tables, pages, last_index,
         if kind:
             state_pool, conv_pool = pools
             inputs, z, beta, g = _delta_inputs(layer, normed, config)
-            padded = jnp.pad(inputs, ((taps - 1, 0), (0, 0)))
-            conv = _convolved(layer, jnp.stack(
-                [padded[j:j + length] for j in range(taps)]), x.dtype)
+            padded = jnp.pad(inputs, ((taps - 1, 0), (0, 0), (0, 0)))
+            conv = _convolved(
+                layer, [padded[j:j + length] for j in range(taps)], x.dtype)
             q, k, v = _delta_heads(conv, config)
             out, state = gated_delta.chunked_gated_delta(
                 q, k, v, jnp.where(real[:, None], g, 0.0),
@@ -432,7 +461,8 @@ def decode_step_paged(params, tokens, positions, page_tables, pages,
     the full group's, row 1 each lane's slot in column 0 (a padding lane
     the trash slot). A full layer writes the token's K/V and attends
     through ``kernels.attn``; a DeltaNet layer shifts the lane's
-    convolution inputs, and turns the lane's state in its slot
+    convolution inputs by one input (the taps are the slot's three and
+    the new one), and turns the lane's state in its slot
     (``gated_delta.gated_delta_step``, the kernel or the gather and
     scatter as ``kernels.name`` says). Returns (logits [B, V], pages,
     counters int32: :data:`COUNTERS`)."""
@@ -451,10 +481,12 @@ def decode_step_paged(params, tokens, positions, page_tables, pages,
             state_pool, conv_pool = pools
             inputs, z, beta, g = _delta_inputs(layer, normed, config)
             window = jnp.concatenate(
-                [conv_pool[slots], inputs[:, None]], axis=1)  # [B, taps, C]
-            conv = _convolved(layer, jnp.moveaxis(window, 1, 0), x.dtype)
+                [conv_pool[slots], inputs[:, None]], axis=1)  # [B, taps, ..]
+            conv = _convolved(
+                layer, [window[:, j] for j in range(config.conv_kernel)],
+                x.dtype)
             conv_pool = conv_pool.at[slots].set(
-                jnp.where(live[:, None, None], window[:, 1:], 0))
+                jnp.where(live[:, None, None, None], window[:, 1:], 0))
             q, k, v = _delta_heads(conv, config)
             out, state_pool = gated_delta.gated_delta_step(
                 q, k, v, g, beta, slots, state_pool, kernel=kernels.name)

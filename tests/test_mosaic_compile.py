@@ -512,10 +512,12 @@ def test_mosaic_compiles_the_state_update_kernel(one_chip, lanes, slots):
                 if " copy(" in line and f"f32[{slots},32,128,128]" in line]
 
 
-def _qwen3_next_at_the_cells_sizes(one_chip):
-    """(lowered decode step of ``lanes``, lowered prefill of ``tokens``)
-    of `qwen3_next` at the cell's sizes for the described v5e, as two
-    functions of those sizes."""
+@functools.lru_cache(maxsize=None)
+def _qwen3_next_compiled(one_chip, program, size):
+    """(optimised HLO text, memory analysis) of `qwen3_next`'s decode step
+    of ``size`` lanes or its prefill of ``size`` tokens at the cell's
+    sizes, compiled whole for the described v5e; once a program however
+    many tests read it."""
     import jax
     import jax.numpy as jnp
 
@@ -536,22 +538,20 @@ def _qwen3_next_at_the_cells_sizes(one_chip):
         lambda: qwen3_next.init_pages(config, [16385, 129], BLOCK)))
     ints = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
         shape, jnp.int32, sharding=one_chip)
-
-    def decode(lanes):
-        return jax.jit(
+    if program == "decode":
+        lowered = jax.jit(
             lambda p, t, at, tables, pages: qwen3_next.decode_step_paged(
                 p, t, at, tables, pages, config, kernels),
             donate_argnums=(4,)).lower(
-            params, ints(lanes), ints(lanes), ints(2, lanes, 128), pages)
-
-    def prefill(tokens):
-        return jax.jit(
+            params, ints(size), ints(size), ints(2, size, 128), pages)
+    else:
+        lowered = jax.jit(
             lambda p, t, table, pages, last: qwen3_next.prefill_into_pages(
                 p, t, table, pages, last, config, kernels),
             donate_argnums=(3,)).lower(
-            params, ints(1, tokens), ints(2, 128), pages, ints())
-
-    return decode, prefill
+            params, ints(1, size), ints(2, 128), pages, ints())
+    compiled = lowered.compile()
+    return compiled.as_text(), compiled.memory_analysis()
 
 
 def test_qwen3_nexts_longest_prefill_and_decode_fit_the_chip(one_chip):
@@ -561,28 +561,49 @@ def test_qwen3_nexts_longest_prefill_and_decode_fit_the_chip(one_chip):
     blocks, a state group of 129 slots). The bound: 10.1 GB of arguments
     (4.54 of weights, 2.15 of K/V, 3.32 of states) and under 1 GB of
     scratch in the prefill, 11 GB of the chip's 16; read here at
-    10,012,945,408 B of arguments, 248,639,488 B of scratch in the decode
-    step and 909,801,984 B in the prefill. No whole state pool (277 MB a
-    layer) is copied: every one is updated where it lies."""
-    decode, prefill = _qwen3_next_at_the_cells_sizes(one_chip)
-    decode = decode(128).compile()
-    memory = decode.memory_analysis()
+    10,008,816,640 B of arguments, 144,186,880 B of scratch in the decode
+    step (10,012,945,408 B and 248,639,488 B while the convolution pools
+    were ``[129, 3, 8192]``, padded to whole tiles of 16 slots, and each
+    was copied whole, twice a step) and under 1 GB in the prefill."""
+    text, memory = _qwen3_next_compiled(one_chip, "decode", 128)
     assert memory.argument_size_in_bytes < 10.1e9
-    assert memory.temp_size_in_bytes < 300e6
-    text = decode.as_text()
+    assert memory.temp_size_in_bytes < 175e6
     assert text.count("%gated_delta_step") >= 12
     assert text.count("%paged_attention") >= 4
     assert text.count("%moe_experts") >= 16
-    pool = re.compile(r"= f32\[129,32,128,128\]\S* (copy|dynamic-update-slice|"
-                      r"scatter|broadcast)\(")
-    assert not [line for line in text.splitlines() if pool.search(line)]
-    prefill = prefill(2048).compile()
-    memory = prefill.memory_analysis()
+    _, memory = _qwen3_next_compiled(one_chip, "prefill", 2048)
     assert memory.argument_size_in_bytes < 10.1e9
     assert memory.temp_size_in_bytes < 1.0e9
-    copied = re.compile(r"= f32\[129,32,128,128\]\S* copy\(")
-    assert not [line for line in prefill.as_text().splitlines()
-                if copied.search(line)]
+
+
+@pytest.mark.parametrize("program,size", [
+    ("decode", 128), ("prefill", 512), ("prefill", 2048)])
+def test_qwen3_next_updates_its_state_pools_where_they_lie(
+        one_chip, program, size):
+    """No whole state pool (277 MB a layer) and no whole convolution pool
+    (6.3 MB: ``[129, 3, 64, 128]``, a slot's three inputs folded into
+    their heads' rows, so that a slot owns 12 whole tiles and a gather or
+    a scatter by slot moves those alone) is copied in the cell's decode
+    step or in its prefills: every one is updated where it lies. As
+    ``[129, 3, 8192]`` the pool lay in HBM with its slots second to last
+    and was copied whole before every gather and after every scatter, 24
+    copies a decode step; as one row ``[129, 24576]`` a slot it was not
+    copied, and 16 slots shared every tile (a scatter of 110-200 us a
+    layer on the chip). What the prefill holds of a pool is one
+    ``dynamic-update-slice``, the slot's write into the donated buffer."""
+    text, _ = _qwen3_next_compiled(one_chip, program, size)
+    rows = r"bf16\[129,3,64,128\]"
+    if program == "prefill":
+        held = rf"(f32\[129,32,128,128\]|{rows})\S* copy"
+    else:
+        # the convolution pool's scatter is the slots' own, in place
+        held = (rf"(f32\[129,32,128,128\]\S* (copy|dynamic-update-slice|"
+                rf"scatter|broadcast)|{rows}\S* (copy|dynamic-update-slice|"
+                rf"broadcast))")
+    pool = re.compile(rf"= {held}\(")
+    assert "bf16[129,3,64,128]{3,2,1,0:T(8,128)(2,1)}" in text
+    assert "bf16[129,3,8192]" not in text and "bf16[129,24576]" not in text
+    assert not [line for line in text.splitlines() if pool.search(line)]
 
 
 def test_qwen3_nexts_prefill_holds_no_triangular_solve(one_chip):
@@ -594,8 +615,7 @@ def test_qwen3_nexts_prefill_holds_no_triangular_solve(one_chip):
     chip). Its only custom calls on the device are the named Mosaic
     kernels; the four others are the compiler's own bookkeeping of
     buffers and gather indices and run nothing."""
-    _, prefill = _qwen3_next_at_the_cells_sizes(one_chip)
-    text = prefill(512).compile().as_text()
+    text, _ = _qwen3_next_compiled(one_chip, "prefill", 512)
     assert " triangular-solve(" not in text
     calls = [line for line in text.splitlines() if " custom-call(" in line]
     targets = {re.search(r'custom_call_target="([^"]+)"', line).group(1)

@@ -217,6 +217,181 @@ def test_the_kernel_choices_agree_and_a_bf16_state_would_not_pass():
     assert _worst(params, tokens, narrow, TOY) > 100 * TOLERANCE
 
 
+# -- a slot's convolution inputs ---------------------------------------------------
+
+
+#: the prefill bucket and the slot of the one sequence below, of three
+ROW_BUCKET, ROW_SLOT = 8, 2
+
+
+def _layer0_inputs(params, ids):
+    """The first layer's convolution inputs [T, channels] for token
+    ``ids``: that layer is a DeltaNet layer and reads the embeddings, so
+    these depend on no state and no convolution."""
+    from client_tpu.models import qwen3_next
+
+    config = _config()
+    layer = params["layers"][0]
+    normed = qwen3_next._norm(
+        params["embed"][np.asarray(ids)], layer["mixer_norm"],
+        config.norm_eps)
+    inputs = qwen3_next._delta_inputs(layer, normed, config)[0]
+    return np.asarray(inputs).reshape(len(ids), -1)
+
+
+def _slot_rows(conv_pool):
+    """A convolution pool [slots, taps - 1, heads, lanes] as one row a
+    slot: the three inputs side by side, each its channels in order."""
+    return np.asarray(conv_pool).reshape(conv_pool.shape[0], -1)
+
+
+@functools.lru_cache(maxsize=None)
+def _row_programs(kernel_name):
+    """(float32 params, a jitted prefill at the bucket, a jitted decode
+    step, empty pages of three slots and the trash slot)."""
+    import jax
+
+    from benchmark.lib import weights_qwen3next
+    from client_tpu.models import qwen3_next
+
+    kernels, config = _kernels(kernel_name), _config()
+    params = _to32(weights_qwen3next.params(SEED, TOY))
+    pages = qwen3_next.init_pages(
+        config, [1 + _row_tables().shape[1], 4], BLOCK)
+    prefill = jax.jit(
+        lambda *a: qwen3_next.prefill_into_pages(*a, config, kernels))
+    decode = jax.jit(
+        lambda *a: qwen3_next.decode_step_paged(*a, config, kernels))
+    return params, prefill, decode, pages
+
+
+def _row_tables(slot=ROW_SLOT):
+    """[2, columns]: the full group's blocks 1.., the slot in column 0."""
+    width = TOY["max_position_embeddings"] // BLOCK
+    table = np.zeros((2, width), np.int32)
+    table[0] = 1 + np.arange(width)
+    table[1, 0] = slot
+    return table
+
+
+def _prefilled(kernel_name, ids, slot=ROW_SLOT):
+    """(logits [V], pages) after a prefill of ``ids`` into ``slot``, the
+    bucket's rest filled with tokens that the masks must keep out."""
+    params, prefill, _, pages = _row_programs(kernel_name)
+    padded = np.full((1, ROW_BUCKET), 77, np.int32)
+    padded[0, :len(ids)] = ids
+    logits, pages = prefill(
+        params, padded, _row_tables(slot), pages, len(ids) - 1)
+    return np.asarray(logits[0]), pages
+
+
+def _stepped(kernel_name, pages, token, position, padding_token=0):
+    """(the lane's logits [V], pages) after one decode step of the
+    sequence in ``ROW_SLOT`` beside a padding lane on the trash slot."""
+    params, _, decode, _ = _row_programs(kernel_name)
+    tables = np.stack([_row_tables(), _row_tables(0)], axis=1)
+    tables[0, 1] = 0  # the padding lane writes its K/V to the trash block
+    logits, pages, _ = decode(
+        params, np.array([token, padding_token], np.int32),
+        np.array([position, 0], np.int32), tables, pages)
+    return np.asarray(logits[0]), pages
+
+
+@pytest.mark.parametrize("length", [1, 2, 5])
+def test_a_prefill_leaves_the_last_three_inputs_in_the_slot_oldest_first(
+        length):
+    """The slot after a prefill holds ``[input(last - 2), input(last -
+    1), input(last)]``, each folded into its heads' rows, zeros standing
+    for the inputs before the prompt's start, and none of the bucket's
+    padding; no other slot is written, and a prefill into the trash slot
+    leaves zeros there."""
+    ids = list(range(40, 40 + length))
+    params = _row_programs("fused_xla")[0]
+    channels = _config().conv_dim
+    inputs = _layer0_inputs(params, ids)
+    assert np.abs(inputs).max() > 0.1
+    expected = np.concatenate(
+        [np.zeros((3, channels), np.float32), inputs])[-3:].reshape(-1)
+    _, pages = _prefilled("fused_xla", ids)
+    config = _config()
+    assert pages[0][1].shape == (
+        4, 3, channels // config.conv_lanes, config.conv_lanes)
+    conv_pool = _slot_rows(pages[0][1])
+    np.testing.assert_array_equal(conv_pool[ROW_SLOT], expected)
+    assert np.abs(conv_pool[ROW_SLOT, -channels:]).max() > 0.1
+    assert not conv_pool[[0, 1, 3]].any()
+    _, trashed = _prefilled("fused_xla", ids, slot=0)
+    for (state_pool, conv_pool), kind in zip(trashed, _config().layer_kinds):
+        if kind:
+            assert not np.asarray(state_pool).any()
+            assert not np.asarray(conv_pool).any()
+
+
+@pytest.mark.parametrize("kernel", ["fused_xla", "pallas_interpret"])
+def test_a_decode_step_shifts_the_slot_by_one_input_and_spares_the_trash_slot(
+        kernel):
+    """A step drops the slot's oldest input and appends the token's own;
+    the padding lane beside it (a token whose inputs are not zero) names
+    the trash slot, whose row and state stay zero in every layer."""
+    ids = [40, 41, 42, 43, 44]
+    params = _row_programs(kernel)[0]
+    channels = _config().conv_dim
+    _, pages = _prefilled(kernel, ids)
+    before = _slot_rows(pages[0][1])[ROW_SLOT]
+    _, pages = _stepped(kernel, pages, 45, len(ids), padding_token=99)
+    after = _slot_rows(pages[0][1])
+    np.testing.assert_array_equal(
+        after[ROW_SLOT, :2 * channels], before[channels:])
+    np.testing.assert_array_equal(
+        after[ROW_SLOT, 2 * channels:], _layer0_inputs(params, [45])[0])
+    assert np.abs(_layer0_inputs(params, [99])).max() > 0.1
+    assert not after[[1, 3]].any()
+    for (state_pool, conv_pool), kind in zip(pages, _config().layer_kinds):
+        if kind:
+            assert not np.asarray(state_pool[0]).any()
+            assert not np.asarray(conv_pool[0]).any()
+            assert np.abs(np.asarray(conv_pool[ROW_SLOT])).max() > 0.1
+
+
+@pytest.mark.parametrize("prompt", [2, 5])
+@pytest.mark.parametrize("kernel", ["fused_xla", "pallas_interpret"])
+def test_a_prefill_then_decode_steps_equal_the_recurrence_token_by_token(
+        kernel, prompt):
+    """A prompt prefilled whole (the chunked rule, the slot's inputs
+    written at once) and then decoded equals the same tokens walked one a
+    step from a prefill of the first alone (the slot filled an input a
+    step, the state turned a token a step), in its logits, in every
+    layer's inputs and state, and both equal the plain reference's
+    logits."""
+    from benchmark.lib import reference_qwen3next
+
+    rng = np.random.default_rng(prompt)
+    ids = rng.integers(1, 256, size=12).tolist()
+    params = _row_programs(kernel)[0]
+
+    def walk(start):
+        logits, pages = _prefilled(kernel, ids[:start])
+        rows = [logits]
+        for position in range(start, len(ids)):
+            logits, pages = _stepped(kernel, pages, ids[position], position)
+            rows.append(logits)
+        return np.stack(rows), pages
+
+    whole, whole_pages = walk(prompt)
+    stepped, stepped_pages = walk(1)
+    assert np.abs(whole - stepped[prompt - 1:]).max() <= TOLERANCE
+    ref = np.asarray(reference_qwen3next.forward(
+        np.asarray(ids), params, params["layers"], TOY, (0, 16)))
+    assert np.abs(ref).max() > 1.0
+    assert np.abs(whole - ref[prompt - 1:]).max() <= TOLERANCE
+    assert np.abs(stepped - ref).max() <= TOLERANCE
+    for a, b, kind in zip(whole_pages, stepped_pages, _config().layer_kinds):
+        if kind:
+            assert np.abs(np.asarray(a[1])).max() > 0.1
+            assert np.abs(np.asarray(a[1]) - np.asarray(b[1])).max() <= 1e-5
+            assert np.abs(np.asarray(a[0]) - np.asarray(b[0])).max() <= 1e-5
+
+
 # -- the rule's three forms ------------------------------------------------------
 
 
@@ -661,7 +836,7 @@ def test_engine_serves_the_model_over_a_state_group_and_a_full_group():
         engine = model.engine
         state_pool, conv_pool = engine._pages[0]
         assert state_pool.shape == (4, 4, 16, 16)
-        assert conv_pool.shape == (4, 3, 128)
+        assert conv_pool.shape == (4, 3, 8, 16)
         assert all(pool.shape == (49, 16, 16) for pool in engine._pages[3])
         assert engine._tile_pages == (
             pa.pages_per_tile(16, 1, 16, np.float32, 2), 1)
