@@ -9,8 +9,8 @@ this module then sets nothing — and otherwise it is ``<checkout>/.jax_cache``,
 a fixed path with no tmp name, pid or time in it.
 
 Entry points call :func:`enable_compile_cache` once, before their first
-compilation: ``python -m client_tpu.server``, ``client_tpu.pod.worker``,
-``bench.py`` and the pytest TPU tier (``tests/conftest.py``).
+compilation: ``python -m client_tpu.server``, ``client_tpu.pod.worker``
+and the pytest TPU tier (``tests/conftest.py``).
 """
 
 import os

@@ -239,7 +239,7 @@ def build_parser() -> argparse.ArgumentParser:
     parser.add_argument(
         "--json-summary", action="store_true",
         help="print ONE machine-readable JSON line with the headline LLM "
-        "metrics (TTFT/ITL in ms, tokens/sec) — the bench.py/CI "
+        "metrics (TTFT/ITL in ms, tokens/sec), for scripts: the "
         "counterpart of the perf harness's --json-summary",
     )
     parser.add_argument("-v", "--verbose", action="store_true")

@@ -17,9 +17,9 @@ sub-buffers that survive ring churn under load:
 
 Exposed as ``GET /v2/debug/requests``; the perf harness's
 ``--dump-slow-requests N`` prints the slowest sub-buffer stage-decomposed
-at the end of a run. Recording is a dict build + one lock + a deque
-append (+ a heap op when the request makes the slow cut) — cheap enough
-to stay on by default (measured in PERF.md).
+at the end of a run. Recording is one clock read, a dict build, one lock
+and a deque append (+ a heap op when the request makes the slow cut), so
+it stays on by default (``tests/test_logging.py`` holds the counts).
 
 Thread-safe: exemplars arrive from the event loop, the native front-end's
 pump thread, and executor threads. Clock-injectable (wall timestamps

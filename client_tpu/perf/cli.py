@@ -544,9 +544,9 @@ async def run(args) -> int:
     fleet_runner = None
     if args.fleet:
         # Launch the replica fleet FIRST so the url/metrics wiring below
-        # sees the real addresses. One process, N event loops: fine for
-        # robustness/chaos runs; use subprocess replicas
-        # (tools/bench_fleet.py) when measuring aggregate scaling.
+        # sees the real addresses. One process, N event loops under one
+        # GIL: right for robustness/chaos runs, wrong for aggregate
+        # scaling (that needs a process a replica: fleet_runner --serve).
         from client_tpu.perf.fleet_runner import FleetRunner
 
         fleet_runner = FleetRunner(args.fleet, grpc="aio").start()

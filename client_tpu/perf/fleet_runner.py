@@ -19,10 +19,10 @@ replicas:
   ``--rolling-restart``: while a measurement runs, cycle replicas
   through drain -> restart round-robin (one at a time, never two).
 * ``python -m client_tpu.perf.fleet_runner --serve`` — one replica as a
-  subprocess (its own GIL and CPU budget; ``tools/bench_fleet.py``
-  spawns N of these so aggregate throughput can actually scale past one
-  interpreter). Prints a JSON line with the bound ports, serves until
-  SIGTERM, drains on the way out.
+  subprocess (its own GIL and CPU budget, so that N of them scale
+  aggregate throughput past one interpreter; ``client_tpu.router``
+  fronts such a fleet). Publishes its bound ports as a JSON file or a
+  JSON line, serves until SIGTERM, drains on the way out.
 * :class:`DeviceBoundModel` — a host-free stand-in for an
   accelerator-bound model: each batched execution *waits* (the device
   would be computing; the host is idle), so one replica's capacity is
@@ -603,7 +603,7 @@ class Autoscaler:
 
 
 # ---------------------------------------------------------------------------
-# subprocess replica mode (tools/bench_fleet.py spawns N of these)
+# subprocess replica mode: one replica a process, ports handed over by file
 
 
 def write_ports_file(path: str, ports: dict) -> None:

@@ -222,7 +222,6 @@ def format_server_metrics(summary: ServerMetricsSummary) -> str:
 def format_wire_gap(
     summary: ServerMetricsSummary,
     clock_mode: str = "",
-    inproc_us_per_req: float = 0.0,
 ) -> str:
     """The "Wire-gap attribution" table (``--profile-server``): the
     server's per-stage thread-CPU µs per request, from the
@@ -231,8 +230,6 @@ def format_wire_gap(
     Splits the stages into wire-only work (decode/encode/rpc — CPU the
     in-process path never pays: the directly-attributable slice of the
     wire gap) and shared work (assembly/device_put/compute/readback).
-    ``inproc_us_per_req`` (when the caller measured an in-process
-    baseline, e.g. bench.py) adds the explicit gap line.
     """
     from client_tpu.observability.profiling import STAGES, WIRE_ONLY_STAGES
 
@@ -281,42 +278,7 @@ def format_wire_gap(
         f"us/req vs shared stages ({'+'.join(shared_stages)}) "
         f"{shared_us:.1f} us/req"
     )
-    if inproc_us_per_req > 0:
-        lines.append(
-            f"  in-process baseline {inproc_us_per_req:.1f} us/req -> "
-            f"directly-attributed wire gap {wire_us:.1f} us/req"
-        )
     return "\n".join(lines)
-
-
-def format_shm_delta(
-    shm_infer_per_sec: float,
-    native_infer_per_sec: float,
-    tensor_bytes: int = 0,
-    label: str = "shm",
-) -> str:
-    """The shm-vs-inline verdict as a named number.
-
-    The round-5 bench row buried an inversion (tpu-shm slower than inline gRPC at
-    small tensor sizes) in an unlabeled JSON field for four rounds; this
-    renders the delta explicitly and FLAGS the loss, so a shm path that
-    stops paying for itself is a headline, not an easter egg.
-    """
-    if shm_infer_per_sec <= 0 or native_infer_per_sec <= 0:
-        return ""
-    ratio = shm_infer_per_sec / native_infer_per_sec
-    delta_pct = (ratio - 1.0) * 100.0
-    size = f" at {tensor_bytes} B/tensor" if tensor_bytes else ""
-    line = (
-        f"{label} vs inline wire{size}: {shm_infer_per_sec:.0f} vs "
-        f"{native_infer_per_sec:.0f} infer/sec ({delta_pct:+.1f}%)"
-    )
-    if ratio < 1.0:
-        line += (
-            f"  ** {label.upper()} LOSES at this tensor size — the "
-            "copy savings do not cover its per-request overhead **"
-        )
-    return line
 
 
 def format_client_metrics(

@@ -18,7 +18,7 @@ daemon thread — the same harness shape as
 import asyncio
 import json
 import threading
-from typing import Dict, Optional, Sequence, Tuple
+from typing import Dict, Optional, Tuple
 
 import grpc
 

@@ -869,8 +869,8 @@ class ServerCore:
             name="server"
         )
         # Per-request exemplars of recent/failed/slowest requests
-        # (GET /v2/debug/requests). On by default — recording is a dict
-        # build + lock + deque append; measured overhead in PERF.md.
+        # (GET /v2/debug/requests). On by default — recording is one clock
+        # read, a dict build, a lock and a deque append a request.
         self.flight_recorder = (
             flight_recorder if flight_recorder is not None else FlightRecorder()
         )

@@ -44,7 +44,7 @@ perf collector) derive their own rate from ``tpu_device_compute_ns_total``.
 
 import threading
 import time
-from typing import Callable, Optional
+from typing import Callable
 
 from client_tpu.observability.metrics import (
     Counter,

@@ -1,13 +1,12 @@
 """Retry shim for grpcio's process-global aio poller flake.
 
-Deep into a long test or bench session, grpcio's process-global aio
-poller occasionally breaks down with EAGAIN (upstream flake, observed as
-a driver run that completes with ZERO successful requests while the
-server is demonstrably healthy). The affected call sites — the
-genai-perf e2e test and the bench.py LLM cells — all carried their own
-copy of the same two-attempt loop; this is the one shared
-implementation. A genuine regression fails every attempt, so the retry
-cannot mask one.
+Deep into a long test session, grpcio's process-global aio poller
+occasionally breaks down with EAGAIN (upstream flake, observed as a
+driver run that completes with ZERO successful requests while the
+server is demonstrably healthy). The affected call sites (the
+genai-perf e2e test, the pod, router and fleet tests) share this one
+two-attempt loop. A genuine regression fails every attempt, so the
+retry cannot mask one.
 """
 
 import functools

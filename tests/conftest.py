@@ -408,3 +408,156 @@ def pytest_collection_modifyitems(config, items):
         for item in items:
             if "tpu" in item.keywords:
                 item.add_marker(skip_tpu)
+
+
+@pytest.fixture
+def drive_core_path():
+    """``drive(core, path, flags)``: one request a flag down ONE of
+    ``ServerCore``'s four execution paths, all at once, and what each
+    came back as (a response, a list of a stream's responses, or the
+    exception). ``path`` is ``single`` (``_infer_single``), ``batcher``
+    (``_ModelBatcher``), ``direct`` (``infer_direct``) or ``decoupled``
+    (``infer_decoupled``). The model it registers for the path adds one
+    to its ``[1, 4]`` input and raises where a row's flag is 999; it
+    declares an SLO, so that the telemetry's budget window counts
+    failures too. On the two merging paths the flags of one call share
+    one execution, so one 999 among them fails them all.
+    """
+    import asyncio
+
+    import numpy as np
+
+    from client_tpu.server.core import CoreRequest, CoreTensor
+    from client_tpu.server.model_repository import Model
+
+    def add_one(x):
+        if (np.asarray(x) == 999.0).any():
+            raise RuntimeError("flag 999: injected model failure")
+        return x + 1.0
+
+    class PathModel(Model):
+        inputs = [{"name": "X", "datatype": "FP32", "shape": [4]}]
+        outputs = [{"name": "Y", "datatype": "FP32", "shape": [4]}]
+        slo = {"availability": 0.9}
+
+        def __init__(self, name, max_batch_size):
+            self.name = name
+            self.max_batch_size = max_batch_size
+
+        def execute(self, inputs, parameters):
+            return {"Y": add_one(inputs["X"])}
+
+    class StreamModel(PathModel):
+        decoupled = True
+
+        async def execute_decoupled(self, inputs, parameters):
+            y = add_one(inputs["X"])
+            yield {"Y": y}
+            yield {"Y": y, "__final__": True}
+
+    models = {
+        "single": PathModel("path_single", 0),
+        "batcher": PathModel("path_batcher", 8),
+        "direct": PathModel("path_direct", 8),
+        "decoupled": StreamModel("path_decoupled", 0),
+    }
+
+    def drive(core, path, flags):
+        model = models[path]
+        if core.repository.peek(model.name) is None:
+            core.repository.add_model(model)
+        requests = [
+            CoreRequest(
+                model_name=model.name,
+                id=f"{path}-{i}",
+                inputs=[
+                    CoreTensor(
+                        "X", "FP32", [1, 4],
+                        np.full([1, 4], flag, dtype=np.float32),
+                    )
+                ],
+            )
+            for i, flag in enumerate(flags)
+        ]
+        if path == "direct":
+            return core.infer_direct(requests)
+
+        async def one(request):
+            try:
+                if path == "decoupled":
+                    return [r async for r in core.infer_decoupled(request)]
+                return await core.infer(request)
+            except RuntimeError as e:
+                return e
+
+        async def all_at_once():
+            return await asyncio.gather(*(one(r) for r in requests))
+
+        return asyncio.run(all_at_once())
+
+    return drive
+
+
+@pytest.fixture
+def loopback_echo():
+    """``with loopback_echo(core) as echo``: ``core`` serving a pure-numpy
+    echo model (``echo``, unbatched: JAX stays out of the request) over
+    loopback HTTP; ``echo.send(n)`` makes n OK requests one after
+    another on one connection, ``echo.get(path)`` reads a debug
+    endpoint's JSON."""
+    import http.client
+    import json
+
+    from client_tpu.server.model_repository import Model
+    from client_tpu.testing import InProcessServer
+
+    class EchoModel(Model):
+        inputs = [{"name": "X", "datatype": "FP32", "shape": [-1, 4]}]
+        outputs = [{"name": "Y", "datatype": "FP32", "shape": [-1, 4]}]
+        name = "echo"
+        max_batch_size = 0
+
+        def execute(self, inputs, parameters):
+            return {"Y": inputs["X"] + 1.0}
+
+    body = json.dumps({
+        "inputs": [{
+            "name": "X", "datatype": "FP32", "shape": [1, 4],
+            "data": [1.0, 2.0, 3.0, 4.0],
+        }]
+    }).encode()
+
+    class LoopbackEcho:
+        def __init__(self, core):
+            core.repository.add_model(EchoModel())
+            self._server = InProcessServer(
+                core=core, grpc=False, builtin_models=False
+            )
+
+        def __enter__(self):
+            server = self._server.__enter__()
+            self._conn = http.client.HTTPConnection(
+                server._host, server.http_port, timeout=30
+            )
+            return self
+
+        def __exit__(self, *exc):
+            self._conn.close()
+            return self._server.__exit__(*exc)
+
+        def send(self, n):
+            for _ in range(n):
+                self._conn.request(
+                    "POST", "/v2/models/echo/infer", body=body
+                )
+                resp = self._conn.getresponse()
+                resp.read()
+                assert resp.status == 200
+
+        def get(self, path):
+            self._conn.request("GET", path)
+            resp = self._conn.getresponse()
+            assert resp.status == 200
+            return json.loads(resp.read())
+
+    return LoopbackEcho

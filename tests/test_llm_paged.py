@@ -1318,54 +1318,6 @@ def test_submit_accepts_post_match_demand_and_fails_cleanly_when_gone():
 # ---------------------------------------------------------------------------
 
 
-def test_bench_trajectory_kernel_gates(tmp_path):
-    """BENCH_r13+ gates: fused-kernel regression + speedup floor +
-    prefix hit-rate floor, and the new table columns."""
-    import json
-
-    from tools.bench_trajectory import check_regression, format_table, load_runs
-
-    def write(run, kernel_row):
-        parsed = {"value": 100.0, "harness": "python-grpc-aio"}
-        if kernel_row:
-            parsed["llm_decode_kernel"] = kernel_row
-        (tmp_path / f"BENCH_r{run:02d}.json").write_text(
-            json.dumps({"rc": 0, "parsed": parsed})
-        )
-
-    healthy = {
-        "fused_tokens_per_sec": 4000.0,
-        "speedup_min": 1.2,
-        "prefix_sharing": {"prefix_hit_rate": 0.6},
-    }
-    write(1, healthy)
-    write(2, healthy)
-    runs = load_runs(str(tmp_path))
-    assert check_regression(runs) is None
-    table = format_table(runs)
-    assert "kernel tok/s" in table and "prefix hit" in table
-    assert "4000" in table and "0.60" in table
-
-    # >10% fused throughput drop is flagged
-    write(3, {**healthy, "fused_tokens_per_sec": 3000.0})
-    problem = check_regression(load_runs(str(tmp_path)))
-    assert problem and "llm_decode_kernel" in problem
-
-    # fused slower than the stand-in on any cell is flagged
-    write(4, {**healthy, "speedup_min": 0.9})
-    problem = check_regression(load_runs(str(tmp_path)))
-    assert problem and "speedup floor" in problem
-
-    # a zero hit rate on the shared-prefix workload is flagged
-    write(5, {**healthy, "prefix_sharing": {"prefix_hit_rate": 0.0}})
-    problem = check_regression(load_runs(str(tmp_path)))
-    assert problem and "prefix sharing floor" in problem
-
-    # back to healthy: clean again
-    write(6, healthy)
-    assert check_regression(load_runs(str(tmp_path))) is None
-
-
 def test_create_llm_inputs_shared_prefix_and_routing_key(tmp_path):
     from client_tpu.genai_perf.inputs import create_llm_inputs
 

@@ -16,8 +16,8 @@ Five tiers:
   sampled streams replay identically across preemption WITH speculation
   enabled;
 - surfaces: spec counters in /metrics and ``/v2/debug/state``, the
-  genai-perf ``--speculation`` passthrough + ``--json-summary`` fields,
-  and the bench-trajectory tokens/step floor gate.
+  and the genai-perf ``--speculation`` passthrough + ``--json-summary``
+  fields.
 """
 
 import asyncio
@@ -781,40 +781,3 @@ def test_genai_perf_speculation_flag_rides_cli(tmp_path, monkeypatch):
     assert code == 0
     for entry in captured["doc"]["data"]:
         assert entry["parameters"]["speculation"] == "off"
-
-
-def test_bench_trajectory_spec_gate(tmp_path):
-    """BENCH_r14+ gates: the spec tokens/step column renders and the
-    >= 1.0 floor flags broken accounting."""
-    import json
-
-    from tools.bench_trajectory import check_regression, format_table, load_runs
-
-    def write(run, spec):
-        parsed = {
-            "value": 100.0,
-            "harness": "python-grpc-aio",
-            "llm_generate": {"tokens_per_sec": 500.0},
-        }
-        if spec is not None:
-            parsed["llm_generate"]["speculation"] = spec
-        (tmp_path / f"BENCH_r{run:02d}.json").write_text(
-            json.dumps({"rc": 0, "parsed": parsed})
-        )
-
-    healthy = {"tokens_per_step": 2.8, "acceptance_rate": 0.9}
-    write(1, None)
-    write(2, healthy)
-    runs = load_runs(str(tmp_path))
-    assert check_regression(runs) is None
-    table = format_table(runs)
-    assert "spec tok/step" in table
-    assert "2.80" in table
-
-    # a tokens/step below 1.0 can only be broken accounting — flagged
-    write(3, {"tokens_per_step": 0.7, "acceptance_rate": 0.9})
-    problem = check_regression(load_runs(str(tmp_path)))
-    assert problem and "speculation floor" in problem
-
-    write(4, healthy)
-    assert check_regression(load_runs(str(tmp_path))) is None

@@ -7,15 +7,16 @@ that CHANGE emission live on both front-ends, a deliberately failed
 request retrievable from /v2/debug/requests with stage timings + error
 text + trace id, /v2/debug/state under concurrent load and during drain,
 EndpointPool/CircuitBreaker client-side events, the print/stdlib-logging
-lint, the perf harness --dump-slow-requests/--log-file flags, and the
-<2% p50 overhead guard for the default-on recorder (PR 6 A/B pattern).
+lint, the perf harness --dump-slow-requests/--log-file flags, and what
+the default-on recorder and the quiet logger cost as counts on counting
+clocks: clock reads, records and log lines a request, on each of
+``ServerCore``'s four execution paths, and none with no capacity.
 """
 
 import asyncio
 import io
 import json
 import threading
-import time
 import urllib.request
 
 import numpy as np
@@ -721,91 +722,136 @@ def test_cli_dump_slow_requests_and_log_file(tmp_path, capsys):
 
 
 # ---------------------------------------------------------------------------
-# acceptance: default-on recorder + quiet logging cost <2% p50 (PR 6 A/B)
+# what the default-on recorder and the quiet logger cost, as counts
 
 
-def _median(values):
-    ordered = sorted(values)
-    return ordered[len(ordered) // 2]
+class _CountingClock:
+    """A wall-seconds clock that advances a millisecond a read and
+    counts its reads."""
+
+    def __init__(self):
+        self.reads = 0
+
+    def __call__(self) -> float:
+        self.reads += 1
+        return 1000.0 + self.reads / 1e3
 
 
-def test_recorder_and_logging_overhead_under_two_percent():
-    """With default settings (recorder ON, verbose logging OFF) the
-    loopback echo p50 regresses <2% vs a disabled recorder. Same
-    noise-aware A/B harness as the profiling overhead guard: interleaved
-    OFF->ON->OFF triplets, the OFF-vs-OFF null ratio as the host's
-    resolution floor, skip with evidence when the box cannot resolve 2%.
-    """
-    import http.client
-
+def _counted_core(recorder_capacity=None):
+    """A ServerCore whose flight recorder and logger run on counting
+    clocks, the logger writing to a list; ``recorder_capacity`` sizes
+    the recorder's ring and slowest heap (None: the defaults)."""
     from client_tpu.server.core import ServerCore
-    from client_tpu.server.model_repository import Model, ModelRepository
+    from client_tpu.server.model_repository import ModelRepository
 
-    class EchoModel(Model):
-        inputs = [{"name": "X", "datatype": "FP32", "shape": [-1, 4]}]
-        outputs = [{"name": "Y", "datatype": "FP32", "shape": [-1, 4]}]
-        name = "echo"
-        max_batch_size = 0
-
-        def execute(self, inputs, parameters):
-            return {"Y": inputs["X"] + 1.0}
-
-    core = ServerCore(ModelRepository())
-    core.repository.add_model(EchoModel())
-    on_recorder = core.flight_recorder
-    off_recorder = FlightRecorder(capacity=0, slow_capacity=0)
-    body = json.dumps({
-        "inputs": [{
-            "name": "X", "datatype": "FP32", "shape": [1, 4],
-            "data": [1.0, 2.0, 3.0, 4.0],
-        }]
-    }).encode()
-
-    with InProcessServer(core=core, grpc=False, builtin_models=False) as srv:
-        conn = http.client.HTTPConnection(
-            srv._host, srv.http_port, timeout=30
-        )
-        try:
-            def p50(n=30):
-                latencies = []
-                for _ in range(n):
-                    t0 = time.monotonic_ns()
-                    conn.request("POST", "/v2/models/echo/infer", body=body)
-                    resp = conn.getresponse()
-                    resp.read()
-                    assert resp.status == 200
-                    latencies.append(time.monotonic_ns() - t0)
-                latencies.sort()
-                return latencies[len(latencies) // 2]
-
-            p50(60)  # warm up (route caches, connection, allocator)
-            ab_ratios, null_ratios = [], []
-            for _ in range(8):
-                core.flight_recorder = off_recorder
-                off_a = p50()
-                core.flight_recorder = on_recorder
-                on = p50()
-                core.flight_recorder = off_recorder
-                off_b = p50()
-                ab_ratios.append(2 * on / (off_a + off_b))
-                null_ratios.append(off_b / off_a)
-            core.flight_recorder = on_recorder
-        finally:
-            conn.close()
-    ab = _median(ab_ratios)
-    null = _median(null_ratios)
-    null_noise = _median([abs(r - 1.0) for r in null_ratios])
-    if ab < 1.02:
-        return  # the bound holds outright
-    if null_noise > 0.015 or abs(null - 1.0) > 0.015:
-        pytest.skip(
-            f"host noise (null OFF/OFF p50 ratio {null:.3f}, typical "
-            f"deviation {null_noise:.3f}) exceeds the 2% resolution this "
-            "assertion needs"
-        )
-    assert ab <= null + 0.02, (
-        f"recorder+logging overhead too high: median p50 ratio on/off "
-        f"{ab:.4f} vs null {null:.4f} "
-        f"(ab {[round(r, 3) for r in sorted(ab_ratios)]}, "
-        f"null {[round(r, 3) for r in sorted(null_ratios)]})"
+    recorder_clock, logger_clock = _CountingClock(), _CountingClock()
+    events = []
+    sizes = (
+        {}
+        if recorder_capacity is None
+        else {"capacity": recorder_capacity, "slow_capacity": recorder_capacity}
     )
+    core = ServerCore(
+        ModelRepository(),
+        logger=StructuredLogger(
+            name="server", sink=events.append, clock=logger_clock
+        ),
+        flight_recorder=FlightRecorder(clock=recorder_clock, **sizes),
+    )
+    return core, recorder_clock, logger_clock, events
+
+
+def test_recorder_costs_one_clock_read_and_the_quiet_logger_none(loopback_echo):
+    """With default settings (recorder ON, verbose logging OFF) a
+    loopback request costs ONE read of the recorder's clock and leaves
+    ONE record, and the logger neither reads its clock nor writes; at
+    ``log_verbose_level`` 1 the logger writes ONE line a request for one
+    read of its clock. A later change that adds a read or a line a
+    request has to change the numbers here."""
+    n = 24
+    core, recorder_clock, logger_clock, events = _counted_core()
+    with loopback_echo(core) as echo:
+        reads, lines = logger_clock.reads, len(events)  # start-up's own
+        echo.send(n)
+        assert recorder_clock.reads == n
+        assert core.flight_recorder.stats() == {
+            "recorded_total": n, "error_total": 0, "rejected_total": 0,
+            "recent": n, "errors": 0, "slowest": n,
+        }
+        assert (logger_clock.reads, len(events)) == (reads, lines)
+        core.update_log_settings({"log_verbose_level": 1})
+        echo.send(n)
+        assert logger_clock.reads == reads + n
+        written = events[lines:]
+        assert [e["event"] for e in written] == ["request"] * n
+        assert recorder_clock.reads == 2 * n
+
+
+def test_recorder_of_no_capacity_stores_nothing(loopback_echo):
+    """``FlightRecorder(capacity=0, slow_capacity=0)`` under N requests
+    reads no clock, counts, stores and exports nothing, and the default
+    log settings write no line for an OK request."""
+    n = 24
+    core, recorder_clock, logger_clock, events = _counted_core(
+        recorder_capacity=0
+    )
+    with loopback_echo(core) as echo:
+        reads, lines = logger_clock.reads, len(events)  # start-up's own
+        echo.send(n)
+        assert (logger_clock.reads, len(events)) == (reads, lines)
+        exported = echo.get("/v2/debug/requests")
+        state = echo.get("/v2/debug/state")["flight_recorder"]
+    assert recorder_clock.reads == 0
+    assert state == {
+        "recorded_total": 0, "error_total": 0, "rejected_total": 0,
+        "recent": 0, "errors": 0, "slowest": 0,
+    }
+    assert (
+        exported["recent"], exported["errors"], exported["slowest"]
+    ) == ([], [], [])
+
+
+@pytest.mark.parametrize(
+    "path,label",
+    [
+        ("single", "single"),
+        ("batcher", "batch"),
+        ("direct", "direct"),
+        ("decoupled", "decoupled"),
+    ],
+)
+def test_one_record_a_request_on_every_path(drive_core_path, path, label):
+    """The accounting a merged spine of ``ServerCore``'s four execution
+    paths has to keep (ROADMAP D5): N requests leave exactly N
+    flight-recorder records under the path's own label, one read of the
+    recorder's clock each, the failures among them in the errors buffer
+    too. What differs by path is the log: a failed request writes one
+    ``request_failed`` / ``stream_failed`` line on the two unmerged
+    paths, a failed execution ONE ``batch_execution_failed`` line for
+    all its requests on the two merging ones."""
+    ok, failed = 5, 3
+    core, recorder_clock, _logger_clock, events = _counted_core()
+    try:
+        results = drive_core_path(core, path, [1.0] * ok)
+        assert not any(isinstance(r, Exception) for r in results)
+        results = drive_core_path(core, path, [999.0] * failed)
+        assert all(isinstance(r, Exception) for r in results)
+    finally:
+        core.close()
+    assert recorder_clock.reads == ok + failed
+    snap = core.flight_recorder.snapshot()
+    assert snap["recorded_total"] == ok + failed
+    assert snap["error_total"] == failed and snap["rejected_total"] == 0
+    assert [e["path"] for e in snap["recent"]] == [label] * (ok + failed)
+    assert [e["status"] for e in snap["errors"]] == ["error"] * failed
+    if path == "decoupled":
+        assert [e["responses"] for e in snap["recent"]] == (
+            [0] * failed + [2] * ok  # newest first
+        )
+    logged = [e["event"] for e in events if e["severity"] == "ERROR"]
+    assert logged == {
+        "single": ["request_failed"] * failed,
+        "batcher": ["batch_execution_failed"],
+        "direct": ["batch_execution_failed"],
+        "decoupled": ["stream_failed"] * failed,
+    }[path]

@@ -801,67 +801,3 @@ def test_pod_launcher_serves_model_no_member_could_hold_alone():
         assert _pod_up(metrics, 0) == 1.0
     finally:
         launcher.stop()
-
-
-# ---------------------------------------------------------------------------
-# Satellite: the bench trajectory's "pod tok/s" column + regression gate
-# ---------------------------------------------------------------------------
-
-
-def test_bench_trajectory_pod_column(tmp_path):
-    """BENCH_r19+ adds a pod serving row; the trajectory table renders
-    its tok/s and leaves '-' for runs that predate it."""
-    from tools.bench_trajectory import format_table, load_runs
-
-    (tmp_path / "BENCH_r01.json").write_text(
-        json.dumps({"rc": 0, "parsed": {"value": 100.0, "p50_us": 10.0}})
-    )
-    (tmp_path / "BENCH_r02.json").write_text(
-        json.dumps(
-            {
-                "rc": 0,
-                "parsed": {
-                    "value": 120.0,
-                    "p50_us": 9.0,
-                    "pod": {
-                        "tokens_per_sec": 26.1,
-                        "infer_per_sec": 1.6,
-                        "token_parity": True,
-                        "process_count": 2,
-                        "duty": {"0": 0.5, "1": 0.5},
-                    },
-                },
-            }
-        )
-    )
-    table = format_table(load_runs(str(tmp_path)))
-    assert "pod tok/s" in table.splitlines()[0]
-    rows = table.splitlines()[2:]
-    assert rows[0].rstrip().endswith("- |")  # r01 predates the row
-    assert "26.1" in rows[1]
-
-
-def test_bench_trajectory_pod_regression_gate(tmp_path):
-    """Losing >10% of the pod row's tok/s vs the best prior run trips
-    the guard; holding steady does not."""
-    from tools.bench_trajectory import check_regression, load_runs
-
-    def write(run, tok_s):
-        (tmp_path / f"BENCH_r{run:02d}.json").write_text(
-            json.dumps(
-                {
-                    "rc": 0,
-                    "parsed": {
-                        "value": 100.0,
-                        "pod": {"tokens_per_sec": tok_s},
-                    },
-                }
-            )
-        )
-
-    write(1, 26.0)
-    write(2, 25.0)  # within 10% of the best prior: healthy
-    assert check_regression(load_runs(str(tmp_path))) is None
-    write(3, 20.0)  # >10% below r01's 26.0: the gate trips
-    problem = check_regression(load_runs(str(tmp_path)))
-    assert problem is not None and "pod regression" in problem

@@ -1,7 +1,8 @@
 """Hot-path profiling tests (PR-6): stage-CPU accounting units and
-calibration, the default-off overhead guarantee (structural: zero clock
-reads while disabled; statistical: <2% p50 regression in an A/B loopback
-run), the wall-stack sampler on fake clocks, collapsed-stack/speedscope
+calibration, the overhead guarantee as counts (zero clock reads while
+disabled, the default; a fixed number of reads and one sample a stage a
+request while enabled), the wall-stack sampler on fake clocks,
+collapsed-stack/speedscope
 golden exports, the /v2/debug/profile + /v2/debug/profiling endpoints,
 concurrent-scrape safety with /metrics, gRPC-vs-HTTP stage-CPU agreement
 on the same server, the collector/report reduction, and the
@@ -26,7 +27,6 @@ from client_tpu.observability import profiling
 from client_tpu.observability.profiling import (
     CAUSES,
     PROCESS,
-    STAGES,
     STALL_NS,
     LapSpans,
     ProcessEvents,
@@ -998,115 +998,53 @@ def test_grpc_and_http_stage_cpu_agree():
 
 
 # ---------------------------------------------------------------------------
-# overhead guard (statistical half): A/B loopback p50
+# overhead guard (the loopback half): clock reads and samples a request
 
 
-def _median(values):
-    ordered = sorted(values)
-    return ordered[len(ordered) // 2]
-
-
-def test_stage_accounting_overhead_under_two_percent():
-    """The acceptance guard: accounting ON regresses loopback p50 by
-    <2% vs the disabled default.
-
-    A 2% bound is only assertable when the host can RESOLVE 2%, so each
-    interleaved triplet measures OFF -> ON -> OFF and yields both the
-    A/B ratio (ON vs the surrounding OFFs) and a NULL ratio (the two
-    OFF batches against each other — pure host noise). The 2% assertion
-    applies the null as a noise floor; a box whose null comparison
-    alone exceeds the threshold scale skips rather than measure the
-    weather. The deterministic half of the guard —
-    test_core_disabled_hot_path_reads_no_clocks — always runs: the
-    disabled default performs zero clock reads, so the only cost left
-    to bound here is the enabled mode's few reads per request.
-
-    A pure-numpy echo model keeps jax dispatch jitter (hundreds of
-    noisy microseconds on contended CPU hosts) out of the denominator.
-    """
-    import http.client
-
+def test_stage_accounting_costs_a_fixed_number_of_clock_reads(loopback_echo):
+    """Accounting ON, an unbatched request over loopback HTTP is
+    bracketed by a fixed number of reads of the injected measurement
+    clock and leaves one sample in each stage it passes through;
+    accounting OFF (the default) it reads no clock and books nothing
+    (``test_core_disabled_hot_path_reads_no_clocks`` holds that for the
+    direct path). A later change that adds a bracket a request has
+    to change the numbers here."""
     from client_tpu.server.core import ServerCore
-    from client_tpu.server.model_repository import Model, ModelRepository
+    from client_tpu.server.model_repository import ModelRepository
 
-    class EchoModel(Model):
-        inputs = [{"name": "X", "datatype": "FP32", "shape": [-1, 4]}]
-        outputs = [{"name": "Y", "datatype": "FP32", "shape": [-1, 4]}]
-        name = "echo"
-        max_batch_size = 0
-
-        def execute(self, inputs, parameters):
-            return {"Y": inputs["X"] + 1.0}
-
+    n = 24
     core = ServerCore(ModelRepository())
-    core.repository.add_model(EchoModel())
-    payload = {
-        "inputs": [
-            {
-                "name": "X",
-                "datatype": "FP32",
-                "shape": [1, 4],
-                "data": [1.0, 2.0, 3.0, 4.0],
-            }
-        ]
-    }
-    body = json.dumps(payload).encode()
-
-    with InProcessServer(core=core, grpc=False, builtin_models=False) as server:
-        conn = http.client.HTTPConnection(
-            server._host, server.http_port, timeout=30
-        )
-        try:
-            def p50(n=30):
-                latencies = []
-                for _ in range(n):
-                    t0 = time.monotonic_ns()
-                    conn.request(
-                        "POST", "/v2/models/echo/infer", body=body
-                    )
-                    resp = conn.getresponse()
-                    resp.read()
-                    assert resp.status == 200
-                    latencies.append(time.monotonic_ns() - t0)
-                latencies.sort()
-                return latencies[len(latencies) // 2]
-
-            p50(60)  # warm up (route caches, connection, allocator)
-            prof = server.core.profiling
-            ab_ratios, null_ratios = [], []
-            for _ in range(8):
-                prof.disable()
-                off_a = p50()
-                prof.enable()
-                on = p50()
-                prof.disable()
-                off_b = p50()
-                ab_ratios.append(2 * on / (off_a + off_b))
-                null_ratios.append(off_b / off_a)
-            prof.disable()
-        finally:
-            conn.close()
-    ab = _median(ab_ratios)
-    null = _median(null_ratios)
-    # the host's own resolution: typical deviation of the OFF-vs-OFF
-    # comparison from 1.0 (median absolute deviation — a wildly noisy
-    # null can still have an accidentally centered median)
-    null_noise = _median([abs(r - 1.0) for r in null_ratios])
-    if ab < 1.02:
-        return  # the bound holds outright
-    if null_noise > 0.015 or abs(null - 1.0) > 0.015:
-        pytest.skip(
-            f"host noise (null OFF/OFF p50 ratio {null:.3f}, typical "
-            f"deviation {null_noise:.3f}) exceeds the 2% resolution this "
-            "assertion needs; the structural zero-clock-reads guard "
-            "still ran"
-        )
-    assert ab <= null + 0.02, (
-        f"accounting overhead too high: median p50 ratio on/off {ab:.4f} "
-        f"vs null {null:.4f} "
-        f"(ab {[round(r, 3) for r in sorted(ab_ratios)]}, "
-        f"null {[round(r, 3) for r in sorted(null_ratios)]})"
+    cpu = _FakeClock(step=100)
+    wall = _FakeClock(step=100)
+    prof = core.profiling = StageCpuAccounting(
+        metrics_hook=core.metrics.observe_stage_cpu,
+        cpu_clock_ns=cpu,
+        wall_clock_ns=wall,
+        auto_calibrate=False,
     )
+    with loopback_echo(core) as echo:
+        echo.send(n)
+        assert (cpu.calls, wall.calls) == (0, 0)
+        assert prof.snapshot() == {}
+        prof.enable()
+        echo.send(n)
+        prof.disable()
+        reads, snap = cpu.calls, prof.snapshot()
+        echo.send(n)
+        assert cpu.calls == reads and prof.snapshot() == snap
+    # frontend_decode, package and encode are a bracket of two reads
+    # each, compute and readback share their middle read (three), and
+    # queue_wait is the core's own wall laps: no read of either clock
+    assert (reads, wall.calls) == (9 * n, 0)
+    assert {stage: entry["count"] for stage, entry in snap.items()} == {
+        stage: n
+        for stage in ("frontend_decode", "queue_wait", "compute",
+                      "readback", "package", "encode")
+    }
+    assert {stage: entry["cpu_ns"] for stage, entry in snap.items()} == {
+        stage: 0 if stage == "queue_wait" else cpu.step * n
+        for stage in snap
+    }
 
 
 # ---------------------------------------------------------------------------

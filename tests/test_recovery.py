@@ -780,74 +780,3 @@ def test_pod_member_sigkill_supervised_recovery_token_identical():
         if supervisor is not None:
             supervisor.stop()
         launcher.stop()
-
-
-# ---------------------------------------------------------------------------
-# Satellite: the bench trajectory's "recovery MTTR" column + gate
-# ---------------------------------------------------------------------------
-
-
-def test_bench_trajectory_recovery_mttr_column(tmp_path):
-    """BENCH_r20+ adds the self-healing chaos row; the trajectory table
-    renders its MTTR and leaves '-' for runs that predate it."""
-    from tools.bench_trajectory import format_table, load_runs
-
-    (tmp_path / "BENCH_r01.json").write_text(
-        json.dumps({"rc": 0, "parsed": {"value": 100.0, "p50_us": 10.0}})
-    )
-    (tmp_path / "BENCH_r02.json").write_text(
-        json.dumps(
-            {
-                "rc": 0,
-                "parsed": {
-                    "value": 120.0,
-                    "recovery": {
-                        "mttr_s": 8.4,
-                        "supervisor_mttr_s": 8.3,
-                        "resumed_token_parity": True,
-                        "epoch": 1,
-                    },
-                },
-            }
-        )
-    )
-    table = format_table(load_runs(str(tmp_path)))
-    assert "recovery MTTR" in table.splitlines()[0]
-    rows = table.splitlines()[2:]
-    assert "8.4s" not in rows[0]  # r01 predates the row
-    assert "8.4s" in rows[1]
-
-
-def test_bench_trajectory_recovery_mttr_gate_is_inverted(tmp_path):
-    """MTTR is lower-is-better: the gate trips when the newest recovery
-    takes more than RECOVERY_MTTR_HEADROOM times the best prior one, or
-    when the resumed stream lost parity — never for merely being fast."""
-    from tools.bench_trajectory import check_regression, load_runs
-
-    def write(run, mttr_s, parity=True):
-        (tmp_path / f"BENCH_r{run:02d}.json").write_text(
-            json.dumps(
-                {
-                    "rc": 0,
-                    "parsed": {
-                        "value": 100.0,
-                        "recovery": {
-                            "mttr_s": mttr_s,
-                            "resumed_token_parity": parity,
-                        },
-                    },
-                }
-            )
-        )
-
-    write(1, 8.0)
-    write(2, 12.0)  # slower, but under 2x the best prior: healthy
-    assert check_regression(load_runs(str(tmp_path))) is None
-    write(3, 17.0)  # over 2x r01's 8.0s: the gate trips
-    problem = check_regression(load_runs(str(tmp_path)))
-    assert problem is not None and "recovery MTTR regression" in problem
-    write(3, 3.0)  # faster than ever: healthy (inverted, not symmetric)
-    assert check_regression(load_runs(str(tmp_path))) is None
-    write(3, 3.0, parity=False)  # fast but WRONG: absolute stop
-    problem = check_regression(load_runs(str(tmp_path)))
-    assert problem is not None and "parity floor" in problem

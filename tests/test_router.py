@@ -16,7 +16,6 @@ import os
 import signal
 import subprocess
 import sys
-import threading
 import time
 
 import numpy as np

@@ -11,7 +11,6 @@ front-ends, Retry-After honoring in the resilience layer, and the
 
 import asyncio
 import json
-import re
 import threading
 import urllib.error
 import urllib.request

@@ -528,7 +528,7 @@ def test_report_prints_per_device_duty():
 
 
 # ---------------------------------------------------------------------------
-# lint + trajectory satellites
+# lint satellite
 
 
 def test_metric_lint_device_label_conventions():
@@ -548,32 +548,3 @@ def test_metric_lint_device_label_conventions():
     )
     # the real registry is clean under the new rules
     assert run_metric_lint() == []
-
-
-def test_bench_trajectory_sharded_column(tmp_path):
-    from tools.bench_trajectory import format_table, load_runs
-
-    (tmp_path / "BENCH_r01.json").write_text(
-        json.dumps({"rc": 0, "parsed": {"value": 100.0, "p50_us": 10.0}})
-    )
-    (tmp_path / "BENCH_r02.json").write_text(
-        json.dumps(
-            {
-                "rc": 0,
-                "parsed": {
-                    "value": 120.0,
-                    "p50_us": 9.0,
-                    "sharded": {
-                        "infer_per_sec": 432.1,
-                        "device_count": 8,
-                        "mesh": {"dp": 2, "tp": 2},
-                    },
-                },
-            }
-        )
-    )
-    table = format_table(load_runs(str(tmp_path)))
-    assert "sharded inf/s" in table.splitlines()[0]
-    rows = table.splitlines()[2:]
-    assert rows[0].rstrip().endswith("- |")  # r01 predates the row
-    assert "432.1" in rows[1]

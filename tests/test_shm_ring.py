@@ -15,7 +15,7 @@ import pytest
 
 from client_tpu.grpc import _wire as wire
 from client_tpu.grpc._generated import grpc_service_pb2 as pb
-from client_tpu.server._grpc_codec import FastInferCodec, ScratchBuffer
+from client_tpu.server._grpc_codec import FastInferCodec
 from client_tpu.server.core import CoreResponse, CoreTensor, ServerCore
 from client_tpu.server.grpc_server import (
     build_core_request,
@@ -974,44 +974,6 @@ def test_clock_lint_covers_new_modules():
     assert run_clock_lint() == []
 
 
-def test_bench_trajectory_harness_aware_gates(tmp_path):
-    """The regression guard compares headline numbers only within one
-    harness family, and guards the sharded + llm rows."""
-    import json
-
-    from tools.bench_trajectory import check_regression, load_runs
-
-    def write(run, parsed):
-        (tmp_path / f"BENCH_r{run:02d}.json").write_text(
-            json.dumps({"rc": 0, "parsed": parsed})
-        )
-
-    cpp = "simple add_sub infer/sec (loopback gRPC, perf_analyzer(c++))"
-    py = "simple add_sub infer/sec (loopback gRPC, python-grpc-aio)"
-    # harness change: a 90% lower python number after a C++ run is NOT a
-    # regression (different stack), but sharded/llm rows still guard
-    write(5, {"metric": cpp, "value": 13000.0,
-              "sharded": {"infer_per_sec": 80.0},
-              "llm_generate": {"tokens_per_sec": 300.0}})
-    write(11, {"metric": py, "value": 900.0,
-               "sharded": {"infer_per_sec": 79.0},
-               "llm_generate": {"tokens_per_sec": 295.0}})
-    assert check_regression(load_runs(str(tmp_path))) is None
-    # same-family headline regression fires
-    write(12, {"metric": py, "value": 500.0,
-               "sharded": {"infer_per_sec": 79.0},
-               "llm_generate": {"tokens_per_sec": 295.0}})
-    problem = check_regression(load_runs(str(tmp_path)))
-    assert problem and "throughput regression" in problem
-    # sharded / llm regressions fire independently of harness
-    write(13, {"metric": cpp, "value": 14000.0,
-               "sharded": {"infer_per_sec": 30.0},
-               "llm_generate": {"tokens_per_sec": 100.0}})
-    problem = check_regression(load_runs(str(tmp_path)))
-    assert problem and "sharded regression" in problem
-    assert "llm_generate regression" in problem
-
-
 def test_mux_inband_errors_carry_retry_status():
     """In-band stream error frames carry only message text; the mux
     layers restore the retry-relevant gRPC status so drain/queue-full
@@ -1055,13 +1017,3 @@ def test_ring_registry_prunes_unregistered(server):
     finally:
         client.close()
         ring.close()
-
-
-def test_format_shm_delta_flags_loss():
-    from client_tpu.perf.report import format_shm_delta
-
-    wins = format_shm_delta(1500.0, 1000.0, 64, label="shm-ring")
-    assert "+50.0%" in wins and "LOSES" not in wins
-    loses = format_shm_delta(900.0, 1000.0, 64, label="shm-ring")
-    assert "SHM-RING LOSES" in loses and "64 B/tensor" in loses
-    assert format_shm_delta(0.0, 1000.0) == ""

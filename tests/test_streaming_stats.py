@@ -122,6 +122,14 @@ def test_abandoned_stream_books_cancel_entry():
         assert rs["0"]["success"]["count"] == 1
         assert rs["1"]["cancel"]["count"] == 1
         assert rs["1"]["cancel"]["ns"] > 0
+        # ...and that entry is ALL it books (ROADMAP D5): the one request
+        # in four paths that ends as neither a success nor a failure, with
+        # no flight-recorder record and no telemetry observation
+        stats = core.statistics("repeat_int32")["model_stats"][0]
+        assert stats["inference_stats"]["success"]["count"] == 0
+        assert stats["inference_stats"]["fail"]["count"] == 0
+        assert core.flight_recorder.stats()["recorded_total"] == 0
+        assert core.metrics.telemetry.models() == []
     finally:
         core.close()
 

@@ -4,9 +4,10 @@ error-budget burn rates (agreement with histogram-derived values on both
 front-ends), the ``/v2/debug/slo`` document tracking a fake-clock load
 shift while the cumulative histogram lags, per-endpoint pool telemetry,
 OpenMetrics exemplars linking ``/metrics`` to the flight recorder,
-3-replica fleet aggregation with skew detection, the bench-trajectory
-and metric-lint tools, and the <2% p50 A/B overhead guard for the
-window sketch (PR 6/7 paired-triplet pattern).
+3-replica fleet aggregation with skew detection, the metric-lint tool,
+and what the window sketch costs as counts on a counting clock: clock
+reads and observations a request, on each of ``ServerCore``'s four
+execution paths, and none of either when disabled.
 """
 
 import asyncio
@@ -62,11 +63,6 @@ class FakeClock:
 
     def advance(self, seconds: float) -> None:
         self.now_ns += int(seconds * 1e9)
-
-
-def _median(values):
-    ordered = sorted(values)
-    return ordered[len(ordered) // 2]
 
 
 def _simple_inputs(mod):
@@ -967,7 +963,7 @@ def test_cli_fleet_section_and_client_metrics_fix(capsys):
 
 
 # ---------------------------------------------------------------------------
-# tools: metric lint + bench trajectory
+# tools: metric lint
 
 
 def test_metric_lint_repo_is_clean_and_rules_fire():
@@ -990,132 +986,113 @@ def test_metric_lint_repo_is_clean_and_rules_fire():
     assert len(findings) == 1 and findings[0][0] == 1
 
 
-def test_bench_trajectory_table_refresh_and_regression_guard(tmp_path):
-    from tools.bench_trajectory import (
-        check_regression,
-        format_table,
-        load_runs,
-        main,
-        refresh_perf_md,
-    )
-
-    def write_run(n, value, extra=None, rc=0):
-        parsed = {
-            "value": value, "p50_us": 100.0, "ratio_vs_inproc": 0.5,
-            "server_cpu_us_per_req": 42.0,
-        }
-        parsed.update(extra or {})
-        (tmp_path / f"BENCH_r{n:02d}.json").write_text(
-            json.dumps({"rc": rc, "parsed": parsed})
-        )
-
-    write_run(1, 1000.0)
-    write_run(
-        2,
-        1500.0,
-        extra={
-            "server_stage_cpu_us": {"compute": 30.0, "encode": 5.0},
-            "rolling_30s_p99_us": 321.0,
-        },
-    )
-    runs = load_runs(str(tmp_path))
-    assert [r["run"] for r in runs] == [1, 2]
-    table = format_table(runs)
-    assert "| r02 | 1500.0 |" in table
-    assert "compute (30.0us)" in table
-    assert "321.0" in table
-    assert check_regression(runs) is None
-
-    perf = tmp_path / "PERF.md"
-    perf.write_text("# PERF\n\nprose stays\n")
-    assert refresh_perf_md(table, str(perf))
-    assert "prose stays" in perf.read_text()
-    assert "| r02 | 1500.0 |" in perf.read_text()
-    # refresh replaces the marked block without duplicating it
-    write_run(3, 1480.0)  # within the 10% guard of best=1500
-    table3 = format_table(load_runs(str(tmp_path)))
-    refresh_perf_md(table3, str(perf))
-    text = perf.read_text()
-    assert text.count("bench-trajectory:begin") == 1
-    assert "| r03 |" in text and "| r02 | 1500.0 |" in text
-    assert main(["--root", str(tmp_path), "--no-write"]) == 0
-
-    write_run(4, 1200.0)  # 20% below best prior (1500): guard trips
-    runs = load_runs(str(tmp_path))
-    problem = check_regression(runs)
-    assert problem and "r04" in problem and "r02" in problem
-    assert main(["--root", str(tmp_path), "--no-write"]) == 1
-    # a failed bench run is listed but never judged
-    write_run(5, 0.0, rc=1)
-    assert "(bench failed)" in format_table(load_runs(str(tmp_path)))
-    assert check_regression(load_runs(str(tmp_path))) == problem
-
-
 # ---------------------------------------------------------------------------
-# overhead guard
+# what the sketch costs, as counts: clock reads and observations
 
 
-def test_window_sketch_overhead_under_two_percent():
-    """With live telemetry recording (the default) the loopback echo
-    p50 regresses <2% vs telemetry disabled. Same noise-aware A/B
-    harness as the PR 6/7 guards: interleaved OFF->ON->OFF triplets,
-    the OFF-vs-OFF null ratio as the host's resolution floor, skip with
-    evidence when the box cannot resolve 2%."""
-    core = ServerCore(ModelRepository())
-    core.repository.add_model(_EchoModel())
-    telemetry = core.metrics.telemetry
-    body = json.dumps({
-        "inputs": [{
-            "name": "X", "datatype": "FP32", "shape": [1, 4],
-            "data": [1.0, 2.0, 3.0, 4.0],
-        }]
-    }).encode()
+class _CountingClock:
+    """A nanosecond clock that advances a microsecond a read and counts
+    its reads."""
 
-    with InProcessServer(core=core, grpc=False, builtin_models=False) as srv:
-        conn = http.client.HTTPConnection(
-            srv._host, srv.http_port, timeout=30
-        )
-        try:
-            def p50(n=30):
-                latencies = []
-                for _ in range(n):
-                    t0 = time.monotonic_ns()
-                    conn.request("POST", "/v2/models/echo/infer", body=body)
-                    resp = conn.getresponse()
-                    resp.read()
-                    assert resp.status == 200
-                    latencies.append(time.monotonic_ns() - t0)
-                latencies.sort()
-                return latencies[len(latencies) // 2]
+    def __init__(self):
+        self.reads = 0
 
-            p50(60)  # warm up (route caches, connection, allocator)
-            ab_ratios, null_ratios = [], []
-            for _ in range(8):
-                telemetry.enabled = False
-                off_a = p50()
-                telemetry.enabled = True
-                on = p50()
-                telemetry.enabled = False
-                off_b = p50()
-                ab_ratios.append(2 * on / (off_a + off_b))
-                null_ratios.append(off_b / off_a)
-            telemetry.enabled = True
-        finally:
-            conn.close()
-    ab = _median(ab_ratios)
-    null = _median(null_ratios)
-    null_noise = _median([abs(r - 1.0) for r in null_ratios])
-    if ab < 1.02:
-        return  # the bound holds outright
-    if null_noise > 0.015 or abs(null - 1.0) > 0.015:
-        pytest.skip(
-            f"host noise (null OFF/OFF p50 ratio {null:.3f}, typical "
-            f"deviation {null_noise:.3f}) exceeds the 2% resolution this "
-            "assertion needs"
-        )
-    assert ab <= null + 0.02, (
-        f"window-sketch overhead too high: median p50 ratio on/off "
-        f"{ab:.4f} vs null {null:.4f} "
-        f"(ab {[round(r, 3) for r in sorted(ab_ratios)]}, "
-        f"null {[round(r, 3) for r in sorted(null_ratios)]})"
+    def __call__(self) -> int:
+        self.reads += 1
+        return self.reads * 1000
+
+
+def _counted_telemetry(core, objective_resolver=None):
+    """Give ``core`` a LiveTelemetry on a counting clock; returns both."""
+    clock = _CountingClock()
+    telemetry = core.metrics.telemetry = LiveTelemetry(
+        buckets=DURATION_BUCKETS_S,
+        clock_ns=clock,
+        objective_resolver=(
+            objective_resolver or core.metrics._resolve_objective
+        ),
     )
+    return telemetry, clock
+
+
+def _window_counts(telemetry, model):
+    """{window label: observations in it}; reads the clock (rotation), so
+    take the clock's count first."""
+    return {
+        label: entry["count"]
+        for label, entry in telemetry.rolling(model).items()
+    }
+
+
+CORE_PATHS = ("single", "batcher", "direct", "decoupled")
+
+
+def test_window_sketch_costs_one_clock_read_a_request(loopback_echo):
+    """With live telemetry recording (the default) a loopback request
+    costs ONE read of the telemetry's clock and leaves ONE observation
+    in each rolling window; switched off mid-run, further requests cost
+    no read and leave the windows as they were. A later change that adds
+    a read a request has to change the numbers here."""
+    n = 24
+    core = ServerCore(ModelRepository())
+    telemetry, clock = _counted_telemetry(core)
+    with loopback_echo(core) as echo:
+        echo.send(n)
+        assert clock.reads == n
+        assert _window_counts(telemetry, "echo") == {"30s": n, "5m": n}
+        telemetry.enabled = False
+        reads = clock.reads  # the windows' own, for the counts above
+        echo.send(n)
+        assert clock.reads == reads
+        assert _window_counts(telemetry, "echo") == {"30s": n, "5m": n}
+
+
+def test_telemetry_disabled_is_inert(drive_core_path):
+    """Disabled from the start, telemetry keeps no state at all: no
+    clock read, no SLO declaration resolved, no model tracked, and the
+    ``/v2/debug/slo`` document names no model, on every execution path,
+    failures included. Enabled again, the same requests are booked."""
+    core = ServerCore(ModelRepository())
+    resolved = []
+    telemetry, clock = _counted_telemetry(core, resolved.append)
+    telemetry.enabled = False
+    try:
+        for path in CORE_PATHS:
+            drive_core_path(core, path, [1.0, 2.0, 3.0])
+            drive_core_path(core, path, [999.0])
+        assert clock.reads == 0
+        assert resolved == []
+        assert telemetry.models() == []
+        assert core.debug_slo()["models"] == {}
+        telemetry.enabled = True
+        drive_core_path(core, "single", [1.0, 2.0, 3.0])
+        assert clock.reads == 3
+        assert resolved == ["path_single"]
+        assert _window_counts(telemetry, "path_single") == {"30s": 3, "5m": 3}
+    finally:
+        core.close()
+
+
+@pytest.mark.parametrize("path", CORE_PATHS)
+def test_one_observation_a_request_on_every_path(drive_core_path, path):
+    """The accounting a merged spine of the four paths has to keep
+    (ROADMAP D5): N requests leave exactly N observations, the
+    successes in every latency window and successes and failures in
+    the SLO budget window. What differs by path is the number of clock
+    reads: ``infer_direct`` books a merged chunk's successes in ONE
+    record of ``count=n``, the others one record a request."""
+    ok, failed = 5, 3
+    core = ServerCore(ModelRepository())
+    telemetry, clock = _counted_telemetry(core)
+    try:
+        results = drive_core_path(core, path, [1.0] * ok)
+        assert not any(isinstance(r, Exception) for r in results)
+        results = drive_core_path(core, path, [999.0] * failed)
+        assert all(isinstance(r, Exception) for r in results)
+        model = f"path_{path}"
+        assert clock.reads == (1 if path == "direct" else ok) + failed
+        assert _window_counts(telemetry, model) == {"30s": ok, "5m": ok}
+        slo = telemetry.slo_status(model)
+        assert (slo["window_good"], slo["window_bad"]) == (ok, failed)
+    finally:
+        core.close()

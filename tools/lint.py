@@ -9,7 +9,7 @@ external linters cannot be installed. Checks every tracked .py file for
 import ast
 import os
 
-ROOTS = ["client_tpu", "tools", "tests", "examples/model_repository", "bench.py",
+ROOTS = ["client_tpu", "tools", "tests", "examples/model_repository",
          "chip_smoke.py", "__graft_entry__.py"]
 # Imports with side effects or re-export duties.
 ALLOWED_UNUSED = {"client_tpu", "conftest"}
@@ -31,17 +31,16 @@ def iter_py_files():
 
 
 def unused_imports(tree: ast.AST, source: str):
-    imported = {}  # name -> lineno
+    imported = {}  # name -> (first, last) line of its import statement
     for node in ast.walk(tree):
-        if isinstance(node, ast.Import):
-            for alias in node.names:
-                name = (alias.asname or alias.name).split(".")[0]
-                imported[name] = node.lineno
-        elif isinstance(node, ast.ImportFrom):
-            for alias in node.names:
-                if alias.name == "*":
-                    continue
-                imported[alias.asname or alias.name] = node.lineno
+        if not isinstance(node, (ast.Import, ast.ImportFrom)):
+            continue
+        span = (node.lineno, node.end_lineno)
+        for alias in node.names:
+            if isinstance(node, ast.Import):
+                imported[(alias.asname or alias.name).split(".")[0]] = span
+            elif alias.name != "*":
+                imported[alias.asname or alias.name] = span
     used = set()
     for node in ast.walk(tree):
         if isinstance(node, ast.Name):
@@ -54,10 +53,11 @@ def unused_imports(tree: ast.AST, source: str):
         for i, line in enumerate(source.splitlines())
         if "noqa" in line
     }
-    for name, lineno in sorted(imported.items()):
+    for name, (lineno, last) in sorted(imported.items()):
         if name in used or name in ALLOWED_UNUSED:
             continue
-        if lineno in noqa_lines:
+        # a parenthesised import may carry its noqa on any of its lines
+        if noqa_lines.intersection(range(lineno, last + 1)):
             continue
         if f'"{name}"' in source or f"'{name}'" in source:
             continue  # appears in __all__ or string registry
