@@ -30,6 +30,21 @@ new owner finds nothing of the last one. Nothing of a state can be
 shared, rolled back or looked ahead into: prefix sharing and speculation
 are refused at load beside a ``state`` group, as beside a ``window`` one.
 
+A layer is in the group where it STORES, and need not store at all. A
+layer may keep no cache and read the pools another layer writes (a
+cross-decoder's attention layers over the one full layer's K/V,
+``models/phi4flash.py``: a cached token is kept once and read by eight
+layers), or keep none and read none (its gated memory units, which read
+another layer's output of the same step). Such a layer is in no group
+and its entry of ``init_pages`` is empty; the model's own programs hand
+it the pools and the table row of the group it reads. The engine sizes,
+allocates and counts what is stored (``stats()``'s blocks and bytes a
+group are over the group's ``layers``); what is READ a step beyond that
+is the model's to count (``step_counters``). A model may have all three
+kinds of group at once: its tables are ``[3, ...]``, a ring beside a
+slot, and a preempted sequence gives back and is re-prefilled over all
+three.
+
 Which kernels run is chosen once, at load (``llm/serving.py``, from
 ``CLIENT_TPU_LLM_KERNEL`` or the platform), and handed to every program
 of the model as one :class:`Kernels`. Every model behind the seam runs
@@ -50,7 +65,9 @@ FULL, WINDOW, STATE = "full", "window", "state"
 @dataclasses.dataclass(frozen=True)
 class CacheGroup:
     """``kind`` :data:`FULL`, :data:`WINDOW` or :data:`STATE`; ``layers``
-    the indices of the model's layers in the group; ``window`` the tokens
+    the indices of the model's layers that STORE in the group's pools (a
+    layer that only reads them, or keeps nothing, is listed nowhere);
+    ``window`` the tokens
     a :data:`WINDOW` layer's query sees, itself included (the engine holds
     ``kv_cache.window_ring_blocks`` blocks a sequence for it). A
     :data:`STATE` group's sequence holds one slot of ``1 + max_active``."""
@@ -102,8 +119,8 @@ class EngineModel:
     ``init_pages`` returns one entry a layer, a pool or a tuple of pools
     (``(k_pages, v_pages)``; a latent model's one pool holds its values
     inside its key rows; a state layer's ``(state_pool, conv_pool)``,
-    ``num_blocks`` of its group being slots), which the engine hands
-    back as it got them.
+    ``num_blocks`` of its group being slots; ``()`` for a layer that
+    stores nothing), which the engine hands back as it got them.
     ``kv_row_bytes(config) -> [(stored, counted) per group]``: bytes a
     cached token takes in one layer of each group, as its pools store
     it and as the model reads it (rows padded to whole lanes count less

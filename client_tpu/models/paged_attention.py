@@ -26,6 +26,18 @@ have produced. Padding lanes and padding rows produce garbage the
 caller discards. ``masking`` is ``window``, ``sink``, ``scale`` and
 ``kv_heads`` (:func:`paged_attention_pallas`).
 
+A model whose value heads each serve SEVERAL key heads (differential
+attention: a value head of 128 shared by the pair of key heads of 64
+whose two softmaxes are subtracted, ``models/phi4flash.py``) passes
+``keys_per_value = r``: a key row then holds, side by side, the ``r``
+key heads of ``D`` columns that share the row's value head
+(``k_pages[N, bs, KV, r * D]``: key head ``g`` is columns ``(g % r) *
+D`` of row ``g // r``, value head ``g // r`` serves it), ``q`` is ``[B,
+T, H, D]`` and head ``h`` reads key head ``h // (H / (r * KV))``, plain
+grouped-query order over the ``r * KV`` key heads. A token's row is
+stored once and each live row is read once a call; one softmax a query
+head, ``out[B, T, H, Dv]``.
+
 A model whose values lie INSIDE its key rows (a latent cache: one row a
 token for all heads, the values its leading columns) passes ONE pool,
 ``v_pages=None`` and ``v_width``, the width of the values: every
@@ -86,22 +98,27 @@ KERNELS = ("pallas", "pallas_interpret", "fused_xla")
 
 
 def paged_attention_reference(q, k_pages, v_pages, page_tables, positions,
-                              *, v_width=None, scale=None):
+                              *, v_width=None, scale=None, keys_per_value=1):
     """Gather + repeat_kv + a ``[B, T, S]`` mask.
 
     The oracle the XLA and Pallas functions are pinned against: kept as
     dumb as possible (materialized head repeat, full-width softmax) and
     served by nothing. With ``v_pages=None`` the values are the leading
-    ``v_width`` columns of ``k_pages``' rows."""
+    ``v_width`` columns of ``k_pages``' rows. With ``keys_per_value`` a
+    key row is cut into its key heads and each value head repeated for
+    the key heads it serves."""
     b, t, h, d = q.shape
     _, bs, kv, _ = k_pages.shape
+    kv *= keys_per_value
     n_rep = h // kv
     s = page_tables.shape[1] * bs
     k_ctx = k_pages[page_tables].reshape(b, s, kv, d)
     if v_pages is None:
         v_ctx = k_ctx[..., :v_width]
     else:
-        v_ctx = v_pages[page_tables].reshape(b, s, kv, -1)
+        v_ctx = jnp.repeat(
+            v_pages[page_tables].reshape(b, s, kv // keys_per_value, -1),
+            keys_per_value, axis=2)
     k_rep = jnp.broadcast_to(
         k_ctx[:, :, :, None, :], (b, s, kv, n_rep, d)
     ).reshape(b, s, h, d)
@@ -168,7 +185,7 @@ def _softmax_with_sink(scores, sink):
 
 def paged_attention_xla(q, k_pages, v_pages, page_tables, positions,
                         *, window=None, sink=None, scale=None,
-                        kv_heads=None, v_width=None):
+                        kv_heads=None, v_width=None, keys_per_value=1):
     """One fused XLA computation over the gathered pages.
 
     Head layout matches ``_repeat_kv`` (head ``k*g + r`` reads kv head
@@ -184,18 +201,21 @@ def paged_attention_xla(q, k_pages, v_pages, page_tables, positions,
     scores all K+1 verify positions against the same gathered pages
     instead of gathering K+1 times. One pool (``v_pages=None``) is
     gathered once, and the values are the context's leading ``v_width``
-    columns."""
+    columns. With ``keys_per_value = r`` the gathered key rows are
+    re-viewed as ``r * KV`` key heads for the scores, and the weights of
+    the ``r`` key heads of a row meet the row's one value head."""
     b, t, h, d = q.shape
     bs, kv, dv = _pool_shape(k_pages, v_pages, kv_heads, v_width)
-    g = h // kv
+    keys = kv * keys_per_value
+    g = h // keys
     s = page_tables.shape[1] * bs
-    k_ctx = k_pages[page_tables].reshape(b, s, kv, d).transpose(0, 2, 1, 3)
+    k_ctx = k_pages[page_tables].reshape(b, s, keys, d).transpose(0, 2, 1, 3)
     if v_pages is None:
         v_ctx = k_ctx[..., :dv]
     else:
         v_ctx = v_pages[page_tables].reshape(b, s, kv, dv).transpose(
             0, 2, 1, 3)
-    qg = q.reshape(b, t, kv, g, d)
+    qg = q.reshape(b, t, keys, g, d)
     scores = jnp.einsum(
         "btkgd,bksd->bkgts", qg, k_ctx, preferred_element_type=jnp.float32
     ) * (scale or d ** -0.5)
@@ -205,8 +225,11 @@ def paged_attention_xla(q, k_pages, v_pages, page_tables, positions,
     )  # [B, T, S]
     scores = jnp.where(valid[:, None, None, :, :], scores, NEG_INF)
     weights = _softmax_with_sink(
-        scores, None if sink is None else sink.reshape(kv, g, 1)
+        scores, None if sink is None else sink.reshape(keys, g, 1)
     )
+    if keys_per_value > 1:
+        # the key heads of a row side by side: one value head's group
+        weights = weights.reshape(b, kv, h // kv, t, s)
     out = jnp.einsum(
         "bkgts,bksd->btkgd", weights, v_ctx.astype(weights.dtype)
     )
@@ -237,7 +260,9 @@ def pages_per_tile(block_size: int, kv_heads: int, head_dim: int,
     function of the pools' shapes alone, so one program serves every
     batch, and every model finds its own tile: 8 pages (128 tokens) at
     KV 8 / D 128 / bf16, 2 at KV 32, 32 for a KV 2 tensor-parallel
-    shard, 64 at KV 1 / D 128 (a multi-query model's 4 KB pages).
+    shard, 64 at KV 1 / D 128 (a multi-query model's 4 KB pages), 4 at
+    KV 20 / D 64 with a value head of 128 a PAIR of key heads (10 rows of
+    128 a token in either pool, pages of 40 KB: ``keys_per_value`` 2).
 
     A ONE-POOL call gets a longer tile than those bytes hold. What a
     tile stop costs beyond its bytes (the copy's start and its latency
@@ -539,14 +564,25 @@ def _rpa_kernel(kv, scale, window, has_sink, v_width, *refs):
     o_ref[0] = (acc / l).astype(o_ref.dtype)
 
 
+def _widened_to_key_rows(q, kv: int, r: int):
+    """``q`` [B, T, H, D] -> [B, T, H, r * D]: head ``h`` (which reads
+    key head ``h // (H / (r * kv))``, the ``(... % r)``-th of its key
+    row) holds its ``D`` columns where that key head lies in the row,
+    zeros beside them."""
+    b, t, h, d = q.shape
+    place = jnp.eye(r, dtype=q.dtype)[(jnp.arange(h) // (h // (r * kv))) % r]
+    return (q[:, :, :, None, :] * place[:, :, None]).reshape(b, t, h, r * d)
+
+
 @functools.partial(
     jax.jit,
-    static_argnames=("interpret", "window", "scale", "kv_heads", "v_width"),
+    static_argnames=("interpret", "window", "scale", "kv_heads", "v_width",
+                     "keys_per_value"),
 )
 def paged_attention_pallas(q, k_pages, v_pages, page_tables, positions,
                            *, interpret: bool = False, window=None,
                            sink=None, scale=None, kv_heads=None,
-                           v_width=None):
+                           v_width=None, keys_per_value: int = 1):
     """Flash-style ragged paged attention as a Pallas kernel.
 
     One grid step per sequence. ``page_tables``, :func:`whole_tiles` of
@@ -578,7 +614,14 @@ def paged_attention_pallas(q, k_pages, v_pages, page_tables, positions,
     ``v_width`` (static) is the one-pool call of the module docstring: a
     latent model's rows of 576 (512 of them the values) are stored 640
     wide, at KV 1, and all its query heads are rows of one matmul
-    against the tile.
+    against the tile. With ``keys_per_value = r`` (static; the module
+    docstring's shared value heads) the kernel is the one it is without:
+    each query head is widened with zeros to the key row, its ``D``
+    columns where its key head lies in the row, so that its product with
+    the whole row is its product with its own key head and the ``r *
+    G`` heads of a row are one kv head's group. The row is read once;
+    the score matmul is ``r`` times as wide, on an MXU that waits for
+    the copies.
 
     Jitted, so that the layers of a model, which all call it with the
     same shapes, share one trace and one lowering of the kernel: a
@@ -588,6 +631,10 @@ def paged_attention_pallas(q, k_pages, v_pages, page_tables, positions,
     n = k_pages.shape[0]
     bs, kv, dv = _pool_shape(k_pages, v_pages, kv_heads, v_width)
     pools = [k_pages] if v_pages is None else [k_pages, v_pages]
+    if keys_per_value > 1:
+        scale = scale or d ** -0.5
+        q = _widened_to_key_rows(q, kv, keys_per_value)
+        d *= keys_per_value
     g = h // kv
     rows = kv * t * g
     nb = page_tables.shape[1]

@@ -7,7 +7,10 @@ float32 and a token turns it by::
 
 with ``u`` the convolved input, ``delta > 0`` the token's step a channel,
 ``B`` and ``C`` the token's input and output vectors, ``A < 0`` and ``D``
-the layer's own, ``z`` the gate. The transition is diagonal and differs
+the layer's own, ``z`` the gate. Every form takes ``z = None`` for the
+sum UNGATED (``sum_n h[n, d] C[n] + D[d] u[d]``, the memory that
+``models/phi4flash.py``'s gated memory units read of one layer, and what
+its own gate is then applied to outside). The transition is diagonal and differs
 for every (state, channel) pair, so nothing in it is a matmul and nothing
 factors over heads: multiply-adds and exponentials over the ``[d_state,
 d_inner]`` block. The state lies channels-last (``[16, 5120]`` at
@@ -61,36 +64,43 @@ _LANE_ROWS = 8
 def recurrent_step(state, u, delta, b, c, z, a, d_skip):
     """One token of the rule for any leading dims: ``state`` [..., N, D],
     ``u`` / ``delta`` / ``z`` [..., D], ``b`` / ``c`` [..., N], ``a`` [N,
-    D], ``d_skip`` [D], all float32. Returns ``(y [..., D], state)``."""
+    D], ``d_skip`` [D], all float32; ``z = None``: no gate. Returns ``(y
+    [..., D], state)``."""
     state = (jnp.exp(delta[..., None, :] * a) * state
              + (delta * u)[..., None, :] * b[..., :, None])
     y = (state * c[..., :, None]).sum(axis=-2) + d_skip * u
-    return y * jax.nn.silu(z), state
+    return (y if z is None else y * jax.nn.silu(z)), state
 
 
-def _step_kernel(slots_ref, u_ref, delta_ref, z_ref, bc_ref, a_ref, d_ref,
-                 s_ref, y_ref, s_out_ref):
+def _step_kernel(slots_ref, u_ref, delta_ref, *refs):
     """Grid step a lane. ``u_ref`` / ``delta_ref`` / ``z_ref`` / ``y_ref``
     [R, D] hold the rows of ``R`` lanes as they lie in HBM (a block is
     fetched, and ``y``'s written back, once for its ``R`` steps; this
     lane's row is ``lane % R``), ``bc_ref`` [1, N, 2] the lane's ``B`` and
     ``C`` as columns, ``a_ref`` [N, D] and ``d_ref`` [1, D] the layer's
     own (the same block every step, so fetched once), ``s_ref`` [1, N, D]
-    the lane's slot of the pool."""
+    the lane's slot of the pool. An ungated call has no ``z_ref``."""
+    *gate, bc_ref, a_ref, d_ref, s_ref, y_ref, s_out_ref = refs
+    z_ref = gate[0] if gate else None
     lane = pl.program_id(0)
     live = slots_ref[lane] != TRASH_SLOT
     row = pl.ds(lane % u_ref.shape[0], 1)
-    u, delta, z = u_ref[row, :], delta_ref[row, :], z_ref[row, :]
+    u, delta = u_ref[row, :], delta_ref[row, :]
+    if z_ref is not None:
+        z = z_ref[row, :]
     b_col, c_col = bc_ref[0, :, 0:1], bc_ref[0, :, 1:2]
     state = (jnp.exp(delta * a_ref[...]) * s_ref[0]
              + (delta * u) * b_col)
     y = (state * c_col).sum(axis=0, keepdims=True) + d_ref[...] * u
     s_out_ref[0] = jnp.where(live, state, 0.0)
-    y_ref[row, :] = jnp.where(live, y * jax.nn.silu(z), 0.0)
+    if z_ref is not None:
+        y = y * jax.nn.silu(z)
+    y_ref[row, :] = jnp.where(live, y, 0.0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _step_pallas(u, delta, z, bc, a, d_skip, slots, state_pool, *, interpret):
+    gates = [] if z is None else [z]
     lanes, channels = u.shape
     states = bc.shape[1]
     # the lanes' rows come eight to a block, as they are tiled in HBM (no
@@ -104,8 +114,7 @@ def _step_pallas(u, delta, z, bc, a, d_skip, slots, state_pool, *, interpret):
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(lanes,),
-        in_specs=[
-            lane_rows, lane_rows, lane_rows,
+        in_specs=[lane_rows] * (2 + len(gates)) + [
             pl.BlockSpec((1, states, 2), lambda b, slots: (b, 0, 0)),
             pl.BlockSpec((states, channels), lambda b, slots: (0, 0)),
             pl.BlockSpec((1, channels), lambda b, slots: (0, 0)),
@@ -118,21 +127,22 @@ def _step_pallas(u, delta, z, bc, a, d_skip, slots, state_pool, *, interpret):
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((lanes, channels), jnp.float32),
                    jax.ShapeDtypeStruct(state_pool.shape, state_pool.dtype)],
-        # operand 7 (slots come first) is the pool, output 1 the same memory
-        input_output_aliases={7: 1},
+        # the last operand (slots come first) is the pool, output 1 the
+        # same memory
+        input_output_aliases={6 + len(gates): 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="selective_scan_step",
-    )(slots, u, delta, z, bc, a, d_skip[None], state_pool)
+    )(slots, u, delta, *gates, bc, a, d_skip[None], state_pool)
 
 
 def selective_scan_step(u, delta, b, c, z, a, d_skip, slots, state_pool, *,
                         kernel: str):
     """One decode step of ``B`` lanes over the pool. ``u`` / ``delta`` /
     ``z`` [B, D], ``b`` / ``c`` [B, N], ``a`` [N, D], ``d_skip`` [D],
-    float32; ``slots`` [B] int32; ``state_pool`` [slots, N, D] float32.
-    ``kernel`` is the load-time choice's name
+    float32 (``z = None``: the sum ungated); ``slots`` [B] int32;
+    ``state_pool`` [slots, N, D] float32. ``kernel`` is the load-time choice's name
     (``engine_model.Kernels.name``): ``pallas`` / ``pallas_interpret``
     the kernel, anything else the gather, :func:`recurrent_step` and a
     scatter. Returns ``(y [B, D] float32, state_pool)``."""
@@ -161,8 +171,8 @@ def _scan_chunk(state, decay, drive, c):
 
 def chunked_selective_scan(u, delta, b, c, z, a, d_skip, chunk: int = CHUNK):
     """A whole sequence from a zero state. ``u`` / ``delta`` / ``z`` [L,
-    D], ``b`` / ``c`` [L, N], ``a`` [N, D], ``d_skip`` [D], float32; a
-    token with ``delta = 0`` (the padding of a prompt to its bucket, and
+    D], ``b`` / ``c`` [L, N], ``a`` [N, D], ``d_skip`` [D], float32 (``z
+    = None``: the sums ungated); a token with ``delta = 0`` (the padding of a prompt to its bucket, and
     of ``L`` to whole chunks here) leaves the state as it found it.
     Returns ``(y [L, D], state [N, D])``, the state after the last
     token."""
@@ -184,4 +194,4 @@ def chunked_selective_scan(u, delta, b, c, z, a, d_skip, chunk: int = CHUNK):
         one, jnp.zeros((b.shape[1], channels), jnp.float32),
         (chunks(u), chunks(delta), chunks(b), chunks(c)))
     y = read.reshape(n * chunk, channels)[:length] + d_skip * u
-    return y * jax.nn.silu(z), state
+    return (y if z is None else y * jax.nn.silu(z)), state

@@ -261,23 +261,35 @@ def test_the_chunked_scan_equals_the_recurrence(length, bucket):
     assert np.abs(ref_out).max() > 0.1 and np.abs(ref_state).max() > 0.01
     assert np.abs(np.asarray(out)[:length] - ref_out).max() <= 1e-5
     assert np.abs(np.asarray(state) - ref_state).max() <= 1e-5
+    # and with the gate off: the same sums before silu(z), the same state
+    bare, bare_state = selective_scan.chunked_selective_scan(
+        u, delta, b, c, None, a, d_skip)
+    gate = np.asarray(z[:length]) / (1 + np.exp(-np.asarray(z[:length])))
+    assert np.abs(np.asarray(bare)[:length] * gate - ref_out).max() <= 1e-5
+    assert (np.asarray(bare_state) == np.asarray(state)).all()
 
 
+@pytest.mark.parametrize("gated", [True, False], ids=["gated", "ungated"])
 @pytest.mark.parametrize("kernel", ["fused_xla", "pallas_interpret"])
 @pytest.mark.parametrize("channels,states", [(128, 16), (5120, 16)])
 def test_selective_scan_step_turns_each_lanes_slot_and_no_other(
-        kernel, channels, states):
+        kernel, channels, states, gated):
     """The decode step over a pool against the recurrence on each lane's
     own state (at the toy's width and at the published 5,120 channels):
     live lanes' slots are turned, the slots of no lane are left as they
     were, and lanes that name the trash slot read out zeros and leave
-    zeros there, whatever it held."""
+    zeros there, whatever it held. With the gate off (``z = None``: what
+    `models/phi4flash.py` asks for, whose memory layer hands its sums on
+    before the gate) the sums come out as they are, not times
+    ``silu(z)``."""
     import jax.numpy as jnp
 
     from client_tpu.models import selective_scan
 
     lanes = 5
     u, delta, b, c, z, a, d_skip = _scan_inputs(lanes, channels, states)
+    if not gated:
+        z = None
     rng = np.random.default_rng(4)
     pool = rng.normal(size=(7, states, channels)).astype(np.float32)
     slots = np.array([3, 0, 5, 1, 0], np.int32)
@@ -290,8 +302,13 @@ def test_selective_scan_step_turns_each_lanes_slot_and_no_other(
             assert not out[lane].any()
             continue
         ref_out, ref_state = selective_scan.recurrent_step(
-            pool[slot], u[lane], delta[lane], b[lane], c[lane], z[lane], a,
-            d_skip)
+            pool[slot], u[lane], delta[lane], b[lane], c[lane],
+            z[lane] if gated else None, a, d_skip)
+        if not gated:
+            # the rule's sums themselves: the state read by C, and D u
+            by_hand = ((np.asarray(ref_state) * c[lane][:, None]).sum(0)
+                       + d_skip * u[lane])
+            assert np.abs(np.asarray(ref_out) - by_hand).max() <= 1e-5
         assert np.abs(out[lane] - np.asarray(ref_out)).max() <= 1e-5
         assert np.abs(new[slot] - np.asarray(ref_state)).max() <= 1e-5
     assert not new[0].any()

@@ -27,6 +27,7 @@ from client_tpu.llm import (
     LlmEngine,
 )
 from client_tpu.llm.engine import decode_fn_from_logits
+from client_tpu.llm.kv_cache import window_ring_blocks
 from client_tpu.scheduling import QueueFullError, QueueTimeoutError
 from client_tpu.utils import InferenceServerException
 
@@ -849,7 +850,7 @@ def test_genai_perf_drives_engine_end_to_end(llm_server, tmp_path, capsys):
 
 
 def _state_engine(clock, seen, row_bytes=((64, 64), (1000, 1000)),
-                  **overrides):
+                  tile_pages=(2, 1), **overrides):
     """A stub engine over a full group and a state group whose device
     functions record the tables they are handed (``seen``);
     ``row_bytes`` is the model's ``kv_row_bytes``."""
@@ -885,7 +886,7 @@ def _state_engine(clock, seen, row_bytes=((64, 64), (1000, 1000)),
         engine_config=EngineConfig(**defaults),
         model_name="stub",
         clock_ns=clock,
-        attn_tile_pages=(2, 1),
+        attn_tile_pages=tile_pages,
         kv_row_bytes=row_bytes,
     )
     return holder["engine"]
@@ -1013,3 +1014,108 @@ def test_state_bytes_follow_the_models_slot_and_its_layers(layers, slot):
         assert mid["kv_row_bytes_by_group"][1] == {
             "stored": slot, "counted": slot}
     assert engine.stats()["state_bytes_by_group"] == [0, 0]
+
+
+# phi4_mini_flash: ONE full layer whose pool eight layers read, eight
+# window layers, nine Mamba layers; the other fourteen store nothing and
+# are in no group
+_SAMBAY_GROUPS = ((17,), tuple(range(1, 16, 2)), tuple(range(0, 17, 2)))
+
+
+def _three_group_engine(seen, **overrides):
+    from client_tpu.models.engine_model import (
+        FULL, STATE, WINDOW, CacheGroup)
+
+    full, window, state = _SAMBAY_GROUPS
+    return _state_engine(
+        _FakeClock(), seen,
+        row_bytes=((5120, 5120), (5120, 5120), (358400, 358400)),
+        tile_pages=(2, 2, 1),
+        cache_groups=(CacheGroup(FULL, full),
+                      CacheGroup(WINDOW, window, window=8),
+                      CacheGroup(STATE, state)), **overrides)
+
+
+def test_three_cache_groups_at_once_a_ring_beside_a_slot():
+    """A full, a window and a state group together (`phi4_mini_flash`'s
+    three): every device call gets tables ``[3, ...]`` in the groups'
+    order, the window row a ring at its last columns, the state row the
+    slot in column 0 and nothing else; a lane's slot and ring are its own
+    for its whole life; `stats()` serves three entries a group, the full
+    group's blocks those of the ONE layer that stores them, the state
+    group's bytes its nine layers'; and all three are given back."""
+    seen = []
+    engine = _three_group_engine(seen)
+
+    async def run():
+        seqs = [engine.submit([1 + i, 2, 3], max_tokens=14) for i in range(5)]
+        return [await _collect(s) for s in seqs]
+
+    out = asyncio.run(run())
+    assert all(len(tokens) == 14 for tokens in out)
+    decodes = [t for kind, t in seen if kind == "decode"]
+    prefills = [t for kind, t in seen if kind == "prefill"]
+    assert all(t.shape[0] == 3 and t.ndim == 3 for t in decodes)
+    assert all(t.shape[0] == 3 and t.ndim == 2 for t in prefills)
+    ring = window_ring_blocks(8, 4, 2)
+    for tables in decodes:
+        live = tables[2, :, 0] > 0
+        assert not tables[2, :, 1:].any()
+        assert len(set(tables[2, live, 0])) == live.sum()
+        # a ring's blocks at the last columns a lane has reached, none
+        # shared with another lane's, and none of them the trash block's
+        held = [set(row[row > 0]) for row in tables[1, live]]
+        assert all(0 < len(blocks) <= ring for blocks in held)
+        assert sum(map(len, held)) == len(set().union(*held))
+    running = [entry for kind, entry in seen if kind == "stats"]
+    assert max(s["state_slots_in_use"] for s in running) == 3
+    for mid in running:
+        full, window, state = mid["kv_blocks_in_use_by_group"]
+        assert state == mid["state_slots_in_use"] == mid["active_sequences"]
+        assert window == ring * mid["active_sequences"]
+        assert mid["state_bytes_by_group"] == [0, 0, state * 358400 * 9]
+        assert [row["stored"] for row in mid["kv_row_bytes_by_group"]] == [
+            5120, 5120, 358400]
+    groups = engine.config.cache_groups
+    assert [len(g.layers) for g in groups] == [1, 8, 9]
+    stats = engine.stats()
+    assert stats["kv_blocks_in_use_by_group"] == [0, 0, 0]
+    assert stats["state_slots_in_use"] == 0 and stats["completed"] == 5
+    assert stats["window_blocks_whole"] > 0
+
+
+def test_a_preempted_sequence_gives_back_its_blocks_its_ring_and_its_slot():
+    """Three groups and a full pool too small for three sequences: the
+    victim's ring and slot are free while it waits, it is re-prefilled
+    over all three tables when it resumes, and nothing is held at the
+    end."""
+    seen = []
+    tight = _three_group_engine(seen, num_blocks=9)
+
+    async def run(engine):
+        seqs = [engine.submit([1 + i, 2, 3, 4], max_tokens=14)
+                for i in range(3)]
+        return [await _collect(s) for s in seqs]
+
+    resumed = asyncio.run(run(tight))
+    assert [len(tokens) for tokens in resumed] == [14, 14, 14]
+    assert tight.stats()["preemptions"] >= 1
+    ring = window_ring_blocks(8, 4, 2)
+    running = [entry for kind, entry in seen if kind == "stats"]
+    for mid in running:
+        assert mid["state_slots_in_use"] == mid["active_sequences"]
+        assert mid["kv_blocks_in_use_by_group"][1] == (
+            ring * mid["active_sequences"])
+    assert min(s["state_slots_in_use"] for s in running) < 3
+    prefills = sum(kind == "prefill" for kind, _ in seen)
+    assert prefills == 3 + tight.stats()["preemptions"]
+    assert tight.stats()["kv_blocks_in_use_by_group"] == [0, 0, 0]
+
+
+@pytest.mark.parametrize("setting", [
+    dict(prefix_sharing=True), dict(spec_k=2)])
+def test_three_groups_refuse_sharing_and_speculation_at_load(setting):
+    """Beside both a window and a state group the refusal is the state
+    group's, by name (it is checked first)."""
+    with pytest.raises(ValueError, match="state cache group"):
+        _three_group_engine([], **setting)

@@ -353,6 +353,104 @@ def test_pallas_window_sink_and_v_size_match_xla(
         assert np.abs(np.asarray(bare) - ref).max() > 1e-3
 
 
+def _shared_value_oracle(q, k_pages, v_pages, tables, positions, window):
+    """Differential attention's pairing in plain numpy, a lane and a head
+    at a time: ``q`` [B, T, H, D], ``k_pages`` [N, bs, KV, 2 D] (a row the
+    key heads ``2p, 2p + 1`` side by side), ``v_pages`` [N, bs, KV, Dv]
+    (the pair's one value head); head ``h`` reads key head ``h // (H / (2
+    KV))`` and sees slots ``<= position`` and, under ``window``, ``>
+    position - window``."""
+    b, t, h, d = q.shape
+    bs, kv = k_pages.shape[1:3]
+    out = np.zeros((b, t, h, v_pages.shape[-1]), np.float64)
+    for lane in range(b):
+        keys = k_pages[tables[lane]].reshape(-1, 2 * kv, d).astype(np.float64)
+        values = v_pages[tables[lane]].reshape(
+            -1, kv, v_pages.shape[-1]).astype(np.float64)
+        slots = np.arange(len(keys))
+        for row in range(t):
+            seen = slots <= positions[lane, row]
+            if window is not None:
+                seen &= slots > positions[lane, row] - window
+            for head in range(h):
+                key_head = head // (h // (2 * kv))
+                scores = keys[seen, key_head] @ q[lane, row, head] / d ** 0.5
+                weights = np.exp(scores - scores.max())
+                out[lane, row, head] = (
+                    weights / weights.sum()) @ values[seen, key_head // 2]
+    return out
+
+
+SHARED_VALUE_CASES = [
+    # (kv rows, query heads a key head, batch, nb, t, window): the cell's
+    # 40 heads over 20 key heads of 64 and 10 value heads of 128, its
+    # window of 512 (33 blocks: the ring's 36 in a table of 48), one lane,
+    # a table narrower than a tile, verify rows, a toy's two rows
+    (10, 2, 3, 24, 1, None), (10, 2, 3, 48, 1, 512), (10, 2, 1, 8, 1, None),
+    (10, 2, 3, 1, 1, None), (10, 2, 3, 8, 3, 40), (2, 2, 3, 24, 1, 17),
+    (2, 1, 7, 24, 1, None),
+]
+
+
+@pytest.mark.parametrize(
+    "kv,g,b,nb,t,window", SHARED_VALUE_CASES,
+    ids=[f"kv{c[0]}-g{c[1]}-b{c[2]}-nb{c[3]}-t{c[4]}-w{c[5]}"
+         for c in SHARED_VALUE_CASES],
+)
+def test_value_heads_shared_by_key_pairs_match_the_oracle(
+        kv, g, b, nb, t, window):
+    """``keys_per_value = 2``: a key row of 128 holds two key heads of 64
+    that share the row's value head of 128. The oracle
+    (``paged_attention_reference``, which cuts the rows and repeats the
+    values), plain XLA (which re-views the gathered rows) and the kernel
+    (whose queries are widened with zeros to the key row, so that the
+    kernel itself is unchanged) against a numpy loop over lanes and
+    heads; ragged lengths around the tile of 2 pages that float32 rows
+    give, garbage wherever the mask must hold, flat pools as the model
+    keeps them."""
+    from client_tpu.models import paged_attention as pa
+
+    rng = np.random.default_rng(kv * 1000 + b * 100 + nb + t + (window or 0))
+    q, k_pages, v_pages, tables, positions = _ragged_case(
+        rng, kv, 2 * g, b, nb, t, d=128)
+    q = q[..., :64]
+    bs = k_pages.shape[1]
+    if window is not None:
+        for i in range(b):
+            first = max(0, int(positions[i].min()) - window + 1)
+            behind = tables[i, : first // bs].copy()
+            tables[i, : first // bs] = 0
+            k_pages[behind[behind > 0]] = GARBAGE
+            v_pages[behind[behind > 0]] = -GARBAGE
+            block = tables[i, first // bs]
+            if block > 0:
+                k_pages[block, : first % bs] = GARBAGE
+                v_pages[block, : first % bs] = -GARBAGE
+    want = _shared_value_oracle(q, k_pages, v_pages, tables, positions, window)
+    scale = np.maximum(1.0, np.abs(want).max(axis=-1, keepdims=True))
+    args = (q, k_pages.reshape(len(k_pages), bs * kv, -1),
+            v_pages.reshape(len(v_pages), bs * kv, -1), tables, positions)
+    masking = dict(window=window, kv_heads=kv, keys_per_value=2)
+    plain = np.asarray(pa.paged_attention_xla(*args, **masking))
+    kernel = np.asarray(pa.paged_attention_pallas(
+        *args, interpret=True, **masking))
+    assert plain.shape == kernel.shape == want.shape == (b, t, 2 * g * kv, 128)
+    assert np.isfinite(kernel).all()
+    assert (np.abs(plain - want) / scale).max() <= 1e-5
+    assert (np.abs(kernel - want) / scale).max() <= 1e-5
+    if window is None:
+        dumb = np.asarray(pa.paged_attention_reference(
+            q, k_pages, v_pages, tables, positions, keys_per_value=2))
+        assert (np.abs(dumb - want) / scale).max() <= 1e-5
+    # the pairing shows: with the row's halves the other way round the
+    # same call reads the other key head
+    other = np.concatenate([k_pages[..., 64:], k_pages[..., :64]], axis=-1)
+    swapped = np.asarray(pa.paged_attention_xla(
+        args[0], other.reshape(args[1].shape), *args[2:], **masking))
+    live = positions[:, 0] > 0
+    assert np.abs(swapped - plain)[live].max() > 1e-2
+
+
 # how a tile's pages lie in the pool (paged_attention.whole_tiles): the
 # kinds are the kernel's arguments, the layouts what a table can look
 # like to it
@@ -633,6 +731,9 @@ def test_pages_per_tile_follows_the_shapes_alone():
     assert pa.pages_per_tile(16, 8, 128, jnp.bfloat16) == 8
     assert pa.pages_per_tile(16, 32, 128, jnp.bfloat16) == 2
     assert pa.pages_per_tile(16, 2, 128, jnp.bfloat16) == 32
+    # KV 20 / D 64 with a value head of 128 a pair of key heads: 10 rows
+    # of 128 a token in either pool, pages of 40 KB
+    assert pa.pages_per_tile(16, 10, 128, jnp.bfloat16) == 4
     # the served models' two-pool tiles, as `LlmEngineModel` asks for
     # them (a page's rows flat, the wider pool's row): MiMo's full and
     # window groups (K rows of 256 at KV 4 and KV 8), Trinity's (KV 4)

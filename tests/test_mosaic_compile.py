@@ -750,3 +750,118 @@ def test_jambas_longest_prefill_and_decode_fit_the_chip(one_chip):
     copied = re.compile(r"= (f32\[129,16,5120\]|bf16\[129,15360\])\S* copy\(")
     assert not [line for line in prefill.as_text().splitlines()
                 if copied.search(line)]
+
+
+#: phi4_mini_flash.reason8k's pools: the full group's 64 lanes of 8,192
+#: tokens and the trash block, the window group's 64 rings of 36 blocks
+PHI4_FULL, PHI4_RINGS = 32769, 1 + 64 * 36
+
+
+@pytest.mark.parametrize("lanes,columns,window,blocks", [
+    (64, 512, None, PHI4_FULL), (64, 512, 512, PHI4_RINGS),
+    (1, 8, None, PHI4_FULL), (8, 64, 512, PHI4_RINGS)])
+def test_mosaic_compiles_the_shared_value_call(
+        one_chip, lanes, columns, window, blocks):
+    """The paged kernel as `phi4flash` calls it (``keys_per_value`` 2): 40
+    query heads of 64 over 20 key heads of 64 and 10 value heads of 128,
+    the pools 10 rows of 128 a token each (a page 40,960 B in either, so a
+    tile of 4 pages, 64 tokens), with and without the window of 512. The
+    kernel is the one every other model runs, at KV 10 / D 128: the
+    widening of the queries is XLA's, outside it."""
+    import jax
+    import jax.numpy as jnp
+
+    from client_tpu.models import paged_attention as pa
+
+    assert pa.pages_per_tile(BLOCK, 10, 128, jnp.bfloat16, 2) == 4
+
+    def shaped(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(functools.partial(
+        pa.paged_attention_pallas, window=window, kv_heads=10,
+        keys_per_value=2)).lower(
+        shaped((lanes, 1, 40, 64)), shaped((blocks, BLOCK * 10, 128)),
+        shaped((blocks, BLOCK * 10, 128)), shaped((lanes, columns), jnp.int32),
+        shaped((lanes, 1), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "%paged_attention" in text
+    assert f"bf16[{blocks},{BLOCK * 10},128]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
+    assert compiled.memory_analysis().output_size_in_bytes == (
+        lanes * 40 * 128 * 2)
+
+
+def test_phi4flashs_longest_prefill_and_decode_fit_the_chip(one_chip):
+    """`phi4flash`'s 8,192-token prefill and its 64-lane decode step
+    compiled whole for the described v5e at the cell's sizes: all 32
+    layers, all 200,064 rows, ONE full pool of 32,769 blocks, eight ring
+    pools of 2,305, nine state pools of 65 slots, and NO pool for the
+    fourteen layers of the cross-decoder. The bound: 12.2 GB of arguments
+    (7.71 of weights, 2.68 of the full pool, 1.51 of rings, 0.21 of
+    states) and under 2.2 GB of scratch in the prefill, 14.4 GB of the
+    chip's 16; read here at 12,114,144,256 B of arguments, 62,937,600 B of
+    scratch in the decode step and 1,935,856,128 B in the prefill. Sixteen
+    layers attend through the paged kernel, eight of them over the one
+    pool, and that pool is never copied: it is updated where it lies, as
+    every ring, state and convolution pool is."""
+    import jax
+    import jax.numpy as jnp
+
+    from client_tpu.models import paged_attention, phi4flash
+    from client_tpu.models.engine_model import Kernels
+
+    config = phi4flash.Phi4FlashConfig()
+    kernels = Kernels("pallas", paged_attention.paged_attention_pallas)
+
+    def shaped(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    params = shaped(jax.eval_shape(
+        lambda: phi4flash.init_params(jax.random.PRNGKey(0), config)))
+    weights = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree_util.tree_leaves(params))
+    assert weights == 7_706_792_960
+    pages = shaped(jax.eval_shape(lambda: phi4flash.init_pages(
+        config, [PHI4_FULL, PHI4_RINGS, 65], BLOCK)))
+    stored = [sum(a.size * a.dtype.itemsize
+                  for a in jax.tree_util.tree_leaves(layer))
+              for layer in pages]
+    assert stored[17] == PHI4_FULL * BLOCK * 5120  # one layer stores it
+    assert stored[1] == PHI4_RINGS * BLOCK * 5120
+    assert stored[0] == 65 * 358400 and not any(stored[18:])
+    ints = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.int32, sharding=one_chip)
+    decode = jax.jit(
+        lambda p, t, at, tables, pages: phi4flash.decode_step_paged(
+            p, t, at, tables, pages, config, kernels),
+        donate_argnums=(4,)).lower(
+        params, ints(64), ints(64), ints(3, 64, 512), pages).compile()
+    memory = decode.memory_analysis()
+    assert memory.argument_size_in_bytes < 12.2e9
+    assert memory.temp_size_in_bytes < 100e6
+    text = decode.as_text()
+    calls = re.findall(r"custom_call_target=\"tpu_custom_call\"", text)
+    assert len(calls) == 16 + 9  # sixteen attention calls, nine scans
+    assert text.count("%selective_scan_step") >= 9
+    assert text.count("%paged_attention") >= 16
+    pool = re.compile(
+        rf"= (bf16\[{PHI4_FULL},160,128\]|bf16\[{PHI4_RINGS},160,128\]"
+        r"|f32\[65,16,5120\]|bf16\[65,15360\])\S* "
+        r"(copy|dynamic-update-slice|broadcast)\(")
+    assert not [line for line in text.splitlines() if pool.search(line)]
+    prefill = jax.jit(
+        lambda p, t, table, pages, last: phi4flash.prefill_into_pages(
+            p, t, table, pages, last, config, kernels),
+        donate_argnums=(3,)).lower(
+        params, ints(1, 8192), ints(3, 512), pages, ints()).compile()
+    memory = prefill.memory_analysis()
+    assert memory.argument_size_in_bytes < 12.2e9
+    assert memory.temp_size_in_bytes < 2.2e9
+    copied = re.compile(
+        rf"= (bf16\[{PHI4_FULL},160,128\]|bf16\[{PHI4_RINGS},160,128\]"
+        r"|f32\[65,16,5120\]|bf16\[65,15360\])\S* copy\(")
+    assert not [line for line in prefill.as_text().splitlines()
+                if copied.search(line)]
