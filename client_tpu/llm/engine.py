@@ -226,11 +226,12 @@ class EngineConfig:
         order (one full group when none is declared). A window group's
         ring is a whole number of its allocator's runs
         (:meth:`group_runs` of ``tile_pages``: 129 blocks become 144 at
-        tiles of 16, 9 become 12 at tiles of 4), so that no tile of a
-        ring wraps; the full group's pool is ``num_blocks`` whatever the
-        run, and its sequences hold up to a run less one of it in
-        reserve each (``stats()["kv_blocks_reserved_by_group"]``). A
-        state group's pool is ``1 + max_active`` slots.
+        tiles of 16, 33 become 40 at tiles of 8, 9 become 12 at tiles of
+        4), so that no tile of a ring wraps; the full group's pool is
+        ``num_blocks`` whatever the run, and its sequences hold up to a
+        run less one of it in reserve each
+        (``stats()["kv_blocks_reserved_by_group"]``). A state group's
+        pool is ``1 + max_active`` slots.
         Refuses, with the numbers, sizes under which a window group
         cannot do its work:
         a ``max_seq_len`` whose page table has fewer columns than the

@@ -48,18 +48,20 @@ call pick the path, never a model's name.
 - :func:`paged_attention_pallas`: the flash-style Pallas kernel, one
   grid step per sequence. The page table and each sequence's length ride
   scalar prefetch; the pools stay in HBM and the kernel copies a TILE of
-  pages (as many as a fixed VMEM budget holds for the pools' shapes: 8
-  at KV 8 / D 128 / bf16; a one-pool call's as many as give its score
-  block the columns that tile has, 64 at rows of 640:
-  :func:`pages_per_tile`) into one of two VMEM slots itself, the next
-  tile in flight while this one is folded into the online softmax, and
-  stops at the sequence's own last tile: no ``[B, S]`` gather ever
-  materializes and the padding of the table to its bucket is never
-  read. A tile whose live pages lie side by side in the pool (a window
-  group's ring, a prompt allocated in one go) comes by ONE copy of the
-  tile's pages from each pool, any other page by page; which, is read
-  off the table itself a tile at a time (:func:`whole_tiles`), and the
-  arithmetic on a tile does not know how it came. What a TPU serves.
+  pages (the power of two of them whose bytes lie nearest a fixed VMEM
+  budget at the pools' shapes: 8 at KV 8 / D 128 / bf16, whose pages
+  divide it, and 8 at 10 rows of 128 a token, whose 40 KB pages do not;
+  a one-pool call's as many as give its score block the columns that
+  tile has, 64 at rows of 640: :func:`pages_per_tile`) into one of two
+  VMEM slots itself, the next tile in flight while this one is folded
+  into the online softmax, and stops at the sequence's own last tile:
+  no ``[B, S]`` gather ever materializes and the padding of the table
+  to its bucket is never read. A tile whose live pages lie side by side
+  in the pool (a window group's ring, a prompt allocated in one go)
+  comes by ONE copy of the tile's pages from each pool, any other page
+  by page; which, is read off the table itself a tile at a time
+  (:func:`whole_tiles`), and the arithmetic on a tile does not know how
+  it came. What a TPU serves.
   ``interpret=True`` runs the same kernel under the Pallas interpreter,
   for CPU tests and rehearsals.
 - :func:`paged_attention_xla`: one fused XLA computation over the
@@ -78,6 +80,7 @@ The choice is reported in the model's config parameters.
 """
 
 import functools
+import math
 from typing import Callable, Optional, Tuple
 
 import jax
@@ -240,8 +243,9 @@ def paged_attention_xla(q, k_pages, v_pages, page_tables, positions,
 # Pallas kernel: tiles of pages, double-buffered by hand, ragged trip count
 # ---------------------------------------------------------------------------
 
-#: VMEM the kernel may hold in K/V page buffers: two slots (one being
-#: folded, one in flight) of one tile of each pool
+#: VMEM the kernel's K/V page buffers aim at: two slots (one being
+#: folded, one in flight) of one tile of each pool; a tile is a power of
+#: two of pages, so the buffers hold this to within a factor of √2
 _KV_VMEM_BUDGET = 1 << 20
 #: the (token, kv head) row the budget was set for, D 128 in bf16: two
 #: slots of K and V tiles of such rows hold 1,024 of them, which is the
@@ -254,15 +258,24 @@ _ONE_POOL_VMEM_BUDGET = 4 << 20
 
 def pages_per_tile(block_size: int, kv_heads: int, head_dim: int,
                    dtype, pools: int = 2) -> int:
-    """Pages the kernel brings into VMEM per tile: the largest power of
-    two that fits :data:`_KV_VMEM_BUDGET` over ``pools`` pools (K and V,
-    or one whose values lie inside its key rows) of two slots each. A
-    function of the pools' shapes alone, so one program serves every
-    batch, and every model finds its own tile: 8 pages (128 tokens) at
-    KV 8 / D 128 / bf16, 2 at KV 32, 32 for a KV 2 tensor-parallel
-    shard, 64 at KV 1 / D 128 (a multi-query model's 4 KB pages), 4 at
-    KV 20 / D 64 with a value head of 128 a PAIR of key heads (10 rows of
-    128 a token in either pool, pages of 40 KB: ``keys_per_value`` 2).
+    """Pages the kernel brings into VMEM per tile: the power of two whose
+    bytes lie NEAREST (by ratio) what :data:`_KV_VMEM_BUDGET` gives one
+    slot of one of ``pools`` pools (K and V, or one whose values lie
+    inside its key rows), two slots each. The budget is what a tile aims
+    at, not a ceiling: the buffers hold between 1/√2 and √2 of it (at
+    most 1.41 MB of the 16 MB Mosaic gives a kernel), and a page larger
+    than a slot still moves, one at a time. A function of the pools'
+    shapes alone, so one program serves every batch, and every model
+    finds its own tile: 8 pages (128 tokens) at KV 8 / D 128 / bf16, 2
+    at KV 32, 32 for a KV 2 tensor-parallel shard, 64 at KV 1 / D 128 (a
+    multi-query model's 4 KB pages), all of which divide the budget; 8
+    at KV 20 / D 64 with a value head of 128 a PAIR of key heads (10 rows
+    of 128 a token in either pool, pages of 40 KB of which a slot's
+    share holds 6.4: ``keys_per_value`` 2). Nearest and not the largest
+    UNDER the budget, which would give that call 4 pages: 62% of the
+    bytes and of the score-block columns a stop is meant to carry, and
+    what a stop costs beyond its bytes twice as often (PERF.md, PR 45:
+    4 / 8 / 16 pages on the chip).
 
     A ONE-POOL call gets a longer tile than those bytes hold. What a
     tile stop costs beyond its bytes (the copy's start and its latency
@@ -281,12 +294,15 @@ def pages_per_tile(block_size: int, kv_heads: int, head_dim: int,
     sets a stop's time and the dead slots behind a lane's last token
     grow (PERF.md, PR 37: 32 / 64 / 128 pages on the chip)."""
     page_bytes = block_size * kv_heads * head_dim * jnp.dtype(dtype).itemsize
-    pages = max(1, _KV_VMEM_BUDGET // (2 * pools) // page_bytes)
+    share = _KV_VMEM_BUDGET // (2 * pools)
+    pages = 1 << max(0, round(math.log2(share / page_bytes)))
     if pools == 1:
         columns = _KV_VMEM_BUDGET // (2 * 2) // _BUDGET_ROW_BYTES
-        pages = max(pages, min(columns // (block_size * kv_heads),
-                               _ONE_POOL_VMEM_BUDGET // 2 // page_bytes))
-    return 1 << (pages.bit_length() - 1)
+        longer = min(columns // (block_size * kv_heads),
+                     _ONE_POOL_VMEM_BUDGET // 2 // page_bytes)
+        if longer > pages:
+            pages = 1 << (longer.bit_length() - 1)
+    return pages
 
 
 def visible_slots(positions, window):

@@ -54,6 +54,11 @@ projections as they come, ``KV / 2`` rows of ``2 D`` in either pool, and
 head (``models/paged_attention.py``); the query heads are put in
 grouped-query order over the key heads on the way in
 (:func:`_in_key_order`) and the subtraction is plain XLA after the call.
+At the published widths a token takes 10 rows of 128 in either pool, a
+page of 16 tokens 40,960 B: the kernel's tile is 8 pages (128 tokens,
+``paged_attention.pages_per_tile``: the power of two nearest the 6.4
+its budget holds), and a window of 512 keeps a ring of 40 blocks, five
+tiles.
 
 Three cache groups (``models/engine_model.py``), in this order: the full
 group of the ONE full layer, the window group of the window layers, the

@@ -312,8 +312,9 @@ def test_one_pool_takes_the_score_columns_of_a_two_pool_tile(monkeypatch):
     """Two slots of one pool where there were two of two, and a tile
     lengthened to the score block's columns the budget gives K and V at
     KV 8 / D 128: 64 pages (1,024 tokens) at the latent cache's rows of
-    640, where the bytes alone hold 16 and K and V pools of such rows
-    would take 8; the two-pool tiles are what they were."""
+    640, where the bytes alone hold 25.6 and K and V pools of such rows
+    would take 16 (12.8 a slot: the power of two nearest it); the served
+    two-pool tiles, whose pages divide the budget, are what they were."""
     import jax.numpy as jnp
 
     from client_tpu.models import paged_attention as pa
@@ -321,7 +322,7 @@ def test_one_pool_takes_the_score_columns_of_a_two_pool_tile(monkeypatch):
     # the shipped budget, whatever the module's fixture set
     monkeypatch.setattr(pa, "_KV_VMEM_BUDGET", 1 << 20)
     assert pa.pages_per_tile(16, 1, 640, jnp.bfloat16, 1) == 64
-    assert pa.pages_per_tile(16, 1, 640, jnp.bfloat16) == 8
+    assert pa.pages_per_tile(16, 1, 640, jnp.bfloat16) == 16
     assert pa.pages_per_tile(16, 8, 128, jnp.bfloat16) == 8
     assert pa.pages_per_tile(16, 32, 128, jnp.bfloat16) == 2
     assert pa.pages_per_tile(16, 2, 128, jnp.bfloat16) == 32

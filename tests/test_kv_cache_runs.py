@@ -602,27 +602,31 @@ def test_jambas_cell_holds_a_slot_a_lane_and_its_blocks_in_runs_of_64(pool):
 
 
 @pytest.mark.parametrize("pool", [32769, 20481])
-def test_phi4s_cell_holds_runs_of_4_a_ring_of_9_tiles_and_a_slot_a_lane(pool):
+def test_phi4s_cell_holds_runs_of_8_a_ring_of_5_tiles_and_a_slot_a_lane(pool):
     """``phi4_mini_flash.reason8k`` through the allocators alone: THREE
     groups, a full group of one layer and a window group of eight whose
-    tile at 10 rows of 128 a token is 4 pages, beside a state group of
-    nine. A window of 512 touches 33 blocks; its ring is 36, nine whole
-    tiles. The configuration's full pool of 32,769 blocks is 64 lanes of
-    8,192 tokens and the trash block; Trinity's 20,481 under the same mix
-    would do too. 64 sequences of 512 + 120 i tokens grow a token a step
-    to 8,192 and are followed by a fresh prompt of 512 in the lane: an
-    admission always finds its runs, its ring AND its slot."""
+    tile at 10 rows of 128 a token is 8 pages (the power of two nearest
+    the 6.4 that the kernel's budget holds), beside a state group of
+    nine. A window of 512 touches 33 blocks; its ring is 40, five whole
+    tiles (36 at the runs of 4 the cell had before PR 45, 48 at 16: the
+    ring is rounded to the run). The configuration's full pool of 32,769
+    blocks is 64 lanes of 8,192 tokens and the trash block, 64 runs of 8
+    a lane at the longest; Trinity's 20,481 under the same mix would do
+    too. 64 sequences of 512 + 120 i tokens grow a token a step to 8,192
+    and are followed by a fresh prompt of 512 in the lane: an admission
+    always finds its runs, its ring AND its slot."""
     groups = (CacheGroup(FULL, (17,)),
               CacheGroup(WINDOW, tuple(range(1, 16, 2)), window=512),
               CacheGroup(STATE, tuple(range(0, 17, 2))))
     cell = EngineConfig(block_size=16, num_blocks=pool, max_active=64,
                         max_seq_len=8192, cache_groups=groups)
-    assert cell.group_runs((4, 4, 1)) == [4, 4, 1]
+    assert cell.group_runs((8, 8, 1)) == [8, 8, 1]
     assert window_ring_blocks(512, 16) == 33
-    assert window_ring_blocks(512, 16, 4) == 36
-    assert cell.group_num_blocks((4, 4, 1)) == [pool, 1 + 64 * 36, 65]
-    blocks = BlockAllocator(pool, 16, 4)
-    rings, slots = BlockAllocator(1 + 64 * 36, 16, 4), BlockAllocator(65, 1)
+    assert [window_ring_blocks(512, 16, run) for run in (4, 8, 16)] == [
+        36, 40, 48]
+    assert cell.group_num_blocks((8, 8, 1)) == [pool, 1 + 64 * 40, 65]
+    blocks = BlockAllocator(pool, 16, 8)
+    rings, slots = BlockAllocator(1 + 64 * 40, 16, 8), BlockAllocator(65, 1)
     at, fresh = {}, iter(range(1 << 30))
 
     def admit(lane, prompt):
@@ -630,10 +634,10 @@ def test_phi4s_cell_holds_runs_of_4_a_ring_of_9_tiles_and_a_slot_a_lane(pool):
         assert blocks.demand(need) <= blocks.free_blocks, (lane, prompt)
         at[lane] = [next(fresh), prompt]
         blocks.allocate(at[lane][0], need)
-        ring = rings.allocate(at[lane][0], 36)
-        # nine whole tiles: every run of 4 starts on a tile of the pool
-        assert all(ring[j] % 4 == 1 and ring[j:j + 4] == list(
-            range(ring[j], ring[j] + 4)) for j in range(0, 36, 4))
+        ring = rings.allocate(at[lane][0], 40)
+        # five whole tiles: every run of 8 starts on a tile of the pool
+        assert all(ring[j] % 8 == 1 and ring[j:j + 8] == list(
+            range(ring[j], ring[j] + 8)) for j in range(0, 40, 8))
         (slot,) = slots.allocate(at[lane][0], 1)
         return slot
 

@@ -384,7 +384,7 @@ def _shared_value_oracle(q, k_pages, v_pages, tables, positions, window):
 SHARED_VALUE_CASES = [
     # (kv rows, query heads a key head, batch, nb, t, window): the cell's
     # 40 heads over 20 key heads of 64 and 10 value heads of 128, its
-    # window of 512 (33 blocks: the ring's 36 in a table of 48), one lane,
+    # window of 512 (33 blocks: the ring's 40 in a table of 48), one lane,
     # a table narrower than a tile, verify rows, a toy's two rows
     (10, 2, 3, 24, 1, None), (10, 2, 3, 48, 1, 512), (10, 2, 1, 8, 1, None),
     (10, 2, 3, 1, 1, None), (10, 2, 3, 8, 3, 40), (2, 2, 3, 24, 1, 17),
@@ -405,9 +405,10 @@ def test_value_heads_shared_by_key_pairs_match_the_oracle(
     values), plain XLA (which re-views the gathered rows) and the kernel
     (whose queries are widened with zeros to the key row, so that the
     kernel itself is unchanged) against a numpy loop over lanes and
-    heads; ragged lengths around the tile of 2 pages that float32 rows
-    give, garbage wherever the mask must hold, flat pools as the model
-    keeps them."""
+    heads; ragged lengths around the tile of 4 pages that float32 rows
+    give (3.2 of them a slot's share at KV 10, rounded to the nearer
+    power of two; 2 at KV 2), garbage wherever the mask must hold, flat
+    pools as the model keeps them."""
     from client_tpu.models import paged_attention as pa
 
     rng = np.random.default_rng(kv * 1000 + b * 100 + nb + t + (window or 0))
@@ -449,6 +450,105 @@ def test_value_heads_shared_by_key_pairs_match_the_oracle(
         args[0], other.reshape(args[1].shape), *args[2:], **masking))
     live = positions[:, 0] > 0
     assert np.abs(swapped - plain)[live].max() > 1e-2
+
+
+def _rounded_up_case(rng, dtype, window, tile, b_lengths, nb, bs=16, kv=10):
+    """Lanes of the given context lengths at ``keys_per_value`` 2 and 10
+    rows of 128 a token, laid out as the engine would: without a window,
+    every lane's blocks consecutive from a tile of the pool; under one,
+    a ring a lane of ``window_ring_blocks(window, bs, tile)`` blocks
+    (``kv_cache.window_tables``), holding the ``R`` newest columns, so a
+    context longer than the ring has wrapped it. Every slot no row may
+    see (past the position, behind the window, the trash block) holds
+    ``GARBAGE``. Returns (q, k_pages, v_pages, tables, positions)."""
+    from client_tpu.llm.kv_cache import window_ring_blocks, window_tables
+
+    lanes = len(b_lengths)
+    held = nb if window is None else window_ring_blocks(window, bs, tile)
+    # a lane's blocks start on a tile of the pool: 1 + a multiple of it
+    stride = -(-held // tile) * tile
+    k_pages = np.full((1 + lanes * stride, bs, kv, 128), GARBAGE, np.float32)
+    v_pages = np.full((1 + lanes * stride, bs, kv, 128), -GARBAGE, np.float32)
+    blocks = (1 + np.arange(lanes * stride)).reshape(lanes, stride)[:, :held]
+    positions = np.asarray(b_lengths, np.int32)[:, None] - 1
+    if window is None:
+        tables = np.where(
+            np.arange(nb)[None] <= positions // bs, blocks, 0).astype(np.int32)
+    else:
+        tables = window_tables(blocks, (positions[:, 0] // bs).tolist(), nb)
+    for lane, length in enumerate(b_lengths):
+        first = 0 if window is None else max(0, length - window)
+        for column in range(first // bs, (length - 1) // bs + 1):
+            seen = np.arange(column * bs, (column + 1) * bs)
+            seen = (seen >= first) & (seen < length)
+            page = tables[lane, column]
+            assert page > 0
+            k_pages[page, seen] = rng.normal(size=(seen.sum(), kv, 128))
+            v_pages[page, seen] = rng.normal(size=(seen.sum(), kv, 128))
+    q = rng.normal(size=(lanes, 1, 40, 64)).astype(np.float32)
+    return (q.astype(dtype), k_pages.astype(dtype), v_pages.astype(dtype),
+            tables, positions)
+
+
+@pytest.mark.parametrize("window", [None, 200])
+@pytest.mark.parametrize("dtype,tile", [("float32", 4), ("bfloat16", 8)])
+def test_a_tile_rounded_up_past_the_budget_matches_the_oracle(
+        dtype, tile, window):
+    """The rule's rounding UP, at the shipped budget: `phi4flash`'s call
+    (``keys_per_value`` 2, 40 heads, 10 rows of 128 a token) has pages of
+    which a slot's share holds 6.4 in bf16 and 3.2 in float32, so tiles
+    of 8 and 4 where the largest power of two under the budget gave 4 and
+    2. Contexts end inside, at the end of and one past a tile, near the
+    start of the table and far enough in to have wrapped a window's ring
+    (a window of 200: 14 blocks, a ring of 16, whole tiles, in a table of
+    48 columns); whole tiles come by one copy a pool and bring what lies
+    beside the live pages. The interpreted kernel against
+    ``paged_attention_reference`` (which knows no window: under one, the
+    numpy loop of the test above stands in) and plain XLA."""
+    import jax.numpy as jnp
+
+    from client_tpu.models import paged_attention as pa
+
+    dtype = jnp.dtype(dtype)
+    bs, kv, nb = 16, 10, 48
+    assert pa._KV_VMEM_BUDGET == 1 << 20
+    page_bytes = bs * kv * 128 * dtype.itemsize
+    assert tile == pa.pages_per_tile(bs * kv, 1, 128, dtype, 2)
+    # the floor of the pages a slot's share holds is under the tile
+    assert tile > pa._KV_VMEM_BUDGET // 4 // page_bytes >= tile // 2
+    slots = tile * bs
+    lengths = [slots - 5, slots, slots + 1, 1,
+               5 * slots - 7, 5 * slots, 5 * slots + 1, nb * bs]
+    rng = np.random.default_rng(tile * 1000 + (window or 0))
+    q, k_pages, v_pages, tables, positions = _rounded_up_case(
+        rng, dtype, window, tile, lengths, nb)
+    first_slots, seen = pa.visible_slots(positions, window)
+    walked, whole = pa.count_tiles(
+        tables, np.asarray(first_slots), np.asarray(seen), tile, bs,
+        len(k_pages))
+    assert walked == whole > len(lengths)
+    flat = (q, k_pages.reshape(len(k_pages), bs * kv, -1),
+            v_pages.reshape(len(v_pages), bs * kv, -1), tables, positions)
+    masking = dict(window=window, kv_heads=kv, keys_per_value=2)
+    kernel = np.asarray(pa.paged_attention_pallas(
+        *flat, interpret=True, **masking), np.float32)
+    plain = np.asarray(pa.paged_attention_xla(*flat, **masking), np.float32)
+    if window is None:
+        want = np.asarray(pa.paged_attention_reference(
+            q, k_pages, v_pages, tables, positions, keys_per_value=2),
+            np.float32)
+    else:
+        want = _shared_value_oracle(
+            *(np.asarray(a, np.float32) for a in (q, k_pages, v_pages)),
+            tables, positions, window)
+    assert kernel.shape == want.shape == (len(lengths), 1, 40, 128)
+    assert np.isfinite(kernel).all()
+    scale = np.maximum(1.0, np.abs(want).max(axis=-1, keepdims=True))
+    # float32 throughout, or bf16 operands and weights on the way to a
+    # float32 sum and a bf16 result: a few of its steps of 2^-8
+    limit = 1e-5 if dtype == jnp.float32 else 2.0 ** -6
+    assert (np.abs(kernel - want) / scale).max() <= limit
+    assert (np.abs(plain - want) / scale).max() <= limit
 
 
 # how a tile's pages lie in the pool (paged_attention.whole_tiles): the
@@ -722,18 +822,23 @@ def test_whole_tiles_rule_on_hand_made_tables():
 
 def test_pages_per_tile_follows_the_shapes_alone():
     """The tile is a function of the pool's shapes and nothing else (no
-    batch, table or length goes in); K and V, two slots each, stay
-    inside the VMEM budget."""
+    batch, table or length goes in); K and V, two slots each, take the
+    power of two of pages nearest the VMEM budget: at most √2 of it, and
+    no other power of two nearer."""
     import jax.numpy as jnp
 
     from client_tpu.models import paged_attention as pa
 
+    assert pa._KV_VMEM_BUDGET == 1 << 20
     assert pa.pages_per_tile(16, 8, 128, jnp.bfloat16) == 8
     assert pa.pages_per_tile(16, 32, 128, jnp.bfloat16) == 2
     assert pa.pages_per_tile(16, 2, 128, jnp.bfloat16) == 32
     # KV 20 / D 64 with a value head of 128 a pair of key heads: 10 rows
-    # of 128 a token in either pool, pages of 40 KB
-    assert pa.pages_per_tile(16, 10, 128, jnp.bfloat16) == 4
+    # of 128 a token in either pool, pages of 40 KB, 6.4 of them a slot:
+    # 8 (1.31 MB in the four buffers), where the largest under gave 4
+    assert pa.pages_per_tile(16, 10, 128, jnp.bfloat16) == 8
+    # the same rows in float32, as the CPU parity tests hold them: 3.2
+    assert pa.pages_per_tile(16, 10, 128, jnp.float32) == 4
     # the served models' two-pool tiles, as `LlmEngineModel` asks for
     # them (a page's rows flat, the wider pool's row): MiMo's full and
     # window groups (K rows of 256 at KV 4 and KV 8), Trinity's (KV 4)
@@ -741,10 +846,11 @@ def test_pages_per_tile_follows_the_shapes_alone():
     assert pa.pages_per_tile(16 * 8, 1, 256, jnp.bfloat16, 2) == 4
     assert pa.pages_per_tile(16 * 4, 1, 128, jnp.bfloat16, 2) == 16
     # one pool (a latent cache's rows of 640 at KV 1): the budget alone
-    # holds 16 pages, 256 columns of the score block; the tile is
-    # lengthened to the 1,024 columns the budget gives K and V at KV 8
+    # holds 25.6 pages, 512 columns of the score block at most; the tile
+    # is lengthened to the 1,024 columns the budget gives K and V at KV
+    # 8. Two pools of such rows (served by nothing): 12.8 a slot, 16
     assert pa.pages_per_tile(16, 1, 640, jnp.bfloat16, 1) == 64
-    assert pa.pages_per_tile(16, 1, 640, jnp.bfloat16, 2) == 8
+    assert pa.pages_per_tile(16, 1, 640, jnp.bfloat16, 2) == 16
     for bs, kv, d, dtype in [(16, 1, 640, jnp.bfloat16),
                              (16, 1, 128, jnp.float32),
                              (32, 1, 640, jnp.bfloat16),
@@ -761,12 +867,77 @@ def test_pages_per_tile_follows_the_shapes_alone():
     for bs, kv, d, dtype in [(16, 32, 128, jnp.bfloat16),
                              (16, 32, 128, jnp.float32),
                              (32, 8, 128, jnp.bfloat16),
-                             (16, 2, 64, jnp.bfloat16)]:
+                             (16, 2, 64, jnp.bfloat16),
+                             (16, 10, 128, jnp.bfloat16),
+                             (16, 10, 128, jnp.float32),
+                             (16, 1, 640, jnp.bfloat16),
+                             (16, 3, 128, jnp.bfloat16),
+                             (16, 5, 64, jnp.float32),
+                             (8, 7, 384, jnp.bfloat16)]:
         pages = pa.pages_per_tile(bs, kv, d, dtype)
         scratch = 2 * 2 * pages * bs * kv * d * jnp.dtype(dtype).itemsize
-        assert pages >= 1 and scratch <= pa._KV_VMEM_BUDGET
+        assert pages >= 1 and pages & (pages - 1) == 0
+        # the four buffers hold at most √2 of the budget ..
+        assert scratch ** 2 <= 2 * pa._KV_VMEM_BUDGET ** 2
+        # .. and no power of two lies nearer it: half as many pages
+        # fall short by more than these overshoot, twice as many
+        # overshoot by more than these fall short
+        assert 2 * scratch ** 2 >= pa._KV_VMEM_BUDGET ** 2
     # a page larger than a slot still moves, one at a time
     assert pa.pages_per_tile(64, 64, 256, jnp.float32) == 1
+    assert pa.pages_per_tile(16, 64, 256, jnp.bfloat16) == 1
+
+
+#: what `LlmEngineModel` asks `pages_per_tile` for each attending cache
+#: group of the benchmark's seven cells (a page's rows flat, the wider
+#: pool's row, bf16) and the tile it serves: (rows a page, row width,
+#: pools, pages the budget's share of a slot holds, pages a tile)
+SERVED_TILES = {
+    "mistral7b.batch": (16 * 8, 128, 2, 8.0, 8),
+    "mimo_v2_flash.reason-full": (16 * 4, 256, 2, 8.0, 8),
+    "mimo_v2_flash.reason-window": (16 * 8, 256, 2, 4.0, 4),
+    "trinity_mini.reason8k": (16 * 4, 128, 2, 16.0, 16),
+    "gigachat3_702b.reason8k_128": (16, 640, 1, 25.6, 64),  # by columns
+    "qwen3_next_80b.reason2k_128": (16 * 2, 256, 2, 16.0, 16),
+    "jamba2_3b.reason8k_128": (16, 128, 2, 64.0, 64),
+    "phi4_mini_flash.reason8k": (16 * 10, 128, 2, 6.4, 8),
+}
+
+
+@pytest.mark.parametrize("cell", SERVED_TILES)
+def test_every_cells_served_tile(cell):
+    """The tile each cell's paged calls stop at. Every two-pool page but
+    Phi-4-mini-flash's divides the budget, so those tiles are what the
+    largest power of two under it gives as well; phi4's 40 KB pages (6.4
+    a slot) take 8, 655,360 B a stop, where that gives 4."""
+    import jax.numpy as jnp
+
+    from client_tpu.models import paged_attention as pa
+
+    rows, width, pools, held, pages = SERVED_TILES[cell]
+    assert pa._KV_VMEM_BUDGET // (2 * pools) / (rows * width * 2) == held
+    assert pa.pages_per_tile(rows, 1, width, jnp.bfloat16, pools) == pages
+
+
+def test_phi4s_rings_and_runs_follow_the_tile():
+    """`phi4_mini_flash.reason8k`'s engine sizes off its tile of 8 pages,
+    as `LlmEngineModel` derives them: the allocators' runs, and the ring
+    rounded up to the run. A window of 512 touches 33 blocks of 16: 40
+    at tiles of 8 (36 at 4, 48 at 16)."""
+    from client_tpu.llm.engine import EngineConfig
+    from client_tpu.llm.kv_cache import window_ring_blocks
+    from client_tpu.models import phi4flash
+
+    tile = SERVED_TILES["phi4_mini_flash.reason8k"][-1]
+    assert window_ring_blocks(512, 16, tile) == 40
+    cell = EngineConfig(
+        block_size=16, num_blocks=32769, max_active=64, max_seq_len=8192,
+        prefix_sharing=False,
+        cache_groups=phi4flash.cache_groups(phi4flash.Phi4FlashConfig()))
+    assert cell.group_runs((tile, tile, 1)) == [8, 8, 1]
+    assert cell.group_num_blocks((tile, tile, 1)) == [32769, 1 + 64 * 40, 65]
+    # 4,096 runs of 8 in the full pool: 64 a lane at the longest
+    assert (32769 - 1) // tile == 64 * (8192 // (16 * tile))
 
 
 def test_decode_step_kernels_match_reference_on_tiny_llama(tiny_llama):

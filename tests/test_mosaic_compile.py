@@ -753,8 +753,9 @@ def test_jambas_longest_prefill_and_decode_fit_the_chip(one_chip):
 
 
 #: phi4_mini_flash.reason8k's pools: the full group's 64 lanes of 8,192
-#: tokens and the trash block, the window group's 64 rings of 36 blocks
-PHI4_FULL, PHI4_RINGS = 32769, 1 + 64 * 36
+#: tokens and the trash block, the window group's 64 rings of 40 blocks
+#: (the window's 33 in whole tiles of 8)
+PHI4_FULL, PHI4_RINGS = 32769, 1 + 64 * 40
 
 
 @pytest.mark.parametrize("lanes,columns,window,blocks", [
@@ -764,8 +765,10 @@ def test_mosaic_compiles_the_shared_value_call(
         one_chip, lanes, columns, window, blocks):
     """The paged kernel as `phi4flash` calls it (``keys_per_value`` 2): 40
     query heads of 64 over 20 key heads of 64 and 10 value heads of 128,
-    the pools 10 rows of 128 a token each (a page 40,960 B in either, so a
-    tile of 4 pages, 64 tokens), with and without the window of 512. The
+    the pools 10 rows of 128 a token each (a page 40,960 B in either, 6.4
+    of them the budget's share of a slot, so a tile of 8 pages, 128
+    tokens, 1.31 MB in the four buffers), with and without the window of
+    512. The
     kernel is the one every other model runs, at KV 10 / D 128: the
     widening of the queries is XLA's, outside it."""
     import jax
@@ -773,7 +776,7 @@ def test_mosaic_compiles_the_shared_value_call(
 
     from client_tpu.models import paged_attention as pa
 
-    assert pa.pages_per_tile(BLOCK, 10, 128, jnp.bfloat16, 2) == 4
+    assert pa.pages_per_tile(BLOCK, 10, 128, jnp.bfloat16, 2) == 8
 
     def shaped(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -796,12 +799,14 @@ def test_phi4flashs_longest_prefill_and_decode_fit_the_chip(one_chip):
     """`phi4flash`'s 8,192-token prefill and its 64-lane decode step
     compiled whole for the described v5e at the cell's sizes: all 32
     layers, all 200,064 rows, ONE full pool of 32,769 blocks, eight ring
-    pools of 2,305, nine state pools of 65 slots, and NO pool for the
-    fourteen layers of the cross-decoder. The bound: 12.2 GB of arguments
-    (7.71 of weights, 2.68 of the full pool, 1.51 of rings, 0.21 of
-    states) and under 2.2 GB of scratch in the prefill, 14.4 GB of the
-    chip's 16; read here at 12,114,144,256 B of arguments, 62,937,600 B of
-    scratch in the decode step and 1,935,856,128 B in the prefill. Sixteen
+    pools of 2,561 (rings of 40 blocks: five tiles of 8), nine state
+    pools of 65 slots, and NO pool for the fourteen layers of the
+    cross-decoder. The bound: 12.4 GB of arguments (7.71 of weights, 2.68
+    of the full pool, 1.68 of rings, 0.21 of states) and under 2.2 GB of
+    scratch in the prefill, 14.6 GB of the chip's 16; read here at
+    12,281,916,416 B of arguments (12,114,144,256 at PR 44's rings of
+    36), 62,872,064 B of scratch in the decode step and 1,935,856,128 B
+    in the prefill. Sixteen
     layers attend through the paged kernel, eight of them over the one
     pool, and that pool is never copied: it is updated where it lies, as
     every ring, state and convolution pool is."""
@@ -840,7 +845,7 @@ def test_phi4flashs_longest_prefill_and_decode_fit_the_chip(one_chip):
         donate_argnums=(4,)).lower(
         params, ints(64), ints(64), ints(3, 64, 512), pages).compile()
     memory = decode.memory_analysis()
-    assert memory.argument_size_in_bytes < 12.2e9
+    assert memory.argument_size_in_bytes < 12.4e9
     assert memory.temp_size_in_bytes < 100e6
     text = decode.as_text()
     calls = re.findall(r"custom_call_target=\"tpu_custom_call\"", text)
@@ -858,7 +863,7 @@ def test_phi4flashs_longest_prefill_and_decode_fit_the_chip(one_chip):
         donate_argnums=(3,)).lower(
         params, ints(1, 8192), ints(3, 512), pages, ints()).compile()
     memory = prefill.memory_analysis()
-    assert memory.argument_size_in_bytes < 12.2e9
+    assert memory.argument_size_in_bytes < 12.4e9
     assert memory.temp_size_in_bytes < 2.2e9
     copied = re.compile(
         rf"= (bf16\[{PHI4_FULL},160,128\]|bf16\[{PHI4_RINGS},160,128\]"

@@ -1296,17 +1296,18 @@ def test_jambas_decode_step_agrees_through_both_kernel_choices(device):
 def test_compiled_pallas_at_the_phi4_cells_shapes(device, window):
     """The paged kernel as `phi4flash` calls it (``keys_per_value`` 2: 40
     query heads of 64 over 20 key heads of 64 and 10 value heads of 128,
-    10 rows of 128 a token in either pool, tiles of 4 pages), 64 lanes
-    over contexts to 8,192, with and without the window of 512, against
-    plain XLA. Prints ms a call and the share of HBM's bandwidth of the
-    5,120 B a visible token."""
+    10 rows of 128 a token in either pool: pages of 40 KB, of which the
+    budget's share of a slot holds 6.4, so tiles of 8 pages, the power of
+    two nearest it), 64 lanes over contexts to 8,192, with and without
+    the window of 512, against plain XLA. Prints ms a call and the share
+    of HBM's bandwidth of the 5,120 B a visible token."""
     import jax
 
     from client_tpu.models import paged_attention as pa
 
     rng = np.random.default_rng(41)
     lanes, columns, rows, dim, heads = 64, 512, 10, 128, 40
-    assert pa.pages_per_tile(BLOCK, rows, dim, np.dtype("bfloat16"), 2) == 4
+    assert pa.pages_per_tile(BLOCK, rows, dim, np.dtype("bfloat16"), 2) == 8
     keys = jax.random.split(jax.random.PRNGKey(41), 3)
     pools = [_device_normal(key, (1 + lanes * columns, BLOCK * rows, dim), 1.0)
              for key in keys[:2]]
@@ -1351,7 +1352,7 @@ def test_phi4flashs_decode_step_agrees_through_both_kernel_choices(device):
     params = phi4flash.init_params(jax.random.PRNGKey(5), config)
     lanes, columns = 4, 128
     prompts = [17, 700, 64, 1023]
-    ring = window_ring_blocks(config.window, BLOCK, 4)
+    ring = window_ring_blocks(config.window, BLOCK, 8)
     rings = (1 + np.arange(lanes * ring)).reshape(lanes, ring)
 
     def tables_at(positions):
