@@ -52,9 +52,10 @@ call pick the path, never a model's name.
   budget at the pools' shapes: 8 at KV 8 / D 128 / bf16, whose pages
   divide it, and 8 at 10 rows of 128 a token, whose 40 KB pages do not;
   a one-pool call's as many as give its score block the columns that
-  tile has, 64 at rows of 640: :func:`pages_per_tile`) into one of two
-  VMEM slots itself, the next tile in flight while this one is folded
-  into the online softmax, and stops at the sequence's own last tile:
+  tile has, 64 at rows of 640: :func:`pages_per_tile`) into one of three
+  VMEM slots itself, the next two tiles in flight while this one is
+  folded into the online softmax, and stops at the sequence's own last
+  tile:
   no ``[B, S]`` gather ever materializes and the padding of the table
   to its bucket is never read. A tile whose live pages lie side by side
   in the pool (a window group's ring, a prompt allocated in one go)
@@ -243,9 +244,9 @@ def paged_attention_xla(q, k_pages, v_pages, page_tables, positions,
 # Pallas kernel: tiles of pages, double-buffered by hand, ragged trip count
 # ---------------------------------------------------------------------------
 
-#: VMEM the kernel's K/V page buffers aim at: two slots (one being
-#: folded, one in flight) of one tile of each pool; a tile is a power of
-#: two of pages, so the buffers hold this to within a factor of √2
+#: VMEM that sets a tile's length: what two slots of one tile of each
+#: pool aim at; a tile is a power of two of pages, so two slots hold
+#: this to within a factor of √2 (the kernel keeps :data:`_SLOTS`)
 _KV_VMEM_BUDGET = 1 << 20
 #: the (token, kv head) row the budget was set for, D 128 in bf16: two
 #: slots of K and V tiles of such rows hold 1,024 of them, which is the
@@ -254,6 +255,11 @@ _BUDGET_ROW_BYTES = 128 * 2
 #: the most a ONE-POOL call's two slots may take of the 16 MB of VMEM
 #: Mosaic gives a kernel, where the rule below lengthens its tile
 _ONE_POOL_VMEM_BUDGET = 4 << 20
+#: VMEM slots a pool: the tile being folded and TWO in flight behind it.
+#: The budgets above size a tile as if there were two (they set the
+#: tile's length, which this does not change); the third makes the
+#: buffers 1.5 times that, at most 2.1 MB for two pools and 6 MB for one
+_SLOTS = 3
 
 
 def pages_per_tile(block_size: int, kv_heads: int, head_dim: int,
@@ -262,8 +268,9 @@ def pages_per_tile(block_size: int, kv_heads: int, head_dim: int,
     bytes lie NEAREST (by ratio) what :data:`_KV_VMEM_BUDGET` gives one
     slot of one of ``pools`` pools (K and V, or one whose values lie
     inside its key rows), two slots each. The budget is what a tile aims
-    at, not a ceiling: the buffers hold between 1/√2 and √2 of it (at
-    most 1.41 MB of the 16 MB Mosaic gives a kernel), and a page larger
+    at, not a ceiling: two slots hold between 1/√2 and √2 of it (the
+    kernel's three, :data:`_SLOTS`, at most 2.12 MB of the 16 MB Mosaic
+    gives a kernel), and a page larger
     than a slot still moves, one at a time. A function of the pools'
     shapes alone, so one program serves every batch, and every model
     finds its own tile: 8 pages (128 tokens) at KV 8 / D 128 / bf16, 2
@@ -383,19 +390,28 @@ def _rpa_kernel(kv, scale, window, has_sink, v_width, *refs):
     The pools stay in HBM, viewed ``[N, bs*KV, D]`` (pool row ``t*KV +
     h`` is token ``t``, kv head ``h``; K rows are ``Dk`` wide and V rows
     ``Dv``, which need not be equal). A tile comes into slot ``s`` of
-    ``k_buf`` / ``v_buf`` (``[2, P, bs*KV, D]``) on ``sems[0|1, s]`` in
+    ``k_buf`` / ``v_buf`` (``[3, P, bs*KV, D]``) on ``sems[0|1, s]`` in
     one of two ways, as ``whole_ref[b, tile]`` (:func:`whole_tiles`,
     scalar-prefetched beside the table) says: a WHOLE tile, whose live
     pages lie side by side in the pool, by one copy of ``P`` pages from
     each pool (the entry is the first of them); any other (-1) by ``P``
     page copies a pool, each with its table read. The wait is built
     from the flag of the tile it waits for, read again by (sequence,
-    tile): a DMA semaphore counts in the copy's own size. While a tile
-    is folded the NEXT tile's copies are
-    in flight in the other slot, and the next tile of a sequence's last
-    tile is the next sequence's first, so only the very first tile of
-    the call is waited for with nothing to do. ``slot_ref`` (SMEM)
-    carries the slot across grid steps. The trip count is the
+    tile): a DMA semaphore counts in the copy's own size.
+
+    The call's walk is one sequence of stops, every sequence's tiles in
+    order, and TWO tiles are in flight behind the one being folded
+    (:data:`_SLOTS` slots a pool, taken in turn): a stop starts the
+    copies of the stop two after it (``stop_after``, which steps from a
+    sequence's last tile to the first of the next; for a sequence of
+    one tile, two sequences on) into the slot the stop before it was
+    folded in, then waits for its own. With one tile in flight the
+    memory idled from the landing of one copy to the start of the next,
+    once a stop; with two a copy is queued behind the one that runs
+    (PERF.md, PR 46: 8-24% of a lone call on the chip). The first two stops of the
+    call are started by its first grid step, and only the very first is
+    waited for with nothing to do. ``slot_ref`` (SMEM) carries the slot
+    across grid steps. The trip count is the
     sequence's own ``cdiv(len, P*bs)``: table columns past it are never
     read. Under a sliding ``window`` a third scalar-prefetched vector
     gives each sequence's first visible position: the walk starts at the
@@ -416,8 +432,14 @@ def _rpa_kernel(kv, scale, window, has_sink, v_width, *refs):
     column holds each row's sink logit: it seeds the running maximum and
     a denominator of one, a key with no value row.
 
+    The masks (``slot_in_tile``, ``own_head``) are built by every grid
+    step from two iotas: their divisions run over the few vector
+    registers Mosaic keeps of an iota's one varying dimension (15 at
+    ``[40, 1280]``), and handing them in ready made, or leaving them
+    out, read the same on the chip (PERF.md, PR 46).
+
     With ``v_width`` there is ONE pool, whose rows hold their values in
-    their leading ``v_width`` columns: one HBM ref, one buffer of two
+    their leading ``v_width`` columns: one HBM ref, one buffer of three
     slots, one copy a tile (or a page), and the second matmul runs
     against the leading columns of the tile the first one read."""
     refs = list(refs)
@@ -435,22 +457,33 @@ def _rpa_kernel(kv, scale, window, has_sink, v_width, *refs):
     b = pl.program_id(0)
     n_seqs = pl.num_programs(0)
     nb = tbl_ref.shape[1]
-    _, pages, page_rows, _ = k_buf.shape
+    n_slots, pages, page_rows, _ = k_buf.shape
     tile_rows = pages * page_rows
     tile_slots = tile_rows // kv
     rows = q_ref.shape[1]
 
-    def first_tile_of(seq):
-        if first_ref is None:
-            return 0
-        return first_ref[seq] // tile_slots
+    def tiles_of(seq):
+        """[first, end) of the tiles ``seq`` walks: at least one,
+        whatever the length says: the sequences before it have its
+        first tile in flight, and somebody has to wait for it."""
+        first = 0 if first_ref is None else first_ref[seq] // tile_slots
+        return first, jnp.maximum(
+            first + 1, (len_ref[seq] + tile_slots - 1) // tile_slots)
 
-    # at least one tile, whatever the length says: the sequence before
-    # has this one's first tile in flight, and somebody has to wait for it
-    tile0 = first_tile_of(b)
-    n_tiles = jnp.maximum(
-        tile0 + 1, (len_ref[b] + tile_slots - 1) // tile_slots
-    )
+    def stop_after(seq, tile):
+        """The walk's next stop: ``seq``'s next tile, or the first tile
+        of the sequence after it (``n_seqs``: the walk is over)."""
+        _, end = tiles_of(jnp.minimum(seq, n_seqs - 1))
+        first_after, _ = tiles_of(jnp.minimum(seq + 1, n_seqs - 1))
+        last = tile + 1 >= end
+        return (jnp.where(last, seq + 1, seq),
+                jnp.where(last, first_after, tile + 1))
+
+    def slot_after(slot, by):
+        slot = slot + by
+        return jnp.where(slot >= n_slots, slot - n_slots, slot)
+
+    tile0, n_tiles = tiles_of(b)
 
     def page_copies(slot, j, page):
         return [
@@ -511,10 +544,17 @@ def _rpa_kernel(kv, scale, window, has_sink, v_width, *refs):
 
         either_way(seq, tile, whole, by_page)
 
+    def start_stop(seq, tile, slot):
+        pl.when(seq < n_seqs)(lambda: start_tile(
+            jnp.minimum(seq, n_seqs - 1), tile, slot))
+
     @pl.when(b == 0)
-    def _first_tile():
+    def _first_tiles():
         slot_ref[0] = 0
-        start_tile(0, first_tile_of(0), 0)
+        stop = 0, tiles_of(0)[0]
+        for slot in range(n_slots - 1):
+            start_stop(*stop, slot)
+            stop = stop_after(*stop)
 
     first_slot = slot_ref[0]
     q = q_ref[0]  # [rows, Dk]
@@ -528,16 +568,13 @@ def _rpa_kernel(kv, scale, window, has_sink, v_width, *refs):
     slot_in_tile = column // kv
 
     def fold(i, carry):
-        m_prev, l_prev, acc = carry
-        slot = (first_slot + i - tile0) % 2
-        last = i + 1 == n_tiles
-        next_seq = jnp.where(last, b + 1, b)
-
-        @pl.when(next_seq < n_seqs)
-        def _next_tile():
-            after = first_tile_of(jnp.minimum(b + 1, n_seqs - 1))
-            start_tile(next_seq, jnp.where(last, after, i + 1), 1 - slot)
-
+        slot, m_prev, l_prev, acc = carry
+        # the slot the tile before this one was folded in is free: the
+        # stop ``n_slots - 1`` ahead of this one goes there
+        ahead = b, i
+        for _ in range(n_slots - 1):
+            ahead = stop_after(*ahead)
+        start_stop(*ahead, slot_after(slot, n_slots - 1))
         wait_tile(b, i, slot)
         k = k_buf[slot].reshape(tile_rows, -1)
         if v_width:
@@ -564,7 +601,7 @@ def _rpa_kernel(kv, scale, window, has_sink, v_width, *refs):
         acc = acc * alpha + jnp.dot(
             p.astype(v.dtype), v, preferred_element_type=jnp.float32
         )
-        return m_new, l_new, acc
+        return slot_after(slot, 1), m_new, l_new, acc
 
     if sink_ref is None:
         m0 = jnp.full((rows, 1), NEG_INF, jnp.float32)
@@ -572,11 +609,10 @@ def _rpa_kernel(kv, scale, window, has_sink, v_width, *refs):
     else:
         m0 = sink_ref[...]
         l0 = jnp.ones((rows, 1), jnp.float32)
-    _, l, acc = jax.lax.fori_loop(
+    slot_ref[0], _, l, acc = jax.lax.fori_loop(
         tile0, n_tiles, fold,
-        (m0, l0, jnp.zeros((rows, dv), jnp.float32)),
+        (first_slot, m0, l0, jnp.zeros((rows, dv), jnp.float32)),
     )
-    slot_ref[0] = (first_slot + n_tiles - tile0) % 2
     o_ref[0] = (acc / l).astype(o_ref.dtype)
 
 
@@ -606,8 +642,10 @@ def paged_attention_pallas(q, k_pages, v_pages, page_tables, positions,
     scalar-prefetched; the kernel copies pages ``page_tables[b, j]``
     from the pools in HBM into VMEM itself, :func:`pages_per_tile` at a
     time (a whole tile by one copy a pool, which also brings, and masks,
-    what lies beside its live pages) and one tile ahead of the
-    arithmetic, and stops at the sequence's own last tile — no row of
+    what lies beside its live pages) and TWO tiles ahead of the
+    arithmetic (three VMEM slots a pool; across the step from one
+    sequence to the next as well), and stops at the sequence's own last
+    tile — no row of
     sequence ``b`` sees a slot it does not own, the padding of the table
     to its bucket costs nothing, no contiguous per-sequence view is ever
     materialized in HBM, and the T verify rows of a sequence share each
@@ -691,10 +729,10 @@ def paged_attention_pallas(q, k_pages, v_pages, page_tables, positions,
         ],
         out_specs=row_block(dv),
         scratch_shapes=[
-            pltpu.VMEM((2, pages, bs * kv, pool.shape[-1]), pool.dtype)
+            pltpu.VMEM((_SLOTS, pages, bs * kv, pool.shape[-1]), pool.dtype)
             for pool in pools
         ] + [
-            pltpu.SemaphoreType.DMA((len(pools), 2)),
+            pltpu.SemaphoreType.DMA((len(pools), _SLOTS)),
             pltpu.SMEM((1,), jnp.int32),  # the slot the next tile is in
         ],
     )

@@ -244,6 +244,24 @@ def _ragged_case(rng, kv, g, b, nb, t, bs=16, d=128, dv=None):
     return q, k_pages, v_pages, tables, positions
 
 
+def _hide_behind_window(tables, k_pages, v_pages, positions, window):
+    """In place, as the engine's window group hands a table over: the
+    columns wholly behind every row's ``window`` name the trash block,
+    and their pages, with the slots of the first visible block that lie
+    behind the window of every row, hold garbage."""
+    bs = k_pages.shape[1]
+    for i in range(len(tables)):
+        first = max(0, int(positions[i].min()) - window + 1)
+        behind = tables[i, : first // bs].copy()
+        tables[i, : first // bs] = 0
+        k_pages[behind[behind > 0]] = GARBAGE
+        v_pages[behind[behind > 0]] = -GARBAGE
+        block = tables[i, first // bs]
+        if block > 0:
+            k_pages[block, : first % bs] = GARBAGE
+            v_pages[block, : first % bs] = -GARBAGE
+
+
 RAGGED_CASES = [
     # (kv, g, batch, nb, t): GQA 32/8 as the benchmark's cell serves it,
     # MHA (KV 32), a KV 2 tensor-parallel shard
@@ -315,18 +333,7 @@ def test_pallas_window_sink_and_v_size_match_xla(
     q[..., 192:] = 0.0
     bs = k_pages.shape[1]
     if window is not None:
-        for i in range(b):
-            first = max(0, int(positions[i].min()) - window + 1)
-            behind = tables[i, : first // bs].copy()
-            tables[i, : first // bs] = 0
-            k_pages[behind[behind > 0]] = GARBAGE
-            v_pages[behind[behind > 0]] = -GARBAGE
-            # and the slots of the first visible block that lie behind
-            # the window of every row
-            block = tables[i, first // bs]
-            if block > 0:
-                k_pages[block, : first % bs] = GARBAGE
-                v_pages[block, : first % bs] = -GARBAGE
+        _hide_behind_window(tables, k_pages, v_pages, positions, window)
     masking = {"window": window, "scale": 192 ** -0.5}
     if kv == 4:
         # the flat pools a model under 8 kv heads keeps
@@ -417,16 +424,7 @@ def test_value_heads_shared_by_key_pairs_match_the_oracle(
     q = q[..., :64]
     bs = k_pages.shape[1]
     if window is not None:
-        for i in range(b):
-            first = max(0, int(positions[i].min()) - window + 1)
-            behind = tables[i, : first // bs].copy()
-            tables[i, : first // bs] = 0
-            k_pages[behind[behind > 0]] = GARBAGE
-            v_pages[behind[behind > 0]] = -GARBAGE
-            block = tables[i, first // bs]
-            if block > 0:
-                k_pages[block, : first % bs] = GARBAGE
-                v_pages[block, : first % bs] = -GARBAGE
+        _hide_behind_window(tables, k_pages, v_pages, positions, window)
     want = _shared_value_oracle(q, k_pages, v_pages, tables, positions, window)
     scale = np.maximum(1.0, np.abs(want).max(axis=-1, keepdims=True))
     args = (q, k_pages.reshape(len(k_pages), bs * kv, -1),
@@ -549,6 +547,85 @@ def test_a_tile_rounded_up_past_the_budget_matches_the_oracle(
     limit = 1e-5 if dtype == jnp.float32 else 2.0 ** -6
     assert (np.abs(kernel - want) / scale).max() <= limit
     assert (np.abs(plain - want) / scale).max() <= limit
+
+
+LANE_CASES = {
+    # kv, query heads a key head, keys_per_value, T, table columns,
+    # window, sink, a one-pool call's v_width: every shape of the masks a
+    # grid step builds (the slot and kv head of a tile's columns, the kv
+    # head of a row): one kv head (no head mask), the flat pools under
+    # eight, differential attention's ten rows of pairs, verify rows,
+    # rows that are no multiple of the 8 sublanes, tables under a tile
+    "kv1-latent": (1, 8, 1, 1, 80, None, False, 32),
+    "kv1-mqa-20-rows": (1, 20, 1, 1, 80, None, False, None),
+    "kv1-verify-12-rows": (1, 4, 1, 3, 24, None, False, None),
+    "kv4-window-sink": (4, 16, 1, 1, 24, 40, True, None),
+    "kv4-verify-36-rows": (4, 3, 1, 3, 24, None, False, None),
+    "kv8-narrow-table": (8, 4, 1, 1, 2, None, False, None),
+    "kv8-verify-window-sink": (8, 4, 1, 3, 24, 72, True, None),
+    "kv10-pairs": (10, 2, 2, 1, 24, None, False, None),
+    "kv10-pairs-verify-window": (10, 2, 2, 3, 24, 40, False, None),
+    "kv10-pairs-narrow-table": (10, 2, 2, 1, 1, None, False, None),
+    "kv10-ten-rows-window": (10, 1, 1, 1, 24, 17, False, None),
+}
+
+
+def _lane_case(case):
+    """(args, masking, the oracle's output) of one of :data:`LANE_CASES`
+    on :func:`_ragged_case`'s four lanes (lengths around a tile's edge, a
+    padding lane, garbage wherever a mask must hold), float32 throughout.
+    The oracle is ``paged_attention_reference`` wherever it can say the
+    case (it knows neither window nor sink), plain XLA elsewhere."""
+    from client_tpu.models import paged_attention as pa
+
+    kv, g, pairs, t, nb, window, sink, v_width = LANE_CASES[case]
+    rng = np.random.default_rng(sum(map(ord, case)))
+    q, k_pages, v_pages, tables, positions = _ragged_case(
+        rng, kv, pairs * g, 4, nb, t)
+    if pairs > 1:
+        q = q[..., : q.shape[-1] // pairs]
+    if window is not None:
+        _hide_behind_window(tables, k_pages, v_pages, positions, window)
+    masking = {"window": window, "keys_per_value": pairs}
+    if sink:
+        masking["sink"] = 3 * rng.normal(size=q.shape[2]).astype(np.float32)
+    plainly = dict(keys_per_value=pairs)
+    if v_width:
+        v_pages, plainly["v_width"] = None, v_width
+        masking.update(v_width=v_width)
+    if window is None and not sink:
+        want = pa.paged_attention_reference(
+            q, k_pages, v_pages, tables, positions, **plainly)
+    if kv < 8:
+        # the flat pools a model under 8 kv heads keeps
+        flat = lambda pool: pool.reshape(  # noqa: E731
+            len(pool), -1, pool.shape[-1])
+        k_pages, v_pages = flat(k_pages), v_pages if v_width else flat(v_pages)
+        masking["kv_heads"] = kv
+    args = (q, k_pages, v_pages, tables, positions)
+    if window is not None or sink:
+        want = pa.paged_attention_xla(*args, **masking)
+    return args, masking, np.asarray(want)
+
+
+@pytest.mark.parametrize("case", LANE_CASES)
+def test_every_shape_of_a_lanes_masks_matches_the_oracle(case):
+    """What a grid step does outside its walk, held to the oracle over
+    every shape it takes: the head mask at KV 1 (none) / 4 / 8 / 10, key
+    pairs, window and sink, the one-pool call, T = 1 and 3, row counts
+    that are no multiple of the 8 sublanes, a table narrower than a
+    tile; four lanes whose walks are one tile and several, so that the
+    two tiles the kernel keeps in flight cross every kind of step from
+    one lane to the next (a one-tile lane between two long ones, the
+    padding lane last)."""
+    from client_tpu.models import paged_attention as pa
+
+    args, masking, want = _lane_case(case)
+    out = np.asarray(
+        pa.paged_attention_pallas(*args, interpret=True, **masking))
+    assert out.shape == want.shape and np.isfinite(out).all()
+    scale = np.maximum(1.0, np.abs(want).max(axis=-1, keepdims=True))
+    assert (np.abs(out - want) / scale).max() <= 3e-5
 
 
 # how a tile's pages lie in the pool (paged_attention.whole_tiles): the

@@ -245,10 +245,10 @@ def test_mosaic_compiles_the_paged_kernel_at_trinitys_shapes(one_chip, group):
 def test_the_compiled_kernel_holds_both_ways_of_fetching_a_tile(one_chip):
     """Trinity's window group again, for what went into Mosaic: a tile
     starts and is waited for either as one copy of 16 pages from each
-    pool or as 16 page copies from each, at both places a tile starts
-    (the call's first, and the next while this one is folded) and at
-    the one where it is waited for; the page loops stay rolled, so the
-    kernel holds each copy once a site."""
+    pool or as 16 page copies from each, at the three places a tile
+    starts (the call's first two stops, and the stop two ahead of the
+    one being folded) and at the one where it is waited for; the page
+    loops stay rolled, so the kernel holds each copy once a site."""
     import re
 
     import jax
@@ -273,8 +273,8 @@ def test_the_compiled_kernel_holds_both_ways_of_fetching_a_tile(one_chip):
     # K and V at each site; a whole tile's source is a slice of 16 pages,
     # a page's source one index
     assert sorted(":" in source.split(",")[0] for source in starts) == (
-        [False] * 4 + [True] * 4), starts
-    assert sum("+16" in source for source in starts) == 4
+        [False] * 6 + [True] * 6), starts
+    assert sum("+16" in source for source in starts) == 6
     # a wait is written on its destination: slot and page, or the slot
     assert sorted(
         target.split(",")[1] == ":" for target in waits
@@ -388,9 +388,9 @@ def test_mosaic_compiles_the_one_pool_latent_call(one_chip, lanes, columns):
     """`gigachat3_702b.reason8k_128` as the kernel sees it: one pool of
     640-wide rows at KV 1 (576 held: the latent's 512, which are also
     the values, and the roped key's 64), 64 query rows a lane, tiles of
-    64 pages (a `[64, 1024]` score block a stop, two slots of 1.25 MB;
+    64 pages (a `[64, 1024]` score block a stop, three slots of 1.25 MB;
     a table narrower than a tile is one tile, one of 96 columns a tile
-    and a half); ONE HBM operand and one VMEM buffer of two slots,
+    and a half); ONE HBM operand and one VMEM buffer of three slots,
     where two pools of such rows would be two of each."""
     import jax
     import jax.numpy as jnp
@@ -421,9 +421,10 @@ def test_mosaic_compiles_the_one_pool_latent_call(one_chip, lanes, columns):
         jax.ShapeDtypeStruct((40961, BLOCK, 640), jnp.bfloat16), None,
         jax.ShapeDtypeStruct((lanes, columns), jnp.int32),
         jax.ShapeDtypeStruct((lanes, 1), jnp.int32)))
-    # one copy a tile (or a page) at each of the two places a tile
-    # starts, where K and V pools make two
-    assert traced.count("dma_start") == 4
+    # one copy a tile (or a page) at each of the three places a tile
+    # starts (the call's first two stops, and the stop two ahead of the
+    # one being folded), where K and V pools make two
+    assert traced.count("dma_start") == 6
     assert traced.count("dma_wait") == 2
 
 

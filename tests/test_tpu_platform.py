@@ -273,7 +273,8 @@ def test_compiled_pallas_at_the_mimo_cells_shapes(device, group):
     heads over K rows of 256 (192 and zeros) and V rows of 128 in flat
     pools, contexts staggered over 512..2,048; the full group's 4 KV
     heads over every block, the window group's 8 over a ring of 9
-    blocks a lane behind the engine's own window tables, with a sink."""
+    blocks a lane behind the engine's own window tables, with a sink.
+    Prints the kernel's ms a call and us a lane."""
     import jax
     import jax.numpy as jnp
 
@@ -307,11 +308,13 @@ def test_compiled_pallas_at_the_mimo_cells_shapes(device, group):
     k_pages = rows((blocks, BLOCK * kv_heads), 256, 192)
     v_pages = rows((blocks, BLOCK * kv_heads), 128, 128)
     args = (q, k_pages, v_pages, tables.astype(np.int32), positions[:, None])
+    kernel = jax.jit(lambda *a: pa.paged_attention_pallas(*a, **masking))
     _assert_bf16_close(
-        jax.jit(lambda *a: pa.paged_attention_pallas(*a, **masking))(*args),
+        kernel(*args),
         jax.jit(lambda *a: pa.paged_attention_xla(*a, **masking))(*args),
         f"mimo's {group} group",
     )
+    print(f"mimo {group} group: {_lone_call(kernel, *args)}")
 
 
 def _ms_a_call(fn, *args, calls=20, repeats=3):
@@ -330,6 +333,19 @@ def _ms_a_call(fn, *args, calls=20, repeats=3):
         jax.block_until_ready(out)
         best = min(best, (time.perf_counter() - began) / calls)
     return 1e3 * best
+
+
+def _lone_call(kernel, q, *rest):
+    """``"<ms> ms a call, <us> us a lane"`` of a paged call alone on the
+    chip, by the HOST's clock around back-to-back calls: what a lane (a
+    grid step: its walk and what it does outside it) costs, to hold
+    against the lane's bytes. A jit of one call is not dispatched faster
+    than ~0.43 ms, so a call with less device work than that reads the
+    floor (the three window calls: 0.43-0.46 ms for 0.13-0.37 on the
+    device, PERF.md, PR 46); such a call's device time comes from a
+    trace."""
+    ms = _ms_a_call(kernel, q, *rest)
+    return f"{ms:.3f} ms a call, {1e3 * ms / q.shape[0]:.2f} us a lane"
 
 
 @pytest.mark.parametrize("tokens,kernel", [
@@ -470,7 +486,7 @@ def _trinity_group(group):
 @pytest.mark.parametrize("group", ["full", "window"])
 def test_compiled_pallas_at_the_trinity_cells_shapes(device, group):
     """The kernel against plain XLA on :func:`_trinity_group`. Prints the
-    kernel's ms a call beside plain XLA's."""
+    kernel's ms a call and us a lane beside plain XLA's ms."""
     import jax
 
     from client_tpu.models import paged_attention as pa
@@ -479,8 +495,8 @@ def test_compiled_pallas_at_the_trinity_cells_shapes(device, group):
     kernel = jax.jit(lambda *a: pa.paged_attention_pallas(*a, **masking))
     plain = jax.jit(lambda *a: pa.paged_attention_xla(*a, **masking))
     _assert_bf16_close(kernel(*args), plain(*args), f"trinity's {group} group")
-    print(f"trinity {group} group, ms a call: pallas "
-          f"{_ms_a_call(kernel, *args):.3f}, xla {_ms_a_call(plain, *args):.3f}")
+    print(f"trinity {group} group: pallas {_lone_call(kernel, *args)}, "
+          f"xla {_ms_a_call(plain, *args):.3f} ms a call")
 
 
 def _shuffled_pool(rng, k_pages, v_pages, tables):
@@ -1299,8 +1315,8 @@ def test_compiled_pallas_at_the_phi4_cells_shapes(device, window):
     10 rows of 128 a token in either pool: pages of 40 KB, of which the
     budget's share of a slot holds 6.4, so tiles of 8 pages, the power of
     two nearest it), 64 lanes over contexts to 8,192, with and without
-    the window of 512, against plain XLA. Prints ms a call and the share
-    of HBM's bandwidth of the 5,120 B a visible token."""
+    the window of 512, against plain XLA. Prints ms a call, us a lane and
+    the share of HBM's bandwidth of the 5,120 B a visible token."""
     import jax
 
     from client_tpu.models import paged_attention as pa
@@ -1331,8 +1347,8 @@ def test_compiled_pallas_at_the_phi4_cells_shapes(device, window):
     ms = _ms_a_call(kernel, *args)
     tokens = int(np.minimum(positions + 1, window or 1 << 30).sum())
     print(f"phi4flash paged call, 10 rows of 128, window {window}, {tokens} "
-          f"visible tokens: {ms:.3f} ms a call, "
-          f"{100 * tokens * 5120 / 819e9 / (ms / 1e3):.1f}% of HBM")
+          f"visible tokens: {ms:.3f} ms a call, {1e3 * ms / lanes:.2f} us a "
+          f"lane, {100 * tokens * 5120 / 819e9 / (ms / 1e3):.1f}% of HBM")
 
 
 def test_phi4flashs_decode_step_agrees_through_both_kernel_choices(device):
