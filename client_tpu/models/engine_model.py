@@ -45,6 +45,20 @@ kinds of group at once: its tables are ``[3, ...]``, a ring beside a
 slot, and a preempted sequence gives back and is re-prefilled over all
 three.
 
+A cache "layer" need not be a layer of the model. A looped decoder
+(``models/ouro.py``: 48 layers run four times a token, each pass with
+K/V of its own) keeps one cache a (pass, layer) PAIR, 192 of them, and
+keeps them all in ONE pool pair: pair ``p``'s block ``b`` is pool row
+``p * NB + b``, and its rolled loops add ``p * NB`` to the lane's table
+row before the kernel sees it (an offset block id is a block id). To the
+engine that is one ``full`` group of ONE storing layer whose cached
+token takes all the pairs' rows: ``init_pages`` returns the pool pair as
+that layer's entry (its leading size a multiple of the group's
+``num_blocks``, which is all the engine asks of it) and ``()`` for every
+other, ``kv_row_bytes`` the bytes over all pairs. The engine allocates
+block ids below ``num_blocks`` and every pair uses the same ids at its
+own offset; block 0 of every pair is that pair's trash block.
+
 Which kernels run is chosen once, at load (``llm/serving.py``, from
 ``CLIENT_TPU_LLM_KERNEL`` or the platform), and handed to every program
 of the model as one :class:`Kernels`. Every model behind the seam runs

@@ -296,6 +296,48 @@ def test_pallas_tiles_match_reference_on_ragged_lengths(kv, g, b, nb, t):
     assert (np.abs(out - ref) / scale).max() <= 1e-5
 
 
+def test_the_kernel_reads_a_pair_at_its_offset_into_one_pool():
+    """Ouro's call (`models/ouro.py`): multi-head, KV 16 with ONE query
+    row a KV head, the lanes' tables offset into a pool that holds
+    several (pass, layer) pairs one after another. The pair in the
+    middle, read through ``tables + pair * NB``, gives what the pair
+    alone gives through the plain tables, to the bit, and the oracle's
+    rows; its neighbours hold garbage, and a padding lane's zeros name
+    the pair's own trash block. A tile is 4 pages at the served bf16
+    shapes (2 at this test's float32), and an offset keeps it whole."""
+    import jax.numpy as jnp
+
+    from client_tpu.models import paged_attention as pa
+
+    assert pa.pages_per_tile(16, 16, 128, jnp.bfloat16) == 4
+    rng = np.random.default_rng(47)
+    q, k_pages, v_pages, tables, positions = _ragged_case(
+        rng, 16, 1, 3, 24, 1)
+    blocks = k_pages.shape[0]
+    other = lambda pool, sign: np.full_like(pool, sign * GARBAGE)  # noqa: E731
+    k_pool = np.concatenate([other(k_pages, 1), k_pages, other(k_pages, 1)])
+    v_pool = np.concatenate([other(v_pages, -1), v_pages, other(v_pages, -1)])
+    ref = np.asarray(pa.paged_attention_reference(
+        q, k_pages, v_pages, tables, positions))
+    alone = np.asarray(pa.paged_attention_pallas(
+        q, k_pages, v_pages, tables, positions, interpret=True))
+    offset = np.asarray(pa.paged_attention_pallas(
+        q, k_pool, v_pool, tables + blocks, positions, interpret=True))
+    assert np.array_equal(offset, alone)
+    scale = np.maximum(1.0, np.abs(ref).max(axis=-1, keepdims=True))
+    assert (np.abs(offset - ref) / scale).max() <= 1e-5
+    assert (np.abs(np.asarray(pa.paged_attention_xla(
+        q, k_pool, v_pool, tables + blocks, positions)) - ref)
+        / scale).max() <= 1e-5
+    # every live tile of a lane whose blocks lie side by side is whole
+    # at either place, and the pool's end is the whole pool's
+    first, lengths = pa.visible_slots(positions, None)
+    whole = pa.whole_tiles(tables, first, lengths, 2, 16, blocks)
+    moved = pa.whole_tiles(tables + blocks, first, lengths, 2, 16, 3 * blocks)
+    assert (whole[:2] >= 0).sum() > 0
+    assert np.array_equal(np.where(whole >= 0, whole + blocks, -1), moved)
+
+
 MASKING_CASES = [
     # (kv, g, batch, nb, t, window, sink): the two cache groups of
     # mimo_v2_flash (K rows of 192, V rows of 128; 64 query heads over 4
@@ -966,7 +1008,7 @@ def test_pages_per_tile_follows_the_shapes_alone():
 
 
 #: what `LlmEngineModel` asks `pages_per_tile` for each attending cache
-#: group of the benchmark's seven cells (a page's rows flat, the wider
+#: group of the benchmark's eight cells (a page's rows flat, the wider
 #: pool's row, bf16) and the tile it serves: (rows a page, row width,
 #: pools, pages the budget's share of a slot holds, pages a tile)
 SERVED_TILES = {
@@ -978,6 +1020,7 @@ SERVED_TILES = {
     "qwen3_next_80b.reason2k_128": (16 * 2, 256, 2, 16.0, 16),
     "jamba2_3b.reason8k_128": (16, 128, 2, 64.0, 64),
     "phi4_mini_flash.reason8k": (16 * 10, 128, 2, 6.4, 8),
+    "ouro_2_6b.reason384_16": (16 * 16, 128, 2, 4.0, 4),
 }
 
 

@@ -1058,7 +1058,7 @@ def test_phases_tile_the_loop_with_an_admission_pending(ending):
 def test_the_benchmark_reads_the_share_of_prefills_that_went_behind_a_step():
     """``engine.admits_behind_share`` is a metric file over a reader the
     benchmark has (``counters:delta_ratio``) and two keys ``stats()``
-    really serves; ``BENCHMARK.json`` lists it for all seven cells; a
+    really serves; ``BENCHMARK.json`` lists it for all eight cells; a
     parent without the counter reads nothing and leaves it out."""
     import json
     import os
@@ -1105,7 +1105,7 @@ def test_the_benchmark_reads_the_share_of_prefills_that_went_behind_a_step():
     (entry,) = [m for m in benchmark["per_layer"]
                 if m["name"] == "engine.admits_behind_share"]
     cells = [w["name"] for w in benchmark["workloads"]]
-    assert entry["workloads"] == cells and len(cells) == 7
+    assert entry["workloads"] == cells and len(cells) == 8
     (ahead,) = [m for m in benchmark["per_layer"]
                 if m["name"] == "engine.steps_ahead_share"]
     for key in ("unit", "better", "source", "layer", "moves"):

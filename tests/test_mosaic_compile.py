@@ -871,3 +871,74 @@ def test_phi4flashs_longest_prefill_and_decode_fit_the_chip(one_chip):
         r"|f32\[65,16,5120\]|bf16\[65,15360\])\S* copy\(")
     assert not [line for line in prefill.as_text().splitlines()
                 if copied.search(line)]
+
+
+def test_ouros_rolled_decode_and_prefill_fit_the_chip(one_chip):
+    """`ouro`'s 16-lane decode step and its 512-token prefill compiled
+    whole for the described v5e at the cell's sizes: all 48 layers stacked
+    and rolled (192 layer-passes a token), all 49,152 rows, ONE K and ONE
+    V pool of 192 x 337 blocks. The bound: 14.0 GB of arguments (5.34 of
+    weights, 8.48 of the pools), read here at 13,816,837,120 B with
+    484,352 B of scratch in the decode step and 2,679,296 B in the
+    prefill. The decode program holds ONE paged kernel for its 192 calls;
+    neither program copies a pool (each is carried through both loops and
+    updated where it lies) or a stacked weight (q, k and v weights held
+    ``[in, out]`` cost a copy of all 48 layers' every step, 1.2 GB)."""
+    import jax
+    import jax.numpy as jnp
+
+    from client_tpu.models import ouro, paged_attention
+    from client_tpu.models.engine_model import Kernels
+
+    config = ouro.OuroConfig(max_seq_len=512)
+    kernels = Kernels("pallas", paged_attention.paged_attention_pallas)
+    blocks = 337
+
+    def shaped(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    params = shaped(jax.eval_shape(
+        lambda: ouro.init_params(jax.random.PRNGKey(0), config)))
+    weights = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree_util.tree_leaves(params))
+    assert weights == 5_335_949_314
+    pages = shaped(jax.eval_shape(
+        lambda: ouro.init_pages(config, [blocks], BLOCK)))
+    assert sum(a.size * a.dtype.itemsize
+               for a in jax.tree_util.tree_leaves(pages)) == 8_480_882_688
+    ints = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.int32, sharding=one_chip)
+    copied = re.compile(
+        rf"= bf16\[({192 * blocks},16,16,128|48,\d+,\d+)\]\S* "
+        r"(copy|dynamic-update-slice|broadcast)\(")
+    # the cell's step, and the warm-up probe's lone lane, whose write
+    # would be a dynamic-update-slice (and the pools re-laid, 34 GB each)
+    # if the program did not run it as the two-lane bucket
+    for lanes, columns in ((16, 32), (1, 1)):
+        decode = jax.jit(
+            lambda p, t, at, tables, pages: ouro.decode_step_paged(
+                p, t, at, tables, pages, config, kernels),
+            donate_argnums=(4,)).lower(
+            params, ints(lanes), ints(lanes), ints(lanes, columns),
+            pages).compile()
+        memory = decode.memory_analysis()
+        assert memory.argument_size_in_bytes < 14.0e9
+        assert memory.temp_size_in_bytes < 100e6
+        text = decode.as_text()
+        assert len(re.findall(
+            r"custom_call_target=\"tpu_custom_call\"", text)) == 1
+        assert text.count("%paged_attention") >= 1
+        assert not [line for line in text.splitlines()
+                    if copied.search(line)]
+    prefill = jax.jit(
+        lambda p, t, table, pages, last: ouro.prefill_into_pages(
+            p, t, table, pages, last, config, kernels),
+        donate_argnums=(3,)).lower(
+        params, ints(1, 512), ints(32), pages, ints()).compile()
+    memory = prefill.memory_analysis()
+    assert memory.argument_size_in_bytes < 14.0e9
+    assert memory.temp_size_in_bytes < 100e6
+    assert not [line for line in prefill.as_text().splitlines()
+                if copied.search(line)]
